@@ -1086,7 +1086,8 @@ class MergeIntoCommand:
                 self.delta_log.data_path, candidates,
                 epoch=cache.epoch(snapshot.delta_log.log_path),
             )
-        except Exception:
+        except Exception as e:  # noqa: BLE001 — host rung (auto only)
+            self._device_rung("slab-build-failed", e)
             return None, None
         if builder.failed is not None:
             return None, None
@@ -1103,6 +1104,7 @@ class MergeIntoCommand:
         from delta_tpu.utils import telemetry
 
         upload_ctx = telemetry.span_context()
+        upload_errors: List[BaseException] = []
 
         def uploader():
             # device dispatches are async: this thread mostly queues
@@ -1121,8 +1123,10 @@ class MergeIntoCommand:
                             pos = tab.column(POSITION_COL).to_numpy(
                                 zero_copy_only=False)
                             builder.add_file(add, tab, pos)
-                    except Exception:
+                    except Exception as e:  # noqa: BLE001 — surfaced by
+                        # the MERGE thread after the join (_device_rung)
                         builder.failed = builder.failed or "slab append failed"
+                        upload_errors.append(e)
 
         th = threading_mod.Thread(target=uploader, daemon=True,
                                   name="delta-merge-slab-upload")
@@ -1139,6 +1143,13 @@ class MergeIntoCommand:
         finally:
             q.put(None)
             th.join()
+        # a device exception on the uploader thread or in the slab
+        # allocation (the build then ran on host mirrors) is not a designed
+        # decline: under mode=force it propagates from here
+        if upload_errors:
+            self._device_rung("slab-build-failed", upload_errors[0])
+        elif builder.alloc_error is not None:
+            self._device_rung("slab-alloc-failed", builder.alloc_error)
         entry = builder.finish(len(candidates))
         if entry is None:
             self._router.setdefault("reason", "slab-build-failed")
@@ -1169,13 +1180,15 @@ class MergeIntoCommand:
         import numpy as np
 
         from delta_tpu.ops import join_kernel
+        from delta_tpu.ops import key_cache as kc_mod
 
         entry, probe, s_keys, s_ok = resident
 
         def finalize():
-            # any failure in here — the probe itself, or the pair mapping
-            # disagreeing with the slab — must surface as None (documented
-            # host-join fallback), never an exception that crashes the MERGE
+            # designed declines — the probe's candidate windows overflowed,
+            # or the pair mapping disagrees with the slab — surface as None
+            # (documented host-join fallback); any other exception is a
+            # device failure and takes _device_rung
             try:
                 res_p = probe.result()
                 n_target = target.num_rows
@@ -1203,10 +1216,30 @@ class MergeIntoCommand:
                     row_base += t.num_rows
                 return join_kernel.JoinResult(t_first_s, res_p.s_matched,
                                               res_p.any_multi)
-            except Exception:
+            except kc_mod.DeltaProbeOverflow:
+                return None
+            except Exception as e:  # noqa: BLE001 — host rung (auto only)
+                self._device_rung("device-finalize-fallback", e)
                 return None
 
         return join_kernel.PendingJoin(finalize)
+
+    def _device_rung(self, reason: str, e: BaseException) -> None:
+        """An unexpected exception on the device path (compile refusal,
+        XlaRuntimeError, OOM — not a designed decline). ``mode=force`` pins
+        the device, so it propagates: a kernel the chip refuses must not
+        read as a correct MERGE the host quietly did. In ``auto`` the
+        exception rides the ``delta.merge.router`` event, and
+        `_emit_router` counts the MERGE under ``merge.device.fallback`` if
+        the host join then took over (after a failed slab allocation the
+        build continues on host mirrors and the device join may still
+        engage)."""
+        from delta_tpu.utils import telemetry
+
+        if str(conf.get("delta.tpu.merge.devicePath.mode", "auto")) == "force":
+            raise e
+        self._router.setdefault("reason", reason)
+        self._router.setdefault("error", telemetry.exc_text(e))
 
     def _emit_router(self) -> None:
         """One `delta.merge.router` event per MERGE — the production-table
@@ -1221,6 +1254,8 @@ class MergeIntoCommand:
             bump_counter("merge.device.engaged")
             if decision == "resident":
                 bump_counter("merge.device.cacheHit")
+        elif "error" in self._router:
+            bump_counter("merge.device.fallback")  # _device_rung, mode=auto
         data = dict(self._router, decision=decision)
         if "cacheHit" in data:
             # a cache lookup may have hit and then been abandoned (pricing

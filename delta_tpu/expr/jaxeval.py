@@ -21,9 +21,11 @@ import numpy as np
 
 from delta_tpu.expr import ir
 from delta_tpu.utils.errors import DeltaAnalysisError
+from delta_tpu.utils.jaxcache import ensure_compilation_cache
 
 __all__ = ["DeviceColumn", "compile_expr", "NotDeviceCompilable",
-           "ResidualPlan", "compile_residual", "STR_CODE_ABSENT"]
+           "ResidualPlan", "compile_residual", "STR_CODE_ABSENT",
+           "f64_order_key"]
 
 
 class NotDeviceCompilable(DeltaAnalysisError):
@@ -152,6 +154,8 @@ def compile_expr(e: ir.Expression) -> _Compiled:
     Raises :class:`NotDeviceCompilable` for string ops / casts / functions
     that belong on the host.
     """
+    # every caller jits what this returns: place the compile cache first
+    ensure_compilation_cache()
     t = type(e)
     if t is ir.Literal:
         return _lit(e)
@@ -341,6 +345,27 @@ STR_CODE_ABSENT = -2
 _STRLIT_PREFIX = "__strlit"
 _CMP_TYPES = (ir.Eq, ir.Ne, ir.Lt, ir.Le, ir.Gt, ir.Ge)
 _CMP_FLIP = {ir.Lt: ir.Gt, ir.Le: ir.Ge, ir.Gt: ir.Lt, ir.Ge: ir.Le}
+_FLOAT_FUNCS = frozenset({"exp", "log", "sqrt", "pow", "power"})
+
+
+def f64_order_key(values):
+    """IEEE float64 value(s) → int64 key(s) whose signed order IS the float
+    order (−0.0 folds into +0.0; NaN keys lie beyond the keys of ±inf,
+    which `compile_residual` guards so compares stay IEEE's).
+
+    The device encoding of every float lane (`ops/column_cache` data lanes,
+    `ops/state_export` min/max stats lanes). A TPU holds float64 as a
+    float32 pair — about 48 mantissa bits, float32's exponent range — so a
+    float compare there can drop a row within 2^-48 of the literal, or
+    beyond 3.4e38. int64 is emulated exactly, so the same compare over keys
+    is exact on every device."""
+    a = np.asarray(values, np.float64) + 0.0
+    bits = a.view(np.int64)
+    return bits ^ ((bits >> 63) & np.int64(0x7FFFFFFFFFFFFFFF))
+
+
+_KEY_NEG_INF = int(f64_order_key(-np.inf))
+_KEY_POS_INF = int(f64_order_key(np.inf))
 
 
 class ResidualPlan(NamedTuple):
@@ -373,7 +398,12 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
       lower to the device calendar kernels;
     * decimal columns, string partition references, and mixed
       date-vs-timestamp compares raise :class:`NotDeviceCompilable` — the
-      caller falls back to the Arrow path.
+      caller falls back to the Arrow path;
+    * float lanes hold :func:`f64_order_key` keys (a TPU's float64 is not
+      IEEE): a float column compared against a numeric literal (or ``IN``
+      literals), or null-tested, lowers to an exact int64 compare over
+      them, and every other use of a float column, literal, division, cast
+      or function raises :class:`NotDeviceCompilable`.
 
     ``types`` maps lower-cased column names to declared
     :class:`~delta_tpu.schema.types.DataType`; ``partition_names`` marks the
@@ -381,8 +411,8 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
     """
     import datetime as _dt
 
-    from delta_tpu.schema.types import (DateType, DecimalType, StringType,
-                                        TimestampType)
+    from delta_tpu.schema.types import (DateType, DecimalType, DoubleType,
+                                        FloatType, StringType, TimestampType)
 
     parts = frozenset(c.lower() for c in partition_names)
     binds: list = []
@@ -399,11 +429,20 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
             return DateType() if isinstance(ct, (DateType, TimestampType)) else None
         return None
 
-    def _note(c: ir.Column) -> ir.Column:
+    def _inexact(what: str) -> NotDeviceCompilable:
+        return NotDeviceCompilable(
+            f"{what}: float64 is not exact on the device")
+
+    def _is_float(n: str) -> bool:
+        return isinstance(types.get(n), (FloatType, DoubleType))
+
+    def _note(c: ir.Column, as_key: bool = False) -> ir.Column:
         n = c.name.lower()
         if isinstance(types.get(n), DecimalType):
             raise NotDeviceCompilable(
                 f"decimal column {c.name!r} stays on host (exact arithmetic)")
+        if not as_key and _is_float(n):
+            raise _inexact(f"float column {c.name!r} outside a literal compare")
         if n in parts:
             if isinstance(types.get(n), StringType):
                 raise NotDeviceCompilable(
@@ -454,6 +493,26 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
         f.children = (child,)
         return f
 
+    def _key_lane(x) -> Optional[ir.Column]:
+        """The float DATA column ``x`` is (its lane holds order keys)."""
+        x = _strip(x)
+        if (isinstance(x, ir.Column) and x.name.lower() not in parts
+                and _is_float(x.name.lower())):
+            return x
+        return None
+
+    def _key_lit(x) -> Optional[ir.Literal]:
+        x = _strip(x)
+        if isinstance(x, ir.Literal) and not isinstance(x.value, bool):
+            if x.value is None:
+                return x
+            if isinstance(x.value, float) and x.value == x.value \
+                    or isinstance(x.value, int) and abs(x.value) <= 2**53:
+                return ir.Literal(int(f64_order_key(float(x.value))))
+        # NaN literals (no key compare is IEEE's) and integers float64
+        # cannot hold exactly have no key
+        return None
+
     def rw(x: ir.Expression) -> ir.Expression:
         t = type(x)
         if t is ir.Alias:
@@ -462,6 +521,8 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
             return _note(x)
         if t is ir.Literal:
             v = x.value
+            if isinstance(v, float):
+                raise _inexact(f"float literal {v!r} outside a float-column compare")
             if isinstance(v, str):
                 # a string literal outside a code compare has no device form
                 raise NotDeviceCompilable(
@@ -478,6 +539,17 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
             if isinstance(l, ir.Literal) and not isinstance(r, ir.Literal):
                 l, r = r, l
                 t = _CMP_FLIP.get(t, t)
+            kcol, klit = _key_lane(l), _key_lit(r)
+            if kcol is not None and klit is not None:
+                col = _note(kcol, as_key=True)
+                cmp = t(col, klit)
+                # NaN keys lie beyond ±inf: an ordering compare must not
+                # take a NaN row for a large (or small) value
+                if klit.value is not None and t in (ir.Lt, ir.Le):
+                    return ir.And(cmp, ir.Ge(col, ir.Literal(_KEY_NEG_INF)))
+                if klit.value is not None and t in (ir.Gt, ir.Ge):
+                    return ir.And(cmp, ir.Le(col, ir.Literal(_KEY_POS_INF)))
+                return cmp
             lt_, rt_ = _ctype(l), _ctype(r)
             if isinstance(lt_, (DateType, TimestampType)) \
                     and isinstance(rt_, (DateType, TimestampType)):
@@ -509,6 +581,9 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
             v = _strip(x.value)
             vt = _ctype(v)
             opts = list(x.options)
+            kcol, kopts = _key_lane(v), [_key_lit(o) for o in opts]
+            if kcol is not None and all(o is not None for o in kopts):
+                return ir.In(_note(kcol, as_key=True), kopts)
             if isinstance(vt, StringType):
                 if not isinstance(v, ir.Column):
                     raise NotDeviceCompilable("string IN over a non-column")
@@ -557,6 +632,16 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
             if not isinstance(_ctype(x.children[0]), TimestampType):
                 raise NotDeviceCompilable("hour() needs a timestamp lane")
             return ir.Func("hour", [rw(x.children[0])])
+        if t in (ir.IsNull, ir.IsNotNull):
+            kcol = _key_lane(x.child)
+            if kcol is not None:
+                return t(_note(kcol, as_key=True))  # reads validity only
+        if (t is ir.Div
+                or (t is ir.Func and x.name in _FLOAT_FUNCS)
+                or (t is ir.Cast and (
+                    hasattr(x.data_type, "precision")
+                    or x.data_type.name in ("float", "double")))):
+            raise _inexact(x.sql())
         # generic rebuild (And/Or/Not/arith/null tests/Coalesce/CaseWhen/
         # Cast/other Funcs) — unsupported shapes surface from compile_expr
         new_children = tuple(rw(c) for c in x.children)

@@ -117,6 +117,7 @@ COUNTERS = frozenset({
     #    production tables via /metrics and flight-recorder incidents
     "merge.device.engaged",       # a device join produced this merge's pairs
     "merge.device.declined",      # link cost model chose the host
+    "merge.device.fallback",      # device path raised; host join took over
     "merge.device.cacheHit",      # engaged from an HBM-resident key lane
     "merge.keyCache.builds",      # cold key-lane builds (inline or bg)
     "merge.keyCache.advances",    # incremental log-tail applications
@@ -194,6 +195,7 @@ ENGINE_COUNTERS = frozenset({
     "scan.device.engaged",
     "scan.device.declined",
     "scan.device.fallback",
+    "scan.prune.deviceFallback",
     "columnCache.hits",
     "columnCache.misses",
     "columnCache.evictions",
@@ -376,6 +378,7 @@ DESCRIPTIONS = {
     "commit.reconciled": "Ambiguous commit outcomes resolved via the txnId token.",
     "merge.device.engaged": "MERGEs whose join pairs came from a device join.",
     "merge.device.declined": "MERGEs where the cost model chose the host join.",
+    "merge.device.fallback": "MERGEs (mode=auto) whose device path raised and fell back to the host join.",
     "merge.device.cacheHit": "Device MERGEs served from an HBM-resident key lane.",
     "merge.keyCache.builds": "Cold resident key-lane builds.",
     "merge.keyCache.advances": "Incremental log-tail applications to a key lane.",
@@ -442,6 +445,7 @@ DESCRIPTIONS = {
     "scan.device.engaged": "Scans whose residual mask was computed on device.",
     "scan.device.declined": "Scans where the cost model kept the residual on host.",
     "scan.device.fallback": "Device residual attempts that fell back to the host path.",
+    "scan.prune.deviceFallback": "Device file prunes that raised and fell back to the host evaluator.",
     "columnCache.hits": "Scan column-cache lane hits (file, column resident).",
     "columnCache.misses": "Scan column-cache lane misses (cold decode).",
     "columnCache.evictions": "Scan column-cache lanes evicted by the LRU bound.",

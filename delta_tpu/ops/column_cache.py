@@ -49,6 +49,7 @@ from delta_tpu.expr.jaxeval import NotDeviceCompilable
 from delta_tpu.obs import hbm_ledger
 from delta_tpu.ops.state_cache import _next_pow2  # shared pad-size bucketing
 from delta_tpu.utils.config import conf
+from delta_tpu.utils.jaxcache import ensure_compilation_cache
 from delta_tpu.utils.jaxcompat import enable_x64
 
 __all__ = ["ResidentColumn", "ColumnCache", "device_residual_masks",
@@ -73,7 +74,8 @@ def _lane_from_arrow(arr) -> Optional[Tuple[np.ndarray, np.ndarray,
     ``(values, valid, dict)`` — strings become int32 dictionary codes with
     the value→code map returned for literal binding, date32 becomes epoch
     days (int32), timestamps epoch µs (int64), numerics widen to
-    int64/float64. Returns None for types with no lane form."""
+    int64, floats become int64 order keys (`jaxeval.f64_order_key`: a
+    TPU's float64 is not IEEE). Returns None for types with no lane form."""
     import pyarrow as pa
     import pyarrow.compute as pc
 
@@ -100,8 +102,9 @@ def _lane_from_arrow(arr) -> Optional[Tuple[np.ndarray, np.ndarray,
         vals = arr.cast(pa.int64()).fill_null(0).to_numpy(
             zero_copy_only=False).astype(np.int64, copy=False)
     elif pa.types.is_floating(t):
-        vals = arr.cast(pa.float64()).fill_null(0.0).to_numpy(
-            zero_copy_only=False).astype(np.float64, copy=False)
+        vals = jaxeval.f64_order_key(
+            arr.cast(pa.float64()).fill_null(0.0).to_numpy(
+                zero_copy_only=False))
     else:
         return None
     return vals, valid, None
@@ -313,6 +316,7 @@ def _mask_kernel(expr: ir.Expression):
     """jit-compiled Kleene-TRUE mask for a lowered residual — keyed on the
     (hashable) rewritten expression; pow2-padded lanes keep the XLA shape
     cache warm across similarly sized files."""
+    ensure_compilation_cache()
     import jax
 
     fn = jaxeval.compile_expr(expr)
@@ -344,9 +348,7 @@ def _scalar_column(value: Any) -> jaxeval.DeviceColumn:
         arr = np.asarray((value - _dt.date(1970, 1, 1)).days, np.int32)
     elif isinstance(value, int):
         arr = np.asarray(value, np.int64)
-    elif isinstance(value, float):
-        arr = np.asarray(value, np.float64)
-    else:
+    else:  # floats included: compile_residual declines float partitions
         raise NotDeviceCompilable(f"partition value {value!r} has no lane form")
     return jaxeval.DeviceColumn(jnp.asarray(arr), jnp.ones((), bool))
 
@@ -388,7 +390,7 @@ def _ensure_lanes(cache: "ColumnCache", log_path: str, data_path: str, add,
             vals, valid, codes = lane
         else:
             # schema evolution: the file predates the column → all-NULL
-            vals = np.zeros(n_rows, np.float64)
+            vals = np.zeros(n_rows, np.int64)
             valid = np.zeros(n_rows, bool)
             codes = None
         entry = ResidentColumn(log_path, add.path, c, vals, valid, codes,
@@ -401,8 +403,9 @@ def _ensure_lanes(cache: "ColumnCache", log_path: str, data_path: str, add,
 def device_residual_masks(snapshot, files, predicate) -> Optional[Dict[str, np.ndarray]]:
     """Per-file physical-row survivor masks for ``predicate``, computed on
     device from resident lanes — or None when the predicate doesn't lower,
-    the router prices the host faster, or anything on the device path
-    fails (the caller's Arrow path is always correct on its own).
+    the router prices the host faster, or (``mode=auto``) anything on the
+    device path fails — the caller's Arrow path is always correct on its
+    own. Under ``mode=force`` a device exception propagates.
 
     The returned mask is the exact Kleene-TRUE row set of the residual for
     each file of THIS snapshot; deletion vectors are NOT applied here (the
@@ -484,9 +487,16 @@ def device_residual_masks(snapshot, files, predicate) -> Optional[Dict[str, np.n
     except NotDeviceCompilable:
         bump_counter("scan.device.fallback")
         return None
-    except Exception:
-        # the device path must never fail a scan the Arrow path can serve
+    except Exception as e:  # noqa: BLE001
+        # the device path must never fail a scan the Arrow path can serve —
+        # except under deviceResidual.mode=force, which pins the device: a
+        # kernel the chip refuses must not read as a scan the device served
+        if mode == "force":
+            raise
+        from delta_tpu.utils import telemetry
+
         bump_counter("scan.device.fallback")
+        telemetry.add_span_data(deviceError=telemetry.exc_text(e))
         return None
     actual_s = time.perf_counter() - t0
     bump_counter("scan.device.engaged")

@@ -23,6 +23,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 import pyarrow.compute as pc
 
+from delta_tpu.utils.jaxcache import ensure_compilation_cache
 from delta_tpu.utils.jaxcompat import enable_x64
 from delta_tpu.expr import ir
 from delta_tpu.expr import partition as partition_expr
@@ -357,30 +358,48 @@ def _prune_host(files: Sequence[AddFile], metadata: Metadata, pred: ir.Expressio
     return np.asarray(keep)
 
 
+class _StatsLaneTypes:
+    """`compile_residual`'s type view of `FileStateArrays.device_env`: every
+    ``min.c`` / ``max.c`` lane is a float64 lane held as int64 order keys,
+    every other lane (counts, sizes, partition codes) plain int64."""
+
+    @staticmethod
+    def get(name: str):
+        from delta_tpu.schema.types import DoubleType, LongType
+
+        return DoubleType() if name.startswith(("min.", "max.")) else LongType()
+
+
 @lru_cache(maxsize=256)
 def _compiled_skipping(pred: ir.Expression):
     """jit-compiled skipping predicate, cached per expression so repeat scans
-    reuse the executable (env shapes are the jit cache key)."""
+    reuse the executable (env shapes are the jit cache key). A TPU's float64
+    is not IEEE, so the min/max bounds compare as exact int64 order keys —
+    the residual path's lowering (`jaxeval.compile_residual`); shapes that
+    need float arithmetic over the bounds (multi-column synthesis
+    candidates) raise ``NotDeviceCompilable`` and prune on the host."""
+    ensure_compilation_cache()
     import jax
 
-    from delta_tpu.expr.jaxeval import compile_expr
+    from delta_tpu.expr.jaxeval import compile_expr, compile_residual
 
-    return jax.jit(compile_expr(pred))
+    return jax.jit(compile_expr(compile_residual(pred, _StatsLaneTypes).expr))
 
 
 def _prune_device(arrays: state_export.FileStateArrays, pred: ir.Expression) -> Optional[np.ndarray]:
-    import jax
-
     from delta_tpu.expr.jaxeval import NotDeviceCompilable
 
     try:
-        fn = _compiled_skipping(pred)
-    except NotDeviceCompilable:
-        return None
-    try:
         with enable_x64():
-            col = fn(arrays.device_env())
-    except Exception:
+            col = _compiled_skipping(pred)(arrays.device_env())
+    except NotDeviceCompilable:
+        return None  # designed decline: no exact device form / unbound lane
+    except Exception as e:  # noqa: BLE001 — host rung (_prune_host) takes
+        # over, counted and with the exception on the delta.scan.prune span
+        from delta_tpu.utils import telemetry
+
+        telemetry.bump_counter("scan.prune.deviceFallback")
+        telemetry.add_span_data(deviceError=telemetry.exc_text(e))
         return None
     keep = np.asarray(col.values, bool) | ~np.asarray(col.valid, bool)  # NULL keeps
     if keep.ndim == 0:
@@ -411,6 +430,10 @@ def prune_files(
     if prefer_device and len(files) >= min_files:
         arrays = state_export.files_to_arrays(files, metadata)
         keep = _prune_device(arrays, pred)
+    from delta_tpu.utils.telemetry import add_span_data
+
+    # which tier served, on the enclosing delta.scan.prune span
+    add_span_data(tier="host" if keep is None else "device")
     if keep is None:
         keep = _prune_host(files, metadata, pred)
     kept = [f for f, k in zip(files, keep) if k]

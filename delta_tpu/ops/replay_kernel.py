@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from delta_tpu.utils.jaxcache import ensure_compilation_cache
 from delta_tpu.utils.jaxcompat import enable_x64, shard_map
 from delta_tpu.ops.state_export import ReplayArrays
 from delta_tpu.parallel.mesh import P, STATE_AXIS, shard_count
@@ -96,6 +97,7 @@ def replay_alive_mask(arrays: ReplayArrays, min_retention_ts: int = 0) -> Replay
 
     Inputs are padded to the next power of two so XLA compiles one kernel per
     size bucket, not per log length."""
+    ensure_compilation_cache()
     n = arrays.num_rows
     cap = _next_pow2(n)
     # x64 scoped to the kernel: seq keys, sizes and retention timestamps are
@@ -136,6 +138,7 @@ def winner_mask_device(path_id: np.ndarray) -> np.ndarray:
 
     Ships one int32 column up, one bitmask down; everything else
     (alive/tombstone masks, aggregates) is cheap host numpy on the result."""
+    ensure_compilation_cache()
     n = len(path_id)
     cap = _next_pow2(n)
     padded = np.full(cap, -1, np.int32)
@@ -212,6 +215,7 @@ def replay_sharded(
     owns a hash range of paths, replays independently, and the aggregate
     state counts are reduced with `psum` over ICI.
     """
+    ensure_compilation_cache()
     n = shard_count(mesh)
     (path_id, seq, is_add, size, del_ts), order, dest = _bucket_by_path(arrays, n)
 

@@ -9,8 +9,8 @@ as the log tails forward: each new commit appends a handful of rows
 device-side (one small upload + one scatter/slice kernel), so steady-state
 queries pay **zero bulk upload**.
 
-Why this is the piece that makes the chip win: on any link (PCIe or
-tunneled), re-uploading O(files) state per query prices the device out of
+Why this is the piece that makes the chip win: on any host↔device link,
+re-uploading O(files) state per query prices the device out of
 interactive planning; from residency, a *batch* of N predicates over F files
 and C stat columns is one dispatch reading N·F·C lanes from HBM (~800 GB/s)
 against a host evaluator bound by DRAM (~10 GB/s single-core), and one
@@ -36,6 +36,7 @@ import numpy as np
 
 from delta_tpu.expr import ir
 from delta_tpu.utils.config import conf
+from delta_tpu.utils.jaxcache import ensure_compilation_cache
 
 __all__ = [
     "ResidentState", "DeviceStateCache", "PlanResult", "extract_ranges",
@@ -916,14 +917,20 @@ class ResidentState:
                 n_blocks = self.capacity // BLOCK
                 blocks = np.unpackbits(np.asarray(bits)[:m], axis=1,
                                        count=n_blocks)
-        except Exception:  # noqa: BLE001 — degradation ladder, first rung:
-            # a shard_map/lowering failure (mesh reshape race, OOM on the
-            # coarse cull) must cost latency, not the query — the host fine
-            # pass over every block is the same exact evaluation the device
-            # pass would have narrowed
+        except Exception as e:  # noqa: BLE001 — degradation ladder, first
+            # rung: a shard_map/lowering failure (mesh reshape race, OOM on
+            # the coarse cull) must cost latency, not the query — the host
+            # fine pass over every block is the same exact evaluation the
+            # device pass would have narrowed. devicePlan.mode=force pins
+            # the device, so there the failure propagates instead of
+            # reading as a device plan the host quietly served.
+            if conf.get("delta.tpu.stateCache.devicePlan.mode",
+                        "auto") == "force":
+                raise
             from delta_tpu.utils import telemetry
 
             telemetry.bump_counter("dist.degraded.plan")
+            telemetry.add_span_data(deviceError=telemetry.exc_text(e))
             return self._plan_host(lo, hi, ks)
         return self._fine_pass(blocks, lo, hi, ks)
 
@@ -957,6 +964,7 @@ class ResidentState:
 
 @functools.lru_cache(maxsize=None)
 def _scatter_bool_fn(value: bool):
+    ensure_compilation_cache()
     import jax
 
     return jax.jit(lambda a, r: a.at[r].set(value, mode="drop"))
@@ -968,6 +976,7 @@ def _scatter_bool(arr, rows, value: bool):
 
 @functools.lru_cache(maxsize=None)
 def _scatter_cols_fn():
+    ensure_compilation_cache()
     import jax
 
     return jax.jit(lambda a, r, v: a.at[:, r].set(v, mode="drop"))
@@ -984,8 +993,6 @@ BLOCK = 1024
 
 @functools.lru_cache(maxsize=None)
 def _block_kernel_fn(block: int):
-    from delta_tpu.utils.jaxcache import ensure_compilation_cache
-
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
@@ -1012,8 +1019,6 @@ def _block_kernel(mins, maxs, alive, lo, hi, block: int):
 
 @functools.lru_cache(maxsize=None)
 def _sharded_block_kernel_fn(block: int, ncols: int, shards: int):
-    from delta_tpu.utils.jaxcache import ensure_compilation_cache
-
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp

@@ -101,9 +101,12 @@ class FileStateArrays:
 
     def device_env(self):
         """Bind columns as :class:`delta_tpu.expr.jaxeval.DeviceColumn`s using
-        the flat names the skipping rewrite emits (``min.c`` / ``max.c`` /
-        ``nullCount.c`` / ``numRecords`` / partition columns as codes)."""
-        from delta_tpu.expr.jaxeval import DeviceColumn
+        the flat names the skipping rewrite emits, lower-cased (``min.c`` /
+        ``max.c`` / ``nullCount.c`` / ``numRecords`` / partition columns as
+        codes). The float64 min/max lanes bind as exact int64 order keys
+        (`jaxeval.f64_order_key`: a TPU's float64 is not IEEE) — the
+        encoding `ops/pruning._compiled_skipping` lowers compares to."""
+        from delta_tpu.expr.jaxeval import DeviceColumn, f64_order_key
 
         env = {"numRecords": DeviceColumn.of(self.num_records, self.num_records >= 0)}
         env["size"] = DeviceColumn.of(self.size)
@@ -114,12 +117,12 @@ class FileStateArrays:
         for c, codes in self.partition_codes.items():
             env[f"partition_code.{c}"] = DeviceColumn.of(codes, codes >= 0)
         for c, mn in self.stats_min.items():
-            env[f"min.{c}"] = DeviceColumn.of(mn, ~np.isnan(mn))
+            env[f"min.{c}"] = DeviceColumn.of(f64_order_key(mn), ~np.isnan(mn))
         for c, mx in self.stats_max.items():
-            env[f"max.{c}"] = DeviceColumn.of(mx, ~np.isnan(mx))
+            env[f"max.{c}"] = DeviceColumn.of(f64_order_key(mx), ~np.isnan(mx))
         for c, nc in self.stats_null_count.items():
             env[f"nullCount.{c}"] = DeviceColumn.of(nc, nc >= 0)
-        return env
+        return {name.lower(): col for name, col in env.items()}
 
 
 def files_to_arrays(

@@ -14,6 +14,7 @@ from __future__ import annotations
 from typing import Sequence
 
 import numpy as np
+from delta_tpu.utils.jaxcache import ensure_compilation_cache
 from delta_tpu.utils.jaxcompat import enable_x64
 
 __all__ = ["morton_order", "rank_u16"]
@@ -33,30 +34,24 @@ def rank_u16(values: np.ndarray) -> np.ndarray:
 
 def morton_order(columns: Sequence[np.ndarray]) -> np.ndarray:
     """Row permutation sorting by the interleaved (Morton) key of the given
-    rank columns. Uses the device for the bit-interleave when JAX is usable;
-    identical numpy fallback otherwise."""
+    rank columns; the bit-interleave runs on the device."""
     k = len(columns)
     if k == 0:
         raise ValueError("morton_order needs at least one column")
     ranks = [rank_u16(c) for c in columns]
-    try:
-        import jax
-        import jax.numpy as jnp
+    ensure_compilation_cache()
+    import jax
+    import jax.numpy as jnp
 
-        @jax.jit
-        def interleave(rs):
-            key = jnp.zeros(rs[0].shape, jnp.uint64)
-            for b in range(_BITS):
-                for c in range(k):
-                    bit = (rs[c] >> b) & 1
-                    key = key | (bit.astype(jnp.uint64) << (b * k + c))
-            return key
-
-        with enable_x64():
-            key = np.asarray(interleave([jnp.asarray(r) for r in ranks]))
-    except Exception:
-        key = np.zeros(len(ranks[0]), np.uint64)
+    @jax.jit
+    def interleave(rs):
+        key = jnp.zeros(rs[0].shape, jnp.uint64)
         for b in range(_BITS):
             for c in range(k):
-                key |= ((ranks[c].astype(np.uint64) >> b) & 1) << (b * k + c)
+                bit = (rs[c] >> b) & 1
+                key = key | (bit.astype(jnp.uint64) << (b * k + c))
+        return key
+
+    with enable_x64():
+        key = np.asarray(interleave([jnp.asarray(r) for r in ranks]))
     return np.argsort(key, kind="stable")

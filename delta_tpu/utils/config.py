@@ -409,9 +409,6 @@ class SqlConf:
         # conditional-PUT dialect (None = auto by scheme).
         "delta.tpu.storage.objectStore.endpoint": None,
         "delta.tpu.storage.objectStore.dialect": None,
-        # Persistent XLA compilation cache directory (utils/jaxcache).
-        # None = ~/.cache/delta_tpu/xla; empty string disables.
-        "delta.tpu.xla.cacheDir": None,
         # Autopilot maintenance scheduler (delta_tpu/autopilot): closes the
         # observe→decide→act→audit loop over the doctor's remedies and the
         # advisor's recommendations. Strictly opt-in: the daemon only runs

@@ -1,10 +1,4 @@
-"""Version-compat shims for the narrow slice of jax API the engine uses.
-
-Two names have moved across the jax releases the engine targets:
-``enable_x64`` (top-level in newer releases, ``jax.experimental`` before)
-and ``shard_map`` (top-level since 0.5, ``jax.experimental.shard_map``
-before). Kernels import the wrappers below so a version bump is a
-one-file fix.
+"""The narrow slice of jax API the engine's kernels share.
 
 The wrappers resolve jax LAZILY, at call time: several modules
 (``ops/pruning``, ``ops/zorder``, ``ops/key_cache``, ``ops/join_kernel``)
@@ -19,17 +13,13 @@ __all__ = ["enable_x64", "shard_map"]
 
 def enable_x64():
     """Context manager enabling 64-bit dtypes (``jax.enable_x64()``)."""
-    try:  # jax >= 0.5
-        from jax import enable_x64 as _enable_x64
-    except ImportError:  # pragma: no cover - version-dependent import
-        from jax.experimental import enable_x64 as _enable_x64
-    return _enable_x64()
+    import jax
+
+    return jax.enable_x64()
 
 
 def shard_map(*args, **kwargs):
-    """``jax.shard_map`` / ``jax.experimental.shard_map.shard_map``."""
-    try:  # jax >= 0.5
-        from jax import shard_map as _shard_map
-    except ImportError:  # pragma: no cover - version-dependent import
-        from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(*args, **kwargs)
+    """``jax.shard_map``."""
+    import jax
+
+    return jax.shard_map(*args, **kwargs)

@@ -76,7 +76,7 @@ __all__ = [
     "remove_failure_hook", "span_context", "adopt_span_context", "propagated",
     "histogram_rows", "bucket_quantile", "drop_labeled_series",
     "current_trace_id", "last_sampled_trace_id", "add_span_sink",
-    "remove_span_sink", "TRACEPARENT_ENV",
+    "remove_span_sink", "TRACEPARENT_ENV", "exc_text",
 ]
 
 
@@ -577,6 +577,11 @@ def add_span_data(**kv: Any) -> None:
     ev = current_span()
     if ev is not None:
         ev.data.update(kv)
+
+
+def exc_text(e: BaseException) -> str:
+    """An exception as it rides an event payload: ``Type: message[:300]``."""
+    return f"{type(e).__name__}: {str(e)[:300]}"
 
 
 @contextlib.contextmanager

@@ -502,3 +502,74 @@ def test_residual_plan_is_jit_cache_key():
     from delta_tpu.ops.column_cache import _mask_kernel
 
     assert _mask_kernel(p1.expr) is _mask_kernel(p2.expr)
+
+
+# -- float lanes are int64 order keys (a TPU's float64 is not IEEE) -----------
+
+
+def test_f64_order_key_is_the_float_order():
+    rng = np.random.RandomState(3)
+    x = np.concatenate([
+        rng.randn(2000) * 10.0 ** rng.randint(-300, 300, 2000),
+        [0.0, -0.0, np.inf, -np.inf, 5e-324, -5e-324, 1e300, -1e300,
+         0.5, np.nextafter(0.5, 0), np.nextafter(0.5, 1)],
+    ])
+    keys = jaxeval.f64_order_key(x)
+    assert keys.dtype == np.int64
+    i, j = rng.randint(0, len(x), (2, 20000))
+    assert np.array_equal(x[i] < x[j], keys[i] < keys[j])
+    assert np.array_equal(x[i] == x[j], keys[i] == keys[j])
+    assert jaxeval.f64_order_key(-0.0) == jaxeval.f64_order_key(0.0) == 0
+
+
+def _mk_float_table(path):
+    log = DeltaLog.for_table(path)
+    specials = [0.5, np.nextafter(0.5, 0), np.nextafter(0.5, 1), 0.0, -0.0,
+                float("nan"), -float("nan"), 1e300, -1e300, 5e-324, 3.5e38,
+                float("inf"), -float("inf"), 0.2, 0.4, None]
+    rng = np.random.RandomState(11)
+    for i in range(2):
+        vals = specials + rng.rand(300 - len(specials)).tolist()
+        WriteIntoDelta(log, "append", pa.table({
+            "id": np.arange(i * 300, (i + 1) * 300, dtype=np.int64),
+            "x": pa.array(vals, pa.float64()),
+            "f": pa.array(vals, pa.float64()).cast(pa.float32(), safe=False),
+        })).run()
+    return log
+
+
+FLOAT_KEY_PREDS = [
+    "x > 0.5", "x >= 0.5", "x < 0.5", "x <= 0.5", "x = 0.5", "x != 0.5",
+    "0.5 < x", "x <=> 0.5", "x IN (0.5, 0.0, 7)", "x IS NULL",
+    "x IS NOT NULL AND x <= 0", "x BETWEEN 0.2 AND 0.4", "x > 1e299",
+    "x > 3.4e38 AND id < 400", "NOT (x >= 0.5)", "NOT (x < 0.5)",
+    "x >= 0 OR id > 500", "f > 0.5", "f <= 0.2",
+]
+
+
+@pytest.mark.parametrize("pred", FLOAT_KEY_PREDS)
+def test_device_compares_float_lanes_as_order_keys(tmp_table, pred):
+    log = _mk_float_table(tmp_table)
+    engaged = telemetry.counters().get("scan.device.engaged", 0)
+    host, dev = _both(log, pred)
+    # ids name the rows (a NaN row never equals itself under Table.equals)
+    assert host.column("id").equals(dev.column("id")), pred
+    assert telemetry.counters().get("scan.device.engaged", 0) == engaged + 1
+    lanes = [e for (_l, _f, c), e in ColumnCache.instance()._entries.items()
+             if c in ("x", "f")]
+    assert lanes and all(str(e.values.dtype) == "int64" for e in lanes)
+
+
+@pytest.mark.parametrize("pred", [
+    "x * 2 > 1", "x / 2 > 0.1", "sqrt(x) > 0.5", "id > 5.5", "x > id",
+    "CAST(id AS DOUBLE) > 2", "id / 2 > 3", "coalesce(x, 0.0) > 0.5",
+    ir.Ne(ir.Column("x"), ir.Literal(float("nan"))),
+])
+def test_device_declines_float_arithmetic(pred):
+    from delta_tpu.schema.types import DoubleType, LongType
+
+    types = {"x": DoubleType(), "id": LongType()}
+    if isinstance(pred, str):
+        pred = parse_predicate(pred)
+    with pytest.raises(jaxeval.NotDeviceCompilable, match="not exact"):
+        jaxeval.compile_residual(pred, types, ())

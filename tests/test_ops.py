@@ -341,3 +341,86 @@ def test_sharded_replay_1m_actions_matches_host():
         1 for p, i in last.items() if not is_add[i] and del_ts[i] > 0
     )
     print(f"sharded 1M replay: {sharded_s*1000:.0f}ms")
+
+
+def _prune_tier(sql, files=FILES):
+    """`prune_files` through the device tier (minFiles=1): kept paths and
+    the tier the delta.scan.prune span names."""
+    from delta_tpu.utils import telemetry
+    from delta_tpu.utils.config import conf
+
+    with conf.set_temporarily(**{"delta.tpu.device.pruning.minFiles": 1}), \
+            telemetry.record_operation("delta.scan.prune") as ev:
+        kept = pruning.prune_files(files, _meta(), [parse_predicate(sql)])
+    return [f.path for f in kept], ev.data.get("tier")
+
+
+@pytest.mark.parametrize("sql,kept,tier", [
+    ("id = 150", ["f2"], "device"),
+    ("price >= 25.0", ["f3", "f4"], "device"),
+    ("price > 19.9", ["f3", "f4"], "device"),
+    ("id IN (5, 305)", ["f1", "f4"], "device"),
+    ("id < 100 OR price > 30.5", ["f1", "f4"], "device"),
+    ("NOT (id >= 100)", ["f1"], "device"),
+    ("id > 2147483648", [], "device"),
+    # no exact device form / no lane: designed declines, the host serves
+    ("id * price > 11000", ["f4"], "host"),
+    ("name > 'zz'", [], "host"),
+    ("name IS NULL", ["f3"], "host"),
+    ("id > 9007199254740993", [], "host"),
+])
+def test_device_prune_tier_matches_the_host(sql, kept, tier):
+    assert _prune_tier(sql) == (kept, tier)
+    assert [f.path for f in pruning.prune_files(
+        FILES, _meta(), [parse_predicate(sql)])] == kept  # host tier
+
+
+def test_device_prune_tier_is_exact_on_float_bounds():
+    """The min/max lanes ride as int64 order keys: a strict bound one ulp
+    from a stat, and stats beyond float32's range (what a TPU's float64
+    holds), prune exactly as the host does."""
+    lo, hi = float(np.nextafter(0.1, 0)), float(np.nextafter(0.1, 1))
+    files = [_file("a", "us", 0, 9, lo, 0.1), _file("b", "us", 0, 9, 0.1, hi),
+             _file("c", "us", 0, 9, 1e300, 1.5e300),
+             _file("d", "us", 0, 9, -1e-300, 1e-300)]
+    for sql, kept in [("price > 0.1", ["b", "c"]), ("price < 0.1", ["a", "d"]),
+                      (f"price >= {hi!r}", ["b", "c"]),
+                      ("price > 1.2e300", ["c"]), ("price < 1e300", ["a", "b", "d"]),
+                      ("price > 0 AND price < 1e-299", ["d"])]:
+        assert _prune_tier(sql, files) == (kept, "device"), sql
+
+
+def test_device_prune_failure_is_counted_with_its_text(monkeypatch):
+    from delta_tpu.utils import telemetry
+
+    def refuse(_pred):
+        raise RuntimeError("chip says no")
+
+    monkeypatch.setattr(pruning, "_compiled_skipping", refuse)
+    before = telemetry.counters().get("scan.prune.deviceFallback", 0)
+    from delta_tpu.utils.config import conf
+
+    with conf.set_temporarily(**{"delta.tpu.device.pruning.minFiles": 1}), \
+            telemetry.record_operation("delta.scan.prune") as ev:
+        kept = pruning.prune_files(FILES, _meta(),
+                                   [parse_predicate("price > 19.9")])
+    assert [f.path for f in kept] == ["f3", "f4"]
+    assert ev.data["tier"] == "host"
+    assert ev.data["deviceError"] == "RuntimeError: chip says no"
+    assert telemetry.counters()["scan.prune.deviceFallback"] == before + 1
+
+
+def test_morton_order_matches_numpy_interleave():
+    """The device bit-interleave against a plain numpy Morton key."""
+    from delta_tpu.ops import zorder
+
+    rng = np.random.RandomState(9)
+    cols = [rng.randint(0, 1 << 40, 5000).astype(np.int64),
+            rng.rand(5000), rng.randint(0, 7, 5000)]
+    ranks = [zorder.rank_u16(c).astype(np.uint64) for c in cols]
+    key = np.zeros(5000, np.uint64)
+    for b in range(16):
+        for c, r in enumerate(ranks):
+            key |= ((r >> np.uint64(b)) & np.uint64(1)) << np.uint64(b * 3 + c)
+    assert np.array_equal(zorder.morton_order(cols),
+                          np.argsort(key, kind="stable"))
