@@ -1,0 +1,8 @@
+"""The share of the traced window in which no operation ran on the device:
+1 - union of the device-operation intervals over the window."""
+
+
+def read(run, params):
+    if run.trace is None or run.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s() / run.trace.window_s)
