@@ -46,19 +46,6 @@ class Timer:
         self.t0 = now
         return ms
 
-    def lap_ms_f(self) -> float:
-        """Float-precision lap for phases that feed the router calibrator:
-        int truncation turns a sub-millisecond phase into a zero-duration
-        sample the calibrator must reject — starving calibration exactly
-        on the hardware (fast, warm caches) where samples are plentiful."""
-        now = time.perf_counter()
-        ms = (now - self.t0) * 1000.0
-        self.t0 = now
-        return ms
-
-    def peek_ms(self) -> int:
-        return int((time.perf_counter() - self.t0) * 1000)
-
 
 @dataclass
 class TouchedFile:

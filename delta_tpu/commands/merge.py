@@ -32,8 +32,10 @@ fast path (`:397-450`) and `MergeStats` (`:79-174`) follow the reference.
 """
 from __future__ import annotations
 
+import contextlib
+import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple, Union
 
 import pyarrow as pa
 import pyarrow.compute as pc
@@ -48,6 +50,7 @@ from delta_tpu.expr import ir
 from delta_tpu.expr.parser import parse_expression, parse_predicate
 from delta_tpu.expr.vectorized import boolean_mask, evaluate
 from delta_tpu.protocol.actions import Action, AddFile
+from delta_tpu.utils import telemetry
 from delta_tpu.utils.config import conf
 from delta_tpu.utils.errors import DeltaAnalysisError, DeltaUnsupportedOperationError
 from delta_tpu.utils import errors as errors_mod
@@ -324,15 +327,32 @@ class MergeIntoCommand:
     # -- main -------------------------------------------------------------
 
     def run(self) -> int:
-        from delta_tpu.utils.telemetry import record_operation
-
-        with record_operation("delta.dml.merge", path=self.delta_log.data_path):
+        with telemetry.record_operation("delta.dml.merge",
+                                        path=self.delta_log.data_path):
             return self.delta_log.with_new_transaction(self._body)
+
+    @contextlib.contextmanager
+    def _phase(self, op_type: str, key: str,
+               data: Optional[Dict[str, Any]] = None) -> Iterator[Any]:
+        """One phase of the MERGE: a child span of ``delta.dml.merge`` whose
+        own duration is ``phase_ms[key]``, so a phase has one clock reading,
+        a start and a parent. The phases tile `_body` and `_join`: they do
+        not overlap on the calling thread, and only a few lines lie between
+        them. Under a telemetry blackout the span is the no-op event and the
+        phase is timed here."""
+        t0 = time.perf_counter_ns()
+        with telemetry.record_operation(op_type, data) as ev:
+            yield ev
+        self.phase_ms[key] = (
+            ev.duration_us / 1000.0 if ev.duration_us is not None
+            else (time.perf_counter_ns() - t0) / 1e6)
 
     def _body(self, txn) -> int:
         # self-calibrating cost model: install any persisted constant
         # overrides BEFORE routing, so a fresh process routes with what the
         # last one learned (no-op unless router.calibration.enabled)
+        import numpy as np
+
         from delta_tpu.obs import calibration
 
         calibration.apply_state(self.delta_log.log_path)
@@ -355,51 +375,52 @@ class MergeIntoCommand:
         self._use_cdf = cdf_exec.cdf_enabled(txn.metadata)
         self.phase_ms.clear()
         timer = Timer()
-        metadata = self._migrate_schema(txn)
-        target_cols = [f.name for f in metadata.schema.fields]
-        source_cols = list(self.source.column_names)
-        # static star-coverage analysis (the reference resolves stars at
-        # analysis time, `deltaMerge.scala:322-328` — the error must not
-        # depend on whether any row fires the clause)
-        for clause in self.matched_clauses:
-            if clause.is_star:
-                self._check_star_coverage(target_cols, source_cols, "UPDATE", metadata)
-                break
-        for clause in self.not_matched_clauses:
-            if clause.is_star:
-                self._check_star_coverage(target_cols, source_cols, "INSERT", metadata)
-                break
-        # read-side char padding on the merge condition and clause
-        # conditions (literals vs char(n) target columns). Only refs that
-        # resolve to the TARGET pad: a source column sharing a name with a
-        # target char column (s.status = 'x') must keep its literal as-is.
-        from delta_tpu.schema.char_varchar import pad_char_literals
+        with self._phase("delta.dml.merge.analyze", "analyze_ms"):
+            metadata = self._migrate_schema(txn)
+            target_cols = [f.name for f in metadata.schema.fields]
+            source_cols = list(self.source.column_names)
+            # static star-coverage analysis (the reference resolves stars at
+            # analysis time, `deltaMerge.scala:322-328` — the error must not
+            # depend on whether any row fires the clause)
+            for clause in self.matched_clauses:
+                if clause.is_star:
+                    self._check_star_coverage(target_cols, source_cols, "UPDATE", metadata)
+                    break
+            for clause in self.not_matched_clauses:
+                if clause.is_star:
+                    self._check_star_coverage(target_cols, source_cols, "INSERT", metadata)
+                    break
+            # read-side char padding on the merge condition and clause
+            # conditions (literals vs char(n) target columns). Only refs that
+            # resolve to the TARGET pad: a source column sharing a name with a
+            # target char column (s.status = 'x') must keep its literal as-is.
+            from delta_tpu.schema.char_varchar import pad_char_literals
 
-        tq = frozenset({self.target_alias.lower()} if self.target_alias
-                       else ())
-        self.condition = pad_char_literals(self.condition, metadata, tq)
-        self.matched_clauses = [
-            MergeClause(c.kind, pad_char_literals(c.condition, metadata, tq)
-                        if c.condition is not None else None, c.assignments)
-            for c in self.matched_clauses
-        ]
-        # static clause analysis (the reference rejects these shapes at
-        # analysis time regardless of which rows fire,
-        # `deltaMerge.scala:161-221` resolution errors)
-        self._analyze_clauses(target_cols, source_cols)
-        cond = self._resolve(self.condition, target_cols, source_cols)
-        equi, residual = self._split_equi_keys(cond)
+            tq = frozenset({self.target_alias.lower()} if self.target_alias
+                           else ())
+            self.condition = pad_char_literals(self.condition, metadata, tq)
+            self.matched_clauses = [
+                MergeClause(c.kind, pad_char_literals(c.condition, metadata, tq)
+                            if c.condition is not None else None, c.assignments)
+                for c in self.matched_clauses
+            ]
+            # static clause analysis (the reference rejects these shapes at
+            # analysis time regardless of which rows fire,
+            # `deltaMerge.scala:161-221` resolution errors)
+            self._analyze_clauses(target_cols, source_cols)
+            cond = self._resolve(self.condition, target_cols, source_cols)
+            equi, residual = self._split_equi_keys(cond)
 
-        # source with prefixed names + row ids
-        src = self.source.rename_columns([_SRC + c for c in source_cols])
-        src = src.append_column(_SID, pa.array(range(src.num_rows), pa.int64()))
+            # source with prefixed names + row ids
+            src = self.source.rename_columns([_SRC + c for c in source_cols])
+            src = src.append_column(_SID, pa.array(range(src.num_rows), pa.int64()))
 
-        # phase 1: candidates by target-only conjuncts, then the join
-        target_only = [
-            c for c in ir.split_conjuncts(cond)
-            if not any(r.startswith(_SRC) for r in ir.references(c))
-        ]
-        candidates = candidate_files(txn, ir.and_all(target_only) if target_only else None)
+            # phase 1: candidates by target-only conjuncts, then the join
+            target_only = [
+                c for c in ir.split_conjuncts(cond)
+                if not any(r.startswith(_SRC) for r in ir.references(c))
+            ]
+            candidates = candidate_files(txn, ir.and_all(target_only) if target_only else None)
         # distributed findTouchedFiles probe: restrict the candidates to
         # files whose equi keys intersect the source BEFORE the join
         # decodes full rows (conf-gated; result-identical — see the method)
@@ -413,37 +434,74 @@ class MergeIntoCommand:
         self._emit_router()
         scan_ms = timer.lap_ms()
 
-        if not insert_only:
-            # insert-only merges can't modify target rows, so duplicate
-            # matches are harmless (reference fast path, `:397-450`)
-            self._check_multi_match(matched_pairs)
+        with self._phase("delta.dml.merge.apply", "apply_ms"):
+            if not insert_only:
+                # insert-only merges can't modify target rows, so duplicate
+                # matches are harmless (reference fast path, `:397-450`)
+                self._check_multi_match(matched_pairs)
 
-        removes: List[Action] = []
-        dv_adds: List[Action] = []
-        out_blocks: List[pa.Table] = []
-        n_copied = n_updated = n_deleted = 0
-        use_dv = not insert_only and dv_common.dv_enabled(metadata)
+            removes: List[Action] = []
+            dv_adds: List[Action] = []
+            out_blocks: List[pa.Table] = []
+            n_copied = n_updated = n_deleted = 0
+            use_dv = not insert_only and dv_common.dv_enabled(metadata)
 
-        if not insert_only:
-            # matched block → per-clause masks
-            upd, n_updated, n_deleted, n_pair_copied, claimed_tbl, fired_fids = (
-                self._apply_matched(
-                    matched_pairs, target_cols, metadata, dv_mode=use_dv
+            if not insert_only:
+                # matched block → per-clause masks
+                upd, n_updated, n_deleted, n_pair_copied, claimed_tbl, fired_fids = (
+                    self._apply_matched(
+                        matched_pairs, target_cols, metadata, dv_mode=use_dv
+                    )
                 )
-            )
-            n_copied += n_pair_copied
-            if upd is not None:
-                out_blocks.append(upd)
-            import numpy as np
+                n_copied += n_pair_copied
+                if upd is not None:
+                    out_blocks.append(upd)
+                if not use_dv:
+                    for fid in sorted(fired_fids):
+                        removes.append(candidates[fid].remove())
+                    # unmatched target rows inside touched files → copy. _TID is
+                    # the global row index over the candidate concat, so one
+                    # boolean scatter replaces a per-file hash-set probe
+                    total_rows = sum(t.num_rows for t in tgt_tables.values())
+                    claimed = np.zeros(total_rows, bool)
+                    claimed[matched_pairs.column(_TID).to_numpy(zero_copy_only=False)] = True
+                    row_start = 0
+                    starts = {}
+                    for fid in sorted(tgt_tables):
+                        starts[fid] = row_start
+                        row_start += tgt_tables[fid].num_rows
+                    for fid in sorted(fired_fids):
+                        t = tgt_tables[fid]
+                        keep = ~claimed[starts[fid]: starts[fid] + t.num_rows]
+                        if not keep.all():
+                            copied = t.filter(pa.array(keep)).select(target_cols)
+                        else:
+                            copied = t.select(target_cols)
+                        n_copied += copied.num_rows
+                        if copied.num_rows:
+                            out_blocks.append(copied)
 
-            if use_dv:
-                # claimed rows are marked deleted via per-file deletion
-                # vectors; everything else stays live in place — the file
-                # rewrite (and its copy block below) disappears entirely
+            # not-matched source rows → insert clauses
+            inserts, n_inserted = self._apply_not_matched(
+                matched_pairs, src, target_cols, source_cols, metadata
+            )
+            if inserts is not None and inserts.num_rows:
+                out_blocks.append(inserts)
+                if self._use_cdf:
+                    self._cdf_blocks.append(("insert", inserts))
+
+        if use_dv:
+            # claimed rows are marked deleted via per-file deletion vectors;
+            # everything else stays live in place — the file rewrite (and
+            # its copy block above) disappears entirely
+            with self._phase("delta.dml.merge.deletionVectors", "dv_ms",
+                             {"files": 0, "rows": 0}) as dv_ev:
                 if claimed_tbl is not None and claimed_tbl.num_rows:
                     fids = claimed_tbl.column(_FID).to_numpy(zero_copy_only=False)
                     poss = claimed_tbl.column(POSITION_COL).to_numpy(zero_copy_only=False)
-                    for fid in np.unique(fids):
+                    touched = np.unique(fids)
+                    dv_ev.data.update(files=len(touched), rows=len(poss))
+                    for fid in touched:
                         rm, re_add = dv_common.dv_mark_deleted(
                             self.delta_log.data_path,
                             candidates[int(fid)],
@@ -452,59 +510,25 @@ class MergeIntoCommand:
                         removes.append(rm)
                         if re_add is not None:
                             dv_adds.append(re_add)
-            else:
-                for fid in sorted(fired_fids):
-                    removes.append(candidates[fid].remove())
-                # unmatched target rows inside touched files → copy. _TID is
-                # the global row index over the candidate concat, so one
-                # boolean scatter replaces a per-file hash-set probe
-                total_rows = sum(t.num_rows for t in tgt_tables.values())
-                claimed = np.zeros(total_rows, bool)
-                claimed[matched_pairs.column(_TID).to_numpy(zero_copy_only=False)] = True
-                row_start = 0
-                starts = {}
-                for fid in sorted(tgt_tables):
-                    starts[fid] = row_start
-                    row_start += tgt_tables[fid].num_rows
-                for fid in sorted(fired_fids):
-                    t = tgt_tables[fid]
-                    keep = ~claimed[starts[fid]: starts[fid] + t.num_rows]
-                    if not keep.all():
-                        copied = t.filter(pa.array(keep)).select(target_cols)
-                    else:
-                        copied = t.select(target_cols)
-                    n_copied += copied.num_rows
-                    if copied.num_rows:
-                        out_blocks.append(copied)
 
-        # not-matched source rows → insert clauses
-        inserts, n_inserted = self._apply_not_matched(
-            matched_pairs, src, target_cols, source_cols, metadata
-        )
-        if inserts is not None and inserts.num_rows:
-            out_blocks.append(inserts)
-            if self._use_cdf:
-                self._cdf_blocks.append(("insert", inserts))
-
-        self.phase_ms["apply_ms"] = timer.peek_ms()
-        adds: List[Action] = list(dv_adds)
-        cdc_actions: List[Action] = []
-        if self._cdf_blocks:
-            cdc_actions = list(cdf_exec.write_change_data(
-                self.delta_log.data_path, self._cdf_blocks, metadata
-            ))
-        if out_blocks:
-            out = pa.concat_tables(out_blocks, promote_options="permissive")
-            if out.column_names != target_cols:
-                out = out.select(target_cols)
-            if out.num_rows:
-                adds += list(
-                    write_exec.write_files(
-                        self.delta_log.data_path, out, metadata, data_change=True
+        with self._phase("delta.dml.merge.write", "write_ms"):
+            adds: List[Action] = list(dv_adds)
+            cdc_actions: List[Action] = []
+            if self._cdf_blocks:
+                cdc_actions = list(cdf_exec.write_change_data(
+                    self.delta_log.data_path, self._cdf_blocks, metadata
+                ))
+            if out_blocks:
+                out = pa.concat_tables(out_blocks, promote_options="permissive")
+                if out.column_names != target_cols:
+                    out = out.select(target_cols)
+                if out.num_rows:
+                    adds += list(
+                        write_exec.write_files(
+                            self.delta_log.data_path, out, metadata, data_change=True
+                        )
                     )
-                )
         rewrite_ms = timer.lap_ms()
-        self.phase_ms["write_ms"] = rewrite_ms - self.phase_ms["apply_ms"]
 
         self.metrics.update(
             numSourceRows=self.source.num_rows,
@@ -531,7 +555,8 @@ class MergeIntoCommand:
             inserts=[_clause_info(c) for c in self.not_matched_clauses],
         )
         version = txn.commit(removes + adds + cdc_actions, op)
-        self._maybe_build_resident_keys()
+        with self._phase("delta.dml.merge.residentKeys", "resident_ms"):
+            self._maybe_build_resident_keys()
         return version
 
     # -- distributed touched-files probe ----------------------------------
@@ -579,12 +604,10 @@ class MergeIntoCommand:
             return True
 
         from delta_tpu.parallel.executor import run_sharded
-        from delta_tpu.utils import telemetry
 
-        probe_t = Timer()
         telemetry.bump_counter("dist.merge.filesProbed", len(candidates))
-        with telemetry.record_operation(
-            "delta.dist.mergeProbe", {"candidates": len(candidates)}
+        with self._phase(
+            "delta.dist.mergeProbe", "probe_ms", {"candidates": len(candidates)}
         ) as probe_ev:
             try:
                 report = run_sharded(
@@ -606,7 +629,6 @@ class MergeIntoCommand:
             if report.quarantined:
                 telemetry.bump_counter("dist.degraded.probe")
             probe_ev.data["touched"] = len(touched)
-        self.phase_ms["probe_ms"] = probe_t.lap_ms_f()
         return touched
 
     # -- join -------------------------------------------------------------
@@ -631,195 +653,196 @@ class MergeIntoCommand:
         merges (target rows feed the join and nothing else)."""
         import numpy as np
 
-        target_cols = [f.name for f in metadata.schema.fields]
-        insert_only = not self.matched_clauses
-        key_need = {r.lower() for t_e, _ in equi for r in ir.references(t_e)}
-        # insert-only merges never rewrite target rows: read only the columns
-        # the join condition touches (the reference's left-anti fast path
-        # reads the full target; we push the projection into the Parquet scan)
-        read_cols: Optional[List[str]] = None
-        if insert_only:
-            need = key_need | {
-                r.lower()
-                for c in residual
-                for r in ir.references(c)
-                if not r.startswith(_SRC)
-            }
-            cols = [c for c in target_cols if c.lower() in need]
-            read_cols = cols or None
-        else:
-            read_cols = self._referenced_target_columns(
-                metadata, target_cols, [c for c in src.column_names
-                                        if c.startswith(_SRC)],
-                key_need, residual,
+        # routing, the key-column decode, and the slab advance / upload launch
+        with self._phase("delta.dml.merge.keyDecode", "key_decode_ms"):
+            target_cols = [f.name for f in metadata.schema.fields]
+            insert_only = not self.matched_clauses
+            key_need = {r.lower() for t_e, _ in equi for r in ir.references(t_e)}
+            # insert-only merges never rewrite target rows: read only the columns
+            # the join condition touches (the reference's left-anti fast path
+            # reads the full target; we push the projection into the Parquet scan)
+            read_cols: Optional[List[str]] = None
+            if insert_only:
+                need = key_need | {
+                    r.lower()
+                    for c in residual
+                    for r in ir.references(c)
+                    if not r.startswith(_SRC)
+                }
+                cols = [c for c in target_cols if c.lower() in need]
+                read_cols = cols or None
+            else:
+                read_cols = self._referenced_target_columns(
+                    metadata, target_cols, [c for c in src.column_names
+                                            if c.startswith(_SRC)],
+                    key_need, residual,
+                )
+
+            mode = str(conf.get("delta.tpu.merge.devicePath.mode", "auto"))
+            base_eligible = (
+                bool(conf.get("delta.tpu.merge.devicePath.enabled", True))
+                and mode != "off"
+                and 1 <= len(equi) <= 2
+                and not residual
+                and candidates
+                and src.num_rows > 0
             )
+            device_eligible = base_eligible
+            # audit: whether a device route even existed for this condition
+            # shape — a structurally host-only merge is audited without a
+            # device alternative (no hindsight miss against a route that
+            # could not have run)
+            self._audit_eligible = base_eligible
+            if device_eligible and mode == "auto":
+                # pre-decode routing check from AddFile stats row counts: on a
+                # slow link even the *optimistic* plan (int32 keys) loses to the
+                # host hash join — skip the early key decode entirely then.
+                # This is the COLD price (slab upload + sort + probe); the
+                # cache-hit case was already evaluated above with its own,
+                # upload-free economics.
+                n_est = _rows_from_stats(candidates)
+                if n_est is not None:
+                    import jax
 
-        mode = str(conf.get("delta.tpu.merge.devicePath.mode", "auto"))
-        base_eligible = (
-            bool(conf.get("delta.tpu.merge.devicePath.enabled", True))
-            and mode != "off"
-            and 1 <= len(equi) <= 2
-            and not residual
-            and candidates
-            and src.num_rows > 0
-        )
-        device_eligible = base_eligible
-        # audit: whether a device route even existed for this condition
-        # shape — a structurally host-only merge is audited without a
-        # device alternative (no hindsight miss against a route that
-        # could not have run)
-        self._audit_eligible = base_eligible
-        if device_eligible and mode == "auto":
-            # pre-decode routing check from AddFile stats row counts: on a
-            # slow link even the *optimistic* plan (int32 keys) loses to the
-            # host hash join — skip the early key decode entirely then.
-            # This is the COLD price (slab upload + sort + probe); the
-            # cache-hit case was already evaluated above with its own,
-            # upload-free economics.
-            n_est = _rows_from_stats(candidates)
-            if n_est is not None:
-                import jax
+                    from delta_tpu.parallel import link
 
-                from delta_tpu.parallel import link
+                    rows = n_est + src.num_rows
+                    if not (len(jax.devices()) > 1 and conf.get_bool(
+                            "delta.tpu.merge.devicePath.preferMesh", False)):
+                        device_s = link.cold_merge_device_s(
+                            n_est, src.num_rows, link.profile())
+                    else:
+                        device_s = link.estimate_device_s(
+                            up_bytes=rows * 4,
+                            down_bytes=rows // 8,
+                            kernel_rows=rows,
+                            shards=len(jax.devices()),
+                        ).device_s
+                    host_est_s = rows * link.constant("HOST_JOIN_S_PER_ROW")
+                    self._router.setdefault("deviceEstS", round(device_s, 3))
+                    self._router.setdefault("hostEstS", round(host_est_s, 3))
+                    if device_s > host_est_s:
+                        device_eligible = False
+                        from delta_tpu.utils.telemetry import bump_counter
 
-                rows = n_est + src.num_rows
-                if not (len(jax.devices()) > 1 and conf.get_bool(
-                        "delta.tpu.merge.devicePath.preferMesh", False)):
-                    device_s = link.cold_merge_device_s(
-                        n_est, src.num_rows, link.profile())
-                else:
-                    device_s = link.estimate_device_s(
-                        up_bytes=rows * 4,
-                        down_bytes=rows // 8,
-                        kernel_rows=rows,
-                        shards=len(jax.devices()),
-                    ).device_s
-                host_est_s = rows * link.constant("HOST_JOIN_S_PER_ROW")
-                self._router.setdefault("deviceEstS", round(device_s, 3))
-                self._router.setdefault("hostEstS", round(host_est_s, 3))
-                if device_s > host_est_s:
-                    device_eligible = False
-                    from delta_tpu.utils.telemetry import bump_counter
+                        bump_counter("merge.device.declined")
+                        self._router.update(reason="cold-estimate")
 
-                    bump_counter("merge.device.declined")
-                    self._router.update(reason="cold-estimate")
-
-        # DV-mode matched clauses mark physical rows deleted — every scan
-        # that can end up as the phase-2 tables must carry positions
-        pos_col = (
-            POSITION_COL
-            if (not insert_only and dv_common.dv_enabled(metadata))
-            else None
-        )
-        # row-group skipping is only safe when unmatched target rows never
-        # need writing back: DV mode (matched rows mark by physical
-        # position) or insert-only (target rows exist only to probe)
-        if pos_col is None and not insert_only:
-            prune_pred = None
-        decode_t = Timer()
-        pending = None
-        resident = None
-        via = None
-        key_pieces: Optional[List[pa.Table]] = None
-        key_pieces_have_pos = False
-        if base_eligible:
-            # resident-operand path first: the target key lane already lives
-            # in HBM (ops/key_cache), so the probe ships only source keys —
-            # different economics from the cold upload path, hence evaluated
-            # before (and independent of) the upload-cost gate above
-            resident = self._launch_resident_probe(
-                txn, candidates, src, equi, target_cols, key_need,
-                pos_col, insert_only,
+            # DV-mode matched clauses mark physical rows deleted — every scan
+            # that can end up as the phase-2 tables must carry positions
+            pos_col = (
+                POSITION_COL
+                if (not insert_only and dv_common.dv_enabled(metadata))
+                else None
             )
-            if resident is not None:
-                via = "resident"
-        if resident is None and device_eligible:
-            import jax
-
-            prefer_mesh = (
-                len(jax.devices()) > 1
-                and conf.get_bool("delta.tpu.merge.devicePath.preferMesh",
-                                  False)
-            )
-            if not prefer_mesh:
-                # fused cold pipeline: per-file key decode streams into a
-                # pre-sized HBM slab (upload overlaps decode), then the
-                # block-bucketed probe joins + pairs on device — and the
-                # slab registers in the KeyCache so the NEXT merge against
-                # this table skips the upload entirely
-                resident, key_pieces = self._launch_slab_pipeline(
+            # row-group skipping is only safe when unmatched target rows never
+            # need writing back: DV mode (matched rows mark by physical
+            # position) or insert-only (target rows exist only to probe)
+            if pos_col is None and not insert_only:
+                prune_pred = None
+            pending = None
+            resident = None
+            via = None
+            key_pieces: Optional[List[pa.Table]] = None
+            key_pieces_have_pos = False
+            if base_eligible:
+                # resident-operand path first: the target key lane already lives
+                # in HBM (ops/key_cache), so the probe ships only source keys —
+                # different economics from the cold upload path, hence evaluated
+                # before (and independent of) the upload-cost gate above
+                resident = self._launch_resident_probe(
                     txn, candidates, src, equi, target_cols, key_need,
-                    pos_col, insert_only, metadata,
+                    pos_col, insert_only,
                 )
                 if resident is not None:
-                    via = "device-cold"
-                key_pieces_have_pos = key_pieces is not None
-            if resident is None and key_pieces is None:
-                # multichip mesh (all-gather sort-merge kernel, opt-in via
-                # devicePath.preferMesh), or the slab pipeline bailed before
-                # decoding: decode the key projection and launch the upload
-                # join
-                key_cols = [c for c in target_cols if c.lower() in key_need]
-                key_pieces = read_files_as_table(
-                    self.delta_log.data_path, candidates, metadata,
-                    columns=key_cols or None, per_file=True,
-                    position_column=pos_col, predicate=prune_pred,
-                    # the key read and the full read below must stay
-                    # row-aligned (the device probe's indices map onto the
-                    # full decode) — stats-pruning is deterministic across
-                    # both, but late materialization's verdict depends on
-                    # the decoded columns
-                    late_materialize=False,
-                )
-            if resident is None:
-                key_tab = pa.concat_tables(key_pieces,
-                                           promote_options="permissive")
-                if key_tab.num_rows:
-                    pending = self._launch_device_join(key_tab, src, equi)
-                    if pending is not None:
-                        via = "device-upload"
-                    else:
-                        self._router.setdefault("reason", "upload-declined")
-        self.phase_ms["key_decode_ms"] = decode_t.lap_ms_f()
+                    via = "resident"
+            if resident is None and device_eligible:
+                import jax
 
-        # full-column decode (overlaps the in-flight device probe); when the
-        # key projection already covers every needed column, reuse it (the
-        # slab pipeline's pieces carry an extra position column — harmless,
-        # every write-side consumer projects to target_cols)
-        if key_pieces is not None and read_cols is not None and set(
-            c.lower() for c in read_cols
-        ) <= key_need and (not key_pieces_have_pos or pos_col is not None
-                           or insert_only):
-            raw_pieces = key_pieces
-        else:
-            raw_pieces = read_files_as_table(
-                self.delta_log.data_path, candidates, metadata,
-                columns=read_cols, per_file=True, position_column=pos_col,
-                predicate=prune_pred, late_materialize=False,
-            )
-        tgt_tables: Dict[int, pa.Table] = {}
-        pieces: List[pa.Table] = []
-        row_base = 0
-        for fid, t in enumerate(raw_pieces):
-            t = t.append_column(
-                _TID,
-                pa.array(np.arange(row_base, row_base + t.num_rows, dtype=np.int64)),
-            )
-            t = t.append_column(
-                _FID, pa.array(np.full(t.num_rows, fid, dtype=np.int64))
-            )
-            row_base += t.num_rows
-            tgt_tables[fid] = t
-            pieces.append(t)
-        self.phase_ms["decode_ms"] = decode_t.lap_ms_f()
-        if not pieces:
-            empty = pa.schema(
-                [pa.field(_TID, pa.int64()), pa.field(_FID, pa.int64())]
-            ).empty_table()
-            target = empty
-        else:
-            target = pa.concat_tables(pieces, promote_options="permissive")
-        self._audit_units = (target.num_rows, src.num_rows)
+                prefer_mesh = (
+                    len(jax.devices()) > 1
+                    and conf.get_bool("delta.tpu.merge.devicePath.preferMesh",
+                                      False)
+                )
+                if not prefer_mesh:
+                    # fused cold pipeline: per-file key decode streams into a
+                    # pre-sized HBM slab (upload overlaps decode), then the
+                    # block-bucketed probe joins + pairs on device — and the
+                    # slab registers in the KeyCache so the NEXT merge against
+                    # this table skips the upload entirely
+                    resident, key_pieces = self._launch_slab_pipeline(
+                        txn, candidates, src, equi, target_cols, key_need,
+                        pos_col, insert_only, metadata,
+                    )
+                    if resident is not None:
+                        via = "device-cold"
+                    key_pieces_have_pos = key_pieces is not None
+                if resident is None and key_pieces is None:
+                    # multichip mesh (all-gather sort-merge kernel, opt-in via
+                    # devicePath.preferMesh), or the slab pipeline bailed before
+                    # decoding: decode the key projection and launch the upload
+                    # join
+                    key_cols = [c for c in target_cols if c.lower() in key_need]
+                    key_pieces = read_files_as_table(
+                        self.delta_log.data_path, candidates, metadata,
+                        columns=key_cols or None, per_file=True,
+                        position_column=pos_col, predicate=prune_pred,
+                        # the key read and the full read below must stay
+                        # row-aligned (the device probe's indices map onto the
+                        # full decode) — stats-pruning is deterministic across
+                        # both, but late materialization's verdict depends on
+                        # the decoded columns
+                        late_materialize=False,
+                    )
+                if resident is None:
+                    key_tab = pa.concat_tables(key_pieces,
+                                               promote_options="permissive")
+                    if key_tab.num_rows:
+                        pending = self._launch_device_join(key_tab, src, equi)
+                        if pending is not None:
+                            via = "device-upload"
+                        else:
+                            self._router.setdefault("reason", "upload-declined")
+
+        with self._phase("delta.dml.merge.rowDecode", "decode_ms"):
+
+            # full-column decode (overlaps the in-flight device probe); when the
+            # key projection already covers every needed column, reuse it (the
+            # slab pipeline's pieces carry an extra position column — harmless,
+            # every write-side consumer projects to target_cols)
+            if key_pieces is not None and read_cols is not None and set(
+                c.lower() for c in read_cols
+            ) <= key_need and (not key_pieces_have_pos or pos_col is not None
+                               or insert_only):
+                raw_pieces = key_pieces
+            else:
+                raw_pieces = read_files_as_table(
+                    self.delta_log.data_path, candidates, metadata,
+                    columns=read_cols, per_file=True, position_column=pos_col,
+                    predicate=prune_pred, late_materialize=False,
+                )
+            tgt_tables: Dict[int, pa.Table] = {}
+            pieces: List[pa.Table] = []
+            row_base = 0
+            for fid, t in enumerate(raw_pieces):
+                t = t.append_column(
+                    _TID,
+                    pa.array(np.arange(row_base, row_base + t.num_rows, dtype=np.int64)),
+                )
+                t = t.append_column(
+                    _FID, pa.array(np.full(t.num_rows, fid, dtype=np.int64))
+                )
+                row_base += t.num_rows
+                tgt_tables[fid] = t
+                pieces.append(t)
+            if not pieces:
+                empty = pa.schema(
+                    [pa.field(_TID, pa.int64()), pa.field(_FID, pa.int64())]
+                ).empty_table()
+                target = empty
+            else:
+                target = pa.concat_tables(pieces, promote_options="permissive")
+            self._audit_units = (target.num_rows, src.num_rows)
 
         def empty_pairs() -> pa.Table:
             # empty pair table with the full combined (target + source) schema
@@ -833,100 +856,98 @@ class MergeIntoCommand:
         if target.num_rows == 0 or src.num_rows == 0:
             return empty_pairs(), tgt_tables
 
-        join_t = Timer()
-        if resident is not None and pending is None:
-            pending = self._finalize_resident(
-                resident, candidates, tgt_tables, target, src, equi,
-                pos_col, insert_only,
-            )
-        if pending is not None:
-            res = pending.result()
-            if res is None:
-                self._router.setdefault("reason", "device-finalize-fallback")
-            else:
-                self._device_join = res
-                self._join_path = via
-                # insert-only never consumes the pair rows (the not-matched
-                # block comes from s_matched): skip materializing them
-                if insert_only:
-                    joined = empty_pairs()
+        # the wait for the device probe, or the host join; then the pair take
+        with self._phase("delta.dml.merge.join", "join_ms"):
+            if resident is not None and pending is None:
+                pending = self._finalize_resident(
+                    resident, candidates, tgt_tables, target, src, equi,
+                    pos_col, insert_only,
+                )
+            if pending is not None:
+                res = pending.result()
+                if res is None:
+                    self._router.setdefault("reason", "device-finalize-fallback")
                 else:
-                    matched = np.flatnonzero(res.t_matched)
-                    joined = target.take(pa.array(matched, pa.int64()))
-                    s_taken = src.take(
-                        pa.array(res.t_first_s[matched], pa.int64())
-                    )
-                    for name in s_taken.column_names:
-                        joined = joined.append_column(name, s_taken.column(name))
-                self.phase_ms["join_ms"] = join_t.lap_ms_f()
-                return joined, tgt_tables
+                    self._device_join = res
+                    self._join_path = via
+                    # insert-only never consumes the pair rows (the not-matched
+                    # block comes from s_matched): skip materializing them
+                    if insert_only:
+                        joined = empty_pairs()
+                    else:
+                        matched = np.flatnonzero(res.t_matched)
+                        joined = target.take(pa.array(matched, pa.int64()))
+                        s_taken = src.take(
+                            pa.array(res.t_first_s[matched], pa.int64())
+                        )
+                        for name in s_taken.column_names:
+                            joined = joined.append_column(name, s_taken.column(name))
+                    return joined, tgt_tables
 
-        if equi:
-            # Join INDEX tables (keys + row positions), then take the full
-            # rows: Arrow's hash join refuses nested (struct/list/map)
-            # non-key payload columns, and carrying 2 int columns through
-            # the join beats carrying every column anyway.
-            key_cols = []
-            for t_e, s_e in equi:
-                t_vals = evaluate(t_e, target)
-                s_vals = evaluate(s_e, src)
-                key_cols.append(_coerce_join_keys(t_vals, s_vals))
-            t_idx_cols = {"__trow__": pa.array(np.arange(target.num_rows), pa.int64())}
-            s_idx_cols = {"__srow__": pa.array(np.arange(src.num_rows), pa.int64())}
-            tkeys, skeys = [], []
-            for i, (t_vals, s_vals) in enumerate(key_cols):
-                k = f"__k{i}__"
-                t_idx_cols[k] = t_vals
-                s_idx_cols[k] = s_vals
-                tkeys.append(k)
-                skeys.append(k)
-            pairs_idx = pa.table(t_idx_cols).join(
-                pa.table(s_idx_cols), keys=tkeys, right_keys=skeys,
-                join_type="inner", use_threads=False,
-            )
-            t_take = pairs_idx.column("__trow__")
-            s_take = pairs_idx.column("__srow__")
-            joined = target.take(t_take)
-            s_taken = src.take(s_take)
-            for name in s_taken.column_names:
-                joined = joined.append_column(name, s_taken.column(name))
-            # take() emits one chunk per input chunk: defragment once here
-            # or every downstream mask/projection/encode pays per-chunk costs
-            joined = joined.combine_chunks()
-        else:
-            # general condition: BLOCKED cartesian pairing — tile the
-            # target x source grid and stream each tile through the clause
-            # condition immediately, so peak memory is one tile of pairs
-            # (`delta.tpu.merge.nonEquiPairBudget`) regardless of input
-            # sizes. The reference handles arbitrary conditions via a real
-            # join (`MergeIntoCommand.scala:335-341`); this is the bounded
-            # equivalent for a columnar engine without a theta-join kernel.
-            budget = int(conf.get("delta.tpu.merge.nonEquiPairBudget",
-                                  8_000_000))
-            m = src.num_rows
-            tile = max(budget // max(m, 1), 1)
-            cond = ir.and_all(residual) if residual else None
-            pieces = []
-            s_base = np.tile(np.arange(m, dtype=np.int64), tile)
-            for t0 in range(0, target.num_rows, tile):
-                rows = min(tile, target.num_rows - t0)
-                t_idx = np.repeat(np.arange(t0, t0 + rows, dtype=np.int64), m)
-                piece = target.take(pa.array(t_idx, pa.int64()))
-                s_taken = src.take(pa.array(s_base[: rows * m], pa.int64()))
+            if equi:
+                # Join INDEX tables (keys + row positions), then take the full
+                # rows: Arrow's hash join refuses nested (struct/list/map)
+                # non-key payload columns, and carrying 2 int columns through
+                # the join beats carrying every column anyway.
+                key_cols = []
+                for t_e, s_e in equi:
+                    t_vals = evaluate(t_e, target)
+                    s_vals = evaluate(s_e, src)
+                    key_cols.append(_coerce_join_keys(t_vals, s_vals))
+                t_idx_cols = {"__trow__": pa.array(np.arange(target.num_rows), pa.int64())}
+                s_idx_cols = {"__srow__": pa.array(np.arange(src.num_rows), pa.int64())}
+                tkeys, skeys = [], []
+                for i, (t_vals, s_vals) in enumerate(key_cols):
+                    k = f"__k{i}__"
+                    t_idx_cols[k] = t_vals
+                    s_idx_cols[k] = s_vals
+                    tkeys.append(k)
+                    skeys.append(k)
+                pairs_idx = pa.table(t_idx_cols).join(
+                    pa.table(s_idx_cols), keys=tkeys, right_keys=skeys,
+                    join_type="inner", use_threads=False,
+                )
+                t_take = pairs_idx.column("__trow__")
+                s_take = pairs_idx.column("__srow__")
+                joined = target.take(t_take)
+                s_taken = src.take(s_take)
                 for name in s_taken.column_names:
-                    piece = piece.append_column(name, s_taken.column(name))
-                if cond is not None:
-                    piece = piece.filter(boolean_mask(cond, piece))
-                if piece.num_rows:
-                    pieces.append(piece.combine_chunks())
-            joined = (pa.concat_tables(pieces).combine_chunks()
-                      if pieces else empty_pairs())
-            self.phase_ms["join_ms"] = join_t.lap_ms_f()
+                    joined = joined.append_column(name, s_taken.column(name))
+                # take() emits one chunk per input chunk: defragment once here
+                # or every downstream mask/projection/encode pays per-chunk costs
+                joined = joined.combine_chunks()
+            else:
+                # general condition: BLOCKED cartesian pairing — tile the
+                # target x source grid and stream each tile through the clause
+                # condition immediately, so peak memory is one tile of pairs
+                # (`delta.tpu.merge.nonEquiPairBudget`) regardless of input
+                # sizes. The reference handles arbitrary conditions via a real
+                # join (`MergeIntoCommand.scala:335-341`); this is the bounded
+                # equivalent for a columnar engine without a theta-join kernel.
+                budget = int(conf.get("delta.tpu.merge.nonEquiPairBudget",
+                                      8_000_000))
+                m = src.num_rows
+                tile = max(budget // max(m, 1), 1)
+                cond = ir.and_all(residual) if residual else None
+                pieces = []
+                s_base = np.tile(np.arange(m, dtype=np.int64), tile)
+                for t0 in range(0, target.num_rows, tile):
+                    rows = min(tile, target.num_rows - t0)
+                    t_idx = np.repeat(np.arange(t0, t0 + rows, dtype=np.int64), m)
+                    piece = target.take(pa.array(t_idx, pa.int64()))
+                    s_taken = src.take(pa.array(s_base[: rows * m], pa.int64()))
+                    for name in s_taken.column_names:
+                        piece = piece.append_column(name, s_taken.column(name))
+                    if cond is not None:
+                        piece = piece.filter(boolean_mask(cond, piece))
+                    if piece.num_rows:
+                        pieces.append(piece.combine_chunks())
+                joined = (pa.concat_tables(pieces).combine_chunks()
+                          if pieces else empty_pairs())
+                return joined, tgt_tables
+            if residual:
+                joined = joined.filter(boolean_mask(ir.and_all(residual), joined))
             return joined, tgt_tables
-        if residual:
-            joined = joined.filter(boolean_mask(ir.and_all(residual), joined))
-        self.phase_ms["join_ms"] = join_t.lap_ms_f()
-        return joined, tgt_tables
 
     def _referenced_target_columns(
         self, metadata, target_cols, src_prefixed, key_need, residual,
@@ -1101,8 +1122,6 @@ class MergeIntoCommand:
         # upload shows as a `delta.merge.slabUpload` span on its own trace
         # lane under `delta.dml.merge` — the decode/upload overlap the
         # router assumes, finally visible in export_chrome_trace
-        from delta_tpu.utils import telemetry
-
         upload_ctx = telemetry.span_context()
         upload_errors: List[BaseException] = []
 
@@ -1234,8 +1253,6 @@ class MergeIntoCommand:
         the host join then took over (after a failed slab allocation the
         build continues on host mirrors and the device join may still
         engage)."""
-        from delta_tpu.utils import telemetry
-
         if str(conf.get("delta.tpu.merge.devicePath.mode", "auto")) == "force":
             raise e
         self._router.setdefault("reason", reason)

@@ -196,7 +196,14 @@ def read_files_as_table(
                 mt = mt.append_column(pa.field(c, at, f.nullable), arr)
         return mt
 
-    def _decode_pruned(abs_path, meta, keep_idx, add, need_positions):
+    def _groups(meta, idx) -> dict:
+        """What a ``delta.scan.decode.rowGroups`` span says it read: the
+        groups, and their bytes as the footer states them (every column,
+        uncompressed)."""
+        return {"groups": len(idx),
+                "bytes": sum(meta.row_group(i).total_byte_size for i in idx)}
+
+    def _decode_pruned(abs_path, meta, keep_idx, add, need_positions, stage):
         """Decode only ``keep_idx`` row groups (late-materializing around
         the predicate columns); returns (table, physical_positions | None,
         late_skipped_groups, late_skipped_bytes)."""
@@ -229,6 +236,9 @@ def read_files_as_table(
                 - {c.lower() for c in file_cols}
             )
             t = None
+            # one stage for the whole read: under late materialization that
+            # is the predicate columns, their mask, then the rest
+            stage("delta.scan.decode.rowGroups", _groups(meta, keep_idx))
             if late_materialize and refs_covered \
                     and predicate is not None and pred_cols and rest_cols:
                 t1 = pf.read_row_groups(keep_idx, columns=pred_cols)
@@ -279,6 +289,7 @@ def read_files_as_table(
                     t = pf.schema_arrow.empty_table().select(file_cols)
             if t is None:
                 t = pf.read_row_groups(keep_idx, columns=file_cols)
+        stage("delta.scan.decode.assemble")
         pos = None
         if need_positions:
             pos = (
@@ -291,7 +302,7 @@ def read_files_as_table(
         return t, pos, late_skipped, late_bytes
 
     def _decode_device_masked(abs_path, meta, keep_idx, add, need_positions,
-                              dev_mask):
+                              dev_mask, stage):
         """The device residual path's survivor fetch: drop row groups whose
         device mask slice is all-False, decode the survivors' projected
         columns in ONE read (no host predicate evaluation), and filter rows
@@ -325,11 +336,13 @@ def read_files_as_table(
                  if file_cols else _dummy(0))
             pos = np.empty(0, dtype=np.int64) if need_positions else None
             return t, pos, (dev_skipped, dev_bytes, 0)
+        stage("delta.scan.decode.rowGroups", _groups(meta, survivors))
         if file_cols:
             t = pf.read_row_groups(survivors, columns=file_cols)
         else:
             t = _dummy(int(sum(meta.row_group(i).num_rows
                                for i in survivors)))
+        stage("delta.scan.decode.assemble")
         keep = np.concatenate(
             [dev_mask[offsets[i]:offsets[i + 1]] for i in survivors])
         t = t.filter(pa.array(keep))
@@ -340,10 +353,16 @@ def read_files_as_table(
             pos = phys[keep].astype(np.int64)
         return t, pos, (dev_skipped, dev_bytes, surv_bytes)
 
-    def read_one(job) -> pa.Table:
+    def read_one(job, stage) -> pa.Table:
+        """``stage`` opens the next of the decode's three spans, each ending
+        where the next begins: ``open`` (footer, row-group selection, the
+        ``ParquetFile``), ``rowGroups`` (the read), ``assemble`` (mask and
+        deletion-vector filter, casts, partition and position columns)."""
         fidx, add, pos_hint = job
         abs_path = _abs_data_path(data_path, add.path)
         import numpy as np
+
+        stage("delta.scan.decode.open")
 
         need_positions = (
             add.deletion_vector is not None or position_column is not None
@@ -387,7 +406,8 @@ def read_files_as_table(
             dev_mask = device_masks.get(add.path) if device_masks else None
             if dev_mask is not None:
                 res = _decode_device_masked(
-                    abs_path, meta, keep_idx, add, need_positions, dev_mask
+                    abs_path, meta, keep_idx, add, need_positions, dev_mask,
+                    stage,
                 )
                 if res is not None:
                     t, positions, dstats = res
@@ -397,7 +417,7 @@ def read_files_as_table(
                     )
             if t is None and (pruned or late_capable):
                 t, positions, late_n, late_bytes = _decode_pruned(
-                    abs_path, meta, keep_idx, add, need_positions
+                    abs_path, meta, keep_idx, add, need_positions, stage
                 )
                 rg_stats.append(
                     (n_rg, pruned, late_n, skipped_bytes, late_bytes,
@@ -418,11 +438,14 @@ def read_files_as_table(
             # fills them w/ null)
             present = set(pf.schema_arrow.names)
             file_cols = [c for c in data_cols if c in present]
+            stage("delta.scan.decode.rowGroups",
+                  _groups(pf.metadata, range(pf.metadata.num_row_groups)))
             if file_cols:
                 t = pf.read(columns=file_cols)
             else:
                 t = _dummy(pf.metadata.num_rows)
 
+        stage("delta.scan.decode.assemble")
         if add.deletion_vector is not None:
             from delta_tpu.protocol.deletion_vectors import (
                 DeletionVectorDescriptor,
@@ -495,8 +518,8 @@ def read_files_as_table(
         # export_chrome_trace instead of orphaned
         with telemetry.record_operation(
             "delta.scan.decode", {"file": job[1].path}
-        ):
-            return read_one(job)
+        ), telemetry.span_stages() as stage:
+            return read_one(job, stage)
 
     with telemetry.record_operation(
         "delta.scan.read", {"numFiles": len(files)}
@@ -571,9 +594,10 @@ def read_files_as_table(
                     seen_fired.add(key)
                     scan_report_mod.record_rewrite_fired(
                         fe["family"], fe["conjunct"], fe["rewrite"])
-        if per_file:
-            return pieces
-        return pa.concat_tables(pieces, promote_options="permissive")
+        out = pieces if per_file else pa.concat_tables(
+            pieces, promote_options="permissive")
+    scan_report_mod.record_phase("read", rev)
+    return out
 
 
 def scan_files(snapshot, filters: Sequence[Union[str, ir.Expression]] = ()) -> pruning.DeltaScan:
@@ -698,8 +722,6 @@ def scan_to_table(
     (files/row-groups considered vs pruned, bytes, phase durations),
     retrievable via ``obs.last_scan_report()`` and attached to the
     ``delta.scan`` span — skipped entirely under a telemetry blackout."""
-    import time as _time
-
     from delta_tpu.obs import scan_report as scan_report_mod
     from delta_tpu.utils import telemetry
 
@@ -709,13 +731,14 @@ def scan_to_table(
              if track else None)
     scan_ok = False
     try:
+        # the phases are child spans that tile this one: `.planning`
+        # (ops/pruning), `.deviceMask`, `.read` (read_files_as_table),
+        # `.filter`, `.report`; the first four write the report's phaseMs
         with telemetry.record_operation(
             "delta.scan", path=snapshot.delta_log.data_path
         ) as sev:
-            t0 = _time.perf_counter_ns()
             exprs = [parse_predicate(f) if isinstance(f, str) else f for f in filters]
             scan = pruning.files_for_scan(snapshot, exprs)
-            t1 = _time.perf_counter_ns()
             data_path = snapshot.delta_log.data_path
             residual = scan.partition_filters + scan.data_filters
             read_cols = columns
@@ -734,8 +757,11 @@ def scan_to_table(
                 from delta_tpu.ops import column_cache
 
                 if column_cache.column_cache_enabled():
-                    device_masks = column_cache.device_residual_masks(
-                        snapshot, scan.files, ir.and_all(residual))
+                    with telemetry.record_operation(
+                            "delta.scan.deviceMask") as mev:
+                        device_masks = column_cache.device_residual_masks(
+                            snapshot, scan.files, ir.and_all(residual))
+                    scan_report_mod.record_phase("mask", mev)
             # the residual predicate rides into the decode: footer row-group
             # stats prune inside each file (second tier), and the residual
             # filter below re-applies the exact semantics over the survivors
@@ -744,19 +770,21 @@ def scan_to_table(
                                         predicate=(ir.and_all(residual)
                                                    if residual else None),
                                         device_masks=device_masks)
-            t2 = _time.perf_counter_ns()
-            if residual and table.num_rows:
-                table = filter_table(table, ir.and_all(residual))
-            if columns is not None and read_cols != list(columns):
-                table = table.select([c for c in columns if c in table.column_names])
-            t3 = _time.perf_counter_ns()
+            with telemetry.record_operation("delta.scan.filter") as fev:
+                if residual and table.num_rows:
+                    table = filter_table(table, ir.and_all(residual))
+                if columns is not None and read_cols != list(columns):
+                    table = table.select([c for c in columns if c in table.column_names])
+            scan_report_mod.record_phase("filter", fev)
             sev.data.update(
                 filesScanned=len(scan.files), rowsOut=table.num_rows,
                 bytesScanned=scan.scanned.bytes_compressed,
             )
-            if token is not None:
-                rep = scan_report_mod.current_report()
-                if rep is not None:
+            rep = scan_report_mod.current_report() if token is not None else None
+            if rep is not None:
+                # what the operations plane costs a scan: the report and
+                # the journal record, as a span of their own
+                with telemetry.record_operation("delta.scan.report"):
                     rep.predicate = (ir.and_all(residual).sql()
                                      if residual else None)
                     rep.columns = list(columns) if columns is not None else None
@@ -764,11 +792,6 @@ def scan_to_table(
                     rep.files_after_partition = scan.partition.files or 0
                     rep.files_scanned = len(scan.files)
                     rep.rows_out = table.num_rows
-                    rep.phase_ms = {
-                        "planning": (t1 - t0) // 1_000_000,
-                        "read": (t2 - t1) // 1_000_000,
-                        "filter": (t3 - t2) // 1_000_000,
-                    }
                     rep_dict = rep.to_dict()
                     sev.data["scanReport"] = rep_dict
                     # workload journal: the same report dict plus the

@@ -119,6 +119,7 @@ COUNTERS = frozenset({
     "merge.device.declined",      # link cost model chose the host
     "merge.device.fallback",      # device path raised; host join took over
     "merge.device.cacheHit",      # engaged from an HBM-resident key lane
+    "merge.device.compiles",      # XLA compiles inside a MERGE's root span
     "merge.keyCache.builds",      # cold key-lane builds (inline or bg)
     "merge.keyCache.advances",    # incremental log-tail applications
     "merge.keyCache.invalidations",  # entries dropped by a rewrite epoch bump
@@ -195,6 +196,7 @@ ENGINE_COUNTERS = frozenset({
     "scan.device.engaged",
     "scan.device.declined",
     "scan.device.fallback",
+    "scan.device.compiles",
     "scan.prune.deviceFallback",
     "columnCache.hits",
     "columnCache.misses",
@@ -211,6 +213,16 @@ ENGINE_COUNTERS = frozenset({
     "stateCache.scan.fallback.lowering",
     "stateCache.scan.fallback.noentry",
     "stateCache.scan.fallback.version",
+    # -- the host-device link and the compiler, counted where they are
+    #    used (parallel/link.to_device / to_host, utils/jaxcache) ---------
+    "link.h2d.bytes",
+    "link.h2d.count",
+    "link.d2h.bytes",
+    "link.d2h.count",
+    "link.d2h.waitUs",
+    "device.compiles",
+    "device.compileUs",
+    "device.cacheFetches",
     "stateExport.statsLanes.struct",
     "stateExport.statsLanes.json",
     "stateExport.statsLanes.mixed",
@@ -267,7 +279,7 @@ PUBLIC_API = {
                "SEVERITY_RANK"),
     "scan_report": ("ScanReport", "last_scan_report", "clear_last_report",
                     "start_report", "current_report", "contribute",
-                    "record_rewrite_fired", "finish_report"),
+                    "record_phase", "record_rewrite_fired", "finish_report"),
     "server": ("ObsServer", "start_server", "stop_server"),
     "flight_recorder": ("install", "uninstall", "record_incident",
                         "incident_files"),
@@ -380,6 +392,7 @@ DESCRIPTIONS = {
     "merge.device.declined": "MERGEs where the cost model chose the host join.",
     "merge.device.fallback": "MERGEs (mode=auto) whose device path raised and fell back to the host join.",
     "merge.device.cacheHit": "Device MERGEs served from an HBM-resident key lane.",
+    "merge.device.compiles": "XLA compiles that ran with a delta.dml.merge span open on the compiling thread.",
     "merge.keyCache.builds": "Cold resident key-lane builds.",
     "merge.keyCache.advances": "Incremental log-tail applications to a key lane.",
     "merge.keyCache.invalidations": "Key-cache entries dropped by a rewrite epoch bump.",
@@ -445,6 +458,15 @@ DESCRIPTIONS = {
     "scan.device.engaged": "Scans whose residual mask was computed on device.",
     "scan.device.declined": "Scans where the cost model kept the residual on host.",
     "scan.device.fallback": "Device residual attempts that fell back to the host path.",
+    "scan.device.compiles": "XLA compiles that ran with a delta.scan span open on the compiling thread (a new literal or lane shape).",
+    "device.compiles": "XLA compiles in this process (persistent-cache fetches not counted).",
+    "device.compileUs": "Microseconds spent in those XLA compiles.",
+    "device.cacheFetches": "Executables fetched from the persistent compilation cache instead of compiled.",
+    "link.h2d.bytes": "Bytes uploaded host to device by the caches and kernels (exact, from nbytes).",
+    "link.h2d.count": "Host-to-device uploads (asynchronous: bytes and count only, no time).",
+    "link.d2h.bytes": "Bytes fetched device to host (exact, from nbytes).",
+    "link.d2h.count": "Blocking device-to-host fetches.",
+    "link.d2h.waitUs": "Wall microseconds in blocking fetches: the wait for the kernel that makes the array, then the copy.",
     "scan.prune.deviceFallback": "Device file prunes that raised and fell back to the host evaluator.",
     "columnCache.hits": "Scan column-cache lane hits (file, column resident).",
     "columnCache.misses": "Scan column-cache lane misses (cold decode).",

@@ -24,7 +24,7 @@ from typing import Any, Dict, List, Optional
 
 __all__ = ["ScanReport", "last_scan_report", "clear_last_report",
            "start_report", "current_report", "contribute",
-           "record_rewrite_fired", "finish_report"]
+           "record_phase", "record_rewrite_fired", "finish_report"]
 
 
 @dataclass
@@ -61,7 +61,9 @@ class ScanReport:
     #: the pure host path (declined / fallback / not attempted)
     device_residual: Optional[str] = None
     rows_out: int = 0
-    phase_ms: Dict[str, int] = field(default_factory=dict)
+    #: milliseconds in each phase's span (:func:`record_phase`): planning,
+    #: mask (device residual; absent on the host path), read, filter
+    phase_ms: Dict[str, float] = field(default_factory=dict)
     #: synthesized predicate rewrites (expr/synthesis) that excluded at
     #: least one file or row group this scan: {family, conjunct, rewrite}
     #: with shape fingerprints; one entry per (family, conjunct), matching
@@ -127,6 +129,17 @@ def contribute(**deltas: int) -> None:
         return
     for k, v in deltas.items():
         setattr(rep, k, getattr(rep, k) + v)
+
+
+def record_phase(key: str, span) -> None:
+    """One phase of the in-flight scan, timed by the span that covered it
+    (``delta.scan.planning``, ``.deviceMask``, ``.read``, ``.filter``):
+    milliseconds from the span's own ``duration_us``, so the report and the
+    trace cannot disagree. No report in flight (DML reads, blackout): no-op."""
+    rep = _CURRENT.get()
+    if rep is not None and span.duration_us is not None:
+        rep.phase_ms[key] = round(
+            rep.phase_ms.get(key, 0) + span.duration_us / 1000.0, 3)
 
 
 def record_rewrite_fired(family: str, conjunct: str, rewrite: str) -> None:

@@ -48,6 +48,8 @@ from delta_tpu.expr import ir, jaxeval
 from delta_tpu.expr.jaxeval import NotDeviceCompilable
 from delta_tpu.obs import hbm_ledger
 from delta_tpu.ops.state_cache import _next_pow2  # shared pad-size bucketing
+from delta_tpu.parallel import link
+from delta_tpu.utils import telemetry
 from delta_tpu.utils.config import conf
 from delta_tpu.utils.jaxcache import ensure_compilation_cache
 from delta_tpu.utils.jaxcompat import enable_x64
@@ -140,11 +142,9 @@ class ResidentColumn:
         self.last_used = 0
         self._lock = threading.Lock()
         self._account = hbm_ledger.Account("columnCache")
-        import jax
-
         with enable_x64():
-            self.values = jax.device_put(pv)
-            self.valid = jax.device_put(pm)
+            self.values = link.to_device(pv)
+            self.valid = link.to_device(pm)
         self._account.on(self, self.nbytes)
 
     @property
@@ -350,7 +350,7 @@ def _scalar_column(value: Any) -> jaxeval.DeviceColumn:
         arr = np.asarray(value, np.int64)
     else:  # floats included: compile_residual declines float partitions
         raise NotDeviceCompilable(f"partition value {value!r} has no lane form")
-    return jaxeval.DeviceColumn(jnp.asarray(arr), jnp.ones((), bool))
+    return jaxeval.DeviceColumn(link.to_device(arr), jnp.ones((), bool))
 
 
 def _ensure_lanes(cache: "ColumnCache", log_path: str, data_path: str, add,
@@ -372,31 +372,35 @@ def _ensure_lanes(cache: "ColumnCache", log_path: str, data_path: str, add,
             counters["misses"] += 1
     if not missing:
         return out
-    import pyarrow.parquet as pq
+    with telemetry.record_operation(
+            "delta.columnCache.load",
+            {"file": add.path, "columns": len(missing)}):
+        import pyarrow.parquet as pq
 
-    pf = pq.ParquetFile(_abs_data_path(data_path, add.path), memory_map=True)
-    present = {n.lower(): n for n in pf.schema_arrow.names}
-    stored = [present[c] for c in missing if c in present]
-    tbl = pf.read(columns=stored) if stored else None
-    n_rows = pf.metadata.num_rows
-    counters["coldBytes"] += sum(
-        pf.metadata.row_group(i).total_byte_size
-        for i in range(pf.metadata.num_row_groups)) if stored else 0
-    for c in missing:
-        if c in present:
-            lane = _lane_from_arrow(tbl.column(present[c]))
-            if lane is None:
-                return None
-            vals, valid, codes = lane
-        else:
-            # schema evolution: the file predates the column → all-NULL
-            vals = np.zeros(n_rows, np.int64)
-            valid = np.zeros(n_rows, bool)
-            codes = None
-        entry = ResidentColumn(log_path, add.path, c, vals, valid, codes,
-                               epoch)
-        cache.register(entry)  # epoch race → served uncached, still exact
-        out[c] = entry
+        pf = pq.ParquetFile(_abs_data_path(data_path, add.path),
+                            memory_map=True)
+        present = {n.lower(): n for n in pf.schema_arrow.names}
+        stored = [present[c] for c in missing if c in present]
+        tbl = pf.read(columns=stored) if stored else None
+        n_rows = pf.metadata.num_rows
+        counters["coldBytes"] += sum(
+            pf.metadata.row_group(i).total_byte_size
+            for i in range(pf.metadata.num_row_groups)) if stored else 0
+        for c in missing:
+            if c in present:
+                lane = _lane_from_arrow(tbl.column(present[c]))
+                if lane is None:
+                    return None
+                vals, valid, codes = lane
+            else:
+                # schema evolution: the file predates the column → all-NULL
+                vals = np.zeros(n_rows, np.int64)
+                valid = np.zeros(n_rows, bool)
+                codes = None
+            entry = ResidentColumn(log_path, add.path, c, vals, valid, codes,
+                                   epoch)
+            cache.register(entry)  # epoch race → served uncached, still exact
+            out[c] = entry
     return out
 
 
@@ -430,7 +434,6 @@ def device_residual_masks(snapshot, files, predicate) -> Optional[Dict[str, np.n
     if not plan.refs:
         return None  # partition-only residual: file pruning already exact
     from delta_tpu.obs import router_audit, scan_report
-    from delta_tpu.parallel import link
 
     est_rows = sum(max((f.size or 0) // 64, 1024) for f in files)
     ncols = max(len(plan.refs), 1)
@@ -483,7 +486,9 @@ def device_residual_masks(snapshot, files, predicate) -> Optional[Dict[str, np.n
                     lowered = {k.lower(): v for k, v in typed.items()}
                     for c in plan.part_refs:
                         env[c] = _scalar_column(lowered.get(c))
-                masks[add.path] = np.asarray(kernel(env))[:n]
+                with telemetry.record_operation("delta.columnCache.mask",
+                                                {"rows": n}):
+                    masks[add.path] = link.to_host(kernel(env))[:n]
     except NotDeviceCompilable:
         bump_counter("scan.device.fallback")
         return None
@@ -493,13 +498,15 @@ def device_residual_masks(snapshot, files, predicate) -> Optional[Dict[str, np.n
         # kernel the chip refuses must not read as a scan the device served
         if mode == "force":
             raise
-        from delta_tpu.utils import telemetry
-
         bump_counter("scan.device.fallback")
         telemetry.add_span_data(deviceError=telemetry.exc_text(e))
         return None
     actual_s = time.perf_counter() - t0
     bump_counter("scan.device.engaged")
+    # onto the caller's span (`delta.scan.deviceMask` under a scan)
+    telemetry.add_span_data(files=len(files), hits=counters["hits"],
+                            misses=counters["misses"],
+                            coldBytes=counters["coldBytes"])
     if counters["hits"]:
         bump_counter("columnCache.hits", counters["hits"])
     if counters["misses"]:

@@ -57,6 +57,7 @@ hash join.
 from __future__ import annotations
 
 import functools
+from delta_tpu.parallel import link
 from delta_tpu.utils.jaxcache import ensure_compilation_cache
 from delta_tpu.utils.jaxcompat import enable_x64
 import threading
@@ -316,8 +317,6 @@ def inner_join_async(
         cap_s = _bucket((m + p - 1) // p) * p
 
     if budget_s is not None:
-        from delta_tpu.parallel import link
-
         itemsize = np.dtype(kdtype).itemsize
         est = link.estimate_device_s(
             up_bytes=(cap_t + cap_s) * itemsize,
@@ -341,7 +340,7 @@ def inner_join_async(
             with enable_x64():
                 if p == 1:
                     kernel = _single_device_kernel_cached()
-                    args = [jax.device_put(t_in), jax.device_put(s_in)]
+                    args = [link.to_device(t_in), link.to_device(s_in)]
                     state["out"] = kernel(*args)
                 else:
                     from delta_tpu.parallel.mesh import STATE_AXIS
@@ -365,9 +364,9 @@ def inner_join_async(
         if "err" in state:
             raise state["err"]
         t_bits, s_bits, multi = state["out"]
-        t_matched = np.unpackbits(np.asarray(t_bits).reshape(-1))[:n].astype(bool)
-        s_matched = np.unpackbits(np.asarray(s_bits).reshape(-1))[:m].astype(bool)
-        any_multi = bool(multi)
+        t_matched = np.unpackbits(link.to_host(t_bits).reshape(-1))[:n].astype(bool)
+        s_matched = np.unpackbits(link.to_host(s_bits).reshape(-1))[:m].astype(bool)
+        any_multi = bool(link.to_host(multi))
         t_first_s = np.full(n, -1, np.int64)
         idx = np.flatnonzero(t_matched)
         if idx.size:

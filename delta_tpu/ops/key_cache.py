@@ -44,6 +44,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from delta_tpu.parallel import link
+from delta_tpu.utils import telemetry
 from delta_tpu.utils.jaxcache import ensure_compilation_cache
 from delta_tpu.utils.jaxcompat import enable_x64
 from delta_tpu.utils.config import conf
@@ -633,61 +635,65 @@ class ResidentJoinKeys:
         the transfer engine and overlap, and no single transfer stalls the
         process for the whole slab)."""
         ensure_compilation_cache()
-        import jax
-        import jax.numpy as jnp
-
         with self._lock:
             if self._dev is not None:
                 return
-            keys = np.zeros(self.capacity, np.int64)
-            keys[: self.num_rows] = self.h_keys
-            valid = np.zeros(self.capacity, bool)
-            valid[: self.num_rows] = self.h_valid
-            # halve the big transfer when every key fits int32: ship
-            # narrow, cast up on device. Invalid/null rows store 0, so a raw
-            # min/max scan is the exact narrowing test.
-            narrow = (self.num_rows == 0 or (
-                int(keys.min()) >= np.iinfo(np.int32).min
-                and int(keys.max()) <= np.iinfo(np.int32).max))
-            # ~32MB tiles amortize the per-transfer overhead without any
-            # single transfer stalling the process for the whole slab (tile
-            # counts are in ELEMENTS, derived from the byte budget per dtype)
-            tile_bytes = 32 << 20
-            with enable_x64():
-                def ship(arr):
-                    step = max(tile_bytes // arr.itemsize, 1)
-                    if len(arr) <= step:
-                        return jax.device_put(arr)
-                    return jnp.concatenate([
-                        jax.device_put(arr[i:i + step])
-                        for i in range(0, len(arr), step)
-                    ])
-
-                if narrow:
-                    dk = _update_kernels()["widen"](ship(keys.astype(np.int32)))
-                else:
-                    dk = ship(keys)
-                dv = ship(valid)
-                jax.block_until_ready((dk, dv))
-            self._dev = {"keys": dk, "valid": dv}
+            # the bytes land on the span as h2dBytes (link.to_device)
+            with telemetry.record_operation(
+                    "delta.keyCache.upload", {"rows": self.num_rows}):
+                self._dev = self._ship_mirrors()
             self._sort_stale = True
             self._hbm.on(self, self.device_bytes)
+
+    def _ship_mirrors(self) -> Dict[str, object]:
+        import jax
+        import jax.numpy as jnp
+
+        keys = np.zeros(self.capacity, np.int64)
+        keys[: self.num_rows] = self.h_keys
+        valid = np.zeros(self.capacity, bool)
+        valid[: self.num_rows] = self.h_valid
+        # halve the big transfer when every key fits int32: ship
+        # narrow, cast up on device. Invalid/null rows store 0, so a raw
+        # min/max scan is the exact narrowing test.
+        narrow = (self.num_rows == 0 or (
+            int(keys.min()) >= np.iinfo(np.int32).min
+            and int(keys.max()) <= np.iinfo(np.int32).max))
+        # ~32MB tiles amortize the per-transfer overhead without any
+        # single transfer stalling the process for the whole slab (tile
+        # counts are in ELEMENTS, derived from the byte budget per dtype)
+        tile_bytes = 32 << 20
+        with enable_x64():
+            def ship(arr):
+                step = max(tile_bytes // arr.itemsize, 1)
+                if len(arr) <= step:
+                    return link.to_device(arr)
+                return jnp.concatenate([
+                    link.to_device(arr[i:i + step])
+                    for i in range(0, len(arr), step)
+                ])
+
+            if narrow:
+                dk = _update_kernels()["widen"](ship(keys.astype(np.int32)))
+            else:
+                dk = ship(keys)
+            dv = ship(valid)
+            jax.block_until_ready((dk, dv))
+        return {"keys": dk, "valid": dv}
 
     def _ensure_sorted(self) -> None:
         """Dispatch the slab sort if the sorted view is stale (caller holds
         the entry lock). The dispatch is async (~ms); the probe kernel that
         consumes the handles queues behind it on the device."""
-        import jax
-        import jax.numpy as jnp
-
         if self._dev is None:
             return
         if not self._sort_stale and "sorted_keys" in self._dev:
             return
-        with enable_x64():
+        with telemetry.record_operation(
+                "delta.keyCache.sort", {"rows": self.num_rows}), enable_x64():
             sk, pm, inv, sv = _sort_kernel()(
                 self._dev["keys"], self._dev["valid"],
-                jnp.asarray(np.int32(self.num_rows)))
+                link.to_device(np.int32(self.num_rows)))
         self._dev["sorted_keys"] = sk
         self._dev["perm"] = pm
         self._dev["inv_perm"] = inv
@@ -698,13 +704,11 @@ class ResidentJoinKeys:
         """Validity flip in ROW space plus, when the sorted view is live,
         the mirrored flip in SORTED space via the resident inverse
         permutation (a k-row gather+scatter — never an O(n) rebuild)."""
-        import jax.numpy as jnp
-
         d = _next_pow2(max(len(rows), 1), floor=64)
         padded = np.full(d, self.capacity, np.int32)
         padded[: len(rows)] = rows
         kern = _update_kernels()["kill" if not value else "revive"]
-        rows_dev = jnp.asarray(padded)
+        rows_dev = link.to_device(padded)
         self._dev["valid"] = kern(self._dev["valid"], rows_dev)
         if not self._sort_stale and "sorted_valid" in self._dev:
             spos = _update_kernels()["map_rows"](
@@ -720,9 +724,6 @@ class ResidentJoinKeys:
 
     def _dev_scatter_rows(self, row_idx: np.ndarray, keys: np.ndarray,
                           valid: np.ndarray) -> None:
-        import jax
-        import jax.numpy as jnp
-
         k = len(keys)
         a = _next_pow2(max(k, 1), floor=64)
         i32 = np.iinfo(np.int32)
@@ -741,15 +742,15 @@ class ResidentJoinKeys:
         # key rows changed: the sorted view lags; drop it (frees HBM) and
         # let the next probe re-sort
         self._sort_stale = True
-        for k in ("sorted_keys", "perm", "inv_perm", "sorted_valid"):
-            self._dev.pop(k, None)
+        for view in ("sorted_keys", "perm", "inv_perm", "sorted_valid"):
+            self._dev.pop(view, None)
         with enable_x64():
             if contiguous:
                 self._dev["keys"], self._dev["valid"] = (
                     _update_kernels()["slice_append"](
                         self._dev["keys"], self._dev["valid"],
-                        jnp.asarray(np.int32(row_idx[0])),
-                        jnp.asarray(nk), jnp.asarray(nv),
+                        link.to_device(np.int32(row_idx[0])),
+                        link.to_device(nk), link.to_device(nv),
                     )
                 )
                 return
@@ -757,7 +758,7 @@ class ResidentJoinKeys:
             rows[:k] = row_idx
             self._dev["keys"], self._dev["valid"] = _update_kernels()["append"](
                 self._dev["keys"], self._dev["valid"],
-                jnp.asarray(rows), jnp.asarray(nk), jnp.asarray(nv),
+                link.to_device(rows), link.to_device(nk), link.to_device(nv),
             )
 
     # -- probing ----------------------------------------------------------
@@ -780,9 +781,6 @@ class ResidentJoinKeys:
         the entry lock for its whole multi-step application, so under the
         lock the slab is either fully at the caller's version or fully past
         it — never half-advanced. Past it → None (caller falls back)."""
-        import jax
-        import jax.numpy as jnp
-
         from delta_tpu.ops.join_kernel import _bucket
 
         with self._lock:
@@ -834,7 +832,6 @@ class ResidentJoinKeys:
         s_in[:m] = s_enc
         state: dict = {}
         from delta_tpu.obs import hbm_ledger
-        from delta_tpu.utils import telemetry
 
         # transient probe scratch (the uploaded source lane) in the HBM
         # ledger while the probe is in flight; released on the staging
@@ -865,9 +862,9 @@ class ResidentJoinKeys:
                     with enable_x64():
                         head_dev, t_match_dev, s_first_dev = _probe_sorted_kernel()(
                             dev["sorted_keys"], dev["sorted_valid"],
-                            jnp.asarray(np.int32(n)), jax.device_put(s_in),
+                            link.to_device(np.int32(n)), link.to_device(s_in),
                         )
-                        head = np.asarray(head_dev)  # blocks until kernel done
+                        head = link.to_host(head_dev)  # blocks until kernel done
                         state["head"] = head
                         _multi, overflow, mc, _s = _decode_head(head, cap_s, m)
                         if overflow or insert_only or mc == 0:
@@ -901,7 +898,7 @@ class ResidentJoinKeys:
             if mc == 0:
                 empty = np.empty(0, np.int64)
                 return PhysicalProbe(s, multi, slabs, n, (empty, empty))
-            pairs = np.asarray(state["pairs_dev"])
+            pairs = link.to_host(state["pairs_dev"])
             phys = pairs[0, :mc].astype(np.int64)
             srows = pairs[1, :mc].astype(np.int64)
             order = np.argsort(phys, kind="stable")
@@ -1235,7 +1232,12 @@ class KeyCache:
                 e.last_used = tick
                 return e
             if e is not None:
-                if self._advance(e, snapshot, key_cols, exprs):
+                with telemetry.record_operation(
+                        "delta.keyCache.advance",
+                        {"fromVersion": e.version,
+                         "toVersion": snapshot.version}):
+                    advanced = self._advance(e, snapshot, key_cols, exprs)
+                if advanced:
                     bump_counter("merge.keyCache.advances")
                 else:
                     # a failed advance may have half-applied its tail: the

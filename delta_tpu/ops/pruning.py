@@ -579,6 +579,9 @@ def files_for_scan(
         )
     # unmeasured (telemetry blackout) or a bare snapshot shim (tests prune
     # synthetic file lists with no DeltaLog behind them): skip the series
+    from delta_tpu.obs import scan_report
+
+    scan_report.record_phase("planning", pev)
     delta_log = getattr(snapshot, "delta_log", None)
     if pev.duration_us is not None and delta_log is not None:
         from delta_tpu.obs.fleet import table_label

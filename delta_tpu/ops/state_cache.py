@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from delta_tpu.expr import ir
+from delta_tpu.parallel import link
 from delta_tpu.utils.config import conf
 from delta_tpu.utils.jaxcache import ensure_compilation_cache
 
@@ -457,8 +458,6 @@ class ResidentState:
         return out
 
     def _build_device(self, shards: int = 1) -> None:
-        import jax.numpy as jnp
-
         mins = self._pad2(_f32_down(self.h_lo), np.nan)
         maxs = self._pad2(_f32_up(self.h_hi), np.nan)
         alive = np.zeros(self.capacity, bool)
@@ -468,8 +467,6 @@ class ResidentState:
             # sharded residency: lanes split along the file axis over the
             # 1-D state mesh, so the shard_map plan kernel reads its slice
             # locally — each device's slice accounts under ITS ledger entry
-            import jax
-
             from delta_tpu.parallel.mesh import (NamedSharding, P,
                                                  state_mesh)
             from delta_tpu.parallel.mesh import STATE_AXIS as _AX
@@ -478,17 +475,17 @@ class ResidentState:
             lane = NamedSharding(mesh, P(None, _AX))
             flat = NamedSharding(mesh, P(_AX))
             self._dev = {
-                "mins": jax.device_put(mins, lane),
-                "maxs": jax.device_put(maxs, lane),
-                "alive": jax.device_put(alive, flat),
+                "mins": link.to_device(mins, lane),
+                "maxs": link.to_device(maxs, lane),
+                "alive": link.to_device(alive, flat),
             }
             per = self.device_bytes // shards
             per_device = {i: per for i in range(shards)}
         else:
             self._dev = {
-                "mins": jnp.asarray(mins),
-                "maxs": jnp.asarray(maxs),
-                "alive": jnp.asarray(alive),
+                "mins": link.to_device(mins),
+                "maxs": link.to_device(maxs),
+                "alive": link.to_device(alive),
             }
         self._dev_shards = shards
         self._hbm.on(self, self.device_bytes, per_device=per_device)
@@ -644,8 +641,6 @@ class ResidentState:
         Shapes are bucketed (pow2 pads; out-of-range scatter indices use
         XLA drop semantics) so a steady commit stream reuses a handful of
         compiled executables."""
-        import jax.numpy as jnp
-
         dev = self._dev
         cap = self.capacity
         d = _next_pow2(max(len(dead_rows), 1), floor=8)
@@ -658,10 +653,11 @@ class ResidentState:
         hi32 = np.full((self.h_hi.shape[0], a), np.nan, np.float32)
         lo32[:, :k] = _f32_down(add_lo)
         hi32[:, :k] = _f32_up(add_hi)
-        dev["alive"] = _scatter_bool(dev["alive"], jnp.asarray(dead), False)
-        dev["alive"] = _scatter_bool(dev["alive"], jnp.asarray(rows), True)
-        dev["mins"] = _scatter_cols(dev["mins"], jnp.asarray(rows), jnp.asarray(lo32))
-        dev["maxs"] = _scatter_cols(dev["maxs"], jnp.asarray(rows), jnp.asarray(hi32))
+        rows_dev = link.to_device(rows)
+        dev["alive"] = _scatter_bool(dev["alive"], link.to_device(dead), False)
+        dev["alive"] = _scatter_bool(dev["alive"], rows_dev, True)
+        dev["mins"] = _scatter_cols(dev["mins"], rows_dev, link.to_device(lo32))
+        dev["maxs"] = _scatter_cols(dev["maxs"], rows_dev, link.to_device(hi32))
 
     # -- serving ----------------------------------------------------------
 
@@ -773,8 +769,6 @@ class ResidentState:
         the calibratable per-shard constants, so the audit record carries
         the sharded-vs-single decision. Constants read through
         ``link.constant`` so calibration feeds back."""
-        from delta_tpu.parallel import link
-
         cells = m * self.num_rows * max(len(self.columns), 1)
         host_s = cells * link.constant("HOST_PRUNE_S_PER_CELL")
         p = link.profile()
@@ -823,8 +817,6 @@ class ResidentState:
             return 1
         if conf.get("delta.tpu.distributed.plan.mode", "auto") == "force":
             return s
-        from delta_tpu.parallel import link
-
         if priced is not None:
             device_s, _h, _c, fixed_s, sharded_s, shards = priced
             return shards if (sharded_s is not None
@@ -889,8 +881,6 @@ class ResidentState:
         axis, and the identical host fine pass finishes — so sharded
         results equal single-device results equal host results exactly,
         by construction."""
-        import jax.numpy as jnp
-
         self.ensure_resident(shards)
         m = lo.shape[0]
         mb = _next_pow2(m, floor=8)  # bucket the query-batch dim too
@@ -905,17 +895,17 @@ class ResidentState:
                 telemetry.bump_counter("dist.plan.sharded")
                 bl = _sharded_block_kernel(
                     self._dev["mins"], self._dev["maxs"], self._dev["alive"],
-                    jnp.asarray(lo_p), jnp.asarray(hi_p), BLOCK,
+                    link.to_device(lo_p), link.to_device(hi_p), BLOCK,
                     self._dev_shards,
                 )
-                blocks = np.asarray(bl)[:m].astype(bool)
+                blocks = link.to_host(bl)[:m].astype(bool)
             else:
                 bits = _block_kernel(
                     self._dev["mins"], self._dev["maxs"], self._dev["alive"],
-                    jnp.asarray(lo_p), jnp.asarray(hi_p), BLOCK,
+                    link.to_device(lo_p), link.to_device(hi_p), BLOCK,
                 )
                 n_blocks = self.capacity // BLOCK
-                blocks = np.unpackbits(np.asarray(bits)[:m], axis=1,
+                blocks = np.unpackbits(link.to_host(bits)[:m], axis=1,
                                        count=n_blocks)
         except Exception as e:  # noqa: BLE001 — degradation ladder, first
             # rung: a shard_map/lowering failure (mesh reshape race, OOM on

@@ -26,6 +26,7 @@ import time
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
+from delta_tpu.utils import telemetry
 from delta_tpu.utils.jaxcache import ensure_compilation_cache
 
 __all__ = [
@@ -41,7 +42,7 @@ __all__ = [
     "host_residual_filter_s", "device_residual_mask_s",
     "sharded_plan_device_s", "dist_execute_s",
     "CALIBRATABLE", "constant", "set_calibrated", "calibrated_constants",
-    "clear_calibrated",
+    "clear_calibrated", "to_device", "to_host",
 ]
 
 _PROBE_BYTES = 1 << 20  # 1 MB
@@ -148,6 +149,46 @@ def calibrated_constants() -> dict:
 def clear_calibrated() -> None:
     """Back to module defaults (tests, `calibration.reset`)."""
     _calibrated.clear()
+
+
+# -- counted transfers ---------------------------------------------------------
+#
+# What actually crossed the link, counted where it crosses: the device
+# caches and kernels move their operands through this pair, so `/metrics`
+# and the open span say how many bytes a request shipped each way.
+
+
+def to_device(host, sharding=None):
+    """``jax.device_put(host)``, counted: ``link.h2d.bytes`` (exact: the
+    device array's ``nbytes``) and ``link.h2d.count``, and ``h2dBytes`` on
+    the innermost open span. As asynchronous as ``device_put`` is — nothing
+    here waits for the copy, so an upload has bytes and a count, no time."""
+    import jax
+
+    out = jax.device_put(host, sharding)
+    nbytes = int(out.nbytes)
+    telemetry.bump_counter("link.h2d.bytes", nbytes)
+    telemetry.bump_counter("link.h2d.count")
+    telemetry.add_span_counts(h2dBytes=nbytes)
+    return out
+
+
+def to_host(dev):
+    """``np.asarray(dev)`` of a device array, counted: ``link.d2h.bytes``,
+    ``link.d2h.count``, ``d2hBytes`` on the innermost open span, and
+    ``link.d2h.waitUs``, the wall time of this blocking fetch — which holds
+    the wait for the kernel that makes ``dev`` before the copy itself."""
+    import numpy as np
+
+    t0 = time.perf_counter_ns()
+    out = np.asarray(dev)
+    wait_us = (time.perf_counter_ns() - t0) // 1000
+    nbytes = int(out.nbytes)
+    telemetry.bump_counter("link.d2h.bytes", nbytes)
+    telemetry.bump_counter("link.d2h.count")
+    telemetry.bump_counter("link.d2h.waitUs", wait_us)
+    telemetry.add_span_counts(d2hBytes=nbytes)
+    return out
 
 
 def resident_probe_device_s(n: int, m: int, p: "LinkProfile") -> float:

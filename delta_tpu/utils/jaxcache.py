@@ -18,6 +18,13 @@ Placement, in order:
 Every module that jits passes :func:`ensure_compilation_cache` before its
 first compile. A directory that cannot be created is an error naming the
 path, never a silent in-memory cache.
+
+The same first pass registers one ``jax.monitoring`` listener, so that a
+compile is counted where it happens: ``device.compiles`` / ``device.compileUs``
+for every XLA compile, ``scan.device.compiles`` / ``merge.device.compiles`` by
+the request span open on the compiling thread, ``compiles`` / ``compileMs`` in
+the innermost open span's data. An executable fetched from the persistent
+cache is no compile: it counts under ``device.cacheFetches``.
 """
 from __future__ import annotations
 
@@ -29,6 +36,36 @@ __all__ = ["ensure_compilation_cache", "cache_dir"]
 _ENV = "JAX_COMPILATION_CACHE_DIR"
 _done = False
 _lock = threading.Lock()
+
+# JAX times `compile_or_get_cached` as a whole under the first name, and a
+# hit in the persistent cache under the second, inside it and before it ends
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_FETCH_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_thread = threading.local()
+
+
+def _on_duration(event: str, secs: float, **_kw) -> None:
+    from delta_tpu.utils import telemetry
+
+    if event == _FETCH_EVENT:
+        _thread.fetched = True
+        telemetry.bump_counter("device.cacheFetches")
+        return
+    if event != _COMPILE_EVENT:
+        return
+    if getattr(_thread, "fetched", False):
+        _thread.fetched = False  # the fetch's own enclosing event
+        return
+    telemetry.bump_counter("device.compiles")
+    telemetry.bump_counter("device.compileUs", int(secs * 1e6))
+    for span in telemetry.open_spans():  # outermost first: the request
+        if span.op_type == "delta.scan":
+            telemetry.bump_counter("scan.device.compiles")
+            break
+        if span.op_type == "delta.dml.merge":
+            telemetry.bump_counter("merge.device.compiles")
+            break
+    telemetry.add_span_counts(compiles=1, compileMs=round(secs * 1e3, 3))
 
 
 def cache_dir() -> str:
@@ -60,4 +97,7 @@ def ensure_compilation_cache() -> None:
             import jax
 
             jax.config.update("jax_compilation_cache_dir", path)
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
         _done = True

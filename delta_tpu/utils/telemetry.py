@@ -9,10 +9,13 @@ backend is a no-op stub. Here the backend is real, in three pieces:
    parent stack, so ``delta.commit`` contains its ``prepare`` /
    ``conflictCheck`` / ``write`` / ``postCommit`` phases and a scan contains
    its planning/prune phases. Spans export as Chrome trace-event JSON
-   (:func:`export_chrome_trace`) loadable in Perfetto / ``chrome://tracing``
-   alongside the ``jax.named_scope`` annotations each span also opens, so
-   device timelines line up with engine operations. Contextvars give each
-   thread its own stack: concurrent writers never parent each other's spans.
+   (:func:`export_chrome_trace`) loadable in Perfetto / ``chrome://tracing``.
+   Each span also opens a ``jax.profiler.TraceAnnotation`` of its name:
+   while a profiler session is open (``jax.profiler.trace``) the span is a
+   host event of the ``.xplane.pb`` itself, on the profiler's clock beside
+   the device planes; with no session open that is a flag test. Contextvars
+   give each thread its own stack: concurrent writers never parent each
+   other's spans.
 
 2. **A metrics registry** — monotonic counters (:func:`bump_counter`),
    gauges (:func:`set_gauge`) and fixed log2-bucket latency histograms
@@ -76,7 +79,8 @@ __all__ = [
     "remove_failure_hook", "span_context", "adopt_span_context", "propagated",
     "histogram_rows", "bucket_quantile", "drop_labeled_series",
     "current_trace_id", "last_sampled_trace_id", "add_span_sink",
-    "remove_span_sink", "TRACEPARENT_ENV", "exc_text",
+    "remove_span_sink", "TRACEPARENT_ENV", "exc_text", "open_spans",
+    "add_span_counts", "span_stages",
 ]
 
 
@@ -355,9 +359,9 @@ def record_event(op_type: str, data: Optional[Dict[str, Any]] = None, **tags: st
 @contextlib.contextmanager
 def record_operation(op_type: str, data: Optional[Dict[str, Any]] = None, **tags: str) -> Iterator[UsageEvent]:
     """Wrap an operation in a span: duration + error capture + parent/child
-    nesting + JAX profiler annotation. The yielded event is live — mutate
-    ``ev.data`` (or call :func:`add_span_data` from anywhere below) to attach
-    payloads before the span closes."""
+    nesting + a host event in an open JAX profiler session. The yielded
+    event is live — mutate ``ev.data`` (or call :func:`add_span_data` from
+    anywhere below) to attach payloads before the span closes."""
     if not _enabled():
         # zero-overhead: no span bookkeeping, no buffer append, no timing
         yield UsageEvent(op_type, 0, data=dict(data or {}))
@@ -430,6 +434,16 @@ def current_span() -> Optional[UsageEvent]:
         return None
     with _LOCK:
         return _ACTIVE.get(stack[-1])
+
+
+def open_spans() -> List[UsageEvent]:
+    """The open span chain of THIS context, outermost first — the live
+    events themselves (``op_type`` is fixed; ``data`` still mutates)."""
+    stack = _SPAN_STACK.get()
+    if not stack:
+        return []
+    with _LOCK:
+        return [ev for ev in map(_ACTIVE.get, stack) if ev is not None]
 
 
 def span_stack_snapshot() -> List[Dict[str, Any]]:
@@ -579,6 +593,49 @@ def add_span_data(**kv: Any) -> None:
         ev.data.update(kv)
 
 
+def add_span_counts(**kv: float) -> None:
+    """Add each value to the number the innermost open span's data holds
+    under that key (absent counts as 0) — tallies that several calls below
+    one span contribute to: bytes moved over the link, compiles."""
+    ev = current_span()
+    if ev is not None:
+        data = ev.data
+        for k, v in kv.items():
+            data[k] = data.get(k, 0) + v
+
+
+@contextlib.contextmanager
+def span_stages() -> Iterator[Any]:
+    """Consecutive child spans that tile the enclosing one. Yields
+    ``enter(op_type, data=None)``: it closes the stage that is open, opens
+    the next and returns its live event (entering the stage that is open
+    already changes nothing, so a helper and its caller may both name it).
+    The last stage closes with the block; an exception closes the open
+    stage with the error on it."""
+    cm: Any = None
+    ev: Optional[UsageEvent] = None
+
+    def enter(op_type: str, data: Optional[Dict[str, Any]] = None) -> UsageEvent:
+        nonlocal cm, ev
+        if ev is not None and ev.op_type == op_type:
+            return ev
+        if cm is not None:
+            cm.__exit__(None, None, None)
+        cm = record_operation(op_type, data)
+        ev = cm.__enter__()
+        return ev
+
+    try:
+        yield enter
+    except BaseException as e:
+        if cm is not None:
+            cm.__exit__(type(e), e, e.__traceback__)  # re-raised below
+        raise
+    else:
+        if cm is not None:
+            cm.__exit__(None, None, None)
+
+
 def exc_text(e: BaseException) -> str:
     """An exception as it rides an event payload: ``Type: message[:300]``."""
     return f"{type(e).__name__}: {str(e)[:300]}"
@@ -597,16 +654,16 @@ def with_status(message: str, **tags: str) -> Iterator[None]:
         yield
 
 
-def _maybe_jax_trace(name: str):
-    try:
-        import sys
+_NO_TRACE = contextlib.nullcontext()
 
-        jax = sys.modules.get("jax")
-        if jax is not None:
-            return jax.named_scope(name.replace("delta.", "delta/"))
-    except Exception:  # noqa: BLE001
-        pass
-    return contextlib.nullcontext()
+
+def _maybe_jax_trace(name: str):
+    """The span as a host event of an open profiler session. jax is never
+    imported for this: a process that has not loaded it has no session."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return _NO_TRACE
+    return profiler.TraceAnnotation(name)
 
 
 def _prefix_match(name: str, prefix: str) -> bool:
@@ -938,9 +995,12 @@ def export_chrome_trace(path: Optional[str] = None, op_prefix: str = "",
     to "now" and ``args.incomplete = true`` — an export taken mid-operation
     must show the operation, not silently drop it. Thread-name metadata rows
     keep multi-writer traces readable. Load the result in
-    https://ui.perfetto.dev or ``chrome://tracing``; with the JAX profiler
-    active, span names also appear as ``delta/...`` named scopes on the
-    device timeline.
+    https://ui.perfetto.dev or ``chrome://tracing``. ``metadata.clock``
+    holds ``perf_counter_ns`` and ``time_ns`` read together at export:
+    ``ts`` is ``perf_counter`` microseconds, so the pair lays the file
+    against any timeline on the epoch clock (a JAX profile names its own
+    start; under an open profiler session the spans are in that profile
+    already, see the module docstring).
 
     ``op_prefix`` keeps only ops on a dotted-name boundary match
     (``delta.commit`` matches ``delta.commit.*``); ``limit`` keeps only the
@@ -1039,7 +1099,9 @@ def export_chrome_trace(path: Optional[str] = None, op_prefix: str = "",
             "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
             "args": {"name": tname},
         })
-    trace = {"traceEvents": rows, "displayTimeUnit": "ms"}
+    trace = {"traceEvents": rows, "displayTimeUnit": "ms",
+             "metadata": {"clock": {"perf_counter_ns": time.perf_counter_ns(),
+                                    "time_ns": time.time_ns()}}}
     if path is not None:
         with open(path, "w", encoding="utf-8") as f:
             json.dump(trace, f, default=str)

@@ -227,7 +227,8 @@ def test_scan_report_matches_rowgroup_counters_exactly(tmp_table):
     assert rep.files_scanned == c.get("scan.files.read", 0) == 1
     assert rep.rows_out == 1500
     assert rep.predicate == "(id < 1500)"
-    assert set(rep.phase_ms) == {"planning", "read", "filter"}
+    # "mask" is there too whenever the device residual route was priced
+    assert set(rep.phase_ms) - {"mask"} == {"planning", "read", "filter"}
     assert rep.version == t.delta_log.update().version
     json.dumps(rep.to_dict())
 

@@ -487,7 +487,8 @@ def test_merge_produces_span_tree_stats_prometheus_and_trace(tmp_table, tmp_path
      .when_matched_update_all().when_not_matched_insert_all().execute())
 
     # 1. nested span tree: merge -> commit -> {prepare, write, postCommit}
-    [merge] = telemetry.recent_events("delta.dml.merge")
+    [merge] = [e for e in telemetry.recent_events("delta.dml.merge")
+               if e.op_type == "delta.dml.merge"]  # its phases match the prefix too
     commits = [e for e in telemetry.recent_events("delta.commit")
                if e.op_type == "delta.commit" and e.parent_id == merge.span_id]
     assert commits, "delta.commit span must nest under delta.dml.merge"
@@ -672,3 +673,312 @@ def test_obs_public_api_matches_catalog():
         assert tuple(sorted(m.__all__)) == tuple(
             sorted(metric_names.PUBLIC_API[mod])
         ), f"obs/{mod}.py __all__ out of sync with PUBLIC_API"
+
+
+# -- phases as spans, link and compile counters (ISSUE 25) --------------------
+
+MERGE_PHASES = {  # span -> the phase_ms key it fills
+    "delta.dml.merge.analyze": "analyze_ms",
+    "delta.dist.mergeProbe": "probe_ms",
+    "delta.dml.merge.keyDecode": "key_decode_ms",
+    "delta.dml.merge.rowDecode": "decode_ms",
+    "delta.dml.merge.join": "join_ms",
+    "delta.dml.merge.apply": "apply_ms",
+    "delta.dml.merge.deletionVectors": "dv_ms",
+    "delta.dml.merge.write": "write_ms",
+    "delta.dml.merge.residentKeys": "resident_ms",
+}
+DEVICE = {"delta.tpu.merge.devicePath.mode": "force",
+          "delta.tpu.read.deviceResidual.mode": "force"}
+
+
+def _covered_us(intervals):
+    total, end = 0, None
+    for a, b in sorted(intervals):
+        if end is None or a > end:
+            total += b - a
+            end = b
+        elif b > end:
+            total += b - end
+            end = b
+    return total
+
+
+def _cover(events, root):
+    lo, hi = root.start_us, root.start_us + root.duration_us
+    inside = [(e.start_us, min(e.start_us + e.duration_us, hi))
+              for e in events if e is not root and e.duration_us is not None
+              and lo <= e.start_us < hi]
+    return _covered_us(inside) / (hi - lo)
+
+
+def _keyed_table(path, rows=8000, files=8):
+    import numpy as np
+
+    data = pa.table({
+        "k": pa.array(np.arange(rows, dtype=np.int64)),
+        "d": pa.array((np.arange(rows) % 500).astype(np.int32)),
+        "q": pa.array((np.arange(rows) % 97).astype(np.int32)),
+    })
+    with conf.set_temporarily(**{"delta.tpu.write.targetFileRows": rows // files}):
+        return DeltaTable.create(
+            str(path), data=data,
+            configuration={"delta.tpu.enableDeletionVectors": "true"})
+
+
+def _upsert(table, lo, hi):
+    """MERGE ``k`` in [lo, hi) into the table through the command the public
+    builder runs; returns the command (for ``phase_ms``)."""
+    import numpy as np
+
+    from delta_tpu.commands.merge import MergeClause, MergeIntoCommand
+
+    n = hi - lo
+    src = pa.table({"k": pa.array(np.arange(lo, hi, dtype=np.int64)),
+                    "d": pa.array(np.zeros(n, np.int32)),
+                    "q": pa.array(np.zeros(n, np.int32))})
+    cmd = MergeIntoCommand(
+        table.delta_log, src, "t.k = s.k",
+        [MergeClause("update", assignments=None)],
+        [MergeClause("insert", assignments=None)],
+        source_alias="s", target_alias="t")
+    cmd.run()
+    return cmd
+
+
+@pytest.fixture
+def _fresh_device_caches():
+    from delta_tpu.ops.column_cache import ColumnCache
+    from delta_tpu.ops.key_cache import KeyCache
+
+    KeyCache.reset()
+    ColumnCache.reset()
+    yield
+    KeyCache.reset()
+    ColumnCache.reset()
+
+
+@pytest.mark.parametrize("resident", [False, True], ids=["cold", "resident"])
+def test_merge_phases_are_child_spans_that_fill_phase_ms(
+        tmp_path, _fresh_device_caches, resident):
+    t = _keyed_table(tmp_path / "t")
+    with conf.set_temporarily(**DEVICE):
+        if resident:
+            _upsert(t, 7990, 8010)  # builds and registers the key slab
+        telemetry.clear_events()
+        cmd = _upsert(t, 7900, 8100)  # half existing keys, half fresh
+    assert cmd.metrics["numTargetRowsUpdated"] > 0
+    events = telemetry.recent_events()
+    [root] = [e for e in events if e.op_type == "delta.dml.merge"]
+    for name, key in MERGE_PHASES.items():
+        [ev] = [e for e in events if e.op_type == name]  # exactly once
+        assert ev.parent_id == root.span_id, name
+        assert cmd.phase_ms[key] == ev.duration_us / 1000.0, name
+    [commit] = [e for e in events if e.op_type == "delta.commit"]
+    assert commit.parent_id == root.span_id
+    # the phases tile the command: in order, not overlapping on its thread
+    phases = sorted((e for e in events
+                     if e.op_type in MERGE_PHASES or e is commit),
+                    key=lambda e: e.start_us)
+    assert [e.op_type for e in phases][:5] == list(MERGE_PHASES)[:5]
+    for a, b in zip(phases, phases[1:]):
+        assert a.start_us + a.duration_us <= b.start_us + 1
+    assert _cover(events, root) >= 0.9
+    [dv] = [e for e in events if e.op_type == "delta.dml.merge.deletionVectors"]
+    assert dv.data["rows"] == cmd.metrics["numTargetRowsUpdated"]
+    assert dv.data["files"] >= 1
+    # the key cache's own spans lie under the phase that waited for them
+    names = {e.op_type for e in events}
+    assert "delta.merge.deviceProbe" in names and "delta.keyCache.sort" in names
+    assert ("delta.keyCache.advance" in names) == resident
+    # the command's own metrics keep their meaning: whole milliseconds
+    assert cmd.metrics["rewriteTimeMs"] >= int(
+        cmd.phase_ms["apply_ms"] + cmd.phase_ms["dv_ms"]
+        + cmd.phase_ms["write_ms"]) - 1
+
+
+def test_scan_phases_are_spans_and_fill_the_report(tmp_path,
+                                                   _fresh_device_caches):
+    from delta_tpu import obs
+
+    t = _keyed_table(tmp_path / "t", files=2)
+    filters = ["d >= 17", "d < 29", "q < 60"]
+    with conf.set_temporarily(**DEVICE):
+        t.to_arrow(filters=filters, columns=["k"])  # lanes up, mask compiled
+        telemetry.clear_events()
+        got = t.to_arrow(filters=filters, columns=["k"])
+    events = telemetry.recent_events()
+    [root] = [e for e in events if e.op_type == "delta.scan"]
+    for name in ("delta.scan.planning", "delta.scan.deviceMask",
+                 "delta.scan.read", "delta.scan.filter", "delta.scan.report"):
+        [ev] = [e for e in events if e.op_type == name]
+        assert ev.parent_id == root.span_id, name
+    by_name = {e.op_type: e for e in events}
+    mask = by_name["delta.scan.deviceMask"]
+    assert (mask.data["files"], mask.data["hits"], mask.data["misses"]) == (2, 4, 0)
+    assert mask.data["coldBytes"] == 0
+    masks = [e for e in events if e.op_type == "delta.columnCache.mask"]
+    assert len(masks) == 2 and all(e.parent_id == mask.span_id for e in masks)
+    # one decode a file, and inside it the three stages once each, in order
+    decodes = [e for e in events if e.op_type == "delta.scan.decode"]
+    assert len(decodes) == 2
+    for dec in decodes:
+        stages = sorted((e for e in events if e.parent_id == dec.span_id),
+                        key=lambda e: e.start_us)
+        assert [e.op_type for e in stages] == [
+            "delta.scan.decode.open", "delta.scan.decode.rowGroups",
+            "delta.scan.decode.assemble"]
+        for a, b in zip(stages, stages[1:]):
+            assert a.start_us + a.duration_us <= b.start_us + 1
+        assert stages[1].data["groups"] >= 1 and stages[1].data["bytes"] > 0
+    assert _cover(events, root) >= 0.9
+    rep = obs.last_scan_report()
+    assert rep.rows_out == got.num_rows
+    assert rep.phase_ms == {
+        key: round(by_name[name].duration_us / 1000.0, 3)
+        for key, name in (("planning", "delta.scan.planning"),
+                          ("mask", "delta.scan.deviceMask"),
+                          ("read", "delta.scan.read"),
+                          ("filter", "delta.scan.filter"))}
+
+
+def test_link_counters_are_the_nbytes_of_what_moved():
+    import numpy as np
+
+    from delta_tpu.parallel import link
+
+    before = telemetry.counters("link")
+    with telemetry.record_operation("delta.test.link") as ev:
+        up = link.to_device(np.arange(1000, dtype=np.int32))      # 4,000 B
+        link.to_device(np.zeros(64, bool))                        # 64 B
+        link.to_device(np.int32(7))                               # 4 B
+        down = link.to_host(up)                                   # 4,000 B
+    assert down.tolist() == list(range(1000))
+    after = telemetry.counters("link")
+    moved = {k: after[k] - before.get(k, 0) for k in after}
+    assert moved["link.h2d.bytes"] == 4000 + 64 + 4
+    assert moved["link.h2d.count"] == 3
+    assert moved["link.d2h.bytes"] == 4000
+    assert moved["link.d2h.count"] == 1
+    assert moved["link.d2h.waitUs"] >= 0
+    assert ev.data == {"h2dBytes": 4068, "d2hBytes": 4000}
+
+
+def test_scan_link_bytes_are_the_lanes_up_and_the_masks_down(
+        tmp_path, _fresh_device_caches):
+    """A cold scan ships two int64 lanes and their validity a file, padded to
+    the next power of two, and fetches one boolean mask a file; a warm scan
+    ships nothing."""
+    t = _keyed_table(tmp_path / "t", rows=6000, files=2)  # 3,000 -> 4,096 rows
+    filters = ["d >= 3", "d < 9", "q < 50"]
+    with conf.set_temporarily(**DEVICE):
+        c0 = telemetry.counters("link")
+        t.to_arrow(filters=filters, columns=["k"])
+        c1 = telemetry.counters("link")
+        t.to_arrow(filters=filters, columns=["k"])
+        c2 = telemetry.counters("link")
+    cold = {k: c1[k] - c0.get(k, 0) for k in c1}
+    warm = {k: c2[k] - c1.get(k, 0) for k in c2}
+    assert cold["link.h2d.bytes"] == 2 * 2 * 4096 * (8 + 1)
+    assert cold["link.d2h.bytes"] == warm["link.d2h.bytes"] == 2 * 4096
+    assert warm["link.h2d.bytes"] == 0 and warm["link.d2h.count"] == 2
+
+
+def test_compile_inside_a_scan_is_counted_where_it_happens(
+        tmp_path, _fresh_device_caches):
+    t = _keyed_table(tmp_path / "t", rows=2000, files=1)
+    # literals no other test uses: the mask's program is keyed on them
+    filters = ["d >= 123", "d < 457", "q < 71"]
+
+    def scan():
+        before = telemetry.counters()
+        telemetry.clear_events()
+        with conf.set_temporarily(**DEVICE):
+            t.to_arrow(filters=filters, columns=["k"])
+        after = telemetry.counters()
+        moved = {k: after[k] - before.get(k, 0) for k in after
+                 if k.endswith((".compiles", ".compileUs", ".cacheFetches"))}
+        spans = [e for e in telemetry.recent_events() if "compiles" in e.data]
+        return moved, spans
+
+    moved, spans = scan()
+    assert moved["scan.device.compiles"] >= 1
+    assert moved["device.compiles"] == moved["scan.device.compiles"]
+    assert moved["device.compileUs"] > 0
+    assert moved.get("merge.device.compiles", 0) == 0
+    assert {e.op_type for e in spans} == {"delta.columnCache.mask"}
+    assert sum(e.data["compiles"] for e in spans) == moved["device.compiles"]
+    assert all(e.data["compileMs"] > 0 for e in spans)
+    # the same literals and lane shape again: nothing compiles, nothing counts
+    moved, spans = scan()
+    assert not any(moved.values()) and not spans
+
+
+def test_blackout_records_no_span_but_counts_and_times_the_phases(
+        tmp_path, _fresh_device_caches):
+    t = _keyed_table(tmp_path / "t")
+    before = telemetry.counters("link")
+    with conf.set_temporarily(delta__tpu__telemetry__enabled=False, **DEVICE):
+        telemetry.clear_events()
+        cmd = _upsert(t, 7900, 8100)
+        t.to_arrow(filters=["d >= 40", "d < 44"], columns=["k"])
+        assert telemetry.recent_events() == []
+    after = telemetry.counters("link")
+    assert after["link.h2d.bytes"] > before.get("link.h2d.bytes", 0)
+    assert after["link.d2h.bytes"] > before.get("link.d2h.bytes", 0)
+    assert set(MERGE_PHASES.values()) <= set(cmd.phase_ms)
+    assert all(v >= 0 for v in cmd.phase_ms.values())
+    assert cmd.phase_ms["join_ms"] > 0
+
+
+def test_span_stages_tile_their_parent():
+    with telemetry.record_operation("delta.test.decode") as parent, \
+            telemetry.span_stages() as stage:
+        a = stage("delta.test.decode.open")
+        assert stage("delta.test.decode.open") is a  # already open: no new span
+        b = stage("delta.test.decode.read", {"groups": 2})
+        assert a.duration_us is not None and b.duration_us is None
+        stage("delta.test.decode.assemble")
+    stages = [e for e in telemetry.recent_events("delta.test.decode")
+              if e is not parent]
+    assert [e.op_type.rsplit(".", 1)[1] for e in stages] == [
+        "open", "read", "assemble"]
+    assert all(e.parent_id == parent.span_id and e.duration_us is not None
+               for e in stages)
+    assert b.data == {"groups": 2}
+    assert telemetry.current_span() is None
+
+
+def test_span_stages_close_the_open_stage_with_the_error():
+    with pytest.raises(ValueError):
+        with telemetry.span_stages() as stage:
+            stage("delta.test.stage.one")
+            stage("delta.test.stage.two")
+            raise ValueError("kapow")
+    one, two = telemetry.recent_events("delta.test.stage")
+    assert one.error is None and "kapow" in two.error
+    assert telemetry.current_span() is None
+
+
+def test_open_spans_and_span_counts():
+    assert telemetry.open_spans() == []
+    telemetry.add_span_counts(h2dBytes=5)  # no span open: nothing to add to
+    with telemetry.record_operation("delta.test.outer") as outer:
+        with telemetry.record_operation("delta.test.inner", {"compiles": 1}) as inner:
+            assert telemetry.open_spans() == [outer, inner]
+            telemetry.add_span_counts(compiles=1, compileMs=2.5)
+            telemetry.add_span_counts(compileMs=0.5)
+    assert inner.data == {"compiles": 2, "compileMs": 3.0}
+    assert outer.data == {}
+
+
+def test_chrome_trace_carries_both_clocks():
+    import time
+
+    with telemetry.record_operation("delta.test.clock"):
+        pass
+    lo = time.perf_counter_ns(), time.time_ns()
+    clock = telemetry.export_chrome_trace()["metadata"]["clock"]
+    hi = time.perf_counter_ns(), time.time_ns()
+    assert lo[0] <= clock["perf_counter_ns"] <= hi[0]
+    assert lo[1] <= clock["time_ns"] <= hi[1]

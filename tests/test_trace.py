@@ -457,3 +457,45 @@ def test_incident_carries_trace_id_once_per_exception(tmp_path):
     assert incident["traceId"] == outer.trace_id
     assert {r["traceId"] for r in rows} == {outer.trace_id}
     assert len(rows) == 3
+
+
+# -- one clock with the device trace ------------------------------------------
+
+
+def test_spans_are_host_events_of_an_open_profiler_session(tmp_path):
+    """With a ``jax.profiler`` session open, every span of the program is an
+    event of the ``.xplane.pb`` itself (``TraceAnnotation``), on the
+    profiler's clock; the operator who traces a slow MERGE sees the engine's
+    phases beside the device's planes."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with telemetry.record_operation("delta.test.beforeSession"):
+        pass  # no session open: the annotation is a flag test, nothing kept
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with telemetry.record_operation("delta.test.profiled") as outer:
+            with telemetry.record_operation("delta.test.profiled.phase"):
+                jax.block_until_ready(jax.numpy.arange(8) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("delta.test."):
+                    found[e.name] = (e.start_ns, e.duration_ns)
+    assert set(found) == {"delta.test.profiled", "delta.test.profiled.phase"}
+    (a, da), (b, db) = found["delta.test.profiled"], found["delta.test.profiled.phase"]
+    assert a <= b and b + db <= a + da  # nested on the profiler's clock too
+    # and the two clocks agree on how long the span took, to well under the span of a phase
+    assert abs(da / 1000 - outer.duration_us) < 20_000
