@@ -101,6 +101,17 @@ _SRC = "__s__"  # prefix for source columns in the combined pair table
 _TID = "__t_row__"
 _SID = "__s_row__"
 _FID = "__t_file__"
+# the two stages that tile `delta.dml.merge.join` on a device route
+_JOIN_WAIT = "delta.dml.merge.join.wait"
+_JOIN_PAIRS = "delta.dml.merge.join.pairs"
+
+
+class _StaleResidentSlab(RuntimeError):
+    """Internal control flow: on the pairs-only route the resident slab
+    named a row the snapshot does not hold live (a pair outside the
+    candidate files, or a matched row its file's deletion vector already
+    covers). The entry has been invalidated; `MergeIntoCommand.run` runs the
+    body once more, which then rebuilds the slab from the files."""
 
 
 def _rows_from_stats(candidates) -> Optional[int]:
@@ -329,7 +340,18 @@ class MergeIntoCommand:
     def run(self) -> int:
         with telemetry.record_operation("delta.dml.merge",
                                         path=self.delta_log.data_path):
-            return self.delta_log.with_new_transaction(self._body)
+            try:
+                return self.delta_log.with_new_transaction(self._body)
+            except _StaleResidentSlab:
+                # nothing was committed and the entry is gone: this time the
+                # join decodes the files and the slab is built anew
+                return self.delta_log.with_new_transaction(self._body)
+
+    @property
+    def _pairs_only(self) -> bool:
+        """The resident probe's pairs were the join: no target row was
+        decoded (`_pairs_from_probe`)."""
+        return self._router.get("route") == "pairs-only"
 
     @contextlib.contextmanager
     def _phase(self, op_type: str, key: str,
@@ -338,12 +360,13 @@ class MergeIntoCommand:
         own duration is ``phase_ms[key]``, so a phase has one clock reading,
         a start and a parent. The phases tile `_body` and `_join`: they do
         not overlap on the calling thread, and only a few lines lie between
-        them. Under a telemetry blackout the span is the no-op event and the
-        phase is timed here."""
+        them. A phase that opens twice in one MERGE (the pairs-only route
+        that declines and decodes late) adds up. Under a telemetry blackout
+        the span is the no-op event and the phase is timed here."""
         t0 = time.perf_counter_ns()
         with telemetry.record_operation(op_type, data) as ev:
             yield ev
-        self.phase_ms[key] = (
+        self.phase_ms[key] = self.phase_ms.get(key, 0.0) + (
             ev.duration_us / 1000.0 if ev.duration_us is not None
             else (time.perf_counter_ns() - t0) / 1e6)
 
@@ -421,13 +444,11 @@ class MergeIntoCommand:
                 if not any(r.startswith(_SRC) for r in ir.references(c))
             ]
             candidates = candidate_files(txn, ir.and_all(target_only) if target_only else None)
-        # distributed findTouchedFiles probe: restrict the candidates to
-        # files whose equi keys intersect the source BEFORE the join
-        # decodes full rows (conf-gated; result-identical — see the method)
-        if equi:
-            candidates = self._probe_touched_files(candidates, src, equi, metadata)
         insert_only = not self.matched_clauses
-        matched_pairs, tgt_tables = self._join(
+        # the join narrows the candidates itself (the distributed
+        # findTouchedFiles probe), unless the resident pairs-only route
+        # serves it: file ids in the pairs index the list it returns
+        matched_pairs, tgt_tables, candidates = self._join(
             txn, candidates, src, equi, residual, metadata,
             prune_pred=ir.and_all(target_only) if target_only else None,
         )
@@ -502,11 +523,13 @@ class MergeIntoCommand:
                     touched = np.unique(fids)
                     dv_ev.data.update(files=len(touched), rows=len(poss))
                     for fid in touched:
+                        add = candidates[int(fid)]
+                        claimed = poss[fids == fid]
                         rm, re_add = dv_common.dv_mark_deleted(
-                            self.delta_log.data_path,
-                            candidates[int(fid)],
-                            poss[fids == fid],
-                        )
+                            self.delta_log.data_path, add, claimed)
+                        if self._pairs_only:
+                            self._check_claimed_were_live(
+                                add, re_add, len(claimed))
                         removes.append(rm)
                         if re_add is not None:
                             dv_adds.append(re_add)
@@ -635,15 +658,23 @@ class MergeIntoCommand:
 
     def _join(self, txn, candidates: List[AddFile], src: pa.Table, equi, residual,
               metadata, prune_pred: Optional[ir.Expression] = None,
-              ) -> Tuple[pa.Table, Dict[int, pa.Table]]:
+              ) -> Tuple[pa.Table, Dict[int, pa.Table], List[AddFile]]:
         """Inner-join source×candidate-target. Returns (pair table with
         target cols bare + source cols prefixed + ids, per-file target
-        tables with row ids).
+        tables with row ids, the candidates as the join narrowed them: the
+        pairs' file ids index that list).
 
-        Device path: the join-key columns decode first (a cheap projected
-        Parquet read), the membership kernel launches asynchronously, and
-        the full-column decode of the candidates runs on the host *while the
-        device probes* — the kernel's wall-clock hides under the decode.
+        Resident pairs-only route: when the key cache holds the table's
+        slab and nothing of a target row is needed but which row it is
+        (`_pairs_only_shape`), the probe's pairs are the join: no
+        touched-files pre-probe, no decode of the target, see
+        `_pairs_from_probe`.
+
+        Device path otherwise: the join-key columns decode first (a cheap
+        projected Parquet read), the membership kernel launches
+        asynchronously, and the full-column decode of the candidates runs on
+        the host *while the device probes* — the kernel's wall-clock hides
+        under the decode.
 
         ``prune_pred`` (the target-only conjuncts of the merge condition)
         enables row-group skipping inside candidate files: a pruned group
@@ -653,40 +684,94 @@ class MergeIntoCommand:
         merges (target rows feed the join and nothing else)."""
         import numpy as np
 
+        # what the statement needs of the target: from the statement alone
+        target_cols = [f.name for f in metadata.schema.fields]
+        insert_only = not self.matched_clauses
+        key_need = {r.lower() for t_e, _ in equi for r in ir.references(t_e)}
+        # insert-only merges never rewrite target rows: read only the columns
+        # the join condition touches (the reference's left-anti fast path
+        # reads the full target; we push the projection into the Parquet scan)
+        read_cols: Optional[List[str]] = None
+        if insert_only:
+            need = key_need | {
+                r.lower()
+                for c in residual
+                for r in ir.references(c)
+                if not r.startswith(_SRC)
+            }
+            cols = [c for c in target_cols if c.lower() in need]
+            read_cols = cols or None
+        else:
+            read_cols = self._referenced_target_columns(
+                metadata, target_cols, [c for c in src.column_names
+                                        if c.startswith(_SRC)],
+                key_need, residual,
+            )
+        # DV-mode matched clauses mark physical rows deleted — every scan
+        # that can end up as the phase-2 tables must carry positions
+        pos_col = (
+            POSITION_COL
+            if (not insert_only and dv_common.dv_enabled(metadata))
+            else None
+        )
+        mode = str(conf.get("delta.tpu.merge.devicePath.mode", "auto"))
+        shape_eligible = (
+            bool(conf.get("delta.tpu.merge.devicePath.enabled", True))
+            and mode != "off"
+            and 1 <= len(equi) <= 2
+            and not residual
+            and src.num_rows > 0
+        )
+
+        # the resident route is decided first: when the slab is cached and
+        # the pairs alone will do, the probe launches on every file of the
+        # snapshot and the touched-files pre-probe (whose answer is a
+        # by-product of that probe) is not run
+        resident = None
+        resident_tried = False
+        whole_table = True
+        if (shape_eligible and candidates
+                and self._pairs_only_shape(equi, read_cols, key_need, pos_col,
+                                           insert_only)
+                and self._resident_entry_cached(txn, equi)):
+            resident_tried = True
+            with self._phase("delta.dml.merge.keyDecode", "key_decode_ms"):
+                resident = self._launch_resident_probe(
+                    txn, candidates, src, equi, target_cols, key_need,
+                    pos_col, insert_only,
+                )
+        if resident is not None:
+            telemetry.bump_counter("merge.resident.pairsOnly")
+            self._audit_eligible = True
+            with self._phase("delta.dml.merge.rowDecode", "decode_ms"):
+                # nothing of the target is read
+                n_target = _rows_from_stats(candidates)
+                self._audit_units = (
+                    resident[0].num_rows if n_target is None else n_target,
+                    src.num_rows)
+            with self._phase("delta.dml.merge.join", "join_ms",
+                             {"route": "pairs-only"}):
+                joined = self._pairs_from_probe(
+                    resident[1], candidates, src, equi, read_cols or [],
+                    metadata, insert_only)
+            if joined is not None:
+                return joined, {}, candidates
+            # a designed decline: decode late and take the path below, the
+            # probe still in hand
+            telemetry.bump_counter("merge.resident.pairsOnly.declined")
+        elif equi:
+            # distributed findTouchedFiles probe: restrict the candidates to
+            # files whose equi keys intersect the source BEFORE the join
+            # decodes full rows (conf-gated; result-identical — see the method)
+            n_all = len(candidates)
+            candidates = self._probe_touched_files(candidates, src, equi, metadata)
+            # a slab built over fewer files than the table holds must not
+            # be registered as the table's
+            whole_table = len(candidates) == n_all
+
         # routing, the key-column decode, and the slab advance / upload launch
         with self._phase("delta.dml.merge.keyDecode", "key_decode_ms"):
-            target_cols = [f.name for f in metadata.schema.fields]
-            insert_only = not self.matched_clauses
-            key_need = {r.lower() for t_e, _ in equi for r in ir.references(t_e)}
-            # insert-only merges never rewrite target rows: read only the columns
-            # the join condition touches (the reference's left-anti fast path
-            # reads the full target; we push the projection into the Parquet scan)
-            read_cols: Optional[List[str]] = None
-            if insert_only:
-                need = key_need | {
-                    r.lower()
-                    for c in residual
-                    for r in ir.references(c)
-                    if not r.startswith(_SRC)
-                }
-                cols = [c for c in target_cols if c.lower() in need]
-                read_cols = cols or None
-            else:
-                read_cols = self._referenced_target_columns(
-                    metadata, target_cols, [c for c in src.column_names
-                                            if c.startswith(_SRC)],
-                    key_need, residual,
-                )
-
-            mode = str(conf.get("delta.tpu.merge.devicePath.mode", "auto"))
-            base_eligible = (
-                bool(conf.get("delta.tpu.merge.devicePath.enabled", True))
-                and mode != "off"
-                and 1 <= len(equi) <= 2
-                and not residual
-                and candidates
-                and src.num_rows > 0
-            )
+            base_eligible = bool(shape_eligible and candidates)
             device_eligible = base_eligible
             # audit: whether a device route even existed for this condition
             # shape — a structurally host-only merge is audited without a
@@ -728,20 +813,12 @@ class MergeIntoCommand:
                         bump_counter("merge.device.declined")
                         self._router.update(reason="cold-estimate")
 
-            # DV-mode matched clauses mark physical rows deleted — every scan
-            # that can end up as the phase-2 tables must carry positions
-            pos_col = (
-                POSITION_COL
-                if (not insert_only and dv_common.dv_enabled(metadata))
-                else None
-            )
             # row-group skipping is only safe when unmatched target rows never
             # need writing back: DV mode (matched rows mark by physical
             # position) or insert-only (target rows exist only to probe)
             if pos_col is None and not insert_only:
                 prune_pred = None
             pending = None
-            resident = None
             via = None
             key_pieces: Optional[List[pa.Table]] = None
             key_pieces_have_pos = False
@@ -749,11 +826,14 @@ class MergeIntoCommand:
                 # resident-operand path first: the target key lane already lives
                 # in HBM (ops/key_cache), so the probe ships only source keys —
                 # different economics from the cold upload path, hence evaluated
-                # before (and independent of) the upload-cost gate above
-                resident = self._launch_resident_probe(
-                    txn, candidates, src, equi, target_cols, key_need,
-                    pos_col, insert_only,
-                )
+                # before (and independent of) the upload-cost gate above. Not
+                # asked twice: what the pairs-only route was refused, or
+                # launched and then declined, stands
+                if not resident_tried:
+                    resident = self._launch_resident_probe(
+                        txn, candidates, src, equi, target_cols, key_need,
+                        pos_col, insert_only,
+                    )
                 if resident is not None:
                     via = "resident"
             if resident is None and device_eligible:
@@ -772,7 +852,7 @@ class MergeIntoCommand:
                     # this table skips the upload entirely
                     resident, key_pieces = self._launch_slab_pipeline(
                         txn, candidates, src, equi, target_cols, key_need,
-                        pos_col, insert_only, metadata,
+                        pos_col, insert_only, metadata, register=whole_table,
                     )
                     if resident is not None:
                         via = "device-cold"
@@ -854,35 +934,46 @@ class MergeIntoCommand:
             return combined
 
         if target.num_rows == 0 or src.num_rows == 0:
-            return empty_pairs(), tgt_tables
+            return empty_pairs(), tgt_tables, candidates
 
         # the wait for the device probe, or the host join; then the pair take
-        with self._phase("delta.dml.merge.join", "join_ms"):
-            if resident is not None and pending is None:
-                pending = self._finalize_resident(
-                    resident, candidates, tgt_tables, target, src, equi,
-                    pos_col, insert_only,
-                )
-            if pending is not None:
-                res = pending.result()
-                if res is None:
-                    self._router.setdefault("reason", "device-finalize-fallback")
-                else:
-                    self._device_join = res
-                    self._join_path = via
-                    # insert-only never consumes the pair rows (the not-matched
-                    # block comes from s_matched): skip materializing them
-                    if insert_only:
-                        joined = empty_pairs()
-                    else:
-                        matched = np.flatnonzero(res.t_matched)
-                        joined = target.take(pa.array(matched, pa.int64()))
-                        s_taken = src.take(
-                            pa.array(res.t_first_s[matched], pa.int64())
+        with self._phase("delta.dml.merge.join", "join_ms",
+                         {"route": "host"}) as join_ev:
+            if resident is not None or pending is not None:
+                # two stages tile the span on a device route: the wait for
+                # the device's answer, then the host's pair mapping and take
+                with telemetry.span_stages() as stage:
+                    stage(_JOIN_WAIT)
+                    if pending is None:
+                        pending = self._finalize_resident(
+                            resident, candidates, tgt_tables, target, src,
+                            equi, pos_col, insert_only,
+                            got_pairs=lambda: stage(_JOIN_PAIRS),
                         )
-                        for name in s_taken.column_names:
-                            joined = joined.append_column(name, s_taken.column(name))
-                    return joined, tgt_tables
+                    res = pending.result()
+                    stage(_JOIN_PAIRS)
+                    if res is None:
+                        self._router.setdefault(
+                            "reason", "device-finalize-fallback")
+                    else:
+                        self._device_join = res
+                        self._join_path = via
+                        self._router["route"] = join_ev.data["route"] = "decode"
+                        # insert-only never consumes the pair rows (the
+                        # not-matched block comes from s_matched): skip
+                        # materializing them
+                        if insert_only:
+                            joined = empty_pairs()
+                        else:
+                            matched = np.flatnonzero(res.t_matched)
+                            joined = target.take(pa.array(matched, pa.int64()))
+                            s_taken = src.take(
+                                pa.array(res.t_first_s[matched], pa.int64())
+                            )
+                            for name in s_taken.column_names:
+                                joined = joined.append_column(
+                                    name, s_taken.column(name))
+                        return joined, tgt_tables, candidates
 
             if equi:
                 # Join INDEX tables (keys + row positions), then take the full
@@ -944,10 +1035,10 @@ class MergeIntoCommand:
                         pieces.append(piece.combine_chunks())
                 joined = (pa.concat_tables(pieces).combine_chunks()
                           if pieces else empty_pairs())
-                return joined, tgt_tables
+                return joined, tgt_tables, candidates
             if residual:
                 joined = joined.filter(boolean_mask(ir.and_all(residual), joined))
-            return joined, tgt_tables
+            return joined, tgt_tables, candidates
 
     def _referenced_target_columns(
         self, metadata, target_cols, src_prefixed, key_need, residual,
@@ -1000,6 +1091,135 @@ class MergeIntoCommand:
     @staticmethod
     def _key_signature(t_exprs) -> str:
         return repr([repr(e) for e in t_exprs])
+
+    def _pairs_only_shape(self, equi, read_cols, key_need, pos_col,
+                          insert_only) -> bool:
+        """Whether nothing of a matched target row is needed but which row
+        it is, so that the resident probe's pairs can be the join: matched
+        rows are marked by position in deletion vectors (or the merge only
+        inserts); the statement reads no target column but the keys (star
+        update, no CDF, no generated column, no clause condition on another
+        column: `_referenced_target_columns`); and every target key is a
+        bare column, so a matched row's key *is* its source row's key in
+        the column's type (the slab packs integer keys only, NULL never
+        matches)."""
+        if pos_col is None and not insert_only:
+            return False
+        if read_cols is None or not {c.lower() for c in read_cols} <= key_need:
+            return False
+        return all(isinstance(t_e, ir.Column) for t_e, _ in equi)
+
+    def _resident_entry_cached(self, txn, equi) -> bool:
+        """A slab for this table and key signature is in the key cache
+        (whether it can be advanced to the snapshot is
+        `_launch_resident_probe`'s to find out)."""
+        from delta_tpu.ops import key_cache as kc_mod
+
+        return kc_mod.key_cache_enabled() and kc_mod.KeyCache.instance().peek(
+            txn.snapshot.delta_log.log_path,
+            self._key_signature([t for t, _ in equi])) is not None
+
+    def _pairs_from_probe(self, probe, candidates, src, equi, key_cols,
+                          metadata, insert_only) -> Optional[pa.Table]:
+        """The pairs-only join: the pair table from the resident probe's
+        pairs and the source alone. A pair is (physical slab row, source
+        row) and the slab maps a file to its rows, so a pair *is* (file,
+        position, source row); the key columns are the source's key
+        expressions in the target's types. Rows in the order the decode
+        route gives them: ascending candidate file, then position.
+
+        None is a designed decline (the probe's windows overflowed, the slab
+        lacks a candidate file; in ``auto`` also a device exception): the
+        caller decodes after all. A pair in no candidate file means the slab
+        is not this snapshot's: `_stale_slab`."""
+        import numpy as np
+
+        from delta_tpu.expr.vectorized import arrow_type_for
+        from delta_tpu.ops import join_kernel
+        from delta_tpu.ops import key_cache as kc_mod
+
+        with telemetry.span_stages() as stage:
+            stage(_JOIN_WAIT)
+            try:
+                res_p = probe.result()
+            except kc_mod.DeltaProbeOverflow:
+                return None
+            except Exception as e:  # noqa: BLE001 — host rung (auto only)
+                self._device_rung("device-finalize-fallback", e)
+                return None
+            stage(_JOIN_PAIRS)
+            slabs = [res_p.slabs.get(f.path) for f in candidates]
+            if any(ent is None for ent in slabs):
+                return None
+            declared = {f.name: arrow_type_for(f.data_type)
+                        for f in metadata.schema.fields}
+            fields = [pa.field(c, declared[c]) for c in key_cols]
+            if not insert_only:
+                fields.append(pa.field(POSITION_COL, pa.int64()))
+            fields += [pa.field(_TID, pa.int64()), pa.field(_FID, pa.int64())]
+            fields += [pa.field(n, src.column(n).type) for n in src.column_names]
+            if insert_only or not len(res_p.t_pairs[0]):
+                # insert-only consumes s_matched alone (no pairs were fetched)
+                joined = pa.schema(fields).empty_table()
+            else:
+                phys, s_rows = res_p.t_pairs
+                offs = np.array([off for off, _ in slabs], np.int64)
+                lo = np.searchsorted(phys, offs)
+                hi = np.searchsorted(
+                    phys, offs + np.array([n for _, n in slabs], np.int64))
+                per_file = hi - lo
+                if int(per_file.sum()) != len(phys):
+                    self._stale_slab("a matched slab row lies in no file of "
+                                     "the snapshot")
+                if (np.diff(offs) < 0).any():
+                    # the slab holds the files in another order: the pairs
+                    # (ascending slab row) go into the candidates' order
+                    order = np.concatenate(
+                        [np.arange(a, b) for a, b in zip(lo, hi)])
+                    phys, s_rows = phys[order], s_rows[order]
+                s_taken = src.take(pa.array(s_rows, pa.int64()))
+                cols = []
+                for c in key_cols:
+                    s_e = next(s_e for t_e, s_e in equi
+                               if t_e.name.lower() == c.lower())
+                    cols.append(pc.cast(evaluate(s_e, s_taken), declared[c]))
+                cols.append(pa.array(phys - np.repeat(offs, per_file)))
+                # an id per target row: a slab row is in one pair at most
+                cols.append(pa.array(np.arange(len(phys), dtype=np.int64)))
+                cols.append(pa.array(np.repeat(
+                    np.arange(len(candidates), dtype=np.int64), per_file)))
+                cols += s_taken.columns
+                joined = pa.table(cols, schema=pa.schema(fields))
+            self._device_join = join_kernel.JoinResult(
+                np.empty(0, np.int64), res_p.s_matched, res_p.any_multi)
+            self._join_path = "resident"
+            self._router["route"] = "pairs-only"
+            return joined
+
+    def _stale_slab(self, why: str) -> None:
+        """Drop the table's slab and end this run of the body: `run` makes
+        the next."""
+        from delta_tpu.ops.key_cache import KeyCache
+
+        KeyCache.instance().invalidate(self.delta_log.log_path)
+        telemetry.bump_counter("merge.resident.pairsOnly.declined")
+        raise _StaleResidentSlab(why)
+
+    def _check_claimed_were_live(self, add, re_add, n_claimed: int) -> None:
+        """What the decode route cross-checked ("the slab matched a row the
+        decode dropped"), where it is free on the pairs-only route:
+        `dv_mark_deleted` united the file's old vector with the claimed
+        positions, and a union smaller than the two together means the slab
+        matched a row that was deleted already. That MERGE does not commit."""
+        now_dead = n_claimed + int(
+            (add.deletion_vector or {}).get("cardinality", 0))
+        if re_add is not None:
+            stale = int(re_add.deletion_vector["cardinality"]) != now_dead
+        else:  # the whole file went: more dead rows than rows
+            rows = add.num_logical_records
+            stale = rows is not None and now_dead > rows
+        if stale:
+            self._stale_slab(f"a matched row of {add.path} was deleted already")
 
     def _launch_resident_probe(self, txn, candidates, src, equi, target_cols,
                                key_need, pos_col, insert_only):
@@ -1068,7 +1288,8 @@ class MergeIntoCommand:
         return entry, probe, s_keys, s_ok
 
     def _launch_slab_pipeline(self, txn, candidates, src, equi, target_cols,
-                              key_need, pos_col, insert_only, metadata):
+                              key_need, pos_col, insert_only, metadata,
+                              register: bool = True):
         """The cold fused device MERGE pipeline: decode the key projection
         per file, streaming each decoded file's packed lane onto a
         pre-sized HBM slab from an uploader thread (transfer overlaps the
@@ -1079,7 +1300,9 @@ class MergeIntoCommand:
         Returns ``(resident_tuple_or_None, key_pieces_or_None)`` —
         ``resident_tuple`` feeds `_finalize_resident`; ``key_pieces`` (the
         per-file decoded key tables, position column attached) is returned
-        even on build failure so the caller can reuse the decode."""
+        even on build failure so the caller can reuse the decode.
+        ``register`` is False when the pre-probe narrowed ``candidates``:
+        such a slab serves this MERGE and is not the table's."""
         import queue as queue_mod
         import threading as threading_mod
 
@@ -1174,9 +1397,10 @@ class MergeIntoCommand:
             self._router.setdefault("reason", "slab-build-failed")
             return None, key_pieces
         # under device eligibility the candidate set is the whole table (a
-        # residual-free condition prunes nothing), so the slab is complete
-        # and future merges can cache-hit it
-        registered = cache.register(entry)
+        # residual-free condition prunes nothing) unless the touched-files
+        # pre-probe dropped files: only the complete slab is registered, so
+        # that future merges can cache-hit it
+        registered = register and cache.register(entry)
         if registered:
             self._resident_candidate = None  # no background build needed
         probe = entry.probe_async(
@@ -1190,12 +1414,14 @@ class MergeIntoCommand:
         return (entry, probe, s_keys, s_ok), key_pieces
 
     def _finalize_resident(self, resident, candidates, tgt_tables, target,
-                           src, equi, pos_col, insert_only):
+                           src, equi, pos_col, insert_only,
+                           got_pairs=lambda: None):
         """Map the device-computed pairs (physical slab row → first-match
         source row) onto the DV-filtered decode: the host does only the
         O(matched) position mapping — no key re-derivation, no host-side
         pairing sort. Returns a PendingJoin whose result is a JoinResult
-        (or None → the caller falls back to the host hash join)."""
+        (or None → the caller falls back to the host hash join).
+        ``got_pairs`` is called once the pairs are in host memory."""
         import numpy as np
 
         from delta_tpu.ops import join_kernel
@@ -1210,6 +1436,7 @@ class MergeIntoCommand:
             # device failure and takes _device_rung
             try:
                 res_p = probe.result()
+                got_pairs()
                 n_target = target.num_rows
                 t_first_s = np.full(n_target, -1, np.int64)
                 if insert_only:
@@ -1274,6 +1501,10 @@ class MergeIntoCommand:
         elif "error" in self._router:
             bump_counter("merge.device.fallback")  # _device_rung, mode=auto
         data = dict(self._router, decision=decision)
+        # how the pairs came about: 'pairs-only' (the resident probe's pairs
+        # were the join), 'decode' (a device join mapped onto decoded rows)
+        # or 'host'
+        data.setdefault("route", "host")
         if "cacheHit" in data:
             # a cache lookup may have hit and then been abandoned (pricing
             # decline, no sentinel room): the emitted flag reports whether

@@ -119,6 +119,8 @@ COUNTERS = frozenset({
     "merge.device.declined",      # link cost model chose the host
     "merge.device.fallback",      # device path raised; host join took over
     "merge.device.cacheHit",      # engaged from an HBM-resident key lane
+    "merge.resident.pairsOnly",   # the resident probe's pairs were the join
+    "merge.resident.pairsOnly.declined",  # engaged, then decoded after all
     "merge.device.compiles",      # XLA compiles inside a MERGE's root span
     "merge.keyCache.builds",      # cold key-lane builds (inline or bg)
     "merge.keyCache.advances",    # incremental log-tail applications
@@ -392,6 +394,8 @@ DESCRIPTIONS = {
     "merge.device.declined": "MERGEs where the cost model chose the host join.",
     "merge.device.fallback": "MERGEs (mode=auto) whose device path raised and fell back to the host join.",
     "merge.device.cacheHit": "Device MERGEs served from an HBM-resident key lane.",
+    "merge.resident.pairsOnly": "Resident MERGEs that took the pairs-only route: no touched-files pre-probe, no decode of the target.",
+    "merge.resident.pairsOnly.declined": "Pairs-only MERGEs that decoded the target after all (probe overflow, a slab that disagrees with the snapshot).",
     "merge.device.compiles": "XLA compiles that ran with a delta.dml.merge span open on the compiling thread.",
     "merge.keyCache.builds": "Cold resident key-lane builds.",
     "merge.keyCache.advances": "Incremental log-tail applications to a key lane.",

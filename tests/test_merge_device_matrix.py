@@ -9,6 +9,7 @@ composite packed keys, deletion vectors included. Every scenario runs the
 same merge on two copies of a seeded table, fused-forced vs host-pinned,
 and compares the full sorted row sets.
 """
+import contextlib
 import shutil
 
 import numpy as np
@@ -294,4 +295,340 @@ def test_post_optimize_merge_parity(tmp_path, fused):
     cmd_a = _run(log_a, src, "t.k = s.k", [UP], [INS], "force")
     cmd_b = _run(log_b, src, "t.k = s.k", [UP], [INS], "off")
     assert cmd_a._device_join is not None
+    assert _rows(log_a) == _rows(log_b)
+
+
+# -- the resident pairs-only route (ISSUE 26) --------------------------------
+#
+# When the key cache serves the MERGE and nothing of a target row is needed
+# but which row it is, the probe's pairs are the join: no touched-files
+# pre-probe, no decode of the target. Every case below runs the same MERGE
+# on two copies of a table with deletion vectors, both with the slab in the
+# cache: copy a takes the route, copy b the decode route (its observable
+# patched false; never a conf), and everything they leave behind is compared.
+
+DV_PROPS = {"delta.tpu.enableDeletionVectors": "true"}
+PAIRS_ONLY = "merge.resident.pairsOnly"
+DECLINED = "merge.resident.pairsOnly.declined"
+
+
+def _dv_tables(tmp_path, *, rows=800, files=8, composite=False,
+               key_type=pa.int64(), props=None):
+    """Two copies of one table with deletion vectors: ``files`` files of
+    keys 0..rows in order, so a key names its file."""
+    from delta_tpu import DeltaTable
+
+    rng = np.random.RandomState(5)
+    keys = np.arange(rows, dtype=np.int64)
+    cols = {"k": pa.array(keys).cast(key_type),
+            "v": pa.array(rng.rand(rows)),
+            "tag": pa.array([f"r{j}" for j in keys])}
+    if composite:
+        cols["k2"] = pa.array(keys % 7)
+    a, b = str(tmp_path / "a"), str(tmp_path / "b")
+    with conf.set_temporarily(**{
+            "delta.tpu.write.targetFileRows": rows // files}):
+        DeltaTable.create(a, data=pa.table(cols),
+                          configuration={**DV_PROPS, **(props or {})})
+    shutil.copytree(a, b)
+    log_a, log_b = DeltaLog.for_table(a), DeltaLog.for_table(b)
+    assert len(log_a.update().all_files) == files
+    return log_a, log_b
+
+
+def _source(keys, *, k2=None, key_type=pa.int64(), names=("k", "v", "tag")):
+    keys = list(keys)
+    cols = {names[0]: pa.array(keys, key_type),
+            names[1]: pa.array(np.linspace(0.0, 1.0, len(keys))),
+            names[2]: pa.array([f"s{i}" for i in range(len(keys))])}
+    if k2 is not None:
+        cols["k2"] = pa.array(list(k2), pa.int64())
+    return pa.table(cols)
+
+
+def _counts():
+    from delta_tpu.utils import telemetry
+
+    c = telemetry.counters("merge.resident")
+    return c.get(PAIRS_ONLY, 0), c.get(DECLINED, 0)
+
+
+@contextlib.contextmanager
+def _decode_route(monkeypatch):
+    """The decode route of today on a resident MERGE: the observable that
+    admits the pairs-only route reads false."""
+    with monkeypatch.context() as m:
+        m.setattr(MergeIntoCommand, "_pairs_only_shape",
+                  lambda self, *a: False)
+        yield
+
+
+def _left_behind(log):
+    """What the MERGEs left: the deletion vector of every live file, as
+    (cardinality, bytes) or None, a file named by the commit that first
+    added it and its place there (the copies' new files differ in name
+    only); and each data file the last commit added, in its order, as
+    (rows, bytes)."""
+    import os
+
+    import pyarrow.parquet as pq
+
+    from delta_tpu.protocol.actions import AddFile
+
+    snap = log.update()
+    label, new = {}, []
+    for version, actions in log.get_changes(0):
+        adds = [a for a in actions if isinstance(a, AddFile)]
+        first = [a for a in adds if a.path not in label]
+        for i, a in enumerate(first):
+            label[a.path] = f"v{version}#{i}"
+        if version == snap.version:
+            new = [(pq.read_table(os.path.join(log.data_path, a.path)), a.size)
+                   for a in first]
+    dvs = {label[f.path]: f.deletion_vector and (
+               f.deletion_vector["cardinality"], f.deletion_vector["sizeInBytes"])
+           for f in snap.all_files}
+    return dvs, new
+
+
+def _same_outcome(log_a, log_b, cmd_a, cmd_b, keys=("k",), order=True):
+    """``order``: the new files hold the same rows in the same order (two
+    device routes; the host join pairs in its own order)."""
+    for k in sorted(set(cmd_a.metrics) | set(cmd_b.metrics)):
+        if not k.endswith("TimeMs"):
+            assert cmd_a.metrics[k] == cmd_b.metrics[k], k
+    dvs_a, new_a = _left_behind(log_a)
+    dvs_b, new_b = _left_behind(log_b)
+    assert dvs_a == dvs_b
+    assert len(new_a) == len(new_b)
+    for (ta, size_a), (tb, size_b) in zip(new_a, new_b):
+        if order:
+            assert ta.equals(tb) and size_a == size_b
+        else:
+            assert ta.sort_by("k").equals(tb.sort_by("k"))
+    assert _rows(log_a, keys) == _rows(log_b, keys)
+
+
+def _both_routes(log_a, log_b, monkeypatch, source, cond, matched,
+                 not_matched, keys=("k",)):
+    n0, d0 = _counts()
+    cmd_a = _run(log_a, source, cond, matched, not_matched, "force")
+    assert _counts() == (n0 + 1, d0)
+    with _decode_route(monkeypatch):
+        cmd_b = _run(log_b, source, cond, matched, not_matched, "force")
+    assert _counts() == (n0 + 1, d0)
+    assert cmd_a._pairs_only and not cmd_b._pairs_only
+    assert cmd_a._join_path == cmd_b._join_path == "resident"
+    _same_outcome(log_a, log_b, cmd_a, cmd_b, keys)
+    return cmd_a, cmd_b
+
+
+PAIRS_ONLY_CASES = {
+    # hits in every file (one of them the first row of a file) and misses
+    "first-resident": dict(source=_source([0, 3, 150, 399, 400, 799, 900, 901])),
+    # the second round merges over the vectors and the file of the first:
+    # the slab advances through them
+    "second-round": dict(source=_source([3, 150, 400, 901, 902]),
+                         then=_source([3, 151, 400, 902, 903])),
+    "composite": dict(
+        composite=True, cond="t.k = s.k AND t.k2 = s.k2", keys=("k", "k2"),
+        source=_source([5, 9, 9, 333, 1000], k2=[5, 9 % 7, 6, 333 % 7, 1])),
+    "int32-target-int64-source": dict(
+        key_type=pa.int32(),
+        source=_source([1, 250, 799, 2**31 + 5, 2**40])),
+    "null-source-keys": dict(source=_source([7, None, None, 900])),
+    "other-order-and-case": dict(
+        source=_source([2, 450, 1234], names=("K", "V", "TAG"))
+        .select(["TAG", "K", "V"])),
+    "one-file-of-eight": dict(source=_source([510, 520, 530])),
+    "insert-only": dict(source=_source([5, 5, 700, 900, 900, 901]),
+                        matched=[]),
+}
+
+
+@pytest.mark.parametrize("case", list(PAIRS_ONLY_CASES))
+def test_pairs_only_route_leaves_what_the_decode_route_leaves(
+        tmp_path, monkeypatch, case):
+    p = dict(PAIRS_ONLY_CASES[case])
+    source = p.pop("source")
+    cond = p.pop("cond", "t.k = s.k")
+    keys = p.pop("keys", ("k",))
+    matched = p.pop("matched", [UP])
+    then = p.pop("then", None)
+    log_a, log_b = _dv_tables(tmp_path, **p)
+    tcols = [f.name for f in log_a.update().metadata.schema.fields]
+    for log in (log_a, log_b):
+        _prebuild(log, cond, tcols, source.column_names)
+    for src in [source] + ([then] if then is not None else []):
+        cmd_a, _ = _both_routes(log_a, log_b, monkeypatch, src, cond,
+                                matched, [INS], keys)
+    if case == "second-round":
+        assert _left_behind(log_a)[0]["v1#0"][0] == 3  # of the first round's five
+    if case == "one-file-of-eight":
+        assert cmd_a.metrics["numTargetFilesRemoved"] == 1
+    if case == "int32-target-int64-source":
+        assert cmd_a.metrics["numTargetRowsUpdated"] == 3
+        assert cmd_a.metrics["numTargetRowsInserted"] == 2
+    if case == "insert-only":
+        assert cmd_a.metrics["numTargetRowsInserted"] == 3  # 900, 900, 901
+
+
+def _overflowing_probe(monkeypatch):
+    from delta_tpu.ops import key_cache as kc
+
+    def probe_async(self, *a, **kw):
+        def finalize():
+            raise kc.DeltaProbeOverflow("candidate windows overflowed")
+        return kc.PendingProbe(finalize)
+
+    monkeypatch.setattr(kc.ResidentJoinKeys, "probe_async", probe_async)
+
+
+def _file_of(log, k):
+    """The path of the file the table was created with that holds key k."""
+    for f in log.update().all_files:
+        st = f.stats_dict()
+        if (st["numRecords"] == 100
+                and st["minValues"]["k"] <= k <= st["maxValues"]["k"]):
+            return f.path
+
+
+def _slab_lacks_a_file(log):
+    [entry] = KeyCache.instance()._entries.values()
+    del entry.slabs[_file_of(log, 150)]
+
+
+@pytest.mark.parametrize("why", ["overflow", "slab-lacks-a-file"])
+def test_pairs_only_decline_takes_the_decode_route(tmp_path, monkeypatch, why):
+    """A designed decline decodes late: the path of today (which, with a
+    probe that overflowed or a slab that lacks a file, ends in the host
+    join) and the same table."""
+    log_a, log_b = _dv_tables(tmp_path)
+    source = _source([0, 150, 151, 400, 799, 900])
+    _prebuild(log_a, "t.k = s.k", ["k", "v", "tag"], source.column_names)
+    if why == "overflow":
+        _overflowing_probe(monkeypatch)
+    else:
+        _slab_lacks_a_file(log_a)
+    n0, d0 = _counts()
+    cmd_a = _run(log_a, source, "t.k = s.k", [UP], [INS], "force")
+    assert _counts() == (n0 + 1, d0 + 1)
+    assert not cmd_a._pairs_only and cmd_a._join_path == "host"
+    # the target was decoded after all, late: both phases ran twice
+    assert cmd_a.phase_ms["decode_ms"] > 0
+    cmd_b = _run(log_b, source, "t.k = s.k", [UP], [INS], "off")
+    _same_outcome(log_a, log_b, cmd_a, cmd_b)
+
+
+def _generated_tables(tmp_path):
+    from delta_tpu import DeltaTable
+    from delta_tpu.schema.types import DoubleType, LongType, StringType, StructType
+
+    schema = (StructType().add("k", LongType()).add("v", DoubleType())
+              .add("tag", StringType())
+              .add("k_twice", LongType(),
+                   metadata={"delta.generationExpression": "k * 2"}))
+    a = str(tmp_path / "a")
+    t = DeltaTable.create(a, schema=schema, configuration=dict(DV_PROPS))
+    for lo in range(0, 800, 100):
+        WriteIntoDelta(t.delta_log, "append", _source(range(lo, lo + 100))).run()
+    return t.delta_log
+
+
+@pytest.mark.parametrize("why", ["non-star-update", "change-data-feed",
+                                 "generated-column"])
+def test_a_merge_that_needs_target_values_keeps_the_decode_route(
+        tmp_path, why):
+    """The route adapts to what the statement reads of the target: an
+    explicit assignment keeps the unassigned columns, the change feed wants
+    pre-images, a generated column may read any column. Each keeps the path
+    of today whole, the touched-files pre-probe included."""
+    from delta_tpu.utils import telemetry
+
+    matched = [UP]
+    if why == "generated-column":
+        log = _generated_tables(tmp_path)
+    else:
+        props = ({"delta.enableChangeDataFeed": "true"}
+                 if why == "change-data-feed" else None)
+        log, _ = _dv_tables(tmp_path, props=props)
+    if why == "non-star-update":
+        matched = [MergeClause("update", assignments={"v": "s.v"})]
+    assert len(log.update().all_files) == 8
+    source = _source([3, 150, 400, 901])
+    tcols = [f.name for f in log.update().metadata.schema.fields]
+    _prebuild(log, "t.k = s.k", tcols, source.column_names)
+    n0, d0 = _counts()
+    telemetry.clear_events()
+    cmd = _run(log, source, "t.k = s.k", matched, [INS], "force")
+    assert _counts() == (n0, d0)
+    assert cmd._join_path == "resident" and not cmd._pairs_only
+    names = [e.op_type for e in telemetry.recent_events()]
+    assert names.count("delta.dist.mergeProbe") == 1
+    assert "delta.scan.read" in names  # the target was decoded
+    assert cmd.metrics["numTargetRowsUpdated"] == 3
+    assert cmd.metrics["numTargetRowsInserted"] == 1
+
+
+def _revive_deleted_rows(log):
+    """A slab made stale by hand: the rows the first file's deletion vector
+    covers read live again, and the tag still says the vector is applied."""
+    [entry] = KeyCache.instance()._entries.values()
+    assert entry._set_dv(_file_of(log, 5), np.empty(0, np.int64))
+
+
+def _shrink_a_file(log):
+    """Another way to be stale: the slab's first file is shorter than its
+    matched rows, so a pair lies in no file of the snapshot."""
+    [entry] = KeyCache.instance()._entries.values()
+    path = _file_of(log, 20)
+    off, _rows_ = entry.slabs[path]
+    entry.slabs[path] = (off, 2)
+
+
+@pytest.mark.parametrize("stale", ["matched-a-deleted-row",
+                                   "pair-in-no-file"])
+def test_pairs_only_stale_slab_does_not_commit(tmp_path, stale):
+    """No decode vouches for the slab on this route, so what the decode
+    route cross-checked is checked where it is free: the union of a file's
+    old vector with the claimed positions must grow by every claimed row.
+    A slab that matched a deleted row is dropped and the MERGE runs once
+    more, from the files."""
+    log_a, log_b = _dv_tables(tmp_path)
+    first = _source([5, 6, 7, 650])
+    _prebuild(log_a, "t.k = s.k", ["k", "v", "tag"], first.column_names)
+    _run(log_a, first, "t.k = s.k", [UP], [INS], "force")
+    _run(log_b, first, "t.k = s.k", [UP], [INS], "off")
+    # keys 5 and 6 now live in the new file and lie deleted in the first
+    second = _source([5, 6, 20, 700, 950])
+    # the entry is advanced to the snapshot first, then broken
+    MergeIntoCommand(log_a, second, "t.k = s.k", [UP], [INS], **ALIAS)
+    tcols = ["k", "v", "tag"]
+    _prebuild(log_a, "t.k = s.k", tcols, tcols)
+    (_revive_deleted_rows if stale == "matched-a-deleted-row"
+     else _shrink_a_file)(log_a)
+    version = log_a.update().version
+    n0, d0 = _counts()
+    cmd_a = _run(log_a, second, "t.k = s.k", [UP], [INS], "force")
+    assert _counts() == (n0 + 1, d0 + 1)
+    assert log_a.update().version == version + 1  # one commit, the second run's
+    assert cmd_a._join_path == "device-cold" and not cmd_a._pairs_only
+    assert cmd_a.metrics["numTargetRowsUpdated"] == 4
+    assert cmd_a.metrics["numTargetRowsInserted"] == 1
+    cmd_b = _run(log_b, second, "t.k = s.k", [UP], [INS], "off")
+    _same_outcome(log_a, log_b, cmd_a, cmd_b, order=False)
+
+
+def test_a_slab_built_over_touched_files_only_is_not_the_tables(tmp_path):
+    """The touched-files pre-probe narrows a cold build to the files the
+    source touches; registered as the table's, that slab answered a later
+    insert-only MERGE for files it had never seen (duplicate rows)."""
+    log_a, log_b = _dv_tables(tmp_path)
+    _run(log_a, _source([10, 11]), "t.k = s.k", [UP], [INS], "force")
+    _run(log_b, _source([10, 11]), "t.k = s.k", [UP], [INS], "off")
+    assert not KeyCache.instance()._entries
+    again = _source([510, 511, 900])
+    cmd_a = _run(log_a, again, "t.k = s.k", [], [INS], "force")
+    cmd_b = _run(log_b, again, "t.k = s.k", [], [INS], "off")
+    assert cmd_a.metrics["numTargetRowsInserted"] == 1
     assert _rows(log_a) == _rows(log_b)

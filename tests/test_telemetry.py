@@ -726,15 +726,16 @@ def _keyed_table(path, rows=8000, files=8):
             configuration={"delta.tpu.enableDeletionVectors": "true"})
 
 
-def _upsert(table, lo, hi):
-    """MERGE ``k`` in [lo, hi) into the table through the command the public
-    builder runs; returns the command (for ``phase_ms``)."""
+def _upsert(table, lo, hi, step=1):
+    """MERGE every ``step``-th ``k`` in [lo, hi) into the table through the
+    command the public builder runs; returns the command (for ``phase_ms``)."""
     import numpy as np
 
     from delta_tpu.commands.merge import MergeClause, MergeIntoCommand
 
-    n = hi - lo
-    src = pa.table({"k": pa.array(np.arange(lo, hi, dtype=np.int64)),
+    keys = np.arange(lo, hi, step, dtype=np.int64)
+    n = len(keys)
+    src = pa.table({"k": pa.array(keys),
                     "d": pa.array(np.zeros(n, np.int32)),
                     "q": pa.array(np.zeros(n, np.int32))})
     cmd = MergeIntoCommand(
@@ -764,25 +765,51 @@ def test_merge_phases_are_child_spans_that_fill_phase_ms(
     t = _keyed_table(tmp_path / "t")
     with conf.set_temporarily(**DEVICE):
         if resident:
-            _upsert(t, 7990, 8010)  # builds and registers the key slab
+            # a source in every file: the slab is built over the whole table
+            # and registered
+            _upsert(t, 5, 8010, step=500)
         telemetry.clear_events()
         cmd = _upsert(t, 7900, 8100)  # half existing keys, half fresh
     assert cmd.metrics["numTargetRowsUpdated"] > 0
     events = telemetry.recent_events()
     [root] = [e for e in events if e.op_type == "delta.dml.merge"]
-    for name, key in MERGE_PHASES.items():
+    # a resident star upsert over deletion vectors takes the pairs-only
+    # route: the probe's pairs are the join, so no touched-files pre-probe
+    # runs and the row decode reads nothing (its span still fills decode_ms)
+    expect = {name: key for name, key in MERGE_PHASES.items()
+              if not (resident and name == "delta.dist.mergeProbe")}
+    for name, key in expect.items():
         [ev] = [e for e in events if e.op_type == name]  # exactly once
         assert ev.parent_id == root.span_id, name
         assert cmd.phase_ms[key] == ev.duration_us / 1000.0, name
+    by_name = {e.op_type: e for e in events}
+    if resident:
+        assert "delta.dist.mergeProbe" not in by_name
+        assert "probe_ms" not in cmd.phase_ms
+        assert not any(e.op_type.startswith("delta.scan") for e in events)
+        assert by_name["delta.dml.merge.join"].data["route"] == "pairs-only"
+    else:
+        assert by_name["delta.dml.merge.join"].data["route"] == "decode"
+    [router] = [e for e in events if e.op_type == "delta.merge.router"]
+    assert router.data["route"] == by_name["delta.dml.merge.join"].data["route"]
     [commit] = [e for e in events if e.op_type == "delta.commit"]
     assert commit.parent_id == root.span_id
     # the phases tile the command: in order, not overlapping on its thread
     phases = sorted((e for e in events
                      if e.op_type in MERGE_PHASES or e is commit),
                     key=lambda e: e.start_us)
-    assert [e.op_type for e in phases][:5] == list(MERGE_PHASES)[:5]
+    assert [e.op_type for e in phases][:len(expect) - 4] == list(expect)[:-4]
     for a, b in zip(phases, phases[1:]):
         assert a.start_us + a.duration_us <= b.start_us + 1
+    # on a device route the wait for the device and the host's pair work
+    # tile the join
+    join = by_name["delta.dml.merge.join"]
+    stages = sorted((e for e in events if e.parent_id == join.span_id),
+                    key=lambda e: e.start_us)
+    assert [e.op_type for e in stages] == ["delta.dml.merge.join.wait",
+                                           "delta.dml.merge.join.pairs"]
+    assert stages[0].start_us + stages[0].duration_us <= stages[1].start_us + 1
+    assert sum(e.duration_us for e in stages) >= 0.9 * join.duration_us
     assert _cover(events, root) >= 0.9
     [dv] = [e for e in events if e.op_type == "delta.dml.merge.deletionVectors"]
     assert dv.data["rows"] == cmd.metrics["numTargetRowsUpdated"]
