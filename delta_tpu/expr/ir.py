@@ -18,6 +18,7 @@ yield NULL; AND/OR use Kleene logic — matching Spark SQL.
 from __future__ import annotations
 
 import math
+from decimal import Decimal
 import re
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -157,9 +158,18 @@ class Column(Expression):
 
 
 class Literal(Expression):
-    def __init__(self, value: Any, data_type: Optional[DataType] = None):
+    """``exact`` is the literal as a ``decimal.Decimal`` when the source
+    wrote it in decimal notation (``0.07``, or ``0.06 + 0.01`` folded by
+    the parser): ``value`` stays the nearest float for every consumer that
+    computes in floats, and a comparison with a decimal column or lane
+    takes ``exact``, so that ``0.06 + 0.01`` is 0.07 and not the float
+    below it."""
+
+    def __init__(self, value: Any, data_type: Optional[DataType] = None,
+                 exact: Optional[Decimal] = None):
         self.value = value
         self.data_type = data_type or _infer_type(value)
+        self.exact = exact
         self.children = ()
 
     def eval(self, row: Dict[str, Any]) -> Any:
@@ -168,6 +178,8 @@ class Literal(Expression):
     def sql(self) -> str:
         if self.value is None:
             return "NULL"
+        if self.exact is not None:
+            return format(self.exact, "f")
         if isinstance(self.value, bool):
             return "TRUE" if self.value else "FALSE"
         if isinstance(self.value, str):
@@ -320,6 +332,12 @@ class _Comparison(_Binary):
         r = self.right.eval(row)
         if l is None or r is None:
             return None
+        # a decimal value against a literal written in decimal notation
+        # compares with the literal as written, not with its nearest float
+        if isinstance(l, Decimal) and getattr(self.right, "exact", None) is not None:
+            r = self.right.exact
+        elif isinstance(r, Decimal) and getattr(self.left, "exact", None) is not None:
+            l = self.left.exact
         l, r = _coerce_pair(l, r)
         try:
             return self.py(l, r)
@@ -386,6 +404,8 @@ class In(Expression):
         saw_null = False
         for o in self.options:
             ov = o.eval(row)
+            if isinstance(v, Decimal) and getattr(o, "exact", None) is not None:
+                ov = o.exact  # the option as written, not its nearest float
             if ov is None:
                 saw_null = True
             elif ov == v:

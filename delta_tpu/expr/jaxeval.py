@@ -25,7 +25,7 @@ from delta_tpu.utils.jaxcache import ensure_compilation_cache
 
 __all__ = ["DeviceColumn", "compile_expr", "NotDeviceCompilable",
            "ResidualPlan", "compile_residual", "STR_CODE_ABSENT",
-           "f64_order_key"]
+           "f64_order_key", "DECIMAL_LANE_PRECISION", "decimal_literal_units"]
 
 
 class NotDeviceCompilable(DeltaAnalysisError):
@@ -364,6 +364,36 @@ def f64_order_key(values):
     return bits ^ ((bits >> 63) & np.int64(0x7FFFFFFFFFFFFFFF))
 
 
+#: a ``decimal(p, s)`` column with ``p`` up to this is an int64 lane of its
+#: unscaled values (10^18 < 2^63); a wider one has no lane
+DECIMAL_LANE_PRECISION = 18
+
+
+def decimal_literal_units(lit: ir.Literal, scale: int):
+    """``(floor(L * 10^scale), L * 10^scale is whole)`` for an exact numeric
+    literal ``L`` (an integer, or one written in decimal notation), in
+    integer arithmetic; None for any other literal. What a compare of a
+    ``decimal(p, scale)`` lane against ``L`` lowers through: a literal with
+    more digits than the scale tightens the bound and is never rounded."""
+    v = lit.value
+    if isinstance(v, bool):
+        return None
+    if lit.exact is not None:
+        sign, digits, exp = lit.exact.as_tuple()
+        if not isinstance(exp, int):  # NaN, Infinity
+            return None
+        n = int("".join(map(str, digits)) or "0") * (-1 if sign else 1)
+    elif isinstance(v, int):
+        n, exp = v, 0
+    else:
+        return None
+    exp += scale
+    if exp >= 0:
+        return n * 10 ** exp, True
+    q, r = divmod(n, 10 ** -exp)  # floors, also below zero
+    return q, r == 0
+
+
 _KEY_NEG_INF = int(f64_order_key(-np.inf))
 _KEY_POS_INF = int(f64_order_key(np.inf))
 
@@ -396,9 +426,17 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
       lane encodings (epoch days / epoch microseconds), and
       ``year``/``month``/``day``/``to_date``/``hour`` over temporal columns
       lower to the device calendar kernels;
-    * decimal columns, string partition references, and mixed
-      date-vs-timestamp compares raise :class:`NotDeviceCompilable` — the
-      caller falls back to the Arrow path;
+    * a ``decimal(p, s)`` column with ``p <= 18`` is an int64 lane of
+      unscaled values: compared against an exact literal (an integer or
+      one written in decimal notation, scaled to ``s`` by
+      :func:`decimal_literal_units`; more digits than ``s`` tighten the
+      bound), against ``IN`` literals, against a decimal column of the same
+      scale, or null-tested, it lowers to an exact int64 compare; every
+      other use of a decimal column (arithmetic, a float literal, ``p >
+      18``, a partition column) raises :class:`NotDeviceCompilable`;
+    * string partition references and mixed date-vs-timestamp compares
+      raise :class:`NotDeviceCompilable` — the caller falls back to the
+      Arrow path;
     * float lanes hold :func:`f64_order_key` keys (a TPU's float64 is not
       IEEE): a float column compared against a numeric literal (or ``IN``
       literals), or null-tested, lowers to an exact int64 compare over
@@ -437,10 +475,13 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
         return isinstance(types.get(n), (FloatType, DoubleType))
 
     def _note(c: ir.Column, as_key: bool = False) -> ir.Column:
+        """``as_key``: the caller reads the lane in its own encoding (a
+        float's order keys, a decimal's unscaled integers)."""
         n = c.name.lower()
-        if isinstance(types.get(n), DecimalType):
+        if not as_key and isinstance(types.get(n), DecimalType):
             raise NotDeviceCompilable(
-                f"decimal column {c.name!r} stays on host (exact arithmetic)")
+                f"decimal column {c.name!r} outside an exact compare stays "
+                f"on host (exact arithmetic)")
         if not as_key and _is_float(n):
             raise _inexact(f"float column {c.name!r} outside a literal compare")
         if n in parts:
@@ -501,6 +542,39 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
             return x
         return None
 
+    def _dec_lane(x) -> Optional[ir.Column]:
+        """The decimal DATA column ``x`` is, when it has an int64 lane."""
+        x = _strip(x)
+        if isinstance(x, ir.Column) and x.name.lower() not in parts:
+            dt = types.get(x.name.lower())
+            if isinstance(dt, DecimalType) \
+                    and dt.precision <= DECIMAL_LANE_PRECISION:
+                return x
+        return None
+
+    def _dec_compare(t, c: ir.Column, lit: ir.Literal) -> ir.Expression:
+        """``c <t> lit`` over the lane's unscaled integers."""
+        col = _note(c, as_key=True)
+        if lit.value is None:
+            return t(col, lit)
+        units = decimal_literal_units(lit, types[col.name].scale)
+        if units is None:
+            raise _inexact(f"decimal column {c.name!r} against the inexact "
+                           f"literal {lit.sql()}")
+        floor, whole = units
+        if not whole:
+            # between two lane values: no row equals it (Ne(c, c) is FALSE
+            # for a value and NULL for a NULL, as the compare would be), and
+            # an ordering compare moves to the whole number below
+            if t is ir.NullSafeEq:
+                raise NotDeviceCompilable(
+                    f"<=> against {lit.sql()}, which no {c.name!r} holds")
+            t = {ir.Eq: ir.Ne, ir.Ne: ir.Eq, ir.Ge: ir.Gt,
+                 ir.Lt: ir.Le}.get(t, t)
+            if t in (ir.Eq, ir.Ne):
+                return t(col, col)
+        return t(col, ir.Literal(floor))
+
     def _key_lit(x) -> Optional[ir.Literal]:
         x = _strip(x)
         if isinstance(x, ir.Literal) and not isinstance(x.value, bool):
@@ -539,6 +613,13 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
             if isinstance(l, ir.Literal) and not isinstance(r, ir.Literal):
                 l, r = r, l
                 t = _CMP_FLIP.get(t, t)
+            dcol = _dec_lane(l)
+            if dcol is not None and isinstance(_strip(r), ir.Literal):
+                return _dec_compare(t, dcol, _strip(r))
+            if dcol is not None and _dec_lane(r) is not None \
+                    and _ctype(l).scale == _ctype(r).scale:
+                return t(_note(dcol, as_key=True),
+                         _note(_dec_lane(r), as_key=True))
             kcol, klit = _key_lane(l), _key_lit(r)
             if kcol is not None and klit is not None:
                 col = _note(kcol, as_key=True)
@@ -581,6 +662,21 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
             v = _strip(x.value)
             vt = _ctype(v)
             opts = list(x.options)
+            dcol = _dec_lane(v)
+            if dcol is not None and all(
+                    isinstance(_strip(o), ir.Literal) for o in opts):
+                col, new_opts = _note(dcol, as_key=True), []
+                for o in map(_strip, opts):
+                    units = None if o.value is None else \
+                        decimal_literal_units(o, types[col.name].scale)
+                    if o.value is not None and units is None:
+                        raise _inexact(f"decimal IN option {o.sql()}")
+                    if units is None:
+                        new_opts.append(o)  # NULL option: Kleene semantics
+                    elif units[1]:
+                        new_opts.append(ir.Literal(units[0]))
+                    # an option between two lane values matches no row
+                return ir.In(col, new_opts) if new_opts else ir.Ne(col, col)
             kcol, kopts = _key_lane(v), [_key_lit(o) for o in opts]
             if kcol is not None and all(o is not None for o in kopts):
                 return ir.In(_note(kcol, as_key=True), kopts)
@@ -633,7 +729,7 @@ def compile_residual(e: ir.Expression, types: Dict[str, Any],
                 raise NotDeviceCompilable("hour() needs a timestamp lane")
             return ir.Func("hour", [rw(x.children[0])])
         if t in (ir.IsNull, ir.IsNotNull):
-            kcol = _key_lane(x.child)
+            kcol = _key_lane(x.child) or _dec_lane(x.child)
             if kcol is not None:
                 return t(_note(kcol, as_key=True))  # reads validity only
         if (t is ir.Div

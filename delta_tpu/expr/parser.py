@@ -8,14 +8,22 @@ this is our equivalent for strings like ``"date > '2020-01-01' AND id IN
 Grammar (Pratt parser, precedence low→high):
     OR < AND < NOT < comparison (= == != <> < <= > >= <=> IS IN BETWEEN LIKE)
     < additive (+ -) < multiplicative (* / %) < unary (- NOT) < primary
+
+Literals are folded as they are parsed, exactly: a number written in decimal
+notation keeps its digits (``ir.Literal.exact``), ``+ - *`` between such
+literals (and integers) is computed in ``decimal.Decimal``, never in floats,
+so ``0.06 + 0.01`` is 0.07; ``DATE 'yyyy-mm-dd'`` is a typed date literal and
+``DATE '…' ± INTERVAL 'n' YEAR|MONTH|DAY`` is the date it names.
 """
 from __future__ import annotations
 
+import datetime as _dt
 import re
+from decimal import Decimal, InvalidOperation
 from typing import List, Optional
 
 from delta_tpu.expr import ir
-from delta_tpu.schema.types import parse_data_type
+from delta_tpu.schema.types import DateType, parse_data_type
 from delta_tpu.utils.errors import DeltaAnalysisError
 from delta_tpu.utils import errors
 
@@ -67,6 +75,62 @@ def _tokenize(s: str) -> List[_Tok]:
     return out
 
 
+class _Interval(ir.Expression):
+    """``INTERVAL 'n' unit`` while it waits for the date it is added to; it
+    never leaves the parser."""
+
+    def __init__(self, months: int, days: int):
+        self.months, self.days = months, days
+
+    def sql(self) -> str:
+        return f"INTERVAL {self.months} MONTH {self.days} DAY"
+
+
+def _decimal_literal(d: Decimal) -> ir.Literal:
+    return ir.Literal(float(d), exact=d)
+
+
+def _exact_number(e: ir.Expression) -> Optional[Decimal]:
+    """The exact value of a numeric literal: an integer, or a number
+    written in decimal notation."""
+    if not isinstance(e, ir.Literal) or isinstance(e.value, bool):
+        return None
+    if e.exact is not None:
+        return e.exact
+    return Decimal(e.value) if isinstance(e.value, int) else None
+
+
+def _fold_numbers(op: str, left: ir.Expression,
+                  right: ir.Expression) -> Optional[ir.Literal]:
+    """``left op right`` between two exact numeric literals of which one at
+    least is written in decimal notation (integers alone are left to the
+    evaluators, which are exact on them)."""
+    l, r = _exact_number(left), _exact_number(right)
+    if l is None or r is None or (left.exact is None and right.exact is None):
+        return None
+    try:
+        return _decimal_literal({"+": l + r, "-": l - r, "*": l * r}[op])
+    except InvalidOperation:  # beyond the context's precision: not exact
+        return None
+
+
+def _shift_date(day: _dt.date, iv: _Interval, sign: int) -> _dt.date:
+    """Months first, the day of the month clamped to the month's last (31
+    January + 1 month is the last of February), then days."""
+    months = day.year * 12 + (day.month - 1) + sign * iv.months
+    year, month = divmod(months, 12)
+    first_of_next = _dt.date(year + (month == 11), (month + 1) % 12 + 1, 1)
+    last = (first_of_next - _dt.timedelta(days=1)).day
+    return (_dt.date(year, month + 1, min(day.day, last))
+            + _dt.timedelta(days=sign * iv.days))
+
+
+def _date_literal(day: _dt.date) -> ir.Literal:
+    # the value stays the ISO string every evaluator and planner already
+    # coerces against date columns; the type says what it is
+    return ir.Literal(day.isoformat(), DateType())
+
+
 class _Parser:
     def __init__(self, tokens: List[_Tok], source: str):
         self.toks = tokens
@@ -103,6 +167,9 @@ class _Parser:
         e = self.parse_or()
         if self.peek() is not None:
             raise errors.trailing_tokens(self.peek(), self.source)
+        for node in e.walk():
+            if isinstance(node, _Interval):
+                raise errors.interval_without_date(self.source)
         return e
 
     def parse_or(self) -> ir.Expression:
@@ -175,7 +242,14 @@ class _Parser:
             if t and t.kind == "op" and t.text in ("+", "-"):
                 self.next()
                 right = self.parse_multiplicative()
-                left = (ir.Add if t.text == "+" else ir.Sub)(left, right)
+                if isinstance(right, _Interval) and isinstance(left, ir.Literal) \
+                        and isinstance(left.data_type, DateType):
+                    left = _date_literal(_shift_date(
+                        _dt.date.fromisoformat(left.value), right,
+                        1 if t.text == "+" else -1))
+                    continue
+                left = _fold_numbers(t.text, left, right) or \
+                    (ir.Add if t.text == "+" else ir.Sub)(left, right)
             else:
                 return left
 
@@ -186,13 +260,18 @@ class _Parser:
             if t and t.kind == "op" and t.text in ("*", "/", "%"):
                 self.next()
                 right = self.parse_unary()
-                left = {"*": ir.Mul, "/": ir.Div, "%": ir.Mod}[t.text](left, right)
+                folded = _fold_numbers("*", left, right) if t.text == "*" else None
+                left = folded or {"*": ir.Mul, "/": ir.Div,
+                                  "%": ir.Mod}[t.text](left, right)
             else:
                 return left
 
     def parse_unary(self) -> ir.Expression:
         if self.accept("op", "-"):
-            return ir.Neg(self.parse_unary())
+            child = self.parse_unary()
+            if isinstance(child, ir.Literal) and child.exact is not None:
+                return _decimal_literal(-child.exact)
+            return ir.Neg(child)
         if self.accept("op", "+"):
             return self.parse_unary()
         return self.parse_primary()
@@ -204,8 +283,10 @@ class _Parser:
             if text[-1] in "LlDd" and not text[-1].isdigit():
                 suffix, text = text[-1].lower(), text[:-1]
                 return ir.Literal(int(text) if suffix == "l" else float(text))
-            if "." in text or "e" in text.lower():
+            if "e" in text.lower():
                 return ir.Literal(float(text))
+            if "." in text:
+                return _decimal_literal(Decimal(text))
             return ir.Literal(int(text))
         if t.kind == "str":
             q = t.text[0]
@@ -243,6 +324,11 @@ class _Parser:
             e = self.parse_or()
             self.expect("op", ")")
             return e
+        if t.kind == "id" and t.text.upper() in ("DATE", "INTERVAL") \
+                and self.peek() and self.peek().kind == "str":
+            typed = self._typed_literal(t.text.upper())
+            if typed is not None:
+                return typed
         if t.kind in ("id", "bq"):
             name = t.text[1:-1].replace("``", "`") if t.kind == "bq" else t.text
             # function call?
@@ -270,6 +356,25 @@ class _Parser:
                 parts.append(nxt.text[1:-1].replace("``", "`") if nxt.kind == "bq" else nxt.text)
             return ir.Column(".".join(parts))
         raise errors.unexpected_token(t, self.source)
+
+    def _typed_literal(self, word: str) -> Optional[ir.Expression]:
+        """``DATE 'yyyy-mm-dd'`` or ``INTERVAL 'n' YEAR|MONTH|DAY``, the
+        word already consumed and a string next."""
+        text = self.next().text[1:-1].strip()
+        if word == "DATE":
+            try:
+                return _date_literal(_dt.date.fromisoformat(text))
+            except ValueError:
+                raise errors.bad_date_literal(text, self.source) from None
+        unit = self.peek()
+        units = {"YEAR": (12, 0), "YEARS": (12, 0), "MONTH": (1, 0),
+                 "MONTHS": (1, 0), "DAY": (0, 1), "DAYS": (0, 1)}
+        if unit is None or unit.kind != "id" or unit.text.upper() not in units \
+                or not re.fullmatch(r"[+-]?\d+", text):
+            raise errors.bad_interval_literal(self.source)
+        self.next()
+        months, days = units[unit.text.upper()]
+        return _Interval(months * int(text), days * int(text))
 
     def _parse_type_name(self) -> str:
         tok = self.next()

@@ -127,6 +127,11 @@ def _numeric_coerce(l: Any, r: Any):
     return l, r
 
 
+def _is_decimal(x: Any) -> bool:
+    t = getattr(x, "type", None)
+    return t is not None and pa.types.is_decimal(t)
+
+
 class _Vectorizer:
     def __init__(self, table: pa.Table):
         self.table = table
@@ -181,23 +186,34 @@ class _Vectorizer:
         l, r = _numeric_coerce(self.visit(e.left), self.visit(e.right))
         return fn(l, r)
 
+    def _compare(self, e, fn):
+        """A decimal column against a literal written in decimal notation
+        compares decimal with decimal (Arrow rescales exactly); the float
+        the literal also carries would be ``0.06 + 0.01 < 0.07``."""
+        l, r = self.visit(e.left), self.visit(e.right)
+        if _is_decimal(l) and getattr(e.right, "exact", None) is not None:
+            r = pa.scalar(e.right.exact)
+        elif _is_decimal(r) and getattr(e.left, "exact", None) is not None:
+            l = pa.scalar(e.left.exact)
+        return fn(*_numeric_coerce(l, r))
+
     def _v_Eq(self, e):
-        return self._cmp(e, pc.equal)
+        return self._compare(e, pc.equal)
 
     def _v_Ne(self, e):
-        return self._cmp(e, pc.not_equal)
+        return self._compare(e, pc.not_equal)
 
     def _v_Lt(self, e):
-        return self._cmp(e, pc.less)
+        return self._compare(e, pc.less)
 
     def _v_Le(self, e):
-        return self._cmp(e, pc.less_equal)
+        return self._compare(e, pc.less_equal)
 
     def _v_Gt(self, e):
-        return self._cmp(e, pc.greater)
+        return self._compare(e, pc.greater)
 
     def _v_Ge(self, e):
-        return self._cmp(e, pc.greater_equal)
+        return self._compare(e, pc.greater_equal)
 
     def _v_NullSafeEq(self, e):
         l = _as_array(self.visit(e.left), self.n)
@@ -208,9 +224,11 @@ class _Vectorizer:
 
     def _v_In(self, e: ir.In):
         v = _as_array(self.visit(e.value), self.n)
-        opts = [o.value for o in e.options if isinstance(o, ir.Literal)]
-        if len(opts) != len(e.options):
+        if not all(isinstance(o, ir.Literal) for o in e.options):
             return self._fallback(e)
+        if _is_decimal(v) and any(o.exact is not None for o in e.options):
+            return self._fallback(e)  # the row evaluator compares exactly
+        opts = [o.value for o in e.options]
         has_null_opt = any(o is None for o in opts)
         vals = [o for o in opts if o is not None]
         found = pc.is_in(v, value_set=pa.array(vals, type=v.type) if vals else pa.nulls(0, v.type))

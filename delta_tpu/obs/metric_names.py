@@ -199,6 +199,8 @@ ENGINE_COUNTERS = frozenset({
     "scan.device.declined",
     "scan.device.fallback",
     "scan.device.compiles",
+    "scan.aggregate.device",
+    "scan.aggregate.declined",
     "scan.prune.deviceFallback",
     "columnCache.hits",
     "columnCache.misses",
@@ -463,6 +465,8 @@ DESCRIPTIONS = {
     "scan.device.declined": "Scans where the cost model kept the residual on host.",
     "scan.device.fallback": "Device residual attempts that fell back to the host path.",
     "scan.device.compiles": "XLA compiles that ran with a delta.scan span open on the compiling thread (a new literal or lane shape).",
+    "scan.aggregate.device": "Ungrouped aggregate SELECTs answered by the fused filter-and-sum kernel over resident lanes.",
+    "scan.aggregate.declined": "Ungrouped aggregate SELECTs the device route declined (the delta.scan.deviceAggregate span's route says why); the host scan answered.",
     "device.compiles": "XLA compiles in this process (persistent-cache fetches not counted).",
     "device.compileUs": "Microseconds spent in those XLA compiles.",
     "device.cacheFetches": "Executables fetched from the persistent compilation cache instead of compiled.",

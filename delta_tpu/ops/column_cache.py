@@ -63,6 +63,14 @@ def column_cache_enabled() -> bool:
                ).lower() != "off"
 
 
+def lane_budget() -> int:
+    """Bytes the cache may keep resident: its own bound, under the HBM
+    ledger's allowance where a device budget is set."""
+    budget = int(conf.get("delta.tpu.columnCache.maxBytes", 1 << 30))
+    allowance = hbm_ledger.column_cache_allowance()
+    return budget if allowance is None else min(budget, allowance)
+
+
 def _abs_data_path(data_path: str, file_path: str) -> str:
     if "://" in file_path or os.path.isabs(file_path):
         return urllib.parse.unquote(file_path)
@@ -77,7 +85,9 @@ def _lane_from_arrow(arr) -> Optional[Tuple[np.ndarray, np.ndarray,
     the value→code map returned for literal binding, date32 becomes epoch
     days (int32), timestamps epoch µs (int64), numerics widen to
     int64, floats become int64 order keys (`jaxeval.f64_order_key`: a
-    TPU's float64 is not IEEE). Returns None for types with no lane form."""
+    TPU's float64 is not IEEE), ``decimal(p, s)`` with ``p <= 18`` becomes
+    its unscaled values as int64 (exact; `jaxeval.compile_residual` scales
+    the literals to ``s``). Returns None for types with no lane form."""
     import pyarrow as pa
     import pyarrow.compute as pc
 
@@ -107,6 +117,13 @@ def _lane_from_arrow(arr) -> Optional[Tuple[np.ndarray, np.ndarray,
         vals = jaxeval.f64_order_key(
             arr.cast(pa.float64()).fill_null(0.0).to_numpy(
                 zero_copy_only=False))
+    elif pa.types.is_decimal128(t) \
+            and t.precision <= jaxeval.DECIMAL_LANE_PRECISION:
+        # 16 little-endian bytes a value; below 10^18 the low word is the
+        # value (two's complement), whatever a NULL's slot holds is zeroed
+        words = np.frombuffer(arr.buffers()[1], np.int64)
+        vals = np.where(valid, words[2 * arr.offset:
+                                     2 * (arr.offset + len(arr)):2], 0)
     else:
         return None
     return vals, valid, None
@@ -118,11 +135,13 @@ class ResidentColumn:
     similar size hit the same jit shape-cache entry; pad rows carry
     ``valid=False`` and slice away after the mask download. String lanes
     keep their host-side value→code dictionary for per-scan literal
-    binding."""
+    binding. ``lo`` / ``hi`` bound the lane's integers (a NULL's slot holds
+    0), from the decode: what `ops/column_aggregate` proves an exact int64
+    sum from."""
 
     __slots__ = ("log_path", "file_path", "column", "values", "valid", "n",
-                 "dict_codes", "nbytes", "epoch", "last_used", "_account",
-                 "_lock", "__weakref__")
+                 "dict_codes", "nbytes", "epoch", "last_used", "lo", "hi",
+                 "_account", "_lock", "__weakref__")
 
     def __init__(self, log_path: str, file_path: str, column: str,
                  values: np.ndarray, valid: np.ndarray,
@@ -131,6 +150,9 @@ class ResidentColumn:
         self.file_path = file_path
         self.column = column
         self.n = int(len(values))
+        integers = self.n and values.dtype.kind == "i"
+        self.lo = int(values.min()) if integers else 0
+        self.hi = int(values.max()) if integers else 0
         cap = _next_pow2(max(self.n, 1), floor=64)
         pv = np.zeros(cap, dtype=values.dtype)
         pv[: self.n] = values
@@ -284,10 +306,7 @@ class ColumnCache:
     def _evict(self, keep=None) -> None:
         from delta_tpu.utils.telemetry import bump_counter
 
-        budget = int(conf.get("delta.tpu.columnCache.maxBytes", 1 << 30))
-        allowance = hbm_ledger.column_cache_allowance()
-        if allowance is not None:
-            budget = min(budget, allowance)
+        budget = lane_budget()
         max_entries = int(conf.get("delta.tpu.columnCache.maxEntries", 4096))
         dropped = 0
         with self._lock:

@@ -432,6 +432,16 @@ def _select(p: _Parser):
     p.expect_end()
 
     def run():
+        # the query's root span: the log's update, then either the device
+        # aggregate or the scan (`delta.scan`) and the host's aggregate
+        from delta_tpu.utils import telemetry
+
+        with telemetry.record_operation("delta.sql.select") as ev:
+            out = select()
+            ev.data["rowsOut"] = out.num_rows
+            return out
+
+    def select():
         from delta_tpu.exec.scan import scan_to_table
         from delta_tpu.expr import ir as _ir
         from delta_tpu.expr.parser import parse_expression
@@ -502,15 +512,24 @@ def _select(p: _Parser):
                 read_cols = None
         if (has_agg or group_by) and star:
             raise DeltaParseError("SELECT * cannot be combined with GROUP BY")
-        table = scan_to_table(snap, filters=[cond] if cond else (),
-                              columns=read_cols)
+        out = None
+        if has_agg and not group_by:
+            # an ungrouped aggregate over columns that have lanes is
+            # answered from them (ops/column_aggregate), or declines
+            from delta_tpu.ops.column_aggregate import device_aggregate
+
+            out = device_aggregate(
+                snap, [parse_expression(cond)] if cond else [], parsed_items)
+        # answered from the lanes, or the scan decodes the columns it needs
+        table = None if out is not None else scan_to_table(
+            snap, filters=[cond] if cond else (), columns=read_cols)
         pre_sort = False
         hidden: List[str] = []
-        if has_agg or group_by:
+        if table is not None and (has_agg or group_by):
             order_keys = [c.strip("`").lower() for c, _d in order]
             out, hidden = _run_aggregate(table, parsed_items, group_by,
                                          order_keys, evaluate)
-        else:
+        elif table is not None:
             # ORDER BY resolves against source columns first (SQL allows
             # sorting by non-projected columns), then aliases
             src_lower = {c.lower(): c for c in table.column_names}

@@ -498,6 +498,26 @@ def trailing_tokens(token, source: str) -> DeltaParseError:
     )
 
 
+def interval_without_date(source: str) -> DeltaParseError:
+    return DeltaParseError(
+        "INTERVAL is supported only added to or subtracted from a DATE "
+        f"literal, in {source!r}."
+    )
+
+
+def bad_date_literal(text: str, source: str) -> DeltaParseError:
+    return DeltaParseError(
+        f"DATE literal {text!r} is not yyyy-mm-dd, in {source!r}."
+    )
+
+
+def bad_interval_literal(source: str) -> DeltaParseError:
+    return DeltaParseError(
+        "INTERVAL takes a whole number and YEAR, MONTH or DAY, in "
+        f"{source!r}."
+    )
+
+
 def unexpected_keyword(text: str, source: str) -> DeltaParseError:
     return DeltaParseError(
         f"Unexpected keyword {text} in {source!r}."

@@ -415,7 +415,7 @@ def _types():
 
     return {"a": IntegerType(), "s": StringType(), "d": DateType(),
             "ts": TimestampType(), "x": DoubleType(),
-            "m": DecimalType(10, 2)}
+            "m": DecimalType(10, 2), "w": DecimalType(20, 2)}
 
 
 def test_residual_string_literals_become_code_binds():
@@ -450,7 +450,9 @@ def test_residual_date_vs_timestamp_midnight_combine():
 @pytest.mark.parametrize("bad", [
     "s < 'm'",                 # string ordering has no code semantics
     "upper(s) = 'A'",          # string function
-    "m > 5",                   # decimal stays on host
+    "m * 2 > 5",               # decimal arithmetic stays on host
+    "m > 5e0",                 # an inexact literal against a decimal lane
+    "w > 5",                   # decimal(20, 2): no int64 lane
     "d = ts",                  # mixed temporal compare
     "a = 'five'",              # string literal vs numeric lane
 ])
