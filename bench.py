@@ -1397,7 +1397,6 @@ def bench_resident_probe(workdir):
     import jax
     import jax.numpy as jnp
 
-    from delta_tpu.ops import key_cache as kc
     from delta_tpu.ops.join_kernel import _bucket
     from delta_tpu.ops.key_cache import ResidentJoinKeys
     from delta_tpu.parallel import link
@@ -1506,51 +1505,8 @@ def bench_resident_probe(workdir):
                 _timed(lambda: e.probe_async(s_keys, s_ok).result())[0]
                 for _ in range(trials))
 
-            # phase decomposition (replicates probe_async internals)
-            s_enc = s_keys.astype(np.int32)
-            cap_s = _bucket(len(s_enc))
-            s_in = np.full(cap_s, np.iinfo(np.int32).max - 1, np.int32)
-            s_in[: len(s_enc)] = s_enc
-            up_s = min(_timed(lambda: jax.block_until_ready(
-                jax.device_put(s_in)))[0] for _ in range(trials))
-            s_dev = jax.device_put(s_in)
-            jax.block_until_ready(s_dev)
-            dev_h = e._dev
-
-            def kernel_only():
-                with enable_x64():
-                    out = kc._probe_sorted_kernel()(
-                        dev_h["sorted_keys"], dev_h["sorted_valid"],
-                        jnp.asarray(np.int32(n)), s_dev)
-                np.asarray(out[0][:2])  # force completion (tiny fetch)
-                return out
-
-            head_dev, t_match_dev, s_first_dev = kernel_only()
-            k_s = min(_timed(kernel_only)[0] for _ in range(trials))
-            head_s, head = _timed(lambda: np.asarray(head_dev))
-            _multi, overflow, mc, _sm = kc._decode_head(
-                head, cap_s, len(s_keys))
-            assert not overflow, "probe overflow on a bench shape"
-
-            def pairs_fetch():
-                # the fused path's O(matched) pair download (physical row +
-                # first-match source row, compacted on device)
-                if mc == 0:
-                    return None
-                out_cap = kc._next_pow2(mc, floor=64)
-                return np.asarray(kc._pair_compact_kernel()(
-                    t_match_dev, s_first_dev, dev_h["perm"], out_cap))
-
-            pairs_fetch()
-            fine_s = min(_timed(pairs_fetch)[0] for _ in range(trials))
-            resident_source_s = k_s + head_s + fine_s
-
             # the engine's real host join additionally decodes target keys
             host_engine_modeled = host_best + n * link.HOST_KEY_DECODE_S_PER_ROW
-            s_bytes = cap_s // 8
-            # attached-chip terms: same measured kernel, PCIe-class link
-            attached = k_s + (4 * len(s_keys)) / 12e9 + 2 * 0.0002 \
-                + (mc * 8 + s_bytes) / 12e9
             # the MERGE router's decision for this shape (the cost model
             # in commands/merge.py:_launch_resident_probe, live link terms)
             auto_device_s = link.resident_probe_device_s(n, len(s_keys), lp)
@@ -1564,17 +1520,7 @@ def bench_resident_probe(workdir):
                 if h_tab != float("inf") else None,
                 "host_engine_modeled_ms": round(host_engine_modeled * 1000, 1),
                 "device_total_ms": round(dev_total * 1000, 1),
-                "device_resident_source_ms": round(resident_source_s * 1000, 1),
-                "attached_chip_extrapolated_ms": round(attached * 1000, 2),
-                "phases_ms": {
-                    "upload": round(up_s * 1000, 1),
-                    "kernel": round(k_s * 1000, 1),
-                    "head_fetch": round(head_s * 1000, 1),
-                    "pairs_fetch": round(fine_s * 1000, 1),
-                },
-                "matched_pairs": int(mc),
                 "device_beats_host_resident": bool(dev_total < host_best),
-                "attached_beats_host_resident": bool(attached < host_best),
             }
         del e
         sweep.append(entry_res)
@@ -1597,9 +1543,8 @@ def bench_resident_probe(workdir):
                       "down": round(lp.down_mbps, 1),
                       "latency_ms": round(lp.latency_s * 1000, 1)},
         "note": "device_total is the public probe_async round trip (source "
-                "upload + fused sorted-slab kernel + head + compacted "
-                "O(matched) pair fetch); attached_chip_extrapolated "
-                "re-prices only the link terms at PCIe 12 GB/s + 0.2 ms",
+                "upload + probe kernel + head + pair kernel + O(matched) "
+                "pair fetch)",
     }
 
 

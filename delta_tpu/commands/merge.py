@@ -847,7 +847,7 @@ class MergeIntoCommand:
                 if not prefer_mesh:
                     # fused cold pipeline: per-file key decode streams into a
                     # pre-sized HBM slab (upload overlaps decode), then the
-                    # block-bucketed probe joins + pairs on device — and the
+                    # resident probe joins + pairs on device — and the
                     # slab registers in the KeyCache so the NEXT merge against
                     # this table skips the upload entirely
                     resident, key_pieces = self._launch_slab_pipeline(
@@ -1293,7 +1293,7 @@ class MergeIntoCommand:
         """The cold fused device MERGE pipeline: decode the key projection
         per file, streaming each decoded file's packed lane onto a
         pre-sized HBM slab from an uploader thread (transfer overlaps the
-        remaining Parquet decode), then launch the block-bucketed probe —
+        remaining Parquet decode), then launch the resident probe —
         and register the slab in the KeyCache so repeated MERGEs against a
         hot table skip the upload entirely.
 

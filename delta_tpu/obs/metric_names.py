@@ -121,6 +121,7 @@ COUNTERS = frozenset({
     "merge.device.cacheHit",      # engaged from an HBM-resident key lane
     "merge.resident.pairsOnly",   # the resident probe's pairs were the join
     "merge.resident.pairsOnly.declined",  # engaged, then decoded after all
+    "merge.resident.probe.overflow",  # candidate rows past the pair scratch
     "merge.device.compiles",      # XLA compiles inside a MERGE's root span
     "merge.keyCache.builds",      # cold key-lane builds (inline or bg)
     "merge.keyCache.advances",    # incremental log-tail applications
@@ -398,6 +399,7 @@ DESCRIPTIONS = {
     "merge.device.cacheHit": "Device MERGEs served from an HBM-resident key lane.",
     "merge.resident.pairsOnly": "Resident MERGEs that took the pairs-only route: no touched-files pre-probe, no decode of the target.",
     "merge.resident.pairsOnly.declined": "Pairs-only MERGEs that decoded the target after all (probe overflow, a slab that disagrees with the snapshot).",
+    "merge.resident.probe.overflow": "Resident probes declined to the host join because the matched keys' candidate slab rows (dead versions, duplicate target keys) pass the pair kernel's scratch bound.",
     "merge.device.compiles": "XLA compiles that ran with a delta.dml.merge span open on the compiling thread.",
     "merge.keyCache.builds": "Cold resident key-lane builds.",
     "merge.keyCache.advances": "Incremental log-tail applications to a key lane.",

@@ -6,7 +6,7 @@ and phase 2 as an outer join + row-at-a-time clause interpreter (`:456-561`).
 Here the join itself is a device kernel; clause application stays columnar
 Arrow on the host (`commands/merge.py`).
 
-Since PR 6 the PRIMARY single-chip join is the fused block-bucketed
+Since PR 6 the PRIMARY single-chip join is the fused source-centric
 membership probe in `ops/key_cache.py` (resident slab + O(matched) pair
 download); `commands/merge.py` routes there first. This module remains the
 multichip path (`delta.tpu.merge.devicePath.preferMesh`) — the sharded

@@ -72,9 +72,10 @@ _POISON_VERSION = 1 << 62
 
 
 class DeltaProbeOverflow(RuntimeError):
-    """Internal control-flow signal: the probe kernel's candidate windows
-    overflowed both tiers (pathologically skewed source); the caller takes
-    the host-join fallback."""
+    """Internal control-flow signal: the matched source keys' candidate slab
+    rows (dead versions and duplicate target keys included) pass what the
+    pair kernel's scratch may hold; the caller takes the host-join
+    fallback."""
 
 
 @dataclass
@@ -158,13 +159,6 @@ class PhysicalProbe:
 from delta_tpu.ops.join_kernel import PendingJoin as PendingProbe
 
 
-def _block_rows(cap: int) -> int:
-    """Coarse-fine granularity for the t_bits download: 4096-row blocks
-    (512 B of packed bits each) whenever the capacity tiles evenly,
-    else one block (tiny slabs)."""
-    return 4096 if cap % 4096 == 0 else cap
-
-
 @functools.lru_cache(maxsize=None)
 def _sort_kernel():
     """Sort the slab's key lane once per KEY mutation (build/append), NOT
@@ -192,182 +186,177 @@ def _sort_kernel():
     return kernel
 
 
-def _tier1_width(cap: int, m: int) -> int:
-    """Tier-1 candidate-window width: ~4x the mean source-keys-per-block so
-    uniformly distributed sources stay in tier 1; power of two, in
-    [64, 4096]."""
-    nb = max(cap // _block_rows(cap), 1)
-    w = 64
-    while w < min(4 * m // nb + 1, 4096):
-        w *= 2
-    return min(w, 4096)
+# what one probe may hold in HBM besides its operands: a chunk of gathered
+# slab blocks, then the pair kernel's buffers (16 B a candidate row)
+_PROBE_SCRATCH_BYTES = 512 << 20
+
+
+def _probe_block(cap: int, m: int) -> int:
+    """Slab rows a block (a power of two that divides every capacity): the
+    probe gathers one block a source key and sorts one boundary a block with
+    the source, so B follows the square root of the slab rows a source key.
+    On a v5e, 37.7M-row capacity and 1M keys, B = 64, 128, 256, 512: 77, 58,
+    59, 67 ms a probe (PR 28, chip call 29); a gathered row costs ~11 ns at
+    128 or at 256 keys, the parent's block-window probe 583 + 358 ms."""
+    b = 64
+    while b < 1024 and b * b * m < 256 * cap:
+        b *= 2
+    return b
 
 
 @functools.lru_cache(maxsize=None)
 def _probe_sorted_kernel():
-    """Block-bucketed brute-force membership probe — the TPU-shaped design,
-    fused with the join's pairing step.
+    """Source-centric membership probe of the PRE-SORTED slab, the first of
+    the join's two programs: its work follows the source (m keys), and only
+    one dense read follows the slab.
 
-    Measured on a v5e (100M-row slab): random O(n) gathers/scatters cost
-    1-3 s and a 1M→100M searchsorted ~0.9 s, while dense elementwise
-    compares run at VPU speed (~10^12 ops/s) and O(n) scans cost ~10 ms.
-    So the kernel never gathers through the permutation at probe time:
+      - the slab is tiled into blocks of B rows (`_probe_block`); one dense
+        pass takes each block's first key (its boundary) and how long, and
+        how valid, the run of that key at the block's head is;
+      - boundaries and source are sorted together, so a running count of
+        boundaries gives every source key the block its run of equal slab
+        keys starts in, and a segmented sum hands it the heads of the later
+        blocks that its run crosses into;
+      - each source key gathers its block (a chunk of keys at a time, under
+        `_PROBE_SCRATCH_BYTES`): one broadcast compare and three counts
+        give where its run starts, how long it is and how many rows of it
+        are valid.
 
-      - the PRE-SORTED slab is tiled into 4096-row blocks;
-      - two small searchsorteds (block boundary keys into the sorted
-        source) give each block its candidate window [win_lo, win_hi);
-      - each block brute-compares its 4096 keys against W window slots as
-        a broadcast compare fused into three reductions (per-row any →
-        t-side; valid-masked per-candidate any → s-side; per-row MIN of
-        the matching candidates' original source index → the pairing) —
-        ~cap*W int64 compares, a few ms of VPU time, nothing materialized;
-      - a second tier re-runs the top-K widest windows at W2=4096, so
-        locally clustered sources stay exact; wider-than-W2 windows set
-        an overflow flag and the caller falls back to the host join.
-
-    Outputs stay in SORTED space. One head array carries
-    [multi | overflow | matched-count (4 bytes LE) | s_bits] — a single
-    small fetch; the matched count sizes the O(matched) pair download
-    (`_pair_compact_kernel`) without another round trip. The per-row
-    first-match is the MINIMAL original source index among equal keys —
-    exactly `_first_match_recovery`'s stable-tie semantics, so the fused
-    path is row-identical to the host pairing."""
+    Nothing of the capacity's length is written. One head array carries
+    [multi | valid pairs (4 bytes LE) | candidate rows (4 bytes LE) |
+    s_bits], a single small fetch; for `_pair_kernel` stay on the device
+    each distinct matched source key's run (start, length) and its MINIMAL
+    original source index: `_first_match_recovery`'s stable-tie semantics,
+    so the pairs are row-identical to the host's."""
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
 
     @jax.jit
-    def kernel(sorted_keys, sorted_valid, n, s_keys):
+    def kernel(sorted_keys, sorted_valid, s_keys):
         cap = sorted_keys.shape[0]
         m = s_keys.shape[0]
-        blk = _block_rows(cap)  # cap is static under jit; host must agree
+        blk = _probe_block(cap, m)  # static under jit
         nb = cap // blk
-        w1 = _tier1_width(cap, m)
-        k2 = min(512, nb)
-        # w2 must exceed blk: a block FULLY covered by source hits (a CDC
-        # band upsert) has wsize >= blk plus its in-range misses
-        w2 = 2 * blk
-        s = s_keys.astype(sorted_keys.dtype)
-        s_perm = jnp.arange(m, dtype=jnp.int32)
-        s_sorted, s_perm = jax.lax.sort((s, s_perm), num_keys=1)
         keys_b = sorted_keys.reshape(nb, blk)
         valid_b = sorted_valid.reshape(nb, blk)
-        # candidate windows: inclusive of boundary keys, so an equal-key
-        # run crossing a block edge lands in BOTH blocks' windows. Ranges
-        # clamp to REAL rows (< n): the i64.max padding tail would otherwise
-        # give the boundary block a range swallowing every source key above
-        # the slab maximum (sentinels included) and overflow the tiers.
-        barange = jnp.arange(nb, dtype=jnp.int32)
-        block_first = barange * blk
-        last_real = jnp.minimum(block_first + (blk - 1), n - 1)
-        block_lo_key = keys_b[:, 0]
-        block_hi_key = sorted_keys[last_real]
-        win_lo = jnp.searchsorted(s_sorted, block_lo_key, side="left",
-                                  method="scan")
-        win_hi = jnp.searchsorted(s_sorted, block_hi_key, side="right",
-                                  method="scan")
-        empty_block = block_first > (n - 1)
-        win_hi = jnp.where(empty_block, win_lo, win_hi)
-        wsize = jnp.maximum(win_hi - win_lo, 0)
+        bnd = keys_b[:, 0]
+        # the run of the boundary key at each block's head (block 0's is
+        # counted by the key's own gather below): length and valid rows
+        head_eq = (keys_b == bnd[:, None]) & (jnp.arange(nb) > 0)[:, None]
+        head_len = jnp.sum(head_eq, axis=1, dtype=jnp.int32)
+        head_valid = jnp.sum(head_eq & valid_b, axis=1, dtype=jnp.int32)
+        total = m + nb
+        # one int32 rides the sort: a source row's original index, or below
+        # zero a boundary's two counts (11 bits each), so that among equal
+        # keys boundaries stand first and source rows in original order
+        k, o = jax.lax.sort((
+            jnp.concatenate([bnd, s_keys.astype(sorted_keys.dtype)]),
+            jnp.concatenate([~((head_len << 11) | head_valid),
+                             jnp.arange(m, dtype=jnp.int32)]),
+        ), num_keys=2, is_stable=False)
+        is_b = o < 0
+        head_len, head_valid = (~o) >> 11, (~o) & 0x7FF
+        seg_start = jnp.concatenate([jnp.ones(1, bool), k[1:] != k[:-1]])
 
-        def tier(kb, vb, lo, hi, width):
-            """(t_any (B, blk), t_first (B, blk), s_any (B, width),
-            idx (B, width)) for the given blocks' windows, clipped/masked
-            to [lo, hi). t_first is the minimal ORIGINAL source row index
-            among the window's equal-key candidates, m when none."""
-            idx = lo[:, None] + jnp.arange(width, dtype=lo.dtype)[None, :]
-            in_win = idx < hi[:, None]
-            safe = jnp.minimum(idx, m - 1)
-            cand = s_sorted[safe]  # (B, width)
-            # original source rows; out-of-window slots encode m so the
-            # min-reduce ignores them
-            cand_src = jnp.where(in_win, s_perm[safe], m)
-            eq = kb[:, :, None] == cand[:, None, :]  # fused into reduces
-            t_any = jnp.any(eq & in_win[:, None, :], axis=2)
-            t_first = jnp.min(
-                jnp.where(eq, cand_src[:, None, :], m), axis=2
-            ).astype(jnp.int32)
-            s_any = jnp.any(eq & vb[:, :, None], axis=1) & in_win
-            return t_any, t_first, s_any, idx
+        def seg_sum(x):
+            """Sum of x over the boundaries of each row's segment of equal
+            keys (they all stand before its source rows)."""
+            c = jnp.cumsum(x)
+            return c - jax.lax.cummax(jnp.where(seg_start, c - x, 0))
 
-        t1, f1, s1, idx1 = tier(keys_b, valid_b, win_lo, win_hi, w1)
-        t_match_b = t1
-        t_first_b = f1
-        s_match_sorted = jnp.zeros(m, bool).at[
-            jnp.minimum(idx1, m - 1).reshape(-1)
-        ].max(s1.reshape(-1), mode="drop")
-        if k2 > 0 and w1 < w2:
-            top_w, top_b = jax.lax.top_k(wsize, k2)
-            t2, f2, s2, idx2 = tier(keys_b[top_b], valid_b[top_b],
-                                    win_lo[top_b], win_hi[top_b], w2)
-            # tier 2 supersedes tier 1 on its blocks (windows are prefixes)
-            t_match_b = t_match_b.at[top_b].set(t2)
-            t_first_b = t_first_b.at[top_b].set(f2)
-            s_match_sorted = s_match_sorted.at[
-                jnp.minimum(idx2, m - 1).reshape(-1)
-            ].max(s2.reshape(-1), mode="drop")
-            in_top = jnp.zeros(nb, bool).at[top_b].set(True)
-            overflow = (jnp.any((wsize > w1) & ~in_top)
-                        | jnp.any(top_w > w2))
-        else:
-            overflow = jnp.any(wsize > w1)
-        t_match_sorted = (t_match_b & valid_b).reshape(cap)
-        s_first_sorted = t_first_b.reshape(cap)
-        s_match = jnp.zeros(m, bool).at[s_perm].set(s_match_sorted)
-        s_bits = jnp.packbits(s_match.astype(jnp.uint8))
-        # multi-match: a matched key duplicated in the sorted source
-        dup = jnp.concatenate([
-            jnp.zeros(1, bool), s_sorted[1:] == s_sorted[:-1]
-        ])
-        dup = dup | jnp.concatenate([dup[1:], jnp.zeros(1, bool)])
-        multi = jnp.any(dup & s_match_sorted)
-        mc = jnp.sum(t_match_sorted.astype(jnp.int32))
-        mc_bytes = (
-            jnp.right_shift(mc, jnp.array([0, 8, 16, 24], jnp.int32)) & 0xFF
-        ).astype(jnp.uint8)
+        b_before = jnp.cumsum(is_b.astype(jnp.int32)) - is_b
+        # boundaries strictly below the key, less one: the block whose first
+        # key is below it and whose successor's is not
+        j0 = jnp.maximum(
+            jax.lax.cummax(jnp.where(seg_start, b_before, 0)) - 1, 0)
+        # gather each row's block, a chunk of rows at a time
+        chunk = min(_next_pow2(total, floor=8), (1 << 24) // blk)
+        pad = -total % chunk
+        kp = jnp.pad(k, (0, pad)).reshape(-1, chunk)
+        jp = jnp.pad(j0, (0, pad)).reshape(-1, chunk)
+
+        def block_counts(args):
+            kc, jc = args
+            rows = keys_b[jc]
+            eq = rows == kc[:, None]
+            return (jnp.sum(rows < kc[:, None], axis=1, dtype=jnp.int32),
+                    jnp.sum(eq, axis=1, dtype=jnp.int32),
+                    jnp.sum(eq & valid_b[jc], axis=1, dtype=jnp.int32))
+
+        below, equal, equal_valid = (
+            x.reshape(-1)[:total] for x in jax.lax.map(block_counts, (kp, jp)))
+        run_len = equal + seg_sum(jnp.where(is_b, head_len, 0))
+        run_valid = equal_valid + seg_sum(jnp.where(is_b, head_valid, 0))
+        prev_b = jnp.concatenate([jnp.ones(1, bool), is_b[:-1]])
+        first = ~is_b & (seg_start | prev_b)  # of its run of equal source keys
+        matched = ~is_b & (run_valid > 0)
+        next_same = jnp.concatenate([~seg_start[1:], jnp.zeros(1, bool)])
+        multi = jnp.any(matched & (~first | next_same))
+        s_match = jnp.zeros(m, bool).at[jnp.where(is_b, m, o)].set(
+            matched, mode="drop")
+        run_len = jnp.where(first & (run_valid > 0), run_len, 0)
+        counts = jnp.stack([jnp.sum(jnp.where(first, run_valid, 0)),
+                            jnp.sum(run_len)])
         head = jnp.concatenate([
             multi.astype(jnp.uint8).reshape(1),
-            overflow.astype(jnp.uint8).reshape(1),
-            mc_bytes, s_bits,
+            ((counts[:, None] >> jnp.arange(0, 32, 8)) & 0xFF).astype(
+                jnp.uint8).reshape(-1),
+            jnp.packbits(s_match.astype(jnp.uint8)),
         ])
-        return head, t_match_sorted, s_first_sorted
+        return head, j0 * blk + below, run_len, o
 
     return kernel
 
 
 def _decode_head(head: np.ndarray, cap_s: int, m: int):
-    """Decode the probe head fetched from device: (multi, overflow,
-    matched_count, s_matched[:m]). Layout documented on
-    `_probe_sorted_kernel` — shared with the bench's phase decomposition
-    so the two cannot drift."""
-    multi = bool(head[0])
-    overflow = bool(head[1])
-    mc = (int(head[2]) | (int(head[3]) << 8) | (int(head[4]) << 16)
-          | (int(head[5]) << 24))
-    s = np.unpackbits(head[6:6 + cap_s // 8], count=cap_s)[:m].astype(bool)
-    return multi, overflow, mc, s
+    """Decode the probe head fetched from device: (multi, valid pairs,
+    candidate rows, s_matched[:m]). Layout documented on
+    `_probe_sorted_kernel`."""
+    mc, tc = (int.from_bytes(head[i:i + 4].tobytes(), "little") for i in (1, 5))
+    s = np.unpackbits(head[9:9 + cap_s // 8], count=cap_s)[:m].astype(bool)
+    return bool(head[0]), mc, tc, s
 
 
 @functools.lru_cache(maxsize=None)
-def _pair_compact_kernel():
-    """O(matched) pair download: compact the matched sorted-space rows into
-    a dense (2, out_cap) int32 buffer of (physical row, first-match source
-    row) via a cumsum + scatter — the host then fetches exactly the pairs
-    instead of the whole cap/8 mask plus an O(n·log n) host pairing pass.
-    ``out_cap`` is a static pow2 bucket sized from the head's matched
-    count; slots past the count hold zeros (sliced off host-side)."""
+def _pair_kernel():
+    """The join's second program: the pairs, from the source side. Every
+    matched run (start, length, source row) of `_probe_sorted_kernel` is
+    laid out into ``cand_cap`` candidate slots (a scatter of the run starts
+    and two running scans), each slot reads its slab row's validity and
+    physical row through the permutation, and a sort by physical row brings
+    the valid pairs to the front in the host's order: a dense (2, out_cap)
+    int32 buffer of (physical row ascending, first-match source row). Dead
+    versions of a key are candidates and not pairs. ``cand_cap`` and
+    ``out_cap`` are static buckets from the head's two counts; slots past
+    the count hold the int32 maximum (sliced off host-side)."""
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
 
-    @functools.partial(jax.jit, static_argnums=(3,))
-    def kernel(t_match_sorted, s_first_sorted, perm, out_cap):
-        pos = jnp.cumsum(t_match_sorted.astype(jnp.int32)) - 1
-        idx = jnp.where(t_match_sorted, pos, out_cap)
-        out_t = jnp.zeros(out_cap, jnp.int32).at[idx].set(perm, mode="drop")
-        out_s = jnp.zeros(out_cap, jnp.int32).at[idx].set(
-            s_first_sorted, mode="drop")
-        return jnp.stack([out_t, out_s])
+    @functools.partial(jax.jit, static_argnums=(5, 6))
+    def kernel(run_lo, run_len, run_src, sorted_valid, perm, cand_cap, out_cap):
+        total = run_len.shape[0]
+        live = run_len > 0
+        end = run_lo + run_len
+        # runs of distinct keys lie apart and in order in the sorted slab
+        prev_end = jnp.concatenate([
+            jnp.zeros(1, jnp.int32),
+            jax.lax.cummax(jnp.where(live, end, 0))[:-1]])
+        at = jnp.where(live, jnp.cumsum(run_len) - run_len, cand_cap)
+        # a slot's slab position: one more than its left neighbour's, but at
+        # a run's first slot, where it jumps to the run's start
+        pos = jnp.cumsum(jnp.ones(cand_cap, jnp.int32).at[at].set(
+            run_lo - prev_end + 1, mode="drop")) - 1
+        run = jax.lax.cummax(jnp.zeros(cand_cap, jnp.int32).at[at].set(
+            jnp.arange(total, dtype=jnp.int32), mode="drop"))
+        inside = jnp.arange(cand_cap, dtype=jnp.int32) < jnp.sum(run_len)
+        pos = jnp.where(inside, pos, 0)
+        phys = jnp.where(inside & sorted_valid[pos], perm[pos],
+                         jnp.iinfo(jnp.int32).max)
+        phys, src = jax.lax.sort((phys, run_src[run]), num_keys=1,
+                                 is_stable=False)
+        return jnp.stack([phys[:out_cap], src[:out_cap]])
 
     return kernel
 
@@ -833,10 +822,14 @@ class ResidentJoinKeys:
         state: dict = {}
         from delta_tpu.obs import hbm_ledger
 
-        # transient probe scratch (the uploaded source lane) in the HBM
-        # ledger while the probe is in flight; released on the staging
-        # thread, which always runs to completion
-        scratch_bytes = int(s_in.nbytes)
+        # transient probe scratch (the uploaded source lane, the source
+        # sorted with the block boundaries, one chunk of gathered blocks) in
+        # the HBM ledger while the probe is in flight; released on the
+        # staging thread, which always runs to completion
+        blk = _probe_block(cap, cap_s)
+        ranked = cap_s + cap // blk
+        scratch_bytes = int(s_in.nbytes) + 40 * ranked + 9 * min(
+            ranked * blk, 1 << 24)
         hbm_ledger.adjust("scratch", scratch_bytes)
         # scratch growth applies eviction pressure immediately (no cache or
         # entry lock held at this point; this probe's arrays are pinned in
@@ -849,33 +842,45 @@ class ResidentJoinKeys:
         probe_ctx = telemetry.span_context()
 
         def launch():
-            # the whole device pipeline runs on this staging thread so every
-            # round trip (kernel, head fetch, pair compaction dispatch)
-            # overlaps the caller's host-side Parquet decode; finalize only
-            # joins the thread and fetches the compacted pairs
+            # the whole device pipeline runs on this staging thread, beside
+            # the caller's host-side work; finalize joins it, fetches the pairs
+            pair_bytes = 0
             try:
                 with telemetry.adopt_span_context(probe_ctx), \
                         telemetry.record_operation(
                             "delta.merge.deviceProbe",
                             {"slabRows": int(n), "sourceRows": int(m),
-                             "insertOnly": insert_only}):
+                             "insertOnly": insert_only, "blockRows": blk,
+                             "candidates": ranked * blk}):
                     with enable_x64():
-                        head_dev, t_match_dev, s_first_dev = _probe_sorted_kernel()(
+                        head_dev, *runs = _probe_sorted_kernel()(
                             dev["sorted_keys"], dev["sorted_valid"],
-                            link.to_device(np.int32(n)), link.to_device(s_in),
-                        )
-                        head = link.to_host(head_dev)  # blocks until kernel done
-                        state["head"] = head
-                        _multi, overflow, mc, _s = _decode_head(head, cap_s, m)
-                        if overflow or insert_only or mc == 0:
+                            link.to_device(s_in))
+                        # blocks until the kernel is done
+                        state["head"] = _decode_head(
+                            link.to_host(head_dev), cap_s, m)
+                        _multi, mc, tc, _s = state["head"]
+                        telemetry.add_span_data(matched=mc, candidateRows=tc)
+                        if insert_only or mc == 0:
                             return
+                        # room for as many dead versions as pairs before the
+                        # candidates' bucket steps (and the program with it)
                         out_cap = _next_pow2(mc, floor=64)
-                        state["pairs_dev"] = _pair_compact_kernel()(
-                            t_match_dev, s_first_dev, dev["perm"], out_cap)
+                        cand_cap = max(2 * out_cap, _next_pow2(tc, floor=64))
+                        if 16 * cand_cap > _PROBE_SCRATCH_BYTES:
+                            state["overflow"] = True
+                            telemetry.bump_counter(
+                                "merge.resident.probe.overflow")
+                            return
+                        pair_bytes = 16 * cand_cap
+                        hbm_ledger.adjust("scratch", pair_bytes)
+                        state["pairs_dev"] = _pair_kernel()(
+                            *runs, dev["sorted_valid"], dev["perm"],
+                            cand_cap, out_cap)
             except BaseException as e:
                 state["err"] = e
             finally:
-                hbm_ledger.adjust("scratch", -scratch_bytes)
+                hbm_ledger.adjust("scratch", -scratch_bytes - pair_bytes)
 
         th = threading.Thread(target=launch, daemon=True,
                               name="delta-merge-device-probe")
@@ -885,13 +890,10 @@ class ResidentJoinKeys:
             th.join()
             if "err" in state:
                 raise state["err"]
-            multi, overflow, mc, s = _decode_head(state["head"], cap_s, m)
-            if overflow:
-                # candidate window overflowed both tiers (pathologically
-                # skewed source): the mask would be incomplete — callers
-                # fall back to the host join
+            if "overflow" in state:
                 raise DeltaProbeOverflow(
-                    "probe candidate window overflow; host fallback")
+                    "probe candidate rows pass the scratch bound; host fallback")
+            multi, mc, _tc, s = state["head"]
             if insert_only:
                 # left-anti fast path: the head already carried everything
                 return PhysicalProbe(s, multi, slabs, n, None)
@@ -899,11 +901,8 @@ class ResidentJoinKeys:
                 empty = np.empty(0, np.int64)
                 return PhysicalProbe(s, multi, slabs, n, (empty, empty))
             pairs = link.to_host(state["pairs_dev"])
-            phys = pairs[0, :mc].astype(np.int64)
-            srows = pairs[1, :mc].astype(np.int64)
-            order = np.argsort(phys, kind="stable")
-            phys, srows = phys[order], srows[order]
-            return PhysicalProbe(s, multi, slabs, n, (phys, srows))
+            return PhysicalProbe(s, multi, slabs, n, (
+                pairs[0, :mc].astype(np.int64), pairs[1, :mc].astype(np.int64)))
 
         return PendingProbe(finalize)
 

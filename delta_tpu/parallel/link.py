@@ -195,7 +195,7 @@ def resident_probe_device_s(n: int, m: int, p: "LinkProfile") -> float:
     """The router's cost model for one steady-state resident MERGE probe
     (n resident target rows, m source rows) on the FUSED path: source
     upload (int32-narrowed, optimistic), the head download (s_bits +
-    matched count), the block-bucketed kernel, the compacted pair download
+    matched count), the probe kernel, the compacted pair download
     (matched count unknown pre-probe: modeled at the upsert-typical m/2
     pairs x 8 bytes), the O(matched) host pair mapping, a fixed dispatch
     floor, and the probe's sequential round trips. ONE definition — the

@@ -187,6 +187,165 @@ def test_probe_sorted_kernel_fuzz_parity():
         assert res.any_multi == exp_multi, label
 
 
+def _oracle(keys, valid, s_keys, s_ok):
+    """The host join's answer in numpy: (s_matched, any_multi, physical rows
+    ascending, each with the minimal original source row of its key)."""
+    keys, s_keys = np.asarray(keys, np.int64), np.asarray(s_keys, np.int64)
+    ok_rows = np.nonzero(s_ok)[0]
+    order = ok_rows[np.argsort(s_keys[ok_rows], kind="stable")]
+    uniq, first, count = np.unique(s_keys[order], return_index=True,
+                                   return_counts=True)
+    s_matched = s_ok & np.isin(s_keys, keys[valid])
+    phys = np.nonzero(valid & np.isin(keys, uniq))[0]
+    at = np.searchsorted(uniq, keys[phys])
+    any_multi = bool((count[at] > 1).any())
+    return s_matched, any_multi, phys, order[first][at]
+
+
+def _wide(rng, n):
+    """Keys that need int64, with duplicates among them."""
+    return (rng.randint(0, n // 3, n).astype(np.int64) << 33) + 7
+
+
+def _probe_case(case):
+    """(slab keys, dead rows, source keys, source ok, insert_only)."""
+    rng = np.random.RandomState(11)
+    n = 20000
+    keys = rng.permutation(n).astype(np.int64) * 3
+    dead = np.empty(0, np.int64)
+    s = rng.choice(keys, 300, replace=False)
+    s_ok = np.ones(300, bool)
+    if case == "long-runs":
+        # three keys held 3, 700 and 5,000 times: runs longer than a block,
+        # over several block edges
+        keys[:3], keys[3:703], keys[703:5703] = 60001, 60004, 60007
+        s = np.array([60007, 60001, 5, 60004, 60010], np.int64)
+    elif case == "dead-and-live":
+        # four versions of 200 keys, the live one in a place of its own; 50
+        # keys all of whose versions are dead
+        keys[:800] = np.repeat(keys[1000:1200], 4)
+        dead = np.concatenate([np.arange(800)[np.arange(800) % 4 != 2],
+                               np.arange(1000, 1250)])
+        s = keys[1000:1300].copy()
+    elif case == "source-duplicates":
+        s = np.concatenate([s[:100], s[:50], s[:10], s[:100] + 1])
+    elif case == "all-miss-above":
+        s = keys.max() + 1 + np.arange(300, dtype=np.int64)
+    elif case == "all-miss-below":
+        s = -1 - np.arange(300, dtype=np.int64)
+    elif case == "beyond-int32":
+        keys = _wide(rng, n)
+        s = np.concatenate([keys[:200], keys[:100] + 1])
+    elif case == "beyond-int32-negative":
+        keys = -_wide(rng, n)
+        s = np.concatenate([keys[:200], keys[:100] + 1])
+    elif case == "not-ok-rows":
+        s_ok = rng.rand(300) > 0.3
+    elif case == "one-row":
+        s = keys[77:78].copy()
+    elif case == "one-row-miss":
+        s = np.array([1], np.int64)
+    elif case == "odd-m":
+        s = np.concatenate([rng.choice(keys, 700, replace=False),
+                            rng.randint(0, 3 * n, 637).astype(np.int64)])
+    elif case == "quarter-of-the-slab":
+        # two chunks of gathered blocks; every other source key a miss
+        n = 1_000_000
+        keys = rng.permutation(n).astype(np.int64) * 2
+        keys[:40000] = keys[40000:80000]
+        dead = np.arange(0, 60000, 3)
+        s = rng.permutation(2 * n)[:250_000].astype(np.int64)
+    elif case == "insert-only":
+        s = np.concatenate([s, s[:5] + 1])
+    elif case == "block-edges":
+        # every key twice, so a run lies over every block's edge
+        keys = np.repeat(np.arange(1, n + 1, 2, dtype=np.int64), 2)[1:]
+        keys = np.append(keys, 0)
+        s = np.arange(0, n, 7, dtype=np.int64)
+    elif case == "whole-slab-one-key":
+        keys[:] = 5
+        dead = np.arange(0, n, 2)
+        s = np.array([4, 5, 6, 5], np.int64)
+    if len(s_ok) != len(s):
+        s_ok = np.ones(len(s), bool)
+    return keys, dead, s, s_ok, case == "insert-only"
+
+
+PROBE_CASES = [
+    "long-runs", "dead-and-live", "source-duplicates", "all-miss-above",
+    "all-miss-below", "beyond-int32", "beyond-int32-negative", "not-ok-rows",
+    "one-row", "one-row-miss", "odd-m", "quarter-of-the-slab", "insert-only",
+    "block-edges", "whole-slab-one-key", "empty-slab",
+]
+
+
+@pytest.mark.parametrize("case", PROBE_CASES)
+def test_probe_equals_the_numpy_oracle(case):
+    """`probe_async(...).result()` against the host join's answer: the
+    matched flags of every source row, `any_multi`, every valid slab row
+    whose key is in the valid source as a pair with the minimal original
+    source row, physical rows ascending, and the bits made from the pairs."""
+    from delta_tpu.ops.key_cache import ResidentJoinKeys
+
+    e = ResidentJoinKeys("log", "mid", 0, "sig", ["k"])
+    if case == "empty-slab":
+        res = e.probe_async(np.array([3, 4], np.int64), np.ones(2, bool)).result()
+        assert res.s_matched.tolist() == [False, False] and not res.any_multi
+        assert len(res.t_pairs[0]) == 0 and len(res.t_bits) == 0
+        return
+    keys, dead, s, s_ok, insert_only = _probe_case(case)
+    n, half = len(keys), len(keys) // 2
+    e._append_file("f1", keys[:half], np.ones(half, bool))
+    e._append_file("f2", keys[half:], np.ones(n - half, bool))
+    e.ensure_resident()
+    assert e._set_dv("f1", dead[dead < half])
+    assert e._set_dv("f2", dead[dead >= half] - half)
+    valid = np.ones(n, bool)
+    valid[dead] = False
+    res = e.probe_async(s, s_ok, insert_only=insert_only).result()
+    exp_s, exp_multi, exp_phys, exp_src = _oracle(keys, valid, s, s_ok)
+    assert (res.s_matched == exp_s).all()
+    assert res.any_multi == exp_multi
+    assert res.num_rows == n
+    if insert_only:
+        assert res.t_pairs is None and res.t_bits is None
+        return
+    assert (res.t_pairs[0] == exp_phys).all()
+    assert (res.t_pairs[1] == exp_src).all()
+    assert (np.nonzero(res.t_bits)[0] == exp_phys).all()
+
+
+def test_probe_declines_counted_when_candidates_pass_the_scratch(monkeypatch):
+    """The one decline the probe keeps: more candidate slab rows than the
+    pair kernel's scratch may hold raise `DeltaProbeOverflow` (the caller
+    joins on the host) and are counted; an insert-only probe, which makes
+    no pairs, is served all the same. The span says how widely the probe
+    engaged."""
+    from delta_tpu.ops import key_cache as kc
+    from delta_tpu.utils import telemetry
+
+    e = kc.ResidentJoinKeys("log", "mid", 0, "sig", ["k"])
+    e._append_file("f", np.arange(5000, dtype=np.int64), np.ones(5000, bool))
+    s, ok = np.arange(4000, 4600, dtype=np.int64), np.ones(600, bool)
+    res = e.probe_async(s, ok).result()
+    assert len(res.t_pairs[0]) == 600
+    data = telemetry.recent_events("delta.merge.deviceProbe")[-1].data
+    assert data["matched"] == 600 and data["candidateRows"] == 600
+    assert data["blockRows"] == kc._probe_block(e.capacity, 1024)
+    assert data["candidates"] == (1024 + e.capacity // data["blockRows"]) \
+        * data["blockRows"]
+    monkeypatch.setattr(kc, "_PROBE_SCRATCH_BYTES", 16 * 2048 - 1)
+    before = telemetry.counters("merge.resident.probe").get(
+        "merge.resident.probe.overflow", 0)
+    with pytest.raises(kc.DeltaProbeOverflow):
+        e.probe_async(s, ok).result()
+    assert telemetry.counters("merge.resident.probe")[
+        "merge.resident.probe.overflow"] == before + 1
+    res = e.probe_async(s, ok, insert_only=True).result()
+    assert res.s_matched.all() and res.t_pairs is None
+    assert len(e.probe_async(s[:100], ok[:100]).result().t_pairs[0]) == 100
+
+
 def test_probe_many_above_max_misses_no_overflow():
     """Source keys above the slab maximum (inserts) fall into NO block's
     candidate window — the padding tail must not swallow them into the

@@ -5,17 +5,22 @@
 
 The arguments and the result line are `benchmark/run.py`'s. Besides, on
 standard error: the mean milliseconds a request of every span of the program
-(and how many of them a request opened), and each MERGE's route. A builder's
-instrument for PERF.md; nothing of the benchmark reads it.
+(and how many of them a request opened), each MERGE's route, what the
+resident probe's spans and counters say of how widely it engaged, and the
+device's time in the window by XLA module, each module with its longest
+operations and the arguments its operations name. A builder's instrument for
+PERF.md; nothing of the benchmark reads it.
 
 ``--decode-route`` makes `MergeIntoCommand._pairs_only_shape` read false, so
 that a resident MERGE decodes the target as before PR 26: the same program
 with one observable turned off, for the before-and-after of that route.
 """
+import bisect
 import collections
 import importlib.util
 import json
 import os
+import re
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -36,6 +41,61 @@ def report(run) -> None:
     print("span means a request [ms, spans]:", json.dumps(means),
           file=sys.stderr)
     print("merge routes:", json.dumps(routes), file=sys.stderr)
+    probes = [s["data"] for r in done for s in r.spans
+              if s["name"] == "delta.merge.deviceProbe"]
+    if probes:
+        from delta_tpu.utils import telemetry
+
+        print("device probes:", json.dumps(probes), "overflows:",
+              telemetry.counters("merge.resident.probe").get(
+                  "merge.resident.probe.overflow", 0), file=sys.stderr)
+    if run.trace is not None:
+        print("device ms a request by module:",
+              json.dumps(module_split(run.trace, len(done))), file=sys.stderr)
+
+
+_NAME = re.compile(r"%([A-Za-z_][A-Za-z_0-9]*?)(?:\.\d+)*(?![\w.\-])")
+_HLO_WORDS = re.compile(
+    r"^(fusion|copy|bitcast|sort|constant|param|tuple|slice|reshape|"
+    r"transpose|broadcast|iota|convert|select|compare|reduce|gather|scatter|"
+    r"concatenate|pad|while|add|subtract|and|or|not|clamp|maximum|minimum|"
+    r"cumsum|cummax|lt|le|eq|ne|gt|ge|region|wrapped|input|loop)")
+
+
+def module_split(trace, requests: int, longest: int = 5):
+    """Device milliseconds a request of every XLA module that ran in the
+    window, longest first: how often it ran, its longest operations (HLO
+    name and first shape) and the names its operations' texts carry that
+    are no HLO words, which are the jitted function's arguments."""
+    from benchmark.harness.trace import short_name
+
+    out = {}
+    for dev in trace.devices.values():
+        ops = sorted(dev.ops, key=lambda e: e.start)
+        starts = [e.start for e in ops]
+        for m in dev.modules:
+            if not trace.window[0] <= m.start < trace.window[1]:
+                continue
+            rec = out.setdefault(m.name, {"runs": 0, "ns": 0,
+                                          "ops": collections.Counter(),
+                                          "names": set()})
+            rec["runs"] += 1
+            rec["ns"] += m.end - m.start
+            lo = bisect.bisect_left(starts, m.start)
+            hi = bisect.bisect_left(starts, m.end)
+            for o in ops[lo:hi]:
+                rec["ops"][short_name(o.name)] += o.end - o.start
+                if rec["runs"] == 1:
+                    rec["names"].update(
+                        n for n in _NAME.findall(o.name)
+                        if not _HLO_WORDS.match(n))
+    rows = sorted(out.items(), key=lambda kv: -kv[1]["ns"])
+    return [{"module": name, "runs": r["runs"],
+             "ms": round(r["ns"] / 1e6 / max(requests, 1), 3),
+             "names": sorted(r["names"])[:12],
+             "ops": [[n, round(ns / 1e6 / max(requests, 1), 3)]
+                     for n, ns in r["ops"].most_common(longest)]}
+            for name, r in rows if r["ns"] / 1e6 / max(requests, 1) >= 0.05]
 
 
 def main() -> int:
