@@ -63,8 +63,7 @@ __all__ = [
 
 def publish_metrics(report: AnalysisReport) -> None:
     """Publish per-rule finding counts as the cataloged ``analysis.findings``
-    gauge (label: rule) so bench snapshots carry them via the include list
-    and ``tools/bench_diff`` gates on finding-count regressions."""
+    gauge (label: rule) so ``/metrics`` scrapes carry them."""
     from delta_tpu.utils import telemetry
 
     counts = report.counts()

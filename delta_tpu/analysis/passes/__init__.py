@@ -1,5 +1,5 @@
-"""Pass registry. ``all_passes()`` is the one list the CLI, the tier-1
-test and the bench wiring share — a new pass registers here and nowhere
+"""Pass registry. ``all_passes()`` is the one list the CLI and the tier-1
+test share — a new pass registers here and nowhere
 else."""
 from __future__ import annotations
 

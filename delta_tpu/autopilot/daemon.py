@@ -483,7 +483,7 @@ def status() -> Dict[str, Any]:
 
 
 def reset() -> None:
-    """Drop per-process autopilot state (tests / bench isolation). The
+    """Drop per-process autopilot state (tests). The
     on-disk action ledger is untouched — it lives in the journal."""
     global _DAEMON
     with _STATE_LOCK:

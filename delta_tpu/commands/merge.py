@@ -178,8 +178,8 @@ class MergeIntoCommand:
         self.source_alias = source_alias
         self.target_alias = target_alias
         self.metrics: Dict[str, int] = {}
-        # wall-clock per phase (decode/key/join/apply/write ms) — the bench
-        # breakdown the optimization loop steers by
+        # wall-clock per phase (decode/key/join/apply/write ms), filled by
+        # the phase spans (`_phase`); the router audit carries a copy
         self.phase_ms: Dict[str, float] = {}
         # set by _join when the device kernel ran: JoinResult with exact
         # per-target match counts and per-source matched flags
@@ -1261,8 +1261,7 @@ class MergeIntoCommand:
             m = len(s_keys)
             n = entry.num_rows
             p = link.profile()
-            # the fused-path probe model (shared with the bench's
-            # auto_routes_device report: link.resident_probe_device_s)
+            # the fused-path probe model (link.resident_probe_device_s)
             device_s = link.resident_probe_device_s(n, m, p)
             if not entry.is_resident:
                 # the device copy was evicted / regrown: the probe would
@@ -1487,7 +1486,7 @@ class MergeIntoCommand:
 
     def _emit_router(self) -> None:
         """One `delta.merge.router` event per MERGE — the production-table
-        observable behind the bench's `auto_used_device` field — plus the
+        observable of which executor `auto` chose — plus the
         `merge.device.*` counters the /metrics endpoint and flight recorder
         surface, and the router AUDIT record pricing the decision against
         the measured phase durations (obs/router_audit)."""

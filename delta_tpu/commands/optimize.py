@@ -101,7 +101,7 @@ class OptimizeCommand:
         # files stay exactly as planned-around, reported in shard_report
         self.on_failure = on_failure
         # the last run's executor evidence (per-worker timings, steals,
-        # skew) — the sharded-scan bench and the MULTICHIP artifact read it
+        # skew) — tests and `chip_smoke.py`'s mesh step read it
         self.shard_report = None
         # multihost crash evidence: this host's lease (heartbeated during
         # the rewrite, cleared after commit) and, on the coordinator, the

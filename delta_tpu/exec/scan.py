@@ -560,7 +560,7 @@ def read_files_as_table(
             if device_survivor:
                 # survivor-group bytes the device path sent to host decode —
                 # the host-decoded remainder of masked files, counted apart
-                # from plain host reads so the bench can split the two
+                # from plain host reads so the counters split the two
                 telemetry.bump_counter("scan.bytes.deviceSurvivor",
                                        device_survivor)
             rev.data.update(

@@ -180,14 +180,14 @@ def _drain(raise_errors: bool) -> int:
 
 def flush() -> int:
     """Synchronously build every pending request on the CALLING thread
-    (tests, the torture harness, bench teardown); returns builds completed.
+    (tests, the torture harness); returns builds completed.
     Unlike the daemon, failures propagate to the caller."""
     return _drain(raise_errors=True)
 
 
 def reset() -> None:
-    """Drop pending requests and cached bases (tests, bench per-config
-    isolation). On-disk checkpoints are untouched."""
+    """Drop pending requests and cached bases (tests). On-disk checkpoints
+    are untouched."""
     with _LOCK:
         _REQUESTS.clear()
     with _BASE_LOCK:

@@ -1,7 +1,7 @@
 """Self-calibrating cost model — EWMA re-fit of the link constants.
 
 The router constants in `parallel/link.py` (host join/decode per-row rates,
-resident-probe and prune cell rates) were measured on one bench machine; on
+resident-probe and prune cell rates) were measured on one CPU host; on
 different hardware the router silently picks the wrong side and nothing
 corrects it. This module closes the loop: the router audit ledger
 (`obs/router_audit`) hands each routed decision's attributable samples —

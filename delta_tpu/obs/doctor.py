@@ -10,8 +10,8 @@ debt, each with a severity (``ok``/``warn``/``critical``), the numbers that
 justified it, and the remedy command (OPTIMIZE / CHECKPOINT / VACUUM / PURGE
 / REPARTITION). Every numeric metric is also published as a
 ``table.health.*`` gauge (labeled by table path, names validated against
-``obs/metric_names.py``) so the report flows into ``/metrics`` scrapes and
-``bench.py`` snapshots without a second pipeline.
+``obs/metric_names.py``) so the report flows into ``/metrics`` scrapes
+without a second pipeline.
 
 Thresholds are module constants, deliberately simple and visible — the
 doctor's job is to rank debt, not to model it precisely.

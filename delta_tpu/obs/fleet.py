@@ -285,7 +285,7 @@ def _label_of(path: str) -> str:
 
 
 def reset() -> None:
-    """Drop the registry and label map (tests / bench isolation)."""
+    """Drop the registry and label map (tests)."""
     with _LOCK:
         _TABLES.clear()
         _LABEL_PATHS.clear()

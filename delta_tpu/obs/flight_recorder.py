@@ -108,7 +108,7 @@ def record_incident(ev, exc: BaseException) -> Optional[str]:
         "thread": threading.current_thread().name,
     }
     # what the query was DOING, not just the span stack: the scan report
-    # in flight on this context (e.g. a bench-deadline breach mid-scan) and
+    # in flight on this context (e.g. a deadline breach mid-scan) and
     # the last router-audit record, when they exist
     try:
         from delta_tpu.obs import router_audit, scan_report

@@ -744,8 +744,8 @@ def read_entries(log_path: str, kinds: Optional[Iterable[str]] = None,
 
 
 def reset() -> None:
-    """Drop in-memory buffers and active-segment bookkeeping (tests, bench
-    per-config isolation). On-disk segments are left alone — delete the
+    """Drop in-memory buffers and active-segment bookkeeping (tests).
+    On-disk segments are left alone — delete the
     ``_journal`` directory to forget a table's history."""
     with _LOCK:
         _BUFFERS.clear()

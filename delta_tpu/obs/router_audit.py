@@ -2,11 +2,11 @@
 
 The link cost model (`parallel/link.py`) decides the MERGE join executor and
 the scan-planning device/host pick, but until now nothing measured the miss:
-on hardware unlike the bench machine the router silently picks the wrong
-side forever. This ledger records one :class:`RouterAudit` per routed
-decision — the per-candidate *predicted* costs the router compared, the
-*actual* measured duration of the side it chose (from the operation's
-existing phase timers), and the hindsight verdict:
+on hardware unlike the host the constants were fit on, the router silently
+picks the wrong side forever. This ledger records one :class:`RouterAudit`
+per routed decision — the per-candidate *predicted* costs the router
+compared, the *actual* measured duration of the side it chose (from the
+operation's existing phase timers), and the hindsight verdict:
 
     miss = some rejected candidate's predicted cost < the chosen side's
            actual cost

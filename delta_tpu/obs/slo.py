@@ -427,7 +427,7 @@ def status() -> Dict[str, Any]:
 
 
 def reset() -> None:
-    """Drop alert state and the last evaluation (tests / bench)."""
+    """Drop alert state and the last evaluation (tests)."""
     global _LAST_EVAL, _LAST_EVAL_MS
     with _LOCK:
         _ALERTS.clear()

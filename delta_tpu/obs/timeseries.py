@@ -382,7 +382,7 @@ def stop_scraper() -> None:
 
 
 def reset() -> None:
-    """Stop the daemon and drop every ring (tests / bench isolation)."""
+    """Stop the daemon and drop every ring (tests)."""
     global _SCRAPES
     stop_scraper()
     with _LOCK:

@@ -142,7 +142,7 @@ def uninstall() -> None:
 
 
 def reset() -> None:
-    """Close the open spool (tests / bench per-config isolation); the next
+    """Close the open spool (tests); the next
     sampled span reopens a fresh spool file."""
     with _LOCK:
         if _STATE["fh"] is not None:

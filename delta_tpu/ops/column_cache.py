@@ -31,7 +31,8 @@ The device-vs-host choice routes through `parallel/link` pricing
 calibratable) and every decision is audited via `obs/router_audit` under
 ``op="scan.residual"`` — the same observability contract as the MERGE
 router. ``delta.tpu.read.deviceResidual.mode``: ``auto`` prices each scan,
-``force`` always engages (bench legs), ``off`` disables.
+``force`` always engages (the benchmark's ``force`` pins, tests), ``off``
+disables.
 """
 from __future__ import annotations
 

@@ -55,13 +55,10 @@ __all__ = ["ResidentJoinKeys", "KeyCache", "PhysicalProbe", "SlabBuilder",
 
 
 def key_cache_enabled() -> bool:
-    """Whether the cross-MERGE resident key cache may serve/retain entries.
-    ``delta.tpu.merge.keyCache.enabled`` is the documented name;
-    ``delta.tpu.merge.residentKeys.enabled`` is honored for back-compat —
-    either set to false disables caching (the fused device path itself is
-    governed by ``delta.tpu.merge.devicePath.*``)."""
-    return (conf.get_bool("delta.tpu.merge.keyCache.enabled", True)
-            and conf.get_bool("delta.tpu.merge.residentKeys.enabled", True))
+    """Whether the cross-MERGE resident key cache may serve/retain entries
+    (the fused device path itself is governed by
+    ``delta.tpu.merge.devicePath.*``)."""
+    return conf.get_bool("delta.tpu.merge.keyCache.enabled", True)
 
 from delta_tpu.ops.state_cache import _next_pow2  # shared pad-size bucketing
 

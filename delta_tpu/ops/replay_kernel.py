@@ -37,7 +37,6 @@ __all__ = [
     "replay_alive_mask",
     "replay_sharded",
     "ReplayStats",
-    "winner_mask_device",
     "replay_columns",
 ]
 
@@ -93,7 +92,7 @@ def _pad(col: np.ndarray, cap: int, fill) -> np.ndarray:
 
 
 def replay_alive_mask(arrays: ReplayArrays, min_retention_ts: int = 0) -> ReplayResult:
-    """Single-device replay of an action stream (bench + small tables).
+    """Single-device replay of an action stream (tests + small tables).
 
     Inputs are padded to the next power of two so XLA compiles one kernel per
     size bucket, not per log length."""
@@ -133,28 +132,21 @@ def _winner_bits_kernel(path_id):
     return jnp.packbits(winner)
 
 
-def winner_mask_device(path_id: np.ndarray) -> np.ndarray:
-    """Device last-writer-wins winner mask for a replay-ordered action stream.
-
-    Ships one int32 column up, one bitmask down; everything else
-    (alive/tombstone masks, aggregates) is cheap host numpy on the result."""
-    ensure_compilation_cache()
-    n = len(path_id)
-    cap = _next_pow2(n)
-    padded = np.full(cap, -1, np.int32)
-    padded[:n] = path_id
-    bits = np.asarray(_winner_bits_kernel(jnp.asarray(padded)))
-    return np.unpackbits(bits, count=n).astype(bool)
-
-
 def replay_columns(cols, min_retention_ts: int = 0, device: bool = True) -> ReplayResult:
     """Replay a :class:`delta_tpu.log.columnar.SegmentColumns` stream.
 
-    The winner computation runs on device (``device=True``) or as the host
-    scatter fallback; alive/tombstone masks and the aggregate stats are
-    elementwise host numpy either way (they are O(n) band-limited and would
-    only add transfer latency on device)."""
-    winner = winner_mask_device(cols.path_id) if device else None
+    The winner computation runs on device (``device=True``: one int32 column
+    up, one bitmask down) or as the host scatter fallback; alive/tombstone
+    masks and the aggregate stats are elementwise host numpy either way (they
+    are O(n) band-limited and would only add transfer latency on device)."""
+    winner = None
+    if device:
+        ensure_compilation_cache()
+        n = len(cols.path_id)
+        padded = np.full(_next_pow2(n), -1, np.int32)
+        padded[:n] = cols.path_id
+        bits = np.asarray(_winner_bits_kernel(jnp.asarray(padded)))
+        winner = np.unpackbits(bits, count=n).astype(bool)
     alive, tombstone = cols.replay(min_retention_ts, winner=winner)
     stats = ReplayStats(
         num_files=np.int32(alive.sum()),

@@ -458,8 +458,9 @@ def arrays_from_columns(
     _t0 = _time.perf_counter()
 
     def _lane_us():
-        # stats-lane build time in µs (telemetry: the BENCH metric-6 "parse
-        # time" component, isolated from the shared path/size extraction)
+        # stats-lane build time in µs (telemetry: the "parse time" component
+        # of a cold state-cache build, isolated from the shared path/size
+        # extraction)
         bump_counter("stateExport.statsLanes.us",
                      int((_time.perf_counter() - _t0) * 1e6))
 

@@ -117,7 +117,7 @@ def lpt_loads(sizes: Sequence[int],
 
 def bytes_skew(sizes: Sequence[int], assignment: Sequence[Sequence[int]]) -> float:
     """max/mean per-host bytes ratio of an assignment — 1.0 is perfectly
-    balanced; the zipf-100k regression gate in tests/bench watches this."""
+    balanced; the zipf-100k regression gate in tests watches this."""
     per_host = lpt_loads(sizes, assignment)
     if not per_host or sum(per_host) == 0:
         return 1.0

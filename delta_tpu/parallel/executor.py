@@ -17,7 +17,7 @@ execution); ours is deliberately smaller:
   and counted (`dist.steals`);
 * **measured, not asserted** — every item's wall clock is recorded
   (`dist.item.duration_ms`), and the report carries per-worker totals +
-  the max/mean byte skew so benches and the MULTICHIP artifact can print
+  the max/mean byte skew so a caller (`chip_smoke.py`, tests) can print
   per-shard timings instead of an "ok" string.
 
 Supervision (fault tolerance — the MapReduce task re-execution model the
@@ -104,7 +104,7 @@ class QuarantinedItem:
 
 @dataclass
 class ShardReport:
-    """What a sharded job actually did — the bench / MULTICHIP evidence."""
+    """What a sharded job actually did: per-worker timings, steals, skew."""
 
     results: List[Any]
     wall_s: float
@@ -312,7 +312,7 @@ def run_sharded(
 
     ``sizes`` are per-item byte weights (defaults to uniform). ``workers``
     defaults to :func:`default_workers`; 1 worker runs inline with no pool,
-    so the single-shard leg of a scaling bench measures the job, not the
+    so a single-shard run measures the job, not the
     machinery (retry + quarantine still apply inline).
 
     ``on_failure`` decides what an item that exhausts its transient

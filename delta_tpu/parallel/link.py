@@ -74,8 +74,7 @@ RESIDENT_PROBE_FIXED_S = 0.3
 # pairs instead; kept exported for calibration comparisons.
 RESIDENT_FINALIZE_S_PER_ROW = 3.0e-8
 # fused-path host finalize per MATCHED pair: positions searchsorted +
-# scatter into t_first_s (estimate pending on-device recalibration; the
-# bench's phase breakdown records the live number each round)
+# scatter into t_first_s (estimate pending on-device recalibration)
 RESIDENT_PAIR_S_PER_ROW = 1.0e-7
 # device slab sort (lax.sort of the key lane + permutation), amortized per
 # row — paid once per cold build / tail append, not per probe
@@ -101,7 +100,7 @@ DIST_ITEM_S = 5.0e-5
 
 # -- self-calibration --------------------------------------------------------
 #
-# The per-row/per-cell constants above were fit on ONE bench machine; on
+# The per-row/per-cell constants above were fit on ONE CPU host; on
 # different hardware the router silently prices the wrong side. The router
 # audit ledger (`obs/router_audit`) measures every routed decision against
 # its prediction, and the EWMA calibrator (`obs/calibration`) re-fits these
@@ -198,10 +197,8 @@ def resident_probe_device_s(n: int, m: int, p: "LinkProfile") -> float:
     matched count), the probe kernel, the compacted pair download
     (matched count unknown pre-probe: modeled at the upsert-typical m/2
     pairs x 8 bytes), the O(matched) host pair mapping, a fixed dispatch
-    floor, and the probe's sequential round trips. ONE definition — the
-    production router (`commands/merge.py`) and the bench's
-    `auto_routes_device` report both call this, so they cannot drift
-    apart."""
+    floor, and the probe's sequential round trips. ONE definition, called by the
+    production router (`commands/merge.py`)."""
     est_pairs = m // 2
     return (
         p.upload_s(m * 4)
@@ -235,8 +232,7 @@ def host_residual_filter_s(rows: int, ncols: int) -> float:
     """The router's cost model for evaluating a scan's residual predicate on
     host over already-decoded Arrow columns. Residual *evaluation* only —
     the host decode of non-predicate projection columns is common to both
-    sides and cancels. ONE definition — `ops/column_cache` and the device
-    scan bench both call this, so they cannot drift apart."""
+    sides and cancels. ONE definition, called by `ops/column_cache`."""
     return rows * ncols * constant("HOST_RESIDUAL_S_PER_CELL")
 
 
@@ -265,8 +261,7 @@ def sharded_plan_device_s(cells: int, shards: int, p: "LinkProfile") -> float:
     per-cell constant's fit). Priced against the single-device plan
     (``cells * DEVICE_PRUNE_S_PER_CELL``) and the host plan — the
     ``scan.plan`` router audit records which side actually won. ONE
-    definition — `ops/state_cache` routing and the sharded-scan bench both
-    call this, so they cannot drift apart."""
+    definition, called by `ops/state_cache` routing."""
     shards = max(int(shards), 1)
     return (
         (cells / shards) * constant("DEVICE_PRUNE_S_PER_CELL")

@@ -14,7 +14,7 @@ against a staging process or follow with ``timeseries.reset()`` +
 The synthetic generators (:func:`zipf_hot_key_storm`, :func:`cdc_burst`,
 :func:`contention_flood`) emit deterministic (seeded) traces in the SAME
 serialized format `replay/trace` produces from the journal, so shadow runs,
-capacity replays, torture, and bench all draw from one scenario library.
+capacity replays and torture all draw from one scenario library.
 """
 from __future__ import annotations
 
@@ -172,7 +172,7 @@ def contention_flood(path: str = "synthetic://contention", writers: int = 8,
                          source="synthetic:contentionFlood")
 
 
-#: name → generator; torture and bench both resolve scenarios through this
+#: name → generator; torture resolves scenarios through this
 SCENARIOS = {
     "zipfHotKeyStorm": zipf_hot_key_storm,
     "cdcBurst": cdc_burst,

@@ -19,7 +19,7 @@ each scan's concrete predicate in priority order:
 Traces serialize to plain JSON (:meth:`WorkloadTrace.save` /
 :meth:`WorkloadTrace.load`) — the synthetic scenario library
 (`replay/scenarios`) emits the same format, so shadow runs, capacity
-replays, torture, and bench all draw from one source.
+replays and torture all draw from one source.
 """
 from __future__ import annotations
 

@@ -41,7 +41,8 @@ Installation: set session conf ``delta.tpu.faults.plan`` to a
 ``"seed=42,rate=0.05,kinds=transient|crash_after_publish"``;
 ``DeltaLog`` wraps its store via :func:`maybe_wrap` at construction. With
 the conf unset, :func:`maybe_wrap` returns the store unchanged — zero
-wrapper, zero overhead (asserted by ``bench.py``).
+wrapper, zero overhead (asserted by
+``tests/test_faults.py::test_maybe_wrap_zero_overhead_when_unset``).
 """
 from __future__ import annotations
 

@@ -84,10 +84,7 @@ class SqlConf:
         # so repeated MERGEs against a hot table skip both the key decode
         # and the upload. False disables caching AND the background build
         # (the fused device path then rebuilds a transient slab per merge).
-        # `delta.tpu.merge.residentKeys.enabled` is the legacy alias; either
-        # set to false disables.
         "delta.tpu.merge.keyCache.enabled": True,
-        "delta.tpu.merge.residentKeys.enabled": True,
         # Minimum estimated table rows before the post-commit background
         # key-lane build kicks in (small tables never win on device).
         "delta.tpu.merge.residentKeys.minRows": 1 << 20,
@@ -96,7 +93,8 @@ class SqlConf:
         "delta.tpu.keyCache.maxEntries": 8,
         # Device residual-filter path (ops/column_cache): "auto" prices
         # device vs host per scan through parallel/link, "force" always
-        # engages (bench legs), "off" disables the path and the cache.
+        # engages (the benchmark's `force` pins), "off" disables the path and
+        # the cache.
         "delta.tpu.read.deviceResidual.mode": "auto",
         # Scan column-cache budgets (ops/column_cache.ColumnCache._evict);
         # entries are per-(file, column) lanes, hence the larger count.
@@ -279,7 +277,7 @@ class SqlConf:
         # when the table does not set delta.checkpoint.writeStatsAsStruct
         # itself. Default ON: the cold state-cache build then reads typed
         # columns instead of re-parsing per-file stats JSON (the dominant
-        # cost of a 1M-file cold build — see BENCH metric 6).
+        # cost of a 1M-file cold build).
         "delta.tpu.checkpoint.writeStatsAsStruct": True,
         # ≈ DELTA_WRITE_CHECKSUM_ENABLED
         "delta.tpu.writeChecksum.enabled": True,
@@ -322,7 +320,7 @@ class SqlConf:
         # Deterministic fault injection (storage/faults.py): a FaultPlan
         # object or a spec string like "seed=42,rate=0.05,kinds=transient".
         # None (the default) installs NO wrapper — zero overhead, asserted
-        # by bench.py.
+        # by tests/test_faults.py.
         "delta.tpu.faults.plan": None,
         # Transient-retry layer over every table's LogStore (storage/
         # retrying.py): idempotent ops (reads, listings, overwrite-PUTs)

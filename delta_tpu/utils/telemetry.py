@@ -73,7 +73,7 @@ __all__ = [
     "record_event", "record_operation", "with_status", "recent_events",
     "clear_events", "UsageEvent", "bump_counter", "counters",
     "clear_counters", "set_gauge", "gauges", "observe", "histograms",
-    "prometheus_text", "metrics_snapshot", "bench_snapshot",
+    "prometheus_text", "metrics_snapshot",
     "export_chrome_trace", "current_span", "add_span_data", "reset_all",
     "HISTOGRAM_BUCKETS", "span_stack_snapshot", "add_failure_hook",
     "remove_failure_hook", "span_context", "adopt_span_context", "propagated",
@@ -799,8 +799,7 @@ def clear_metrics() -> None:
 
 
 def reset_all() -> None:
-    """Events + counters + gauges + histograms back to empty (tests, bench
-    per-config isolation)."""
+    """Events + counters + gauges + histograms back to empty (tests)."""
     with _LOCK:
         _BUFFER.clear()
         _COUNTERS.clear()
@@ -902,7 +901,7 @@ def bucket_quantile(counts: Sequence[int], count: int, q: float) -> Optional[flo
     """Upper bucket bound where the cumulative count crosses q (approximate,
     conservative-upward — the usual bucket-quantile estimate). Public: the
     obs scraper extracts windowed quantiles from cumulative-bucket deltas
-    with exactly this rule, so /slo and bench_snapshot can never disagree.
+    with exactly this rule, so /slo and a histogram summary never disagree.
     Returns None for an empty histogram or a crossing past the last bound
     (the +Inf bucket) — callers choose their own sentinel."""
     if count <= 0:
@@ -914,9 +913,6 @@ def bucket_quantile(counts: Sequence[int], count: int, q: float) -> Optional[flo
         if cum >= target:
             return bound
     return None  # beyond the last bound (+Inf bucket) — keep JSON strict
-
-
-_hist_quantile = bucket_quantile
 
 
 def metrics_snapshot() -> Dict[str, Any]:
@@ -938,42 +934,6 @@ def metrics_snapshot() -> Dict[str, Any]:
             buckets["+Inf"] = counts[-1]
         out["histograms"][f"{n}{_labels_suffix(lb)}"] = {
             "count": count, "sum": round(total, 3), "buckets": buckets,
-        }
-    return out
-
-
-def bench_snapshot(top: int = 12,
-                   include: Sequence[str] = ()) -> Dict[str, Any]:
-    """Compact per-bench-config attachment: top counters by value plus
-    histogram summaries (count/sum/approx p50/p95) — internal metrics for
-    BENCH_*.json trajectories, not just wall-clock. Counters AND gauges
-    matching an ``include`` prefix ride along even when they miss the top-N
-    cut (skip rates and health gauges matter at every magnitude)."""
-    with _LOCK:
-        ctrs = sorted(_COUNTERS.items(), key=lambda kv: (-kv[1], kv[0]))[:top]
-        if include:
-            seen = {k for k, _ in ctrs}
-            ctrs += [
-                (k, v) for k, v in sorted(_COUNTERS.items())
-                if k not in seen and any(_prefix_match(k, p) for p in include)
-            ]
-        gags = (
-            {k: v for k, v in _GAUGES.items()
-             if any(_prefix_match(k[0], p) for p in include)}
-            if include else {}
-        )
-        hists = [((n, lb), list(h.counts), h.sum, h.count)
-                 for (n, lb), h in _HISTOGRAMS.items()]
-    out: Dict[str, Any] = {"counters": dict(ctrs), "histograms": {}}
-    if gags:
-        out["gauges"] = {f"{n}{_labels_suffix(lb)}": v
-                        for (n, lb), v in sorted(gags.items())}
-    for (n, lb), counts, total, count in sorted(hists, key=lambda r: r[0]):
-        out["histograms"][f"{n}{_labels_suffix(lb)}"] = {
-            "count": count,
-            "sum": round(total, 3),
-            "p50": _hist_quantile(counts, count, 0.50),
-            "p95": _hist_quantile(counts, count, 0.95),
         }
     return out
 

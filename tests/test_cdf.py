@@ -259,6 +259,42 @@ def test_streaming_cdf_source_tails_changes(tmp_table):
     assert versions == {1, 2}
 
 
+def test_streaming_tails_of_an_append_only_log_agree(tmp_table):
+    """Over a log of plain appends (no CDF property: every commit is
+    reconstructed), batched by `max_files_per_trigger` from version 0, the
+    row source and the change-feed source both deliver exactly the rows a
+    snapshot scan holds, the feed's all as inserts."""
+    from delta_tpu.exec.scan import scan_to_table
+    from delta_tpu.log.deltalog import DeltaLog
+    from delta_tpu.streaming.source import DeltaCDFSource, DeltaSource
+
+    log = DeltaLog.for_table(tmp_table)
+    n_commits, per = 12, 10
+    for i in range(n_commits):
+        WriteIntoDelta(log, "append", pa.table({
+            "id": pa.array(range(i * per, (i + 1) * per), pa.int64()),
+        })).run()
+
+    def tail(src):
+        off, ids, kinds, batches = src.initial_offset(), [], set(), 0
+        while (end := src.latest_offset(off)) is not None:
+            batch = src.get_batch(off, end)
+            ids.extend(batch.column("id").to_pylist())
+            if CHANGE_TYPE_COL in batch.column_names:
+                kinds.update(batch.column(CHANGE_TYPE_COL).to_pylist())
+            off, batches = end, batches + 1
+        return sorted(ids), kinds, batches
+
+    want = sorted(scan_to_table(log.update()).column("id").to_pylist())
+    assert want == list(range(n_commits * per))
+    rows, _kinds, batches = tail(DeltaSource(
+        log, max_files_per_trigger=5, starting_version=0))
+    assert rows == want and batches == 3
+    feed, kinds, _ = tail(DeltaCDFSource(
+        log, max_files_per_trigger=5, starting_version=0))
+    assert feed == want and kinds == {"insert"}
+
+
 def test_streaming_cdf_source_ignores_hygiene(tmp_table):
     """Updates/deletes never raise on the CDF source (they ARE the data),
     unlike the row source's ignoreChanges contract."""

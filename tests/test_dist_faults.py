@@ -233,6 +233,31 @@ def test_no_speculation_when_disabled():
     assert report.results == [0, 1, 2]
 
 
+@pytest.mark.parametrize("speculation", [False, True],
+                         ids=["waits-it-out", "rescues-it"])
+def test_scripted_slow_fault_is_the_straggler(speculation):
+    """A `slow` fault scripted at `dist.itemExec` stalls whichever attempt
+    fires first; the script is consumed once, so the re-dispatched attempt
+    runs clean. With or without speculation the results are the same, in
+    order; only with it is the stalled item rescued."""
+    plan = FaultPlan(script=[("dist.itemExec", "slow")], slow_ms=400)
+    with _fast_retries(
+        delta__tpu__faults__plan=plan,
+        delta__tpu__distributed__itemTimeoutMs=60,
+        delta__tpu__distributed__supervisor__intervalMs=5,
+        delta__tpu__distributed__speculation__slackFactor=1.0,
+        delta__tpu__distributed__speculation__enabled=speculation,
+    ):
+        report = run_sharded(list(range(8)), lambda i: i * 10, workers=4,
+                             label="t")
+    assert not plan.script, "the scripted straggler never fired"
+    assert report.results == [i * 10 for i in range(8)]
+    if speculation:
+        assert report.speculated >= 1 and report.rescued >= 1
+    else:
+        assert report.speculated == 0 and report.rescued == 0
+
+
 # -- fault points + degradation ladder ---------------------------------------
 
 
@@ -489,6 +514,31 @@ def test_optimize_quarantine_completes_commit_without_poison_group(tmp_path):
           if e.get("event") == "dist.quarantine"]
     assert len(ev) == 1 and ev[0]["op"] == "optimize"
     assert ev[0]["items"][0]["attempts"] == 1
+
+
+def test_optimize_transient_faults_retry_to_the_clean_runs_table(tmp_path):
+    """Four scripted transient `dist.itemExec` faults under a sharded
+    OPTIMIZE with `on_failure="quarantine"`: every one retries to success,
+    nothing is quarantined, and rows and file count equal a clean run's."""
+    from delta_tpu.commands.optimize import OptimizeCommand
+
+    clean = _mk_partitioned_table(str(tmp_path / "clean"))
+    faulted = _mk_partitioned_table(str(tmp_path / "faulted"))
+    OptimizeCommand(clean, workers=4).run()
+    plan = FaultPlan(script=[("dist.itemExec", "transient")] * 4)
+    # more attempts than faults: whichever items the four land on, none of
+    # them can run out
+    with _fast_retries(delta__tpu__faults__plan=plan,
+                       delta__tpu__distributed__retry__maxAttempts=6):
+        cmd = OptimizeCommand(faulted, workers=4, on_failure="quarantine")
+        cmd.run()
+    assert not plan.script, "scripted faults never fired"
+    assert cmd.metrics["numQuarantinedGroups"] == 0
+    assert cmd.shard_report.retried >= 4
+    assert cmd.shard_report.quarantined == []
+    assert _table_rows(faulted) == _table_rows(clean)
+    assert (len(faulted.update().all_files)
+            == len(clean.update().all_files) == 4)
 
 
 def _posed_optimize(log, proc: int, n_procs: int = 2, **kw):

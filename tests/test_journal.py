@@ -758,14 +758,14 @@ def test_flight_recorder_embeds_scan_report_and_last_audit(tmp_path):
     router_audit.clear_audits()
 
 
-def test_bench_snapshot_carries_journal_counters(tmp_table):
+def test_metrics_snapshot_carries_journal_counters(tmp_table):
     t = DeltaTable.create(tmp_table, data=_ids(30))
     t.to_arrow(filters=["v = 1"])
     journal.flush()
-    snap = telemetry.bench_snapshot(include=("journal", "advisor"))
+    snap = telemetry.metrics_snapshot()
     assert snap["counters"].get("journal.entries", 0) >= 1
     advise(tmp_table)
-    snap = telemetry.bench_snapshot(include=("journal", "advisor"))
+    snap = telemetry.metrics_snapshot()
     assert snap["counters"].get("advisor.runs", 0) >= 1
 
 

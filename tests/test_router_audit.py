@@ -447,10 +447,10 @@ def test_router_route_and_metrics_exposition(tmp_path):
         srv.stop()
 
 
-def test_bench_snapshot_carries_router_and_hbm_gauges(tmp_path):
+def test_metrics_snapshot_carries_router_and_hbm_gauges(tmp_path):
     log = _seed(tmp_path / "tsnap")
     _merge(log, "off")
-    snap = telemetry.bench_snapshot(include=("router", "device.hbm"))
+    snap = telemetry.metrics_snapshot()
     assert "router.audits" in snap["counters"]
     assert any(k.startswith("router.missRate") for k in snap["gauges"])
     assert any(k.startswith("router.actual_ms")

@@ -380,7 +380,8 @@ def test_shadow_gate_defers_untested_rewrites(tmp_table):
 # -- capacity replay ---------------------------------------------------------
 
 
-def test_capacity_replay_10x_fires_same_slo_objective(tmp_table):
+@pytest.mark.parametrize("speed", [10, 100], ids=["10x", "100x"])
+def test_capacity_replay_compressed_fires_same_slo_objective(tmp_table, speed):
     from delta_tpu.obs import slo, timeseries
 
     trace = zipf_hot_key_storm(path=tmp_table)
@@ -393,10 +394,11 @@ def test_capacity_replay_10x_fires_same_slo_objective(tmp_table):
     slo.reset()
     timeseries.reset()
     with conf.set_temporarily(**overrides):
-        fast = capacity_replay(trace, speed=10.0, now_ms=2_000_000_000_000)
-    # the compressed burn pre-fires the SAME objective in a tenth the time
+        fast = capacity_replay(trace, speed=float(speed),
+                               now_ms=2_000_000_000_000)
+    # the compressed burn pre-fires the SAME objective in 1/speed the time
     assert fast["objectives"] == full["objectives"]
-    assert fast["simulatedMs"] == full["simulatedMs"] // 10
+    assert fast["simulatedMs"] == full["simulatedMs"] // speed
     assert fast["alerts"] and fast["alerts"][0]["firing"] is True
     assert fast["alerts"][0]["objective"] == "scanPlanningP99"
     c = telemetry.counters("replay.capacity")

@@ -319,19 +319,84 @@ def test_metrics_snapshot_is_json_serializable():
     assert snap["counters"] == {"a.b": 2}
     assert snap["gauges"] == {"g": 1.5}
     assert snap["histograms"]["h.ms{path=/t}"]["count"] == 1
-    compact = json.loads(json.dumps(telemetry.bench_snapshot()))
-    assert compact["counters"]["a.b"] == 2
-    assert compact["histograms"]["h.ms{path=/t}"]["p50"] == 16.0
+    # the one observation of 10 sits in the bucket whose upper bound is 16
+    assert snap["histograms"]["h.ms{path=/t}"]["buckets"] == {"16": 1}
 
 
-def test_bench_snapshot_includes_matching_gauges():
-    """bench.py snapshots carry table.health.* gauges via the include list."""
+def _quantile(values, q):
+    """`bucket_quantile` over the registry's own bucket counts (+Inf last)
+    of a histogram that observed ``values``."""
     telemetry.reset_all()
-    telemetry.set_gauge("table.health.severity", 1, path="/t")
-    telemetry.set_gauge("unrelated.gauge", 9)
-    snap = telemetry.bench_snapshot(include=("table.health",))
-    assert snap["gauges"] == {"table.health.severity{path=/t}": 1.0}
-    assert "gauges" not in telemetry.bench_snapshot()
+    for v in values:
+        telemetry.observe("q.ms", v)
+    rows = telemetry.histogram_rows("q.ms")
+    if not rows:
+        return telemetry.bucket_quantile(
+            [0] * (len(telemetry.HISTOGRAM_BUCKETS) + 1), 0, q)
+    [(_name, _labels, counts, _sum, count)] = rows
+    return telemetry.bucket_quantile(counts, count, q)
+
+
+def _empty_is_none():
+    assert _quantile([], 0.5) is None
+
+
+def _one_observation_of_10_reads_16():
+    assert _quantile([10.0], 0.5) == 16.0
+
+
+def _edge_takes_the_lower_bucket():
+    # q x count = 1.0 is met exactly at the end of the first bucket, which
+    # wins over the bucket above it
+    assert _quantile([10.0, 100.0], 0.5) == 16.0
+
+
+def _crossing_in_inf_is_none():
+    past = telemetry.HISTOGRAM_BUCKETS[-1] * 2
+    assert _quantile([10.0, past, past], 0.95) is None
+    assert _quantile([10.0, past, past], 0.25) == 16.0
+
+
+def _p50_not_above_p95():
+    values = [10.0] * 9 + [100.0]
+    p50, p95 = _quantile(values, 0.5), _quantile(values, 0.95)
+    assert (p50, p95) == (16.0, 128.0) and p50 <= p95
+
+
+def _quantile_window_agrees():
+    """Over the delta of two scrapes `timeseries.quantile_window` (what
+    `/slo` reads) gives what `bucket_quantile` gives on the observations
+    made between them, and leaves out what came before."""
+    from delta_tpu.obs import timeseries
+
+    between = [10.0, 10.0, 100.0, 700.0]
+    want = _quantile(between, 0.75)
+    timeseries.reset()
+    telemetry.reset_all()
+    try:
+        telemetry.observe("q.ms", 3.0)
+        timeseries.scrape_once(now_ms=1_000, evaluate_slo=False)
+        for v in between:
+            telemetry.observe("q.ms", v)
+        timeseries.scrape_once(now_ms=2_000, evaluate_slo=False)
+        got = timeseries.quantile_window("q.ms", (), 0.75, 5_000,
+                                         now_ms=2_000)
+    finally:
+        timeseries.reset()
+    assert got == (want, len(between)) and want == 128.0
+
+
+@pytest.mark.parametrize("case", [
+    _empty_is_none, _one_observation_of_10_reads_16,
+    _edge_takes_the_lower_bucket, _crossing_in_inf_is_none,
+    _p50_not_above_p95, _quantile_window_agrees,
+], ids=lambda f: f.__name__.strip("_"))
+def test_bucket_quantile(case):
+    """The one quantile rule that `/slo` (through
+    `timeseries.quantile_window`) and every histogram summary share: the
+    upper bound of the bucket in which the cumulative count first reaches
+    q x count; None for an empty histogram or a crossing in +Inf."""
+    case()
 
 
 # -- zero-overhead disable ---------------------------------------------------

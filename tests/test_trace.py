@@ -270,6 +270,43 @@ def test_run_sharded_pool_spans_parent_under_job():
     assert {e.trace_id for e in evs} == {root.trace_id}
 
 
+@pytest.mark.parametrize("posture, knob", [
+    pytest.param(p, k, id=p) for p, k in [
+        ("sampled", {"delta.tpu.trace.sampleRate": 1.0}),
+        ("unsampled", {"delta.tpu.trace.sampleRate": 0.0}),
+        ("disabled", {"delta.tpu.telemetry.enabled": False})]])
+def test_sharded_optimize_under_each_tracing_posture(tmp_path, posture, knob):
+    """The pool path of a real command (job, worker and item spans across
+    threads) under the three postures an operator can set: the compaction
+    is the same, `sampleRate=1` spools its spans, and `sampleRate=0` or
+    telemetry off never creates the spool directory."""
+    import pyarrow as pa
+
+    from delta_tpu import DeltaLog
+    from delta_tpu.commands.optimize import OptimizeCommand
+    from delta_tpu.commands.write import WriteIntoDelta
+
+    log = DeltaLog.for_table(str(tmp_path / "t"))
+    for i in range(12):  # 4 partitions x 3 files
+        WriteIntoDelta(log, "append", pa.table({
+            "id": pa.array(range(i * 8, i * 8 + 8), pa.int64()),
+            "part": pa.array([f"p{i % 4}"] * 8),
+        }), partition_columns=["part"]).run()
+    spool = str(tmp_path / "spool")
+    cmd = OptimizeCommand(log, min_file_size=1 << 30, workers=4)
+    with conf.set_temporarily(**{"delta.tpu.trace.dir": spool}, **knob):
+        cmd.run()
+    trace_store.reset()
+    assert cmd.metrics["numRemovedFiles"] == 12
+    assert cmd.metrics["numAddedFiles"] == 4
+    if posture == "sampled":
+        ops = {r["op"] for r in trace_store.read_spools(spool)}
+        assert {"delta.dist.job", "delta.dist.worker",
+                "delta.dist.item"} <= ops
+    else:
+        assert not os.path.exists(spool), f"{posture} touched the spool"
+
+
 def test_run_sharded_inline_path_spans_items_under_job():
     rep = run_sharded([3, 4], lambda x: x + 1, sizes=[5, 7], workers=1,
                       label="inline")
