@@ -126,6 +126,7 @@ COUNTERS = frozenset({
     "merge.keyCache.builds",      # cold key-lane builds (inline or bg)
     "merge.keyCache.advances",    # incremental log-tail applications
     "merge.keyCache.invalidations",  # entries dropped by a rewrite epoch bump
+    "merge.keyCache.inverseBuilds",  # inverse permutations built for a flip
     # -- router audit ledger + calibrator (obs/router_audit, obs/calibration)
     "router.audits",              # one per routed decision recorded
     "router.misses",              # hindsight: rejected route predicted faster
@@ -404,6 +405,7 @@ DESCRIPTIONS = {
     "merge.keyCache.builds": "Cold resident key-lane builds.",
     "merge.keyCache.advances": "Incremental log-tail applications to a key lane.",
     "merge.keyCache.invalidations": "Key-cache entries dropped by a rewrite epoch bump.",
+    "merge.keyCache.inverseBuilds": "Inverse permutations of a sorted key slab built on demand: the first validity flip that found a live sorted view without one (an advance that appends keys builds none).",
     "router.audits": "Routed decisions recorded in the audit ledger.",
     "router.misses": "Audits where a rejected route's prediction beat the actual.",
     "router.calibration.updates": "EWMA samples folded into the calibration state.",

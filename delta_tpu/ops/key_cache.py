@@ -160,12 +160,16 @@ from delta_tpu.ops.join_kernel import PendingJoin as PendingProbe
 def _sort_kernel():
     """Sort the slab's key lane once per KEY mutation (build/append), NOT
     per probe: steady-state probes against an unchanged table then skip
-    the O(n log n) term entirely. Also emits the inverse permutation (so
-    later deletion-vector validity flips update the sorted-space validity
-    with a k-row scatter instead of an O(n) gather) and the sorted-space
-    validity itself. Padding rows encode as int64.max so they sort to the
-    tail; a real key equal to int64.max may share their run — harmless,
-    validity excludes them."""
+    the O(n log n) term entirely. One sort gives all three arrays a probe
+    reads: the int32 that rides with each key is its physical row with the
+    row's validity in the low bit, so the permutation and the sorted-space
+    validity are two dense reads of the sorted payload, never a gather
+    through the permutation. The payload is the second sort key: it orders
+    as the row does, so ties among equal keys stay in physical-row order,
+    valid or dead, without the row-id operand a stable sort would add
+    beside it. Padding rows encode as int64.max so they sort to the tail
+    and read invalid; a real key equal to int64.max may share their run —
+    harmless, validity excludes them."""
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
@@ -174,13 +178,46 @@ def _sort_kernel():
     def kernel(keys, valid, n):
         cap = keys.shape[0]
         iota = jnp.arange(cap, dtype=jnp.int32)
-        enc = jnp.where(iota < n, keys, jnp.iinfo(jnp.int64).max)
-        sk, perm = jax.lax.sort((enc, iota), num_keys=1)
-        inv = jnp.zeros(cap, jnp.int32).at[perm].set(iota)
-        sv = (valid & (iota < n))[perm]
-        return sk, perm, inv, sv
+        inside = iota < n
+        enc = jnp.where(inside, keys, jnp.iinfo(jnp.int64).max)
+        payload = (iota << 1) | (valid & inside).astype(jnp.int32)
+        sk, payload = jax.lax.sort((enc, payload), num_keys=2,
+                                   is_stable=False)
+        return sk, payload >> 1, (payload & 1) == 1
 
     return kernel
+
+
+@functools.lru_cache(maxsize=None)
+def _inverse_permutation():
+    """Physical row -> sorted position, from the resident permutation: one
+    capacity-sized scatter, paid by the first validity flip that finds a
+    live sorted view without it (`ResidentJoinKeys._dev_flip_valid`), never
+    by the sort. An advance that appends keys drops the view before its
+    kills run and so never asks."""
+    ensure_compilation_cache()
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inverse_permutation(perm):
+        cap = perm.shape[0]
+        return jnp.zeros(cap, jnp.int32).at[perm].set(
+            jnp.arange(cap, dtype=jnp.int32))
+
+    return inverse_permutation
+
+
+def _slab_capacity(rows: int) -> int:
+    """Device rows for a slab of ``rows``: `join_kernel._bucket` (pow2 to
+    4M, then 2M steps), at least 1024."""
+    from delta_tpu.ops.join_kernel import _bucket
+
+    cap = max(_bucket(rows), 1024)
+    # a physical row shares its int32 with the validity bit in the sort
+    # (`_sort_kernel`); 4 GiB of slab is 195M rows, so no budget reaches this
+    assert cap <= 1 << 30, f"key slab of {cap} rows: a row id has 30 bits"
+    return cap
 
 
 # what one probe may hold in HBM besides its operands: a chunk of gathered
@@ -431,6 +468,9 @@ class ResidentJoinKeys:
         # key lane: set by key appends, NOT by validity flips (DV kills and
         # revives don't change sort order). The next probe re-sorts once.
         self._sort_stale = True
+        # the sorted view a key append last dropped held an inverse
+        # permutation (reported by the next sort's span)
+        self._inverse_dropped = False
         self._lock = threading.RLock()
         self.last_used = 0.0
         # device-memory accounting (gc-backstopped so a transient
@@ -498,15 +538,12 @@ class ResidentJoinKeys:
             self.num_rows += n
             if self.num_rows > self.capacity:
                 # regrow: drop device arrays; next probe re-ships the mirrors.
-                # Bucketing matches join_kernel._bucket (pow2 to 4M, then 2M
-                # steps) with 25% headroom, so a steady append stream (CDC
-                # rounds) doesn't cross a bucket — and recompile the probe +
+                # 25% headroom, so a steady append stream (CDC rounds)
+                # doesn't cross a bucket — and recompile the probe +
                 # re-upload the slab — every few commits.
-                from delta_tpu.ops.join_kernel import _bucket
-
                 self._dev = None
                 self._hbm.off()  # before capacity changes: bytes were old-cap
-                self.capacity = max(_bucket(int(self.num_rows * 1.25)), 1024)
+                self.capacity = _slab_capacity(int(self.num_rows * 1.25))
                 return True
             if self._pending is not None:
                 self._pending["rows"].append(
@@ -584,8 +621,9 @@ class ResidentJoinKeys:
     @property
     def device_bytes(self) -> int:
         # keys(8) + valid(1) + sorted view: sorted_keys(8) + perm(4) +
-        # inv_perm(4) + sorted_valid(1)
-        return self.capacity * 26
+        # sorted_valid(1); inv_perm(4) while a flip has built it
+        inverse = self._dev is not None and "inv_perm" in self._dev
+        return self.capacity * (26 if inverse else 22)
 
     @property
     def is_resident(self) -> bool:
@@ -675,21 +713,34 @@ class ResidentJoinKeys:
             return
         if not self._sort_stale and "sorted_keys" in self._dev:
             return
+        # `inverse`: whether the view this sort replaces had one built, so
+        # a trace says how often a sorted view's life includes a flip
         with telemetry.record_operation(
-                "delta.keyCache.sort", {"rows": self.num_rows}), enable_x64():
-            sk, pm, inv, sv = _sort_kernel()(
+                "delta.keyCache.sort",
+                {"rows": self.num_rows, "inverse": self._inverse_dropped}), \
+                enable_x64():
+            sk, pm, sv = _sort_kernel()(
                 self._dev["keys"], self._dev["valid"],
                 link.to_device(np.int32(self.num_rows)))
         self._dev["sorted_keys"] = sk
         self._dev["perm"] = pm
-        self._dev["inv_perm"] = inv
         self._dev["sorted_valid"] = sv
         self._sort_stale = False
+        self._inverse_dropped = False
+
+    def _account_device(self) -> None:
+        """Bring the HBM account to `device_bytes` (the inverse permutation
+        comes and goes within one residency)."""
+        self._hbm.off()
+        self._hbm.on(self, self.device_bytes)
 
     def _dev_flip_valid(self, rows: np.ndarray, value: bool) -> None:
         """Validity flip in ROW space plus, when the sorted view is live,
-        the mirrored flip in SORTED space via the resident inverse
-        permutation (a k-row gather+scatter — never an O(n) rebuild)."""
+        the mirrored flip in SORTED space via the inverse permutation (a
+        k-row gather+scatter). The first such flip of a view builds the
+        inverse from the resident permutation, one capacity-sized scatter;
+        it stays until the next key append drops the view. A flip on a
+        stale view is a row-space flip and builds nothing."""
         d = _next_pow2(max(len(rows), 1), floor=64)
         padded = np.full(d, self.capacity, np.int32)
         padded[: len(rows)] = rows
@@ -697,6 +748,14 @@ class ResidentJoinKeys:
         rows_dev = link.to_device(padded)
         self._dev["valid"] = kern(self._dev["valid"], rows_dev)
         if not self._sort_stale and "sorted_valid" in self._dev:
+            if "inv_perm" not in self._dev:
+                with telemetry.record_operation(
+                        "delta.keyCache.inverse",
+                        {"rows": self.num_rows, "flips": len(rows)}):
+                    self._dev["inv_perm"] = _inverse_permutation()(
+                        self._dev["perm"])
+                telemetry.bump_counter("merge.keyCache.inverseBuilds")
+                self._account_device()
             spos = _update_kernels()["map_rows"](
                 self._dev["inv_perm"], rows_dev)
             self._dev["sorted_valid"] = kern(
@@ -728,8 +787,11 @@ class ResidentJoinKeys:
         # key rows changed: the sorted view lags; drop it (frees HBM) and
         # let the next probe re-sort
         self._sort_stale = True
-        for view in ("sorted_keys", "perm", "inv_perm", "sorted_valid"):
+        for view in ("sorted_keys", "perm", "sorted_valid"):
             self._dev.pop(view, None)
+        if self._dev.pop("inv_perm", None) is not None:
+            self._inverse_dropped = True
+            self._account_device()
         with enable_x64():
             if contiguous:
                 self._dev["keys"], self._dev["valid"] = (
@@ -1002,8 +1064,6 @@ class SlabBuilder:
     def __init__(self, log_path: str, metadata_id: str, version: int,
                  signature: str, key_cols: List[str], exprs,
                  data_path: str, files, device: bool = True, epoch: int = 0):
-        from delta_tpu.ops.join_kernel import _bucket
-
         self.exprs = list(exprs)
         self.data_path = data_path
         self.failed: Optional[str] = None
@@ -1028,7 +1088,7 @@ class SlabBuilder:
         entry = ResidentJoinKeys(log_path, metadata_id, version, signature,
                                  list(key_cols))
         entry.epoch = epoch
-        entry.capacity = max(_bucket(max(total, 1)), 1024)
+        entry.capacity = _slab_capacity(max(total, 1))
         self.entry = entry
 
     def _footer_rows(self, add) -> Optional[int]:
@@ -1393,8 +1453,8 @@ class KeyCache:
                     break
                 if k == keep:
                     continue
-                e.drop_device()
                 total -= e.device_bytes
+                e.drop_device()
             max_entries = int(conf.get("delta.tpu.keyCache.maxEntries", 8))
             if len(self._entries) > max_entries:
                 for k, e in sorted(self._entries.items(),

@@ -388,6 +388,155 @@ def test_probe_after_kill_and_append_resorts(tmp_table):
     assert not e._sort_stale
 
 
+SORT_CASES = [
+    "duplicates-valid-and-dead", "padding", "int64-max-key", "all-invalid",
+    "one-row", "narrowed-to-int32", "full-capacity",
+]
+
+
+def _sort_case(case):
+    """(keys, dead rows) of one slab of capacity 1024."""
+    rng = np.random.RandomState(13)
+    n = 700
+    keys = (rng.randint(0, 200, n).astype(np.int64) << 33) - 5  # duplicates
+    dead = rng.choice(n, 150, replace=False)
+    if case == "padding":
+        # rows past n hold key 0 on the device and belong at the tail: real
+        # keys on both sides of it
+        keys = np.concatenate([keys[:100], -keys[:100]])
+        dead = np.empty(0, np.int64)
+    elif case == "int64-max-key":
+        # shares the padding's run; one of the three is dead
+        keys[[3, 300, 699]] = np.iinfo(np.int64).max
+        dead = np.append(dead[(dead != 3) & (dead != 699)], 300)
+    elif case == "all-invalid":
+        dead = np.arange(n)
+    elif case == "one-row":
+        keys, dead = keys[:1], np.empty(0, np.int64)
+    elif case == "narrowed-to-int32":
+        keys = rng.randint(-50, 50, n).astype(np.int64)
+    elif case == "full-capacity":
+        keys = np.resize(keys, 1024)
+    return keys, np.asarray(dead, np.int64)
+
+
+@pytest.mark.parametrize("case", SORT_CASES)
+def test_sort_equals_the_numpy_oracle(case):
+    """The re-sort's three arrays against numpy: `perm` is the stable
+    argsort of the encoded keys (padding as int64.max; ties in physical-row
+    order, valid or dead), `sorted_keys` the encoded keys through it,
+    `sorted_valid` the live rows through it — and no inverse is built."""
+    from delta_tpu.ops.key_cache import ResidentJoinKeys
+
+    keys, dead = _sort_case(case)
+    n = len(keys)
+    e = ResidentJoinKeys("log", "mid", 0, "sig", ["k"])
+    e._append_file("f", keys, np.ones(n, bool))
+    assert e._set_dv("f", dead)
+    e.ensure_resident()
+    e._ensure_sorted()
+    cap = e.capacity
+    assert e._dev["keys"].dtype == np.int64 and e._dev["keys"].shape == (cap,)
+    enc = np.full(cap, np.iinfo(np.int64).max)
+    enc[:n] = keys
+    live = np.zeros(cap, bool)
+    live[:n] = True
+    live[dead] = False
+    perm = np.argsort(enc, kind="stable")
+    assert np.asarray(e._dev["perm"]).dtype == np.int32
+    assert (np.asarray(e._dev["perm"]) == perm).all()
+    assert (np.asarray(e._dev["sorted_keys"]) == enc[perm]).all()
+    assert (np.asarray(e._dev["sorted_valid"]) == live[perm]).all()
+    assert "inv_perm" not in e._dev and e.device_bytes == 22 * cap
+
+
+def test_slab_capacity_leaves_the_payload_its_validity_bit():
+    """Row ids share their int32 with the validity bit in the sort."""
+    from delta_tpu.ops.key_cache import _slab_capacity
+
+    assert _slab_capacity(1) == 1024
+    assert _slab_capacity(28_800_991) == 29_360_128
+    assert _slab_capacity(1 << 30) == 1 << 30
+    with pytest.raises(AssertionError, match="30 bits"):
+        _slab_capacity((1 << 30) + 1)
+
+
+def _inverse_builds():
+    from delta_tpu.utils import telemetry
+
+    return telemetry.counters("merge.keyCache").get(
+        "merge.keyCache.inverseBuilds", 0)
+
+
+def _probe_bits(e, keys):
+    return e.probe_async(np.asarray(keys, np.int64),
+                         np.ones(len(keys), bool)).result().s_matched.tolist()
+
+
+def test_inverse_permutation_is_built_by_the_first_flip_that_needs_it():
+    """(a) the advance's shape, an append and a kill in one batch, builds no
+    inverse: the append drops the sorted view before the kill runs. (b) a
+    kill and a revive on a live view build exactly one, at the first flip.
+    (c) the next append drops it, and the accounts read 22 B a row again."""
+    from delta_tpu.obs import hbm_ledger
+    from delta_tpu.ops.key_cache import ResidentJoinKeys
+    from delta_tpu.utils import telemetry
+
+    hbm_ledger.reset()
+    telemetry.clear_events()
+    rng = np.random.RandomState(17)
+    a = rng.permutation(600).astype(np.int64)
+    e = ResidentJoinKeys("log", "mid", 0, "sig", ["k"])
+    e._append_file("a", a, np.ones(600, bool))
+    e.ensure_resident()
+    cap = e.capacity
+    assert _probe_bits(e, [a[5], 1000]) == [True, False]
+    assert not e._sort_stale
+    before = _inverse_builds()
+
+    with e.device_batch():
+        e._append_file("b", np.arange(1000, 1100, dtype=np.int64),
+                       np.ones(100, bool))
+        assert e._set_dv("a", np.array([5, 7]))
+    assert e._sort_stale and "inv_perm" not in e._dev
+    assert _inverse_builds() == before
+    assert _probe_bits(e, [a[5], a[6], a[7], 1000]) == [False, True, False, True]
+    assert _inverse_builds() == before and "inv_perm" not in e._dev
+    assert e.device_bytes == 22 * cap
+    assert hbm_ledger.totals()["keyCache"] == 22 * cap
+    sorts = telemetry.recent_events("delta.keyCache.sort")
+    assert [ev.data["inverse"] for ev in sorts] == [False, False]
+
+    telemetry.clear_events()
+    with e.device_batch():  # an advance that flips and appends nothing
+        assert e._set_dv("b", np.array([50]))
+    assert not e._sort_stale and _inverse_builds() == before + 1
+    assert e._set_dv("a", np.array([7]))  # revives row 5: the second flip
+    assert _inverse_builds() == before + 1
+    built = telemetry.recent_events("delta.keyCache.inverse")
+    assert [ev.data["flips"] for ev in built] == [1]
+    assert built[0].data["rows"] == 700
+    assert _probe_bits(e, [a[5], a[7], 1050, 1051]) == [True, False, False, True]
+    inv = np.asarray(e._dev["inv_perm"])
+    assert (inv == np.argsort(np.asarray(e._dev["perm"]))).all()
+    assert e.device_bytes == 26 * cap
+    assert hbm_ledger.totals()["keyCache"] == 26 * cap
+
+    telemetry.clear_events()
+    e._append_file("c", np.array([2000], np.int64), np.ones(1, bool))
+    assert e._sort_stale and "inv_perm" not in e._dev
+    assert e.device_bytes == 22 * cap
+    assert hbm_ledger.totals()["keyCache"] == 22 * cap
+    e._kill_file("b")  # stale view: a row-space flip, nothing built
+    assert _inverse_builds() == before + 1
+    assert _probe_bits(e, [2000, 1051, a[5]]) == [True, False, True]
+    sorts = telemetry.recent_events("delta.keyCache.sort")
+    assert [ev.data["inverse"] for ev in sorts] == [True]
+    e.drop_device()
+    assert hbm_ledger.totals()["keyCache"] == 0
+    hbm_ledger.reset()
+
+
 def test_set_dv_out_of_range_positions_signal_rebuild(tmp_table):
     """DV positions beyond the slab's recorded row count mean the slab and
     the file disagree; masking them would let deleted rows keep matching

@@ -6,7 +6,8 @@
 The arguments and the result line are `benchmark/run.py`'s. Besides, on
 standard error: the mean milliseconds a request of every span of the program
 (and how many of them a request opened), each MERGE's route, what the
-resident probe's spans and counters say of how widely it engaged, and the
+resident probe's spans and counters say of how widely it engaged, every
+re-sort of the slab and how many inverse permutations were built, and the
 device's time in the window by XLA module, each module with its longest
 operations and the arguments its operations name. A builder's instrument for
 PERF.md; nothing of the benchmark reads it.
@@ -41,14 +42,21 @@ def report(run) -> None:
     print("span means a request [ms, spans]:", json.dumps(means),
           file=sys.stderr)
     print("merge routes:", json.dumps(routes), file=sys.stderr)
-    probes = [s["data"] for r in done for s in r.spans
-              if s["name"] == "delta.merge.deviceProbe"]
+    def span_data(name):
+        return [s["data"] for r in done for s in r.spans if s["name"] == name]
+
+    probes = span_data("delta.merge.deviceProbe")
     if probes:
         from delta_tpu.utils import telemetry
 
+        # the counters are the process's: set-up's MERGEs count too
         print("device probes:", json.dumps(probes), "overflows:",
               telemetry.counters("merge.resident.probe").get(
-                  "merge.resident.probe.overflow", 0), file=sys.stderr)
+                  "merge.resident.probe.overflow", 0), "inverse builds:",
+              telemetry.counters("merge.keyCache").get(
+                  "merge.keyCache.inverseBuilds", 0), file=sys.stderr)
+        print("slab sorts:", json.dumps(span_data("delta.keyCache.sort")),
+              file=sys.stderr)
     if run.trace is not None:
         print("device ms a request by module:",
               json.dumps(module_split(run.trace, len(done))), file=sys.stderr)
