@@ -202,6 +202,7 @@ ENGINE_COUNTERS = frozenset({
     "scan.device.fallback",
     "scan.device.compiles",
     "scan.aggregate.device",
+    "scan.aggregate.grouped",
     "scan.aggregate.declined",
     "scan.prune.deviceFallback",
     "columnCache.hits",
@@ -469,8 +470,9 @@ DESCRIPTIONS = {
     "scan.device.declined": "Scans where the cost model kept the residual on host.",
     "scan.device.fallback": "Device residual attempts that fell back to the host path.",
     "scan.device.compiles": "XLA compiles that ran with a delta.scan span open on the compiling thread (a new literal or lane shape).",
-    "scan.aggregate.device": "Ungrouped aggregate SELECTs answered by the fused filter-and-sum kernel over resident lanes.",
-    "scan.aggregate.declined": "Ungrouped aggregate SELECTs the device route declined (the delta.scan.deviceAggregate span's route says why); the host scan answered.",
+    "scan.aggregate.device": "Aggregate SELECTs, ungrouped or grouped, answered by the fused filter-and-sum kernels over resident lanes.",
+    "scan.aggregate.grouped": "Of scan.aggregate.device, the GROUP BY queries: answered by the grouped kernel, the files' partials merged by value on the host (span delta.scan.deviceAggregate.groups).",
+    "scan.aggregate.declined": "Aggregate SELECTs, ungrouped or grouped, the device route declined (the delta.scan.deviceAggregate span's route says why: host:shape, type, predicate, budget, overflow, groups, off); the host scan answered.",
     "device.compiles": "XLA compiles in this process (persistent-cache fetches not counted).",
     "device.compileUs": "Microseconds spent in those XLA compiles.",
     "device.cacheFetches": "Executables fetched from the persistent compilation cache instead of compiled.",

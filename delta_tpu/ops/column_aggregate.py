@@ -1,31 +1,51 @@
-"""Ungrouped aggregates answered where the data lives: a fused
-filter-and-sum kernel over the scan column cache's resident lanes.
+"""Aggregates answered where the data lives: fused filter-and-sum kernels
+over the scan column cache's resident lanes, ungrouped or grouped by a few
+values.
 
 ``SELECT sum(a * b) FROM t WHERE lo <= c AND c < hi`` through the scan path
 downloads a byte a row of mask and decodes the survivors' columns from
 Parquet to add them up on the host. When every referenced column has a lane
 (`ops/column_cache`: integers, dates, ``decimal(p <= 18)`` as unscaled
-int64), the whole query is one pass over HBM and its answer a few bytes:
-:func:`device_aggregate` plans the files as a scan does, loads the lanes it
-misses, and runs :func:`_aggregate_kernel`'s program once a file, carrying
-the partial sums on the device; one fetch brings them back.
+int64, strings as dictionary codes), the whole query is one pass over HBM
+and its answer a few bytes: :func:`device_aggregate` plans the files as a
+scan does, loads the lanes it misses, and runs one program once a file,
+the partials staying on the device; one fetch brings them back.
+
+Ungrouped (:func:`_aggregate_kernel`, the XLA module
+``jit_filter_aggregate``): one carry of four slots a select item, summed
+across the files on the device.
+
+Grouped (:func:`_group_kernel`, ``jit_filter_group_aggregate``): ``GROUP BY``
+over columns that have lanes. A string lane's codes number a dictionary
+**per file**, so a code means nothing across files: the program works in
+each file's own numbering (the group of a row is a mixed-radix number of its
+key lanes' codes, the radices handed in as operands), writes that file's
+partials into its row of the carry, and the host merges the files' partials
+**by value** through each file's ``dict_codes`` read backwards, a NULL key a
+group of its own. The sums a group are one contraction on the matrix unit:
+a one-hot of the group against the aggregated values cut into 8-bit limbs,
+int8 by int8 into int32, exact (see :func:`_group_kernel`).
 
 Exact by construction: the conjunction of range predicates is compared in
 integers (literals scaled by `jaxeval.compile_residual`, the bounds handed
 to the program as **operands**, so one program a lane shape serves every
-literal), validity and deletion vectors are honoured, and ``sum`` /
-``count`` / ``min`` / ``max`` accumulate in int64 after a bound from the
-lanes' own extremes times the row counts has proved that nothing can
-overflow. The result is the Arrow table the host route
+literal), validity and deletion vectors are honoured, an aggregated
+expression is a product of up to three factors, each a lane or an exact
+literal plus or minus a lane in the lane's own units (``1 - l_discount``
+over hundredths is ``100 - units``), and ``sum`` / ``count`` / ``min`` /
+``max`` accumulate in integers after a bound from the lanes' own extremes
+times the row counts has proved that nothing can overflow. No float is
+anywhere on the way. The result is the Arrow table the host route
 (`sql/parser._run_aggregate`) returns, type included: each type is read off
 the host's own kernels.
 
 The route is taken from what can be observed, by no conf of its own
 (``delta.tpu.read.deviceResidual.mode=off`` turns the column cache, and so
 this, off): the shape of the select list and of the predicate, the columns'
-types, the lanes' bytes against the cache's budget, the overflow bound. A
-decline says why in the span's ``route`` (``host:<reason>``) and the host
-route runs.
+types, the lanes' bytes against the cache's budget, the overflow bound, the
+groups a file can hold. A decline says why in the span's ``route``
+(``host:<reason>``: ``shape``, ``type``, ``predicate``, ``budget``,
+``overflow``, ``groups``, ``off``) and the host route runs.
 """
 from __future__ import annotations
 
@@ -44,7 +64,7 @@ from delta_tpu.utils.config import conf
 from delta_tpu.utils.jaxcache import ensure_compilation_cache
 from delta_tpu.utils.jaxcompat import enable_x64
 
-__all__ = ["device_aggregate", "AggregateSpec"]
+__all__ = ["device_aggregate", "AggregateSpec", "Factor", "GROUP_SLOTS"]
 
 _I64_MAX = np.iinfo(np.int64).max
 _I64_MIN = np.iinfo(np.int64).min
@@ -53,11 +73,49 @@ _SLOTS = 4
 #: a float64 holds every integer below this, so a mean of integers computed
 #: from an exact sum is the host's, whatever order it adds in
 _EXACT_F64 = 2 ** 53
+#: Groups one launch carries: the product over the key lanes of the values
+#: the file holds (one more each where a NULL can be) may not pass it
+#: (``host:groups``). 32 is the height of one int8 tile on the chip (32
+#: sublanes x 128 lanes): the one-hot of the group is one tile high for any
+#: count up to it (8 or 16 slots: 1.71 or 1.75 ms a launch of TPC-H Q1,
+#: PERF.md PR 32), and a group's number fits the int8 the one-hot compares
+#: in. TPC-H Q1 needs 3 x 2.
+GROUP_SLOTS = 32
+#: rows one int8 contraction may add into int32: 2^22 x 128 < 2^31
+_DOT_ROWS = 1 << 22
+
+
+class Factor(NamedTuple):
+    """One factor of an aggregated product, in the lane's own units:
+    ``offset + sign * lane``. A bare lane is ``(column, 1, 0)``;
+    ``1 - l_discount`` over hundredths is ``(l_discount, -1, 100)``."""
+
+    column: str
+    sign: int = 1
+    offset: int = 0
 
 
 class AggregateSpec(NamedTuple):
-    func: str               # count | sum | avg | min | max
-    cols: Tuple[str, ...]   # () is COUNT(*); (a,) a column; (a, b) a * b
+    func: str                      # count | sum | avg | min | max
+    factors: Tuple[Factor, ...]    # () is COUNT(*)
+
+    @property
+    def cols(self) -> Tuple[str, ...]:
+        return tuple(f.column for f in self.factors)
+
+
+class _Term(NamedTuple):
+    """One product the grouped program forms, shared by the select items
+    over it: which of sum / min / max are wanted (its count always is), the
+    index of the first factor from which the running product needs int64
+    (before it, int32: the native width), and the bytes its sum is cut into.
+    The two widths come from the lanes' extremes, never from a literal of
+    the predicate."""
+
+    factors: Tuple[Factor, ...]
+    want: Tuple[str, ...]
+    wide_at: int
+    nbytes: int
 
 
 class _Decline(Exception):
@@ -69,19 +127,50 @@ class _FileLanes(NamedTuple):
     so an eviction between the load and the launch frees nothing in use."""
 
     add: Any
-    env: Dict[str, Tuple[Any, Any]]   # column -> (values, valid)
-    rows: int                         # physical rows; the lanes are padded
-    extremes: Dict[str, int]          # column -> largest magnitude it holds
+    env: Dict[str, Tuple[Any, Any]]       # column -> (values, valid)
+    rows: int                             # physical rows; the lanes are padded
+    ranges: Dict[str, Tuple[int, int]]    # column -> (least, largest) it holds
+    codes: Dict[str, Optional[Dict[str, int]]]  # string column -> value -> code
 
 
-# -- the kernel ----------------------------------------------------------------
+# -- the kernels -----------------------------------------------------------------
+
+
+def _live_rows(jnp, lanes, preds, bounds, n, keep):
+    """Rows of the file that exist, are not deleted and pass every range."""
+    cap = next(iter(lanes.values()))[0].shape[0]
+    live = jnp.arange(cap, dtype=jnp.int32) < n
+    if keep is not None:
+        live = live & keep
+    for i, c in enumerate(preds):
+        v, ok = lanes[c]
+        v = v.astype(jnp.int64)
+        live = live & ok & (v >= bounds[i, 0]) & (v <= bounds[i, 1])
+    return live
+
+
+def _product(jnp, lanes, factors, live, wide_at=0):
+    """``(mask, value)`` of a product of factors over the live rows: the mask
+    drops a row in which a factor is NULL. Factors before ``wide_at``
+    multiply in int32, the rest in int64."""
+    m, x = live, None
+    for j, f in enumerate(factors):
+        v, ok = lanes[f.column]
+        m = m & ok
+        dtype = jnp.int32 if j < wide_at else jnp.int64
+        v = v.astype(dtype)
+        if f.sign != 1 or f.offset:
+            v = dtype(f.offset) + (v if f.sign == 1 else -v)
+        x = v if x is None else x.astype(dtype) * v
+    return m, x
 
 
 @functools.lru_cache(maxsize=64)
 def _aggregate_kernel(preds: Tuple[str, ...], specs: Tuple[AggregateSpec, ...]):
     """One program for a select list and the columns its predicate ranges
     over; XLA keys it further on the lane shape and on whether a deletion
-    vector's ``keep`` comes with the file. Never on a literal."""
+    vector's ``keep`` comes with the file. Never on a literal of the
+    predicate."""
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
@@ -89,21 +178,10 @@ def _aggregate_kernel(preds: Tuple[str, ...], specs: Tuple[AggregateSpec, ...]):
     def filter_aggregate(lanes, bounds, n, keep, carry):
         # lanes: {column: (values, valid)} padded to one pow2 length;
         # bounds: int64[len(preds), 2], inclusive; n: the file's rows
-        cap = next(iter(lanes.values()))[0].shape[0]
-        live = jnp.arange(cap, dtype=jnp.int32) < n
-        if keep is not None:
-            live = live & keep
-        for i, c in enumerate(preds):
-            v, ok = lanes[c]
-            v = v.astype(jnp.int64)
-            live = live & ok & (v >= bounds[i, 0]) & (v <= bounds[i, 1])
+        live = _live_rows(jnp, lanes, preds, bounds, n, keep)
         out = []
         for k, spec in enumerate(specs):
-            m, x = live, None
-            for c in spec.cols:
-                v, ok = lanes[c]
-                m = m & ok
-                x = v.astype(jnp.int64) if x is None else x * v.astype(jnp.int64)
+            m, x = _product(jnp, lanes, spec.factors, live)
             base = _SLOTS * k
             # a file holds fewer than 2^31 rows: count in the native width
             count = jnp.sum(m.astype(jnp.int32)).astype(jnp.int64)
@@ -121,12 +199,130 @@ def _aggregate_kernel(preds: Tuple[str, ...], specs: Tuple[AggregateSpec, ...]):
     return jax.jit(filter_aggregate)
 
 
+def _term_columns(terms: Sequence[_Term]) -> List[Dict[str, int]]:
+    """Where each term's count and wanted aggregates sit in a group's row of
+    the partials."""
+    out, at = [], 1  # column 0: the group's live rows
+    for t in terms:
+        names = ("count",) + t.want
+        out.append({name: at + i for i, name in enumerate(names)})
+        at += len(names)
+    return out
+
+
+@functools.lru_cache(maxsize=64)
+def _group_kernel(preds: Tuple[str, ...], terms: Tuple[_Term, ...],
+                  keys: Tuple[str, ...], slots: int):
+    """The grouped program for a select list's terms, the columns its
+    predicate ranges over and its key columns; XLA keys it further on the
+    lane shapes, on the carry's (files, slots, columns) and on whether a
+    deletion vector comes with the file. Never on a literal of the
+    predicate, never on a dictionary's content: the keys' radices are the
+    operand ``layout``.
+
+    The group of a row is ``sum((key_i - lo_i) * stride_i)`` with a NULL key
+    counted as ``size_i``, below ``slots`` by the caller's check. The count
+    and the sum of a term come, for every group at once, from one
+    contraction over the rows: the one-hot of the group (int8, ``slots`` x
+    rows, never stored: XLA forms it inside the contraction) against a
+    matrix of int8 rows, one the count's mask and one a byte of the summed
+    value, accumulated in int32 on the matrix unit. A value's low bytes are
+    unsigned: each is sent as ``byte - 128`` and the group's row count times
+    128 is added back; its top byte is signed as it stands. At most ``_DOT_ROWS`` rows go into one
+    int32 (2^22 x 128 < 2^31); the blocks add in int64. Nothing rounds."""
+    ensure_compilation_cache()
+    import jax
+    import jax.numpy as jnp
+
+    def filter_group_aggregate(lanes, bounds, n, keep, layout, at, carry):
+        # layout: int64[len(keys), 3]: least value, values, stride of a key
+        live = _live_rows(jnp, lanes, preds, bounds, n, keep)
+        cap = live.shape[0]
+        gid = jnp.zeros(cap, jnp.int32)
+        for i, c in enumerate(keys):
+            v, ok = lanes[c]
+            part = jnp.where(ok, (v - layout[i, 0].astype(v.dtype)
+                                  ).astype(jnp.int32),
+                             layout[i, 1].astype(jnp.int32))
+            gid = gid + part * layout[i, 2].astype(jnp.int32)
+        # in int8: a live row's group is below 32, a dead row's is -1 (a
+        # launch is 1.75 ms so, 2.16 with the compare in int32: call 3)
+        small = jnp.where(live, gid, -1).astype(jnp.int8)
+        hot = (small[None, :] == jnp.arange(slots, dtype=jnp.int8)[:, None]
+               ).astype(jnp.int8)
+        block = min(cap, _DOT_ROWS)
+        hot = hot.reshape(slots, cap // block, block)
+
+        def per_group(mat):
+            """int8[rows, cap] against the one-hot: int64[slots, rows]."""
+            acc = jnp.einsum("gbk,rbk->bgr", hot,
+                             mat.reshape(mat.shape[0], cap // block, block),
+                             preferred_element_type=jnp.int32)
+            return jnp.sum(acc.astype(jnp.int64), axis=0)
+
+        in_group = per_group(jnp.ones((1, cap), jnp.int8))[:, 0]
+        out = [in_group]
+        for t in terms:
+            if not t.factors:  # COUNT(*)
+                out.append(in_group)
+                continue
+            m, x = _product(jnp, lanes, t.factors, live, t.wide_at)
+            # row 0 the count's mask, then a row a byte of the value, all of
+            # one expression, (source >> shift & mask) - bias, so that XLA
+            # writes the term's rows in one pass
+            nb = t.nbytes if "sum" in t.want else 0
+            shift = np.array([0] + [8 * b for b in range(nb)])
+            mask = np.array([1] + [255] * (nb - 1) + [-1] * bool(nb))
+            bias = np.array([0] + [128] * (nb - 1) + [0] * bool(nb))
+            source = jnp.where(jnp.asarray(np.arange(nb + 1) == 0)[:, None],
+                               m.astype(x.dtype)[None, :],
+                               jnp.where(m, x, 0)[None, :])
+            mat = ((source >> jnp.asarray(shift, x.dtype)[:, None])
+                   & jnp.asarray(mask, x.dtype)[:, None]) \
+                - jnp.asarray(bias, x.dtype)[:, None]
+            acc = per_group(mat.astype(jnp.int8))
+            out.append(acc[:, 0])
+            for name in t.want:
+                if name == "sum":
+                    total = acc[:, nb] << (8 * (nb - 1))  # the top byte, signed
+                    for b in range(nb - 1):
+                        total = total + ((acc[:, 1 + b] + 128 * in_group)
+                                         << (8 * b))
+                    out.append(total)
+                    continue
+                # min / max have no contraction: one masked pass a slot
+                fill, pick = (_I64_MAX, jnp.min) if name == "min" \
+                    else (_I64_MIN, jnp.max)
+                x64 = x.astype(jnp.int64)
+                out.append(jnp.stack([
+                    pick(jnp.where(m & (gid == g), x64, fill))
+                    for g in range(slots)]))
+        part = jnp.stack(out, axis=1)                      # [slots, columns]
+        zero = jnp.zeros((), at.dtype)
+        return jax.lax.dynamic_update_slice(carry, part[None], (at, zero, zero))
+
+    return jax.jit(filter_group_aggregate)
+
+
 @functools.lru_cache(maxsize=1024)
 def _rows_on_device(n: int):
     """A file's row count as a device scalar, kept: handed to the program
     as a host number it is one blocking upload a launch (0.29 ms of a
     0.53 ms launch on a v5e's host, 15 launches a query; PERF.md, PR 27)."""
     return link.to_device(np.int32(n))
+
+
+@functools.lru_cache(maxsize=1024)
+def _layout_on_device(layout: Tuple[Tuple[int, int, int], ...]):
+    """A file's key radices as a device array, kept for the same reason."""
+    return link.to_device(np.array(layout, np.int64).reshape(-1, 3))
+
+
+@functools.lru_cache(maxsize=64)
+def _zeros_on_device(shape: Tuple[int, ...]):
+    """The grouped carry before its first launch, kept: device arrays do
+    not change, so every query starts from the same one."""
+    return link.to_device(np.zeros(shape, np.int64))
 
 
 def _empty_carry(specs: Sequence[AggregateSpec]) -> np.ndarray:
@@ -136,53 +332,103 @@ def _empty_carry(specs: Sequence[AggregateSpec]) -> np.ndarray:
 # -- what the select list and the predicate have to look like -------------------
 
 
-def _specs(parsed_items) -> Tuple[AggregateSpec, ...]:
-    out = []
+def _strip(e):
+    while isinstance(e, ir.Alias):
+        e = e.child
+    return e
+
+
+def _flatten(e) -> List[Any]:
+    e = _strip(e)
+    if isinstance(e, ir.Mul):
+        return _flatten(e.left) + _flatten(e.right)
+    return [e]
+
+
+def _is_text(t) -> bool:
+    import pyarrow as pa
+
+    return pa.types.is_string(t) or pa.types.is_large_string(t)
+
+
+def _lane_type(column: str, fields, parts):
+    """Arrow type of a column as the scan would decode it; declines on a
+    partition column (no file stores it) or an unknown one."""
+    from delta_tpu.expr.vectorized import arrow_type_for
+
+    if column not in fields or column in parts:
+        raise _Decline("type")
+    return arrow_type_for(fields[column])
+
+
+def _factor(e, types) -> Factor:
+    """A lane, or an exact literal plus or minus a lane, in the lane's
+    units."""
+    import pyarrow as pa
+
+    if isinstance(e, ir.Column):
+        return Factor(e.name.lower())
+    if type(e) not in (ir.Add, ir.Sub):
+        raise _Decline("shape")
+    left, right = _strip(e.left), _strip(e.right)
+    if isinstance(left, ir.Literal) and isinstance(right, ir.Column):
+        lit, col, sign = left, right, (1 if type(e) is ir.Add else -1)
+    elif isinstance(left, ir.Column) and isinstance(right, ir.Literal):
+        lit, col, sign = right, left, 1
+    else:
+        raise _Decline("shape")
+    t = types[col.name.lower()]
+    scaled = jaxeval.decimal_literal_units(
+        lit, t.scale if pa.types.is_decimal(t) else 0)
+    if scaled is None:
+        raise _Decline("shape")  # not an exact number
+    units, whole = scaled
+    if not whole:
+        raise _Decline("type")  # finer than the lane's scale: the host's to type
+    if lit is right and type(e) is ir.Sub:
+        units = -units
+    if abs(units) > _I64_MAX:
+        raise _Decline("overflow")
+    return Factor(col.name.lower(), sign, units)
+
+
+def _specs(parsed_items, keys, fields, parts):
+    """``(specs, types, inners, layout)`` of a select list: every item an
+    aggregate of a product of up to three factors, or one of the group
+    ``keys``. ``types`` maps each aggregated column to its Arrow type,
+    ``inners`` holds each spec's expression as parsed (the host types it),
+    ``layout`` says for each item which key or spec it shows. Declines
+    ``shape`` or ``type``."""
+    import pyarrow as pa
+
+    specs, inners, layout, types = [], [], [], {}
     for kind, payload, _alias in parsed_items:
+        if kind == "col" and payload.lower() in keys:
+            layout.append(("key", payload.lower()))
+            continue
         if kind != "agg":
             raise _Decline("shape")
         func, inner = payload
-        while isinstance(inner, ir.Alias):
-            inner = inner.child
-        if inner is None:
-            cols: Tuple[str, ...] = ()
-        elif isinstance(inner, ir.Column):
-            cols = (inner.name.lower(),)
-        elif isinstance(inner, ir.Mul) and isinstance(inner.left, ir.Column) \
-                and isinstance(inner.right, ir.Column):
-            cols = (inner.left.name.lower(), inner.right.name.lower())
-        else:
+        nodes = [] if inner is None else _flatten(inner)
+        if len(nodes) > 3:
             raise _Decline("shape")
-        out.append(AggregateSpec(func, cols))
-    return tuple(out)
-
-
-def _arrow_types(specs, fields, partition_columns) -> Dict[str, Any]:
-    """Arrow type of every aggregated column, as the scan would decode it;
-    declines on a column without an integer lane or a partition column
-    (no file stores it). ``fields``: `synthesis.schema_types`."""
-    import pyarrow as pa
-
-    from delta_tpu.expr.vectorized import arrow_type_for
-
-    parts = {c.lower() for c in partition_columns}
-    out = {}
-    for spec in specs:
-        for c in spec.cols:
-            if c not in fields or c in parts:
-                raise _Decline("type")
-            t = arrow_type_for(fields[c])
+        for node in nodes:
+            for name in ir.references(node):
+                types[name.lower()] = _lane_type(name.lower(), fields, parts)
+        factors = tuple(_factor(node, types) for node in nodes)
+        for f in factors:
+            t = types[f.column]
             summable = pa.types.is_integer(t) or (
                 pa.types.is_decimal128(t)
                 and t.precision <= jaxeval.DECIMAL_LANE_PRECISION)
-            if not (summable or (pa.types.is_date32(t) and len(spec.cols) == 1
-                                 and spec.func in ("count", "min", "max"))):
+            if not (summable or (pa.types.is_date32(t) and factors == (f,)
+                                 and f == Factor(f.column)
+                                 and func in ("count", "min", "max"))):
                 raise _Decline("type")
-            out[c] = t
-        if len(spec.cols) == 2 and pa.types.is_integer(out[spec.cols[0]]) \
-                != pa.types.is_integer(out[spec.cols[1]]):
-            raise _Decline("type")  # integer times decimal: the host's to type
-    return out
+        layout.append(("agg", len(specs)))
+        specs.append(AggregateSpec(func, factors))
+        inners.append(inner)
+    return tuple(specs), types, inners, layout
 
 
 def _ranges(predicate: Optional[ir.Expression], fields, partition_columns):
@@ -217,80 +463,267 @@ def _ranges(predicate: Optional[ir.Expression], fields, partition_columns):
     return cols, np.array(bounds, np.int64).reshape(-1, 2)
 
 
-# -- the bounds that make int64 exact --------------------------------------------
+# -- the types the host would give ---------------------------------------------------
 
 
-def _magnitude(extremes: Dict[str, int], cols: Sequence[str]) -> int:
-    out = 1
-    for c in cols:
-        out *= extremes[c]
+_ARITH = {ir.Add: "add", ir.Sub: "subtract", ir.Mul: "multiply"}
+
+
+def _over_no_rows(e, types, outermost: bool = False):
+    """``e`` over no rows, through the kernels `expr/vectorized` calls: the
+    type of the result is the host's. Where Arrow refuses an operation (a
+    product of decimals past 38 digits) the host evaluates that node row by
+    row in ``decimal.Decimal`` and Arrow infers ``decimal128(p, s)`` with
+    ``p`` from the values: None for the ``outermost`` node, a decline for
+    one inside, whose parent's type would follow the data."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    from delta_tpu.expr.vectorized import _numeric_coerce
+
+    e = _strip(e)
+    if isinstance(e, ir.Column):
+        return pa.array([], types[e.name.lower()])
+    if isinstance(e, ir.Literal):
+        return pa.scalar(e.value)
+    sides = _numeric_coerce(_over_no_rows(e.left, types),
+                            _over_no_rows(e.right, types))
+    try:
+        out = getattr(pc, _ARITH[type(e)])(*sides)
+    except (pa.ArrowInvalid, pa.ArrowNotImplementedError, pa.ArrowTypeError):
+        if outermost:
+            return None
+        raise _Decline("type") from None
+    if not outermost and pa.types.is_integer(out.type) \
+            and out.type.bit_width < 64:
+        raise _Decline("type")  # an inner product the host may wrap
     return out
 
 
 @functools.lru_cache(maxsize=256)
-def _host_types(func: str, column_types: Tuple[Any, ...]):
+def _host_types(func: str, inner, column_types: Tuple[Tuple[str, Any], ...]):
     """``(type of the aggregated expression, type of the aggregate)`` as the
-    host route types them, read off its own kernels over empty arrays."""
+    host route types them, read off its own kernels over empty arrays;
+    ``(None, None)`` where the host types the expression by rows
+    (``a * (1 - b) * (1 + c)`` over ``decimal(15,2)``: see
+    :func:`_over_no_rows`), and :func:`_typed` answers only what does not
+    depend on the values."""
     import pyarrow as pa
     import pyarrow.compute as pc
 
-    expr = pa.array([], column_types[0])
-    for t in column_types[1:]:
-        expr = pc.multiply(expr, pa.array([], t))
+    expr = _over_no_rows(inner, dict(column_types), outermost=True)
+    if expr is None:
+        return None, None
+    if not (pa.types.is_integer(expr.type) or pa.types.is_decimal(expr.type)
+            or pa.types.is_date(expr.type)):
+        raise _Decline("type")  # a literal written 1.0 makes the host's a float
     kern = {"sum": pc.sum, "avg": pc.mean, "min": pc.min, "max": pc.max,
             "count": pc.count}
     return expr.type, kern[func](expr).type
 
 
-def _check_overflow(specs, types, per_file) -> None:
-    """Decline unless every sum stays inside int64 whatever rows survive,
-    and every product inside the type the host multiplies in (the host's
-    int32 product wraps where int64 does not: the routes have to agree)."""
+def _typed(specs, types, inners):
+    """``[(expression type or None, aggregate type)]`` a spec. An expression
+    the host types by rows (see :func:`_host_types`) has no type here: its
+    ``count`` is int64 and its ``sum`` ``decimal128(38, s)``, whatever the
+    values; a mean or an extreme of it would be typed by the data, and is
+    declined. Declines too when the host's scale is not the integers' (it
+    never should be)."""
     import pyarrow as pa
 
-    for spec in specs:
-        if not spec.cols or not per_file:
+    out = []
+    for spec, inner in zip(specs, inners):
+        if not spec.factors:
+            out.append((None, pa.int64()))
             continue
-        expr_type, _out = _host_types(spec.func,
-                                      tuple(types[c] for c in spec.cols))
-        width = expr_type.bit_width if pa.types.is_integer(expr_type) else 64
-        if max(_magnitude(f.extremes, spec.cols)
-               for f in per_file) >= 2 ** (width - 1):
+        expr_type, out_type = _host_types(
+            spec.func, inner, tuple(sorted({c: types[c] for c in spec.cols}.items())))
+        scale = sum(types[c].scale for c in spec.cols
+                    if pa.types.is_decimal(types[c]))
+        if out_type is None:
+            if spec.func not in ("count", "sum") or not scale:
+                raise _Decline("type")
+            out_type = pa.int64() if spec.func == "count" \
+                else pa.decimal128(38, scale)
+        shown = out_type if expr_type is None else expr_type
+        if spec.func != "count" and scale != (
+                shown.scale if pa.types.is_decimal(shown) else 0):
+            raise _Decline("type")
+        out.append((expr_type, out_type))
+    return out
+
+
+# -- the bounds that make the integers exact ------------------------------------------
+
+
+def _magnitude(ranges: Dict[str, Tuple[int, int]],
+               factors: Sequence[Factor]) -> List[int]:
+    """The running product's largest magnitude after each factor."""
+    out, m = [], 1
+    for f in factors:
+        lo, hi = ranges[f.column]
+        m *= max(abs(f.offset + f.sign * lo), abs(f.offset + f.sign * hi),
+                 abs(f.offset))
+        out.append(m)
+    return out
+
+
+def _check_overflow(specs, typed, per_file, grouped: bool) -> None:
+    """Decline unless every sum stays inside what carries it, whatever rows
+    survive, and every product inside the type the host multiplies in (the
+    host's int32 product wraps where int64 does not: the routes have to
+    agree). The device carries int64: ungrouped, the sum over all files;
+    grouped, one file's partial, the files then added in Python integers
+    and held to the result's own type."""
+    import pyarrow as pa
+
+    for spec, (expr_type, out_type) in zip(specs, typed):
+        if not spec.factors or not per_file:
+            continue
+        width = expr_type.bit_width if expr_type is not None \
+            and pa.types.is_integer(expr_type) else 64
+        sizes = [_magnitude(f.ranges, spec.factors)[-1] for f in per_file]
+        if max(sizes) >= 2 ** (width - 1):
             raise _Decline("overflow")
         if spec.func in ("sum", "avg"):
-            total = sum(_magnitude(f.extremes, spec.cols) * f.rows
-                        for f in per_file)
-            limit = _EXACT_F64 if spec.func == "avg" \
-                and pa.types.is_integer(expr_type) else _I64_MAX
-            if total > limit:
+            partial = [m * f.rows for m, f in zip(sizes, per_file)]
+            limit = _EXACT_F64 if pa.types.is_floating(out_type) else \
+                10 ** 38 - 1 if grouped and pa.types.is_decimal(out_type) \
+                else _I64_MAX
+            if sum(partial) > limit or max(partial) > _I64_MAX:
                 raise _Decline("overflow")
 
 
 # -- the answer, typed as the host types it ---------------------------------------
 
 
-def _scalar(spec: AggregateSpec, slots, types):
-    """One select item as a one-row Arrow array of the host route's type."""
+def _column(func: str, types_of, rows):
+    """One select item as an Arrow array of the host route's type, a value
+    a group; ``rows`` holds each group's ``(sum, count, min, max)``."""
     import pyarrow as pa
 
-    total, count, low, high = (int(x) for x in slots)
-    if spec.func == "count":
-        return pa.array([count], pa.int64())
-    _expr, out_type = _host_types(spec.func, tuple(types[c] for c in spec.cols))
-    if count == 0:
-        return pa.array([None], out_type)
-    if spec.func == "avg" and pa.types.is_floating(out_type):
-        return pa.array([total / count], out_type)
-    if spec.func == "avg":  # a decimal mean rounds half away from zero
-        q, r = divmod(abs(total), count)
-        units = (q + (2 * r >= count)) * (1 if total >= 0 else -1)
-    else:
-        units = {"sum": total, "min": low, "max": high}[spec.func]
+    expr_type, out_type = types_of
+    if func == "count":
+        return pa.array([int(r[1]) for r in rows], pa.int64())
+    if expr_type is None and not any(r[1] for r in rows):
+        # typed by rows on the host (`_host_types`): with no value at all
+        # Arrow infers the null type there, whose sum is an int64
+        return pa.array([None] * len(rows), pa.int64())
+    units: List[Optional[Any]] = []
+    for total, count, low, high in rows:
+        total, count = int(total), int(count)
+        if count == 0:
+            units.append(None)
+        elif func == "avg" and pa.types.is_floating(out_type):
+            units.append(total / count)
+        elif func == "avg":  # a decimal mean rounds half away from zero
+            q, r = divmod(abs(total), count)
+            units.append((q + (2 * r >= count)) * (1 if total >= 0 else -1))
+        else:
+            units.append(int({"sum": total, "min": low, "max": high}[func]))
+    if pa.types.is_floating(out_type):
+        return pa.array(units, out_type)
     if pa.types.is_decimal(out_type):
-        return pa.array([Decimal(units).scaleb(-out_type.scale)], out_type)
+        return pa.array([None if u is None else
+                         Decimal(u).scaleb(-out_type.scale) for u in units],
+                        out_type)
     if pa.types.is_date(out_type):
-        return pa.array([units], pa.int32()).cast(out_type)
-    return pa.array([units], pa.int64()).cast(out_type)
+        return pa.array(units, pa.int32()).cast(out_type)
+    return pa.array(units, pa.int64()).cast(out_type)
+
+
+def _key_column(t, values):
+    import pyarrow as pa
+
+    if pa.types.is_date(t):
+        return pa.array(values, pa.int32()).cast(t)
+    if pa.types.is_integer(t):
+        return pa.array(values, pa.int64()).cast(t)
+    return pa.array(values, t)
+
+
+# -- groups ------------------------------------------------------------------------
+
+
+def _terms(specs, per_file) -> Tuple[Tuple[_Term, ...], List[int]]:
+    """The distinct products of the select list, each with what is wanted of
+    it and the widths its values need over these files; and each spec's
+    term."""
+    wants: Dict[Tuple[Factor, ...], List[str]] = {}
+    for spec in specs:
+        have = wants.setdefault(spec.factors, [])
+        name = "sum" if spec.func == "avg" else spec.func
+        if name != "count" and name not in have:
+            have.append(name)
+    terms = []
+    for factors, want in wants.items():
+        sizes = [max(ms) for ms in zip(*(
+            _magnitude(f.ranges, factors) for f in per_file))] \
+            if per_file and factors else []
+        wide_at = next((j for j, m in enumerate(sizes) if m >= 2 ** 31),
+                       len(sizes))
+        # the top byte is signed: a value below 2^(8 nbytes - 1) in size
+        nbytes = max(-(-(max(sizes, default=0).bit_length() + 1) // 8), 1)
+        terms.append(_Term(factors, tuple(sorted(want)), wide_at, nbytes))
+    order = list(wants)
+    return tuple(terms), [order.index(spec.factors) for spec in specs]
+
+
+def _key_layout(f: _FileLanes, keys):
+    """``(least value, values, stride)`` of each key lane in this file, and
+    the slots the file's groups take: a lane of dictionary codes holds its
+    dictionary's values, an integer or date lane (or a string column the
+    file predates: all NULL) the range it spans, which bounds the distinct
+    ones. A NULL key is the digit ``values``: one more slot a key, but for
+    a dictionary whose codes show that the lane holds none (a NULL's code
+    is -1, the lane's least)."""
+    layout, stride = [], 1
+    for c in keys:
+        lo, hi = f.ranges[c]
+        if f.codes[c] is not None:
+            size = len(f.codes[c])
+            lo, null = 0, not size or lo < 0
+        else:
+            size, null = hi - lo + 1, True
+        layout.append((lo, size, stride))
+        stride *= size + null
+    return tuple(layout), stride
+
+
+def _merge_groups(partials, per_file, layouts, keys, terms, spec_term, specs):
+    """The files' partials merged **by value**: each file's slot is read back
+    to its key values through the file's own dictionary, a NULL key a value
+    of its own, and equal keys add in Python integers. Returns the keys in
+    order and, a spec, each group's ``(sum, count, min, max)``."""
+    columns = _term_columns(terms)
+    merged: Dict[Tuple, List[List[int]]] = {}
+    for part, f, layout in zip(partials, per_file, layouts):
+        names = {c: None if f.codes[c] is None else
+                 {code: value for value, code in f.codes[c].items()}
+                 for c in keys}
+        radix = [nxt[2] // this[2] for this, nxt in zip(layout, layout[1:])] \
+            + [part.shape[0]]
+        for g in np.flatnonzero(part[:, 0]):
+            key = []
+            for c, (lo, size, stride), base in zip(keys, layout, radix):
+                digit = (int(g) // stride) % base
+                key.append(None if digit == size else
+                           lo + digit if names[c] is None else names[c][digit])
+            row = part[g]
+            into = merged.setdefault(tuple(key), [
+                [0, 0, _I64_MAX, _I64_MIN] for _ in specs])
+            for k, spec in enumerate(specs):
+                at = columns[spec_term[k]]
+                acc = into[k]
+                acc[1] += int(row[at["count"]])
+                if "sum" in at:
+                    acc[0] += int(row[at["sum"]])
+                if "min" in at:
+                    acc[2] = min(acc[2], int(row[at["min"]]))
+                if "max" in at:
+                    acc[3] = max(acc[3], int(row[at["max"]]))
+    order = sorted(merged, key=lambda key: tuple((v is None, v) for v in key))
+    return order, [[merged[key][k] for key in order] for k in range(len(specs))]
 
 
 # -- the route -------------------------------------------------------------------
@@ -305,9 +738,10 @@ def _lane_bytes(files, columns, fields) -> int:
 
     from delta_tpu.expr.vectorized import arrow_type_for
 
-    # a date lane is int32, every other int64; a byte of validity each
-    width = sum(5 if pa.types.is_date(arrow_type_for(fields[c])) else 9
-                for c in columns)
+    # a date lane and a string lane's codes are int32, every other int64; a
+    # byte of validity each
+    types = [arrow_type_for(fields[c]) for c in columns]
+    width = sum(5 if pa.types.is_date(t) or _is_text(t) else 9 for t in types)
     total = 0
     for f in files:
         rows = f.num_logical_records
@@ -319,27 +753,35 @@ def _lane_bytes(files, columns, fields) -> int:
     return total
 
 
-def _keep_mask(add, data_path: str, cap: int):
-    """The file's deletion vector as a row mask on the device."""
+def _keep_mask(f: _FileLanes, data_path: str):
+    """The file's deletion vector as a row mask on the device; None for a
+    file that has none."""
     from delta_tpu.protocol.deletion_vectors import (DeletionVectorDescriptor,
                                                      read_deletion_vector)
 
-    keep = np.ones(cap, bool)
-    keep[read_deletion_vector(
-        DeletionVectorDescriptor.from_dict(add.deletion_vector), data_path)] = False
+    if f.add.deletion_vector is None:
+        return None
+    keep = np.ones(next(iter(f.env.values()))[0].shape[0], bool)
+    keep[read_deletion_vector(DeletionVectorDescriptor.from_dict(
+        f.add.deletion_vector), data_path)] = False
     return link.to_device(keep)
 
 
-def device_aggregate(snapshot, filters: Sequence[ir.Expression], parsed_items):
+def device_aggregate(snapshot, filters: Sequence[ir.Expression], parsed_items,
+                     group_by: Sequence[str] = (), order_keys: Sequence[str] = ()):
     """The select list ``parsed_items`` (`sql/parser._select`) over the rows
-    of ``snapshot`` that ``filters`` hold for, as a one-row Arrow table
-    computed on the device; or None, with the reason on the span, when the
-    host route has to answer."""
+    of ``snapshot`` that ``filters`` hold for, computed on the device: one
+    row, or with ``group_by`` one row a group, in the order of the keys'
+    values (a NULL last). A group key that the select list leaves out and
+    ``order_keys`` names comes as one more column after the select list's,
+    for the caller to sort by and drop. None, with the reason on the span,
+    when the host route has to answer."""
     import pyarrow as pa
 
     with telemetry.record_operation("delta.scan.deviceAggregate") as ev:
         try:
-            columns = _device_aggregate(snapshot, filters, parsed_items, ev)
+            names, columns = _device_aggregate(snapshot, filters, parsed_items,
+                                               group_by, order_keys, ev)
         except _Decline as d:
             ev.data["route"] = f"host:{d.args[0]}"
         except Exception as e:  # noqa: BLE001
@@ -353,13 +795,81 @@ def device_aggregate(snapshot, filters: Sequence[ir.Expression], parsed_items):
         else:
             ev.data["route"] = "device"
             telemetry.bump_counter("scan.aggregate.device")
-            return pa.Table.from_arrays(
-                columns, names=[alias for _kind, _payload, alias in parsed_items])
+            if group_by:
+                telemetry.bump_counter("scan.aggregate.grouped")
+            return pa.Table.from_arrays(columns, names=names)
         telemetry.bump_counter("scan.aggregate.declined")
         return None
 
 
-def _device_aggregate(snapshot, filters, parsed_items, ev):
+def _group_keys(snapshot, group_by, fields, parts) -> Tuple[Dict[str, str], Dict]:
+    """``({key: its name in the schema}, {key: Arrow type})`` in the order
+    asked; a key has to be a stored column of a type whose lane numbers
+    values: a string, an integer, a date."""
+    import pyarrow as pa
+
+    names: Dict[str, str] = {}
+    types: Dict[str, Any] = {}
+    if not group_by:
+        return names, types
+    real = {f.name.lower(): f.name for f in snapshot.metadata.schema.fields}
+    for g in group_by:
+        c = g.strip("`").lower()
+        if c not in real or c in names:
+            raise _Decline("shape")  # unknown or twice: the host's to say
+        t = _lane_type(c, fields, parts)
+        if not (_is_text(t) or pa.types.is_integer(t) or pa.types.is_date32(t)):
+            raise _Decline("type")
+        names[c], types[c] = real[c], t
+    return names, types
+
+
+def _launch_ungrouped(preds, bounds, specs, per_file, data_path, rows):
+    """The ungrouped program once a file, the carry summed on the device;
+    one fetch: four slots a spec."""
+    carry = _empty_carry(specs)
+    if per_file:
+        with telemetry.record_operation("delta.columnCache.aggregate",
+                                        {"rows": rows}), enable_x64():
+            kernel = _aggregate_kernel(preds, specs)
+            dev_bounds, carry = link.to_device(bounds), link.to_device(carry)
+            for f in per_file:
+                carry = kernel(f.env, dev_bounds, _rows_on_device(f.rows),
+                               _keep_mask(f, data_path), carry)
+            carry = link.to_host(carry)
+    return carry
+
+
+def _launch_grouped(preds, bounds, terms, keys, per_file, data_path, rows):
+    """The grouped program once a file, each into its own row of the carry;
+    one fetch: ``(partials[file, slot, column], each file's key layout)``.
+    Declines ``groups`` when a file's keys could number more than
+    ``GROUP_SLOTS``."""
+    layouts, slots = [], 1
+    for f in per_file:
+        file_layout, file_slots = _key_layout(f, keys)
+        if file_slots > GROUP_SLOTS:
+            raise _Decline("groups")
+        layouts.append(file_layout)
+        slots = max(slots, file_slots)
+    slots = -(-slots // 8) * 8  # the program is keyed on it: few distinct counts
+    if not per_file:
+        return np.zeros((0, slots, 1), np.int64), layouts
+    with telemetry.record_operation("delta.columnCache.aggregate",
+                                    {"rows": rows}), enable_x64():
+        kernel = _group_kernel(preds, terms, keys, slots)
+        width = 1 + sum(len(c) for c in _term_columns(terms))
+        dev_bounds = link.to_device(bounds)
+        carry = _zeros_on_device((len(per_file), slots, width))
+        for i, (f, file_layout) in enumerate(zip(per_file, layouts)):
+            carry = kernel(f.env, dev_bounds, _rows_on_device(f.rows),
+                           _keep_mask(f, data_path),
+                           _layout_on_device(file_layout), _rows_on_device(i),
+                           carry)
+        return link.to_host(carry), layouts
+
+
+def _device_aggregate(snapshot, filters, parsed_items, group_by, order_keys, ev):
     from delta_tpu.ops import pruning
 
     if not column_cache.column_cache_enabled():
@@ -367,14 +877,17 @@ def _device_aggregate(snapshot, filters, parsed_items, ev):
     from delta_tpu.expr.synthesis import schema_types
 
     metadata = snapshot.metadata
-    fields, parts = schema_types(metadata), metadata.partition_columns
-    specs = _specs(parsed_items)
-    types = _arrow_types(specs, fields, parts)
+    fields = schema_types(metadata)
+    parts = {c.lower() for c in metadata.partition_columns}
+    key_names, key_types = _group_keys(snapshot, group_by, fields, parts)
+    keys = tuple(key_names)
+    specs, types, inners, layout = _specs(parsed_items, key_names, fields, parts)
+    typed = _typed(specs, types, inners)
     scan = pruning.files_for_scan(snapshot, list(filters))
     preds, bounds = _ranges(
         ir.and_all(scan.data_filters) if scan.data_filters else None,
-        fields, parts)
-    need = sorted(set(preds) | {c for s in specs for c in s.cols})
+        fields, metadata.partition_columns)
+    need = sorted(set(preds) | set(keys) | {c for s in specs for c in s.cols})
     if not need:
         raise _Decline("shape")  # COUNT(*) of a whole table: the log's to answer
     log_path = snapshot.delta_log.log_path
@@ -395,31 +908,43 @@ def _device_aggregate(snapshot, filters, parsed_items, ev):
     for add in scan.files:
         lanes = column_cache._ensure_lanes(cache, log_path, data_path, add, need,
                                            cache.epoch(log_path), counters)
-        if lanes is None or any(e.dict_codes is not None for e in lanes.values()):
+        if lanes is None or any(e.dict_codes is not None
+                                for c, e in lanes.items() if c not in keys):
             raise _Decline("type")
         per_file.append(_FileLanes(
             add, {c: (e.values, e.valid) for c, e in lanes.items()},
             max(e.n for e in lanes.values()),
-            {c: max(abs(e.lo), abs(e.hi)) for c, e in lanes.items()}))
+            {c: (e.lo, e.hi) for c, e in lanes.items()},
+            {c: lanes[c].dict_codes for c in keys}))
     for name in ("hits", "misses"):
         if counters[name]:
             telemetry.bump_counter(f"columnCache.{name}", counters[name])
-    _check_overflow(specs, types, per_file)
+    _check_overflow(specs, typed, per_file, bool(keys))
     rows = sum(f.rows for f in per_file)
     ev.data.update(files=len(per_file), rows=rows, hits=counters["hits"],
                    misses=counters["misses"])
-    carry = _empty_carry(specs)
-    if per_file:
-        with telemetry.record_operation("delta.columnCache.aggregate",
-                                        {"rows": rows}), enable_x64():
-            kernel = _aggregate_kernel(preds, specs)
-            dev_bounds, carry = link.to_device(bounds), link.to_device(carry)
-            for f in per_file:
-                cap = next(iter(f.env.values()))[0].shape[0]
-                keep = None if f.add.deletion_vector is None \
-                    else _keep_mask(f.add, data_path, cap)
-                carry = kernel(f.env, dev_bounds, _rows_on_device(f.rows), keep,
-                               carry)
-            carry = link.to_host(carry)
-    return [_scalar(spec, carry[_SLOTS * k:_SLOTS * (k + 1)], types)
-            for k, spec in enumerate(specs)]
+    names = [alias if kind == "agg" else alias or payload
+             for kind, payload, alias in parsed_items]
+    if not keys:
+        carry = _launch_ungrouped(preds, bounds, specs, per_file, data_path, rows)
+        return names, [_column(spec.func, types_of, [carry[_SLOTS * k:_SLOTS * (k + 1)]])
+                       for k, (spec, types_of) in enumerate(zip(specs, typed))]
+    terms, spec_term = _terms(specs, per_file)
+    partials, layouts = _launch_grouped(preds, bounds, terms, keys, per_file,
+                                        data_path, rows)
+    with telemetry.record_operation("delta.scan.deviceAggregate.groups") as gev:
+        order, slots_of = _merge_groups(partials, per_file, layouts, keys,
+                                        terms, spec_term, specs)
+        key_arrays = {c: _key_column(key_types[c], [key[i] for key in order])
+                      for i, c in enumerate(keys)}
+        columns = [key_arrays[ref] if kind == "key" else
+                   _column(specs[ref].func, typed[ref], slots_of[ref])
+                   for kind, ref in layout]
+        shown = {n.lower() for n in names}
+        for c in keys:  # a key only ORDER BY names rides along, the last
+            if c not in shown and c in order_keys:
+                names.append(key_names[c])
+                columns.append(key_arrays[c])
+        gev.data.update(groups=len(order), files=len(per_file))
+    ev.data.update(groups=len(order), groupColumns=list(keys))
+    return names, columns

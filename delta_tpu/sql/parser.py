@@ -513,20 +513,25 @@ def _select(p: _Parser):
         if (has_agg or group_by) and star:
             raise DeltaParseError("SELECT * cannot be combined with GROUP BY")
         out = None
-        if has_agg and not group_by:
-            # an ungrouped aggregate over columns that have lanes is
-            # answered from them (ops/column_aggregate), or declines
+        hidden: List[str] = []
+        order_keys = [c.strip("`").lower() for c, _d in order]
+        if has_agg:
+            # an aggregate over columns that have lanes, ungrouped or
+            # grouped by a few values, is answered from them
+            # (ops/column_aggregate), or declines
             from delta_tpu.ops.column_aggregate import device_aggregate
 
             out = device_aggregate(
-                snap, [parse_expression(cond)] if cond else [], parsed_items)
+                snap, [parse_expression(cond)] if cond else [], parsed_items,
+                group_by, order_keys)
+            if out is not None:
+                # group keys carried only for ORDER BY follow the select list
+                hidden = out.column_names[len(parsed_items):]
         # answered from the lanes, or the scan decodes the columns it needs
         table = None if out is not None else scan_to_table(
             snap, filters=[cond] if cond else (), columns=read_cols)
         pre_sort = False
-        hidden: List[str] = []
         if table is not None and (has_agg or group_by):
-            order_keys = [c.strip("`").lower() for c, _d in order]
             out, hidden = _run_aggregate(table, parsed_items, group_by,
                                          order_keys, evaluate)
         elif table is not None:

@@ -406,12 +406,16 @@ def test_budget_declines_and_off_switches_the_route_off(lineitem_table):
     _both(sql)  # and with room, the lanes load and the device answers
 
 
-def test_group_by_stays_on_the_host(lineitem_table):
+def test_group_by_reaches_the_device_route(lineitem_table):
+    """Until PR 32 a grouped query never reached `device_aggregate`; its own
+    tests are `test_q1_grouped_aggregate.py`."""
     path, _base = lineitem_table
     telemetry.clear_events()
     got = execute_sql(f"select l_returnflag, sum(l_quantity) as q "
                       f"from delta.`{path}` group by l_returnflag")
-    assert _routes() == [] and got.num_rows == 3
+    routes = [e.data.get("route") for e in telemetry.recent_events(
+        "delta.scan.deviceAggregate") if e.op_type == "delta.scan.deviceAggregate"]
+    assert routes == ["device"] and got.num_rows == 3
 
 
 def test_one_program_serves_every_literal(lineitem_table):

@@ -7,9 +7,10 @@ The arguments and the result line are `benchmark/run.py`'s. Besides, on
 standard error: the mean milliseconds a request of every span of the program
 (and how many of them a request opened), each MERGE's route, what the
 resident probe's spans and counters say of how widely it engaged, every
-re-sort of the slab and how many inverse permutations were built, and the
-device's time in the window by XLA module, each module with its longest
-operations and the arguments its operations name. A builder's instrument for
+re-sort of the slab and how many inverse permutations were built, the routes
+and group counts of the aggregate queries, and the device's time in the
+window by XLA module, each module with its longest operations and the
+arguments its operations name. A builder's instrument for
 PERF.md; nothing of the benchmark reads it.
 
 ``--decode-route`` makes `MergeIntoCommand._pairs_only_shape` read false, so
@@ -57,6 +58,16 @@ def report(run) -> None:
                   "merge.keyCache.inverseBuilds", 0), file=sys.stderr)
         print("slab sorts:", json.dumps(span_data("delta.keyCache.sort")),
               file=sys.stderr)
+    aggregates = span_data("delta.scan.deviceAggregate")
+    if aggregates:
+        # grouped queries also open delta.scan.deviceAggregate.groups (the
+        # merge of the files' partials by value), whose mean is above
+        print("aggregate routes:", json.dumps(collections.Counter(
+            d.get("route") for d in aggregates)), "groups a query:",
+            json.dumps(collections.Counter(
+                str(d.get("groups")) for d in aggregates)),
+            "grouped, in the window:", run.counters.get(
+                "scan.aggregate.grouped", 0), file=sys.stderr)
     if run.trace is not None:
         print("device ms a request by module:",
               json.dumps(module_split(run.trace, len(done))), file=sys.stderr)
