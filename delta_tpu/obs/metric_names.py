@@ -203,6 +203,7 @@ ENGINE_COUNTERS = frozenset({
     "scan.device.compiles",
     "scan.aggregate.device",
     "scan.aggregate.grouped",
+    "scan.aggregate.grouped.tiled",
     "scan.aggregate.declined",
     "scan.prune.deviceFallback",
     "columnCache.hits",
@@ -472,6 +473,7 @@ DESCRIPTIONS = {
     "scan.device.compiles": "XLA compiles that ran with a delta.scan span open on the compiling thread (a new literal or lane shape).",
     "scan.aggregate.device": "Aggregate SELECTs, ungrouped or grouped, answered by the fused filter-and-sum kernels over resident lanes.",
     "scan.aggregate.grouped": "Of scan.aggregate.device, the GROUP BY queries: answered by the grouped kernel, the files' partials merged by value on the host (span delta.scan.deviceAggregate.groups).",
+    "scan.aggregate.grouped.tiled": "Of scan.aggregate.grouped, the queries whose launches ran the tile kernel (one pass over a file in on-chip memory, 32-bit integers only); the others ran the wide formulation, which carries an int64 product. The delta.columnCache.aggregate span's program says which; the lanes' extremes choose.",
     "scan.aggregate.declined": "Aggregate SELECTs, ungrouped or grouped, the device route declined (the delta.scan.deviceAggregate span's route says why: host:shape, type, predicate, budget, overflow, groups, off); the host scan answered.",
     "device.compiles": "XLA compiles in this process (persistent-cache fetches not counted).",
     "device.compileUs": "Microseconds spent in those XLA compiles.",

@@ -22,9 +22,17 @@ each file's own numbering (the group of a row is a mixed-radix number of its
 key lanes' codes, the radices handed in as operands), writes that file's
 partials into its row of the carry, and the host merges the files' partials
 **by value** through each file's ``dict_codes`` read backwards, a NULL key a
-group of its own. The sums a group are one contraction on the matrix unit:
-a one-hot of the group against the aggregated values cut into 8-bit limbs,
-int8 by int8 into int32, exact (see :func:`_group_kernel`).
+group of its own. The sums a group come from the matrix unit: a one-hot of
+the group against the aggregated values cut into 8-bit limbs, int8 by int8
+into int32, exact. There are two schedules of that one algorithm
+(:func:`_group_kernel`): ``tiled``, a hand-written kernel that reads a file's
+lanes once, a tile of rows at a time, forms limbs and one-hot in on-chip
+memory in 32-bit integers and contracts them once a tile (:func:`_tile_call`,
+the repo's one kernel in `jax.experimental.pallas`); and ``wide``, XLA's own
+schedule, a contraction a term over byte rows in HBM, the only one that
+carries an int64 product (:func:`_wide_sums`). The lanes' own extremes over
+the planned files choose (:func:`_fits_tiles`), by no conf, and the stage's
+span says which ran (``program``).
 
 Exact by construction: the conjunction of range predicates is compared in
 integers (literals scaled by `jaxeval.compile_residual`, the bounds handed
@@ -76,13 +84,19 @@ _EXACT_F64 = 2 ** 53
 #: Groups one launch carries: the product over the key lanes of the values
 #: the file holds (one more each where a NULL can be) may not pass it
 #: (``host:groups``). 32 is the height of one int8 tile on the chip (32
-#: sublanes x 128 lanes): the one-hot of the group is one tile high for any
-#: count up to it (8 or 16 slots: 1.71 or 1.75 ms a launch of TPC-H Q1,
-#: PERF.md PR 32), and a group's number fits the int8 the one-hot compares
-#: in. TPC-H Q1 needs 3 x 2.
+#: sublanes x 128 lanes): in the wide program the one-hot of the group is one
+#: tile high for any count up to it and a group's number fits the int8 the
+#: one-hot compares in; in the tiled program four slots share a 32-bit word,
+#: so the one-hot is 8 int8 tiles at most (what a launch of TPC-H Q1 takes
+#: under either, by slots: PERF.md, PR 33). TPC-H Q1 needs 3 x 2.
 GROUP_SLOTS = 32
 #: rows one int8 contraction may add into int32: 2^22 x 128 < 2^31
 _DOT_ROWS = 1 << 22
+#: registers of 8 x 128 rows a lane that one step of the tile kernel takes
+#: (32,768 rows)
+_TILE_VREGS = 32
+_I32_MAX = np.iinfo(np.int32).max
+_I32_MIN = np.iinfo(np.int32).min
 
 
 class Factor(NamedTuple):
@@ -107,15 +121,23 @@ class AggregateSpec(NamedTuple):
 class _Term(NamedTuple):
     """One product the grouped program forms, shared by the select items
     over it: which of sum / min / max are wanted (its count always is), the
-    index of the first factor from which the running product needs int64
-    (before it, int32: the native width), and the bytes its sum is cut into.
-    The two widths come from the lanes' extremes, never from a literal of
-    the predicate."""
+    index of the first factor at which the running product passes int32
+    (``len(factors)`` when it never does), and the bytes its sum is cut into.
+    The wide program multiplies in int64 from ``wide_at`` on and sends
+    ``nbytes`` rows; the tiled one has no int64: it takes a term whose
+    ``wide_at`` is the last factor at the earliest, splits that last step in
+    16-bit halves (`_tile_call`) and always sends a value's four bytes. The
+    two widths come from the lanes' extremes, never from a literal of the
+    predicate."""
 
     factors: Tuple[Factor, ...]
     want: Tuple[str, ...]
     wide_at: int
     nbytes: int
+
+    @property
+    def passes_int32(self) -> bool:
+        return self.wide_at < len(self.factors)
 
 
 class _Decline(Exception):
@@ -210,9 +232,304 @@ def _term_columns(terms: Sequence[_Term]) -> List[Dict[str, int]]:
     return out
 
 
+def _off_chip() -> bool:
+    """Off the chip the tile kernel's body runs in the kernel language's
+    interpreter: the same code, so the tests execute what the chip runs."""
+    import jax
+
+    return jax.default_backend() != "tpu"
+
+
+def _group_ids(jnp, lanes, keys, layout):
+    """Each row's group in the file's own numbering,
+    ``sum((key_i - lo_i) * stride_i)`` with a NULL key counted as ``size_i``:
+    below ``slots`` by the caller's check."""
+    gid = None
+    for i, c in enumerate(keys):
+        v, ok = lanes[c]
+        part = jnp.where(ok, (v - layout[i, 0].astype(v.dtype)).astype(jnp.int32),
+                         layout[i, 1].astype(jnp.int32))
+        part = part * layout[i, 2].astype(jnp.int32)
+        gid = part if gid is None else gid + part
+    return gid
+
+
+def _from_limbs(limbs, in_group):
+    """A group's sum from the sums of its value's bytes, least first: a low
+    byte was sent as ``byte - 128``, so the group's rows times 128 come back;
+    the top byte is signed as it stands. int64 over ``[slots]`` values."""
+    total = limbs[-1] << (8 * (len(limbs) - 1))
+    for b, low in enumerate(limbs[:-1]):
+        total = total + ((low + 128 * in_group) << (8 * b))
+    return total
+
+
+def _wide_sums(jnp, lanes, terms, live, gid, slots):
+    """``(rows a group, [(count, sum or None) a term])`` as XLA schedules
+    it: one contraction a term over the whole file, the one-hot of the group
+    (int8, ``slots`` x rows, never stored: XLA forms it inside each
+    contraction) against int8 rows the program writes to HBM, one the
+    count's mask and one a byte of the summed value. The only formulation
+    that carries a product in int64 (from ``_Term.wide_at`` on). At most
+    ``_DOT_ROWS`` rows go into one int32 (2^22 x 128 < 2^31); the blocks add
+    in int64."""
+    cap = live.shape[0]
+    # in int8: a live row's group is below 32, a dead row's is -1 (a launch
+    # is 1.75 ms so, 2.16 with the compare in int32: PR 32, call 3)
+    small = jnp.where(live, gid, -1).astype(jnp.int8)
+    hot = (small[None, :] == jnp.arange(slots, dtype=jnp.int8)[:, None]
+           ).astype(jnp.int8)
+    block = min(cap, _DOT_ROWS)
+    hot = hot.reshape(slots, cap // block, block)
+
+    def per_group(mat):
+        """int8[rows, cap] against the one-hot: int64[slots, rows]."""
+        acc = jnp.einsum("gbk,rbk->bgr", hot,
+                         mat.reshape(mat.shape[0], cap // block, block),
+                         preferred_element_type=jnp.int32)
+        return jnp.sum(acc.astype(jnp.int64), axis=0)
+
+    in_group = per_group(jnp.ones((1, cap), jnp.int8))[:, 0]
+    sums = []
+    for t in terms:
+        if not t.factors:  # COUNT(*)
+            sums.append((in_group, None))
+            continue
+        m, x = _product(jnp, lanes, t.factors, live, t.wide_at)
+        # row 0 the count's mask, then a row a byte of the value, all of
+        # one expression, (source >> shift & mask) - bias, so that XLA
+        # writes the term's rows in one pass
+        nb = t.nbytes if "sum" in t.want else 0
+        shift = np.array([0] + [8 * b for b in range(nb)])
+        mask = np.array([1] + [255] * (nb - 1) + [-1] * bool(nb))
+        bias = np.array([0] + [128] * (nb - 1) + [0] * bool(nb))
+        source = jnp.where(jnp.asarray(np.arange(nb + 1) == 0)[:, None],
+                           m.astype(x.dtype)[None, :],
+                           jnp.where(m, x, 0)[None, :])
+        mat = ((source >> jnp.asarray(shift, x.dtype)[:, None])
+               & jnp.asarray(mask, x.dtype)[:, None]) \
+            - jnp.asarray(bias, x.dtype)[:, None]
+        acc = per_group(mat.astype(jnp.int8))
+        total = _from_limbs([acc[:, 1 + b] for b in range(nb)], in_group) \
+            if nb else None
+        sums.append((acc[:, 0], total))
+    return in_group, sums
+
+
+def _tile_call(preds, terms, keys, slots, columns, blocks, interpret):
+    """The tile kernel: ``(the call, where each mask and each value sits in
+    its result)``. A grid step takes ``tile`` x 1,024 rows of every lane
+    (`_TILE_VREGS` registers of 8 x 128 32-bit values a lane; the row a
+    value belongs to is no matter to a sum, only that every lane is cut the
+    same way) and, in on-chip memory, 32-bit integers only:
+
+    * the live mask from the word of bits the caller packed (bit 0: the row
+      exists and no deletion vector drops it; bit 1 + k: column k is not
+      NULL) and the inclusive bounds, the group number from the key lanes
+      and their radices (scalars, so a fresh literal or another dictionary
+      compiles nothing), each term's product. A product whose last step
+      passes int32 is split, not widened: ``p = ph * 2^16 + pl`` and two
+      sums, ``ph * f`` and ``pl * f``, each below 2^31 while ``|f| < 2^15``
+      (`_fits_tiles` holds a query to that), joined by the caller over
+      ``[slots]`` values;
+    * a 32-bit word is four int8 rows at no cost (`pltpu.bitcast`: byte b of
+      sublane i is row 4 i + b). A value's word, ``x ^ 0x00808080``, is its
+      three low bytes each less 128 and its top byte signed as it stands:
+      the limbs `_wide_sums` cuts one at a time; the count masks go four to
+      a word, the first of them the constant 1 that counts a group's rows;
+      the one-hot of the group is ``1 << 8 (g % 4)`` in word ``g // 4``;
+    * one contraction a step on the matrix unit, the one-hot's rows against
+      all the limbs' at once, added into an int32 accumulator that stays on
+      the chip across the grid. Each of the 8 sublanes is a stream of rows of
+      its own, so the accumulator holds a product for every pair of streams
+      and the caller reads the 8 in which both are the same.
+
+    Exact: a limb is at most 128 in size and one stream holds an eighth of
+    the file, at most `_DOT_ROWS` rows (`_fits_tiles`): below 2^31."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    i32 = jnp.int32
+    tile = min(blocks, _TILE_VREGS)
+    bit = {c: 1 << (1 + k) for k, c in enumerate(columns)}
+    masks: List[Any] = ["rows"]     # what each mask byte counts, in order
+    values: List[Tuple[int, int]] = []  # (term, half) of each value's word
+    for k, t in enumerate(terms):
+        if t.factors:
+            masks.append(k)
+            if "sum" in t.want:
+                values += [(k, h) for h in range(1 + t.passes_int32)]
+    mask_words = -(-len(masks) // 4)
+    words = len(values) + mask_words
+
+    def kernel(scal, bits_ref, *refs):
+        acc = refs[len(columns)]
+
+        def load(ref):
+            # registers side by side: [tile, 8, 128] as [8, tile x 128]
+            x = jnp.concatenate([ref[a] for a in range(tile)], axis=1)
+            return x if x.dtype == i32 else jax.lax.bitcast_convert_type(x, i32)
+
+        bits = load(bits_ref)
+        lane = {c: load(ref) for c, ref in zip(columns, refs)}
+
+        def has(need):
+            return (bits & i32(need)) == i32(need)
+
+        need = 1
+        for c in preds:
+            need |= bit[c]
+        live = has(need)
+        for i, c in enumerate(preds):
+            live = live & (lane[c] >= scal[2 * i]) & (lane[c] <= scal[2 * i + 1])
+        gid, base = None, 2 * len(preds)
+        for i, c in enumerate(keys):
+            lo, size, stride = (scal[base + 3 * i + j] for j in range(3))
+            part = jnp.where(has(bit[c]), lane[c] - lo, size) * stride
+            gid = part if gid is None else gid + part
+        gid = jnp.where(live, gid, i32(-4))   # a dead row: word -1, no slot's
+        word_of = gid >> i32(2)
+        byte = jnp.left_shift(i32(1), (gid & i32(3)) << i32(3))
+        hot = [jnp.where(word_of == i32(j), byte, i32(0))
+               for j in range(slots // 4)]
+
+        def factor(f):
+            v = lane[f.column]
+            return v if f.sign == 1 and not f.offset else \
+                i32(f.offset) + (v if f.sign == 1 else -v)
+
+        count, sent = {}, {}
+        for k, t in enumerate(terms):
+            if not t.factors:
+                continue
+            need = 0
+            for f in t.factors:
+                need |= bit[f.column]
+            m = count[k] = live & has(need)
+            if "sum" not in t.want:
+                continue
+            x = None
+            for f in t.factors[:-1] if t.passes_int32 else t.factors:
+                x = factor(f) if x is None else x * factor(f)
+            halves = [x]
+            if t.passes_int32:  # the last step, in 16-bit halves
+                last = factor(t.factors[-1])
+                halves = [(x & i32(0xFFFF)) * last, (x >> i32(16)) * last]
+            for h, x in enumerate(halves):
+                sent[k, h] = jnp.where(m, x, i32(0)) ^ i32(0x00808080)
+        rows = [sent[v] for v in values]
+        for w in range(mask_words):
+            word = None
+            for b, name in enumerate(masks[4 * w:4 * w + 4]):
+                one = i32(1 << (8 * b)) if name == "rows" else \
+                    jnp.where(count[name], i32(1 << (8 * b)), i32(0))
+                word = one if word is None else word | one
+            rows.append(jnp.broadcast_to(word, bits.shape))
+        mat = jnp.concatenate([pltpu.bitcast(r, jnp.int8) for r in rows], axis=0)
+        one_hot = jnp.concatenate([pltpu.bitcast(r, jnp.int8) for r in hot],
+                                  axis=0)
+        part = jax.lax.dot_general(one_hot, mat, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=i32)
+
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            acc[...] = jnp.zeros(acc.shape, i32)
+
+        acc[...] += part
+
+    # (a bare 0 in an index map is 64-bit under x64, which the chip refuses)
+    lanes_in = pl.BlockSpec((tile, 8, 128), lambda i, scal: (i, i32(0), i32(0)))
+    shape = (slots * 8, words * 32)
+    call = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(shape, i32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(blocks // tile,),
+            in_specs=[lanes_in] * (1 + len(columns)),
+            out_specs=pl.BlockSpec(shape, lambda i, scal: (i32(0), i32(0)))),
+        # the word of bits may be computed in the kernel's own pipeline, from
+        # the bool lanes: no 16 MB of bits is written and read back, and all
+        # four low words then fit beside the lanes in on-chip memory (a
+        # launch of Q1 alone 0.38 -> 0.29 ms: PERF.md, PR 33)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            allow_input_fusion=[False, True] + [False] * len(columns)),
+        name="group_tiles", interpret=interpret)
+    at = {("mask", name): len(values) * 4 + i for i, name in enumerate(masks)}
+    at.update({("value", v): 4 * i for i, v in enumerate(values)})
+    return call, at
+
+
+def _tiled_sums(jnp, lanes, preds, terms, keys, slots, bounds, n, keep, layout,
+                interpret):
+    """What `_wide_sums` returns, from the tile kernel (`_tile_call`). Around
+    it, in the same XLA module: a lane the cache holds as int64 is cut to its
+    low word (`_fits_tiles`: the lane's own extremes fit), the validity
+    lanes, ``keep`` and ``row < n`` are packed into one word of bits a row in
+    one elementwise pass (the kernel language takes no ``bool`` array), the
+    bounds and the keys' radices become int32 scalars, and the accumulator's
+    limbs are put together in int64 over ``[slots]`` values."""
+    columns = tuple(sorted(lanes))
+    cap = max(next(iter(lanes.values()))[0].shape[0], 1024)
+    if cap > 8 * _DOT_ROWS:  # `_fits_tiles` sends such a file to `wide`
+        raise ValueError(f"{cap} rows a launch: a stream of the tile kernel "
+                         f"adds at most {_DOT_ROWS} limbs into an int32")
+    blocks = cap // 1024
+
+    def rows(x):
+        if x.shape[0] < cap:
+            x = jnp.pad(x, (0, cap - x.shape[0]))
+        return x.reshape(blocks, 8, 128)
+
+    bits = jnp.arange(cap, dtype=jnp.int32) < n
+    if keep is not None:
+        bits = bits & rows(keep).reshape(cap)
+    bits = bits.astype(jnp.int32).reshape(blocks, 8, 128)
+    for k, c in enumerate(columns):
+        bits = bits | (rows(lanes[c][1]).astype(jnp.int32) << (1 + k))
+    # the low word of an int64 is XLA's split alone as uint32 (as int32 it
+    # is one more pass over the lane); the kernel reads the same bits
+    values = [rows(v if v.dtype == jnp.int32 else v.astype(jnp.uint32))
+              for v in (lanes[c][0] for c in columns)]
+    # a bound beyond int32 holds for every row of a lane inside it, or none
+    lo, hi = bounds[:, 0], bounds[:, 1]
+    none = (lo > _I32_MAX) | (hi < _I32_MIN)
+    scal = jnp.concatenate([
+        jnp.stack([jnp.where(none, 1, jnp.clip(lo, _I32_MIN, _I32_MAX)),
+                   jnp.where(none, 0, jnp.clip(hi, _I32_MIN, _I32_MAX))],
+                  axis=1).reshape(-1), layout.reshape(-1)]).astype(jnp.int32)
+    call, at = _tile_call(preds, terms, keys, slots, columns, blocks, interpret)
+    acc = call(scal, bits, *values)
+    # [one-hot word, stream, byte] x [word, stream, byte]: the same stream
+    words = acc.shape[1] // 32
+    acc = acc.reshape(slots // 4, 8, 4, words, 8, 4).astype(jnp.int64)
+    same = jnp.eye(8, dtype=jnp.int64)[None, :, None, None, :, None]
+    limbs = jnp.sum(acc * same, axis=(1, 4)).reshape(slots, words * 4)
+    in_group = limbs[:, at["mask", "rows"]]
+
+    def value(k, half):
+        b = at["value", (k, half)]
+        return _from_limbs([limbs[:, b + j] for j in range(4)], in_group)
+
+    sums = []
+    for k, t in enumerate(terms):
+        if not t.factors:  # COUNT(*)
+            sums.append((in_group, None))
+            continue
+        total = None
+        if "sum" in t.want:
+            total = value(k, 0)
+            if t.passes_int32:
+                total = total + (value(k, 1) << 16)
+        sums.append((limbs[:, at["mask", k]], total))
+    return in_group, sums
+
+
 @functools.lru_cache(maxsize=64)
 def _group_kernel(preds: Tuple[str, ...], terms: Tuple[_Term, ...],
-                  keys: Tuple[str, ...], slots: int):
+                  keys: Tuple[str, ...], slots: int, program: str = "wide"):
     """The grouped program for a select list's terms, the columns its
     predicate ranges over and its key columns; XLA keys it further on the
     lane shapes, on the carry's (files, slots, columns) and on whether a
@@ -220,77 +537,43 @@ def _group_kernel(preds: Tuple[str, ...], terms: Tuple[_Term, ...],
     predicate, never on a dictionary's content: the keys' radices are the
     operand ``layout``.
 
-    The group of a row is ``sum((key_i - lo_i) * stride_i)`` with a NULL key
-    counted as ``size_i``, below ``slots`` by the caller's check. The count
-    and the sum of a term come, for every group at once, from one
-    contraction over the rows: the one-hot of the group (int8, ``slots`` x
-    rows, never stored: XLA forms it inside the contraction) against a
-    matrix of int8 rows, one the count's mask and one a byte of the summed
-    value, accumulated in int32 on the matrix unit. A value's low bytes are
-    unsigned: each is sent as ``byte - 128`` and the group's row count times
-    128 is added back; its top byte is signed as it stands. At most ``_DOT_ROWS`` rows go into one
-    int32 (2^22 x 128 < 2^31); the blocks add in int64. Nothing rounds."""
+    One algorithm, the one-hot of a row's group against the aggregated
+    values cut into 8-bit limbs, contracted on the matrix unit into int32
+    and put together in int64 over ``[slots]`` values (a value's low bytes
+    are unsigned: each is sent as ``byte - 128`` and the group's row count
+    times 128 is added back; its top byte is signed as it stands; nothing
+    rounds), in two schedules. ``program`` says which (`_fits_tiles`
+    chooses, from the lanes' extremes): ``tiled`` is one pass over the file
+    in a hand-written kernel, nothing a row leaving the chip (`_tile_call`);
+    ``wide`` is XLA's, a contraction a term over byte rows in HBM, and
+    carries what ``tiled`` does not, a product in int64 (`_wide_sums`).
+    Either way the module is ``jit_filter_group_aggregate`` and holds all a
+    launch does on the device. Grouped ``min`` / ``max`` have no
+    contraction: one masked pass a slot in XLA, under both."""
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
 
+    interpret = _off_chip()
+
     def filter_group_aggregate(lanes, bounds, n, keep, layout, at, carry):
         # layout: int64[len(keys), 3]: least value, values, stride of a key
-        live = _live_rows(jnp, lanes, preds, bounds, n, keep)
-        cap = live.shape[0]
-        gid = jnp.zeros(cap, jnp.int32)
-        for i, c in enumerate(keys):
-            v, ok = lanes[c]
-            part = jnp.where(ok, (v - layout[i, 0].astype(v.dtype)
-                                  ).astype(jnp.int32),
-                             layout[i, 1].astype(jnp.int32))
-            gid = gid + part * layout[i, 2].astype(jnp.int32)
-        # in int8: a live row's group is below 32, a dead row's is -1 (a
-        # launch is 1.75 ms so, 2.16 with the compare in int32: call 3)
-        small = jnp.where(live, gid, -1).astype(jnp.int8)
-        hot = (small[None, :] == jnp.arange(slots, dtype=jnp.int8)[:, None]
-               ).astype(jnp.int8)
-        block = min(cap, _DOT_ROWS)
-        hot = hot.reshape(slots, cap // block, block)
-
-        def per_group(mat):
-            """int8[rows, cap] against the one-hot: int64[slots, rows]."""
-            acc = jnp.einsum("gbk,rbk->bgr", hot,
-                             mat.reshape(mat.shape[0], cap // block, block),
-                             preferred_element_type=jnp.int32)
-            return jnp.sum(acc.astype(jnp.int64), axis=0)
-
-        in_group = per_group(jnp.ones((1, cap), jnp.int8))[:, 0]
+        tiled = program == "tiled"
+        if not tiled or any(set(t.want) - {"sum"} for t in terms):
+            live = _live_rows(jnp, lanes, preds, bounds, n, keep)
+            gid = _group_ids(jnp, lanes, keys, layout)
+        in_group, sums = _tiled_sums(
+            jnp, lanes, preds, terms, keys, slots, bounds, n, keep, layout,
+            interpret) if tiled else _wide_sums(jnp, lanes, terms, live, gid, slots)
         out = [in_group]
-        for t in terms:
-            if not t.factors:  # COUNT(*)
-                out.append(in_group)
-                continue
-            m, x = _product(jnp, lanes, t.factors, live, t.wide_at)
-            # row 0 the count's mask, then a row a byte of the value, all of
-            # one expression, (source >> shift & mask) - bias, so that XLA
-            # writes the term's rows in one pass
-            nb = t.nbytes if "sum" in t.want else 0
-            shift = np.array([0] + [8 * b for b in range(nb)])
-            mask = np.array([1] + [255] * (nb - 1) + [-1] * bool(nb))
-            bias = np.array([0] + [128] * (nb - 1) + [0] * bool(nb))
-            source = jnp.where(jnp.asarray(np.arange(nb + 1) == 0)[:, None],
-                               m.astype(x.dtype)[None, :],
-                               jnp.where(m, x, 0)[None, :])
-            mat = ((source >> jnp.asarray(shift, x.dtype)[:, None])
-                   & jnp.asarray(mask, x.dtype)[:, None]) \
-                - jnp.asarray(bias, x.dtype)[:, None]
-            acc = per_group(mat.astype(jnp.int8))
-            out.append(acc[:, 0])
+        for t, (count, total) in zip(terms, sums):
+            out.append(count)
             for name in t.want:
                 if name == "sum":
-                    total = acc[:, nb] << (8 * (nb - 1))  # the top byte, signed
-                    for b in range(nb - 1):
-                        total = total + ((acc[:, 1 + b] + 128 * in_group)
-                                         << (8 * b))
                     out.append(total)
                     continue
                 # min / max have no contraction: one masked pass a slot
+                m, x = _product(jnp, lanes, t.factors, live, t.wide_at)
                 fill, pick = (_I64_MAX, jnp.min) if name == "min" \
                     else (_I64_MIN, jnp.max)
                 x64 = x.astype(jnp.int64)
@@ -690,6 +973,32 @@ def _key_layout(f: _FileLanes, keys):
     return tuple(layout), stride
 
 
+def _fits_tiles(terms, per_file, columns) -> bool:
+    """Whether the tile kernel (`_tile_call`) can answer, from the lanes' own
+    extremes over these files and from nothing else: every lane the query
+    reads fits int32 (the kernel has no wider integer), so does every factor
+    and every running product but at most the last step's, whose factor is
+    then below 2^15 (the product is split in 16-bit halves there); a stream
+    of the kernel, an eighth of a padded file, adds at most `_DOT_ROWS` rows
+    into an int32; and the validity bits of the lanes fit one word."""
+    if not per_file or len(columns) > 30:
+        return False
+    if max(next(iter(f.env.values()))[0].shape[0] for f in per_file) \
+            > 8 * _DOT_ROWS:
+        return False
+    # over all the files at once: an extreme of the union is some file's
+    union = {c: (min(f.ranges[c][0] for f in per_file),
+                 max(f.ranges[c][1] for f in per_file)) for c in columns}
+    if any(not _I32_MIN <= v <= _I32_MAX for r in union.values() for v in r):
+        return False
+    for t in terms:
+        own = [_magnitude(union, (factor,))[0] for factor in t.factors]
+        if any(m > _I32_MAX for m in own) or t.wide_at < len(own) - 1 \
+                or (t.passes_int32 and own[-1] >= 2 ** 15):
+            return False
+    return True
+
+
 def _merge_groups(partials, per_file, layouts, keys, terms, spec_term, specs):
     """The files' partials merged **by value**: each file's slot is read back
     to its key values through the file's own dictionary, a NULL key a value
@@ -797,6 +1106,8 @@ def device_aggregate(snapshot, filters: Sequence[ir.Expression], parsed_items,
             telemetry.bump_counter("scan.aggregate.device")
             if group_by:
                 telemetry.bump_counter("scan.aggregate.grouped")
+            if ev.data.get("program") == "tiled":
+                telemetry.bump_counter("scan.aggregate.grouped.tiled")
             return pa.Table.from_arrays(columns, names=names)
         telemetry.bump_counter("scan.aggregate.declined")
         return None
@@ -842,9 +1153,9 @@ def _launch_ungrouped(preds, bounds, specs, per_file, data_path, rows):
 
 def _launch_grouped(preds, bounds, terms, keys, per_file, data_path, rows):
     """The grouped program once a file, each into its own row of the carry;
-    one fetch: ``(partials[file, slot, column], each file's key layout)``.
-    Declines ``groups`` when a file's keys could number more than
-    ``GROUP_SLOTS``."""
+    one fetch: ``(partials[file, slot, column], each file's key layout, the
+    program that ran: `_fits_tiles`)``. Declines ``groups`` when a file's
+    keys could number more than ``GROUP_SLOTS``."""
     layouts, slots = [], 1
     for f in per_file:
         file_layout, file_slots = _key_layout(f, keys)
@@ -854,10 +1165,13 @@ def _launch_grouped(preds, bounds, terms, keys, per_file, data_path, rows):
         slots = max(slots, file_slots)
     slots = -(-slots // 8) * 8  # the program is keyed on it: few distinct counts
     if not per_file:
-        return np.zeros((0, slots, 1), np.int64), layouts
+        return np.zeros((0, slots, 1), np.int64), layouts, None
+    program = "tiled" if _fits_tiles(terms, per_file,
+                                     sorted(per_file[0].env)) else "wide"
     with telemetry.record_operation("delta.columnCache.aggregate",
-                                    {"rows": rows}), enable_x64():
-        kernel = _group_kernel(preds, terms, keys, slots)
+                                    {"rows": rows, "program": program}), \
+            enable_x64():
+        kernel = _group_kernel(preds, terms, keys, slots, program)
         width = 1 + sum(len(c) for c in _term_columns(terms))
         dev_bounds = link.to_device(bounds)
         carry = _zeros_on_device((len(per_file), slots, width))
@@ -866,7 +1180,7 @@ def _launch_grouped(preds, bounds, terms, keys, per_file, data_path, rows):
                            _keep_mask(f, data_path),
                            _layout_on_device(file_layout), _rows_on_device(i),
                            carry)
-        return link.to_host(carry), layouts
+        return link.to_host(carry), layouts, program
 
 
 def _device_aggregate(snapshot, filters, parsed_items, group_by, order_keys, ev):
@@ -930,8 +1244,8 @@ def _device_aggregate(snapshot, filters, parsed_items, group_by, order_keys, ev)
         return names, [_column(spec.func, types_of, [carry[_SLOTS * k:_SLOTS * (k + 1)]])
                        for k, (spec, types_of) in enumerate(zip(specs, typed))]
     terms, spec_term = _terms(specs, per_file)
-    partials, layouts = _launch_grouped(preds, bounds, terms, keys, per_file,
-                                        data_path, rows)
+    partials, layouts, program = _launch_grouped(preds, bounds, terms, keys,
+                                                 per_file, data_path, rows)
     with telemetry.record_operation("delta.scan.deviceAggregate.groups") as gev:
         order, slots_of = _merge_groups(partials, per_file, layouts, keys,
                                         terms, spec_term, specs)
@@ -946,5 +1260,5 @@ def _device_aggregate(snapshot, filters, parsed_items, group_by, order_keys, ev)
                 names.append(key_names[c])
                 columns.append(key_arrays[c])
         gev.data.update(groups=len(order), files=len(per_file))
-    ev.data.update(groups=len(order), groupColumns=list(keys))
+    ev.data.update(groups=len(order), groupColumns=list(keys), program=program)
     return names, columns

@@ -160,15 +160,19 @@ def test_a_fresh_delta_compiles_nothing(lineitem_table):
     assert moved == 4 * (4 * 8 * 12 * 8 + 16)
 
 
-def test_the_ungrouped_program_keeps_its_name():
+@pytest.mark.parametrize("program", ["wide", "tiled"])
+def test_the_ungrouped_program_keeps_its_name(program):
     """`agg_roofline` reads `jit_filter_aggregate`, `group_agg_roofline`
-    `jit_filter_group_aggregate`: the trace tells the two apart."""
+    `jit_filter_group_aggregate`: the trace tells the two apart, and both
+    formulations of the grouped program are the second."""
     spec = column_aggregate.AggregateSpec("sum", (column_aggregate.Factor("a"),))
     assert column_aggregate._aggregate_kernel((), (spec,)).__name__ \
         == "filter_aggregate"
     term = column_aggregate._Term(spec.factors, ("sum",), 1, 2)
     assert column_aggregate._group_kernel((), (term,), ("k",), 8).__name__ \
         == "filter_group_aggregate"
+    assert column_aggregate._group_kernel((), (term,), ("k",), 8, program) \
+        .__name__ == "filter_group_aggregate"
 
 
 # -- a table made to be awkward ---------------------------------------------------------
@@ -381,6 +385,320 @@ def test_terms_take_their_widths_from_the_lanes_extremes():
         (("sum",), 2, 4), (("sum",), 2, 5), ((), 0, 1)]
     assert column_aggregate._magnitude(lanes.ranges, (price, less, plus)) == [
         10_494_950, 1_049_495_000, 113_345_460_000]
+
+
+# -- the two formulations of the grouped program ---------------------------------------
+
+
+I32 = 2**31 - 1
+F, S = column_aggregate.Factor, column_aggregate.AggregateSpec
+
+
+def _lane(rng, cap, lo, hi, nulls=0.0, dtype=np.int64, ends=()):
+    """A lane of ``cap`` values uniform in [lo, hi], the first of them
+    ``ends`` (the extremes a rule is tested at), NULL (its slot 0, a key's
+    code -1 as the column cache writes them) with probability ``nulls``."""
+    values = rng.integers(lo, hi + 1, cap).astype(dtype)
+    values[:len(ends)] = ends
+    ok = rng.random(cap) >= nulls
+    ok[:len(ends)] = True
+    return np.where(ok, values, 0).astype(dtype), ok
+
+
+def _case(name):
+    """``(lanes, n, keep, preds, bounds, keys, layout, specs, tile,
+    tiled)``: hand-made lanes of one file for a case of the parametrised
+    test below, the registers a step of the tile kernel is to take, and
+    whether `_fits_tiles` has to say ``tiled``."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    cap, n, tile, keep, tiled = 4096, 3_000, 2, None, True
+    keys, sizes = ("k1", "k2"), (3, 2)
+    null_keys = (0.05, 0.0)
+    preds, bounds = ("d",), [(10_000, 10_400)]
+    specs = [S("sum", (F("a"),)), S("avg", (F("a"), F("b", -1, 100))),
+             S("sum", (F("a"), F("b", -1, 100), F("c", 1, 100))),
+             S("count", (F("b"),)), S("count", ())]
+    ranges = {"a": (90_000, 10_494_950), "b": (0, 10), "c": (0, 8)}
+    nulls = {"a": 0.0, "b": 0.0, "c": 0.0}
+    ends = {}
+    if name == "slots_16":
+        sizes, null_keys = (3, 3), (0.05, 0.05)
+    elif name == "slots_32":
+        sizes, null_keys = (3, 7), (0.05, 0.05)
+    elif name == "the_real_tile":
+        cap, n, tile = 65_536, 40_001, 32  # two steps, the second part empty
+    elif name == "a_small_file":
+        cap, n = 64, 50                     # below one register: padded
+    elif name == "a_deletion_vector":
+        keep = rng.random(cap) > 0.2
+    elif name == "nulls_in_a_key_and_a_factor":
+        null_keys, nulls = (0.3, 0.0), {"a": 0.1, "b": 0.2, "c": 0.3}
+    elif name == "negative_values":
+        ranges = {"a": (-10_494_950, 10_494_950), "b": (-50, 50), "c": (-300, 50)}
+    elif name == "a_product_of_int32_max":
+        specs = [S("sum", (F("a"), F("c"))), S("sum", (F("a"),))]
+        ranges = {"a": (-I32, I32), "c": (-32_767, 32_767)}
+        ends = {"a": (I32, I32, -I32, -I32), "c": (32_767, -32_767, 32_767, -32_767)}
+    elif name == "a_last_factor_of_32768":
+        specs = [S("sum", (F("a"), F("c"))), S("sum", (F("a"),))]
+        ranges = {"a": (-I32, I32), "c": (-32_767, 32_768)}
+        ends = {"a": (I32, -I32), "c": (32_768, 32_768)}
+        tiled = False
+    elif name == "a_third_factor_at_the_edge":
+        specs = [S("sum", (F("a"), F("b"), F("c", -1, 32_767)))]
+        ranges = {"a": (0, 65_535), "b": (0, 32_767), "c": (0, 65_534)}
+        ends = {"a": (65_535, 65_535), "b": (32_767, 32_767), "c": (0, 65_534)}
+    elif name == "a_second_step_past_int32":
+        specs = [S("sum", (F("a"), F("b"), F("c")))]
+        ranges = {"a": (0, 10_494_950), "b": (0, 1_000), "c": (0, 8)}
+        tiled = False
+    elif name == "a_lane_past_int32":
+        specs = [S("sum", (F("a"),)), S("count", ())]
+        ranges = {"a": (0, 2**40), "b": (0, 1), "c": (0, 1)}
+        tiled = False
+    elif name == "every_limb_0":
+        ranges, sizes, null_keys = {"a": (0, 0), "b": (100, 100), "c": (0, 0)}, (1, 1), (0, 0)
+        n, preds, bounds = cap, (), []
+    elif name == "every_limb_255":
+        specs = [S("sum", (F("a"),)), S("sum", (F("b"),)), S("sum", (F("a"), F("c")))]
+        ranges, sizes, null_keys = {"a": (-1, -1), "b": (I32, I32), "c": (1, 1)}, (1, 1), (0, 0)
+        n, preds, bounds = cap, (), []
+    elif name == "min_and_max":
+        specs = [S("min", (F("a"),)), S("max", (F("a"), F("b", -1, 100))),
+                 S("sum", (F("a"),)), S("min", (F("a"), F("b", -1, 100), F("c", 1, 100)))]
+        nulls = {"a": 0.1, "b": 0.0, "c": 0.1}
+    elif name == "bounds_past_int32":
+        bounds = [(-2**63, 2**40)]
+    elif name == "a_bound_no_row_meets":
+        bounds = [(2**31, 2**63 - 1)]
+    elif name == "no_predicate":
+        preds, bounds = (), []
+    else:
+        assert name == "slots_8", name
+    lanes = {c: _lane(rng, cap, *ranges.get(c, (0, 1)), nulls[c],
+                      ends=ends.get(c, ())) for c in ("a", "b", "c")}
+    lanes["d"] = _lane(rng, cap, 9_900, 10_500, 0.02, np.int32)
+    layout, stride = [], 1
+    for c, size, null in zip(keys, sizes, null_keys):
+        # a string lane's codes: int32, a NULL's -1; the other key an integer
+        values, ok = _lane(rng, cap, 0, size - 1, null,
+                           np.int32 if c == "k1" else np.int64)
+        lanes[c] = (np.where(ok, values, -1 if c == "k1" else 0), ok)
+        layout.append((0, size, stride))
+        stride *= size + bool(null)
+    return (lanes, n, keep, preds, bounds, keys, tuple(layout), tuple(specs),
+            tile, tiled)
+
+
+def _python_group_by(lanes, n, keep, preds, bounds, keys, layout, terms, slots):
+    """The partials of one file in Python integers, a row at a time."""
+    where = column_aggregate._term_columns(terms)
+    width = 1 + sum(len(c) for c in where)
+    out = [[0] * width for _ in range(slots)]
+    for row in out:
+        for t, at in zip(terms, where):
+            if "min" in at:
+                row[at["min"]] = column_aggregate._I64_MAX
+            if "max" in at:
+                row[at["max"]] = column_aggregate._I64_MIN
+    cell = {c: (v.tolist(), ok.tolist()) for c, (v, ok) in lanes.items()}
+    for r in range(n):
+        if keep is not None and not keep[r]:
+            continue
+        if not all(cell[c][1][r] and lo <= cell[c][0][r] <= hi
+                   for c, (lo, hi) in zip(preds, bounds)):
+            continue
+        g = sum((cell[c][0][r] - lo if cell[c][1][r] else size) * stride
+                for c, (lo, size, stride) in zip(keys, layout))
+        out[g][0] += 1
+        for t, at in zip(terms, where):
+            if not all(cell[f.column][1][r] for f in t.factors):
+                continue
+            out[g][at["count"]] += 1
+            x = 1
+            for f in t.factors:
+                x *= f.offset + f.sign * cell[f.column][0][r]
+            if "sum" in at:
+                out[g][at["sum"]] += x
+            if "min" in at:
+                out[g][at["min"]] = min(out[g][at["min"]], x)
+            if "max" in at:
+                out[g][at["max"]] = max(out[g][at["max"]], x)
+    return out
+
+
+CASES = ["slots_8", "slots_16", "slots_32", "the_real_tile", "a_small_file",
+         "a_deletion_vector", "nulls_in_a_key_and_a_factor", "negative_values",
+         "a_product_of_int32_max", "a_last_factor_of_32768",
+         "a_third_factor_at_the_edge", "a_second_step_past_int32",
+         "a_lane_past_int32", "every_limb_0", "every_limb_255", "min_and_max",
+         "bounds_past_int32", "a_bound_no_row_meets", "no_predicate"]
+
+
+@pytest.mark.parametrize("program", ["tiled", "wide"])
+@pytest.mark.parametrize("name", CASES)
+def test_each_program_is_a_group_by_in_python_integers(name, program, monkeypatch):
+    """Both formulations of `filter_group_aggregate` against a plain group-by
+    in Python integers on the same lanes: 8, 16 and 32 slots, a row count
+    that is no multiple of the tile and below the padded capacity, a deletion
+    vector, NULLs in a key and in a factor, negative values, factors at the
+    edges of the rule that chooses between the two (`_fits_tiles`), every
+    limb at its least and at its largest for a whole tile, bounds beyond
+    int32. Where the rule says ``wide`` the tile kernel is not run: it would
+    be wrong there, which is what the rule is for."""
+    import jax.numpy as jnp
+
+    from delta_tpu.utils.jaxcompat import enable_x64
+
+    (lanes, n, keep, preds, bounds, keys, layout, specs, tile,
+     tiled) = _case(name)
+    f = column_aggregate._FileLanes(
+        None, lanes, n,
+        {c: (int(v.min()), int(v.max())) for c, (v, _ok) in lanes.items()}, {})
+    terms, _spec_term = column_aggregate._terms(specs, [f])
+    assert column_aggregate._fits_tiles(terms, [f], sorted(lanes)) is tiled
+    if program == "tiled" and not tiled:
+        return
+    slots = -(-(layout[-1][2] * (layout[-1][1] + 1)) // 8) * 8
+    want = _python_group_by(lanes, n, keep, preds, bounds, keys, layout, terms,
+                            min(slots, 32))
+    slots = len(want)
+    monkeypatch.setattr(column_aggregate, "_TILE_VREGS", tile)
+    column_aggregate._group_kernel.cache_clear()
+    with enable_x64():
+        kernel = column_aggregate._group_kernel(preds, terms, keys, slots, program)
+        got = kernel({c: (jnp.asarray(v), jnp.asarray(ok))
+                      for c, (v, ok) in lanes.items()},
+                     jnp.asarray(np.array(bounds, np.int64).reshape(-1, 2)),
+                     jnp.asarray(np.int32(n)),
+                     None if keep is None else jnp.asarray(keep),
+                     jnp.asarray(np.array(layout, np.int64)),
+                     jnp.asarray(np.int32(1)),
+                     jnp.zeros((3, slots, len(want[0])), jnp.int64))
+        got = np.asarray(got)
+    column_aggregate._group_kernel.cache_clear()
+    assert got.dtype == np.int64 and not got[0].any() and not got[2].any()
+    assert got[1].tolist() == want
+    assert sum(row[0] for row in want) > 0 or name == "a_bound_no_row_meets"
+
+
+def test_the_rule_takes_q1_at_sf10s_extremes_and_the_span_says_so(lineitem_table):
+    """Every term of Q1 meets `_fits_tiles` at scale factor 10's extremes
+    (the last step of ``sum_charge`` is the one split), and a Q1 through the
+    SQL surface says which program answered: `program` on the stage's span
+    and on the query's, `scan.aggregate.grouped.tiled` beside `.grouped`."""
+    price, less, plus = F("p"), F("d", -1, 100), F("t", 1, 100)
+    sf10 = column_aggregate._FileLanes(
+        None, {"p": (np.zeros(4_194_304, np.int8),)}, 4_000_000,
+        {"p": (90_100, 10_494_950), "d": (0, 10), "t": (0, 8), "q": (100, 5_000),
+         "s": (8_036, 10_561), "f": (0, 2), "l": (0, 1)}, {})
+    specs = (S("sum", (F("q"),)), S("sum", (price,)), S("sum", (price, less)),
+             S("sum", (price, less, plus)), S("avg", (F("d"),)), S("count", ()))
+    terms, _ = column_aggregate._terms(specs, [sf10])
+    assert column_aggregate._fits_tiles(terms, [sf10], list("pdtqsfl"))
+    assert [t.wide_at < len(t.factors) for t in terms] == [
+        False, False, False, True, False, False]
+    wider = sf10._replace(ranges=dict(sf10.ranges, t=(0, 32_668)))
+    terms, _ = column_aggregate._terms(specs, [wider])
+    assert not column_aggregate._fits_tiles(terms, [wider], list("pdtqsfl"))
+
+    path, _base = lineitem_table
+    telemetry.clear_events()
+    c0 = dict(telemetry.counters())
+    with conf.set_temporarily(**FORCE):
+        execute_sql(Q1.format(path=path, delta=90))
+    (query,) = _spans()
+    (stage,) = telemetry.recent_events("delta.columnCache.aggregate")
+    assert query["program"] == stage.data["program"] == "tiled"
+    c1 = telemetry.counters()
+    for name in ("scan.aggregate.grouped", "scan.aggregate.grouped.tiled"):
+        assert c1[name] - c0.get(name, 0) == 1
+
+
+@pytest.mark.parametrize("select_list,program", [
+    ("line, sum(price * (1 - disc) * (1 + tax)) as charge, count(*) as n", "tiled"),
+    ("line, sum(big) as s, count(*) as n", "wide"),           # a lane past int32
+    ("line, sum(price * qty * wide) as s", "wide"),           # int64 at the second step
+    ("line, sum(wide * (33000 - wide)) as s, min(big) as lo", "wide"),
+    ("line, min(price * (1 + tax)) as lo, sum(qty) as s", "tiled"),
+])
+def test_a_query_outside_the_rule_is_the_hosts_from_the_wide_program(
+        awkward, select_list, program):
+    """The same table from whichever formulation the widths select: the rule
+    reads the lanes' extremes, and a query it sends to ``wide`` equals the
+    host's as one it sends to ``tiled`` does."""
+    path, _log = awkward
+    c0 = dict(telemetry.counters())
+    _both(f"select {select_list} from delta.`{path}` "
+          f"where ship <= date '1998-11-20' group by line order by line")
+    data = _spans()[0]  # the device route's; the host's follows it
+    assert data["program"] == program
+    moved = telemetry.counters().get("scan.aggregate.grouped.tiled", 0) \
+        - c0.get("scan.aggregate.grouped.tiled", 0)
+    assert moved == (program == "tiled")
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """A described TPU v5e, not an attached one: its compiler is installed
+    wherever the tests run."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("slots,keep", [(8, False), (32, True)])
+def test_the_tile_kernel_compiles_for_the_chip_at_a_files_shape(
+        one_v5e, monkeypatch, slots, keep):
+    """What the interpreter cannot show: the chip's compiler takes the tile
+    kernel as written (an int8 contraction along lanes, 32-bit words recast
+    as int8 rows, only 32-bit integers under x64) at 4,194,304 rows, and the
+    launch is one module with one kernel in it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from delta_tpu.utils.jaxcompat import enable_x64
+
+    specs = (S("sum", (F("q"),)), S("sum", (F("p"),)),
+             S("sum", (F("p"), F("d", -1, 100))),
+             S("sum", (F("p"), F("d", -1, 100), F("t", 1, 100))),
+             S("avg", (F("d"),)), S("count", ()))
+    sf10 = column_aggregate._FileLanes(
+        None, {}, 4_000_000, {"p": (90_100, 10_494_950), "d": (0, 10), "t": (0, 8),
+                              "q": (100, 5_000)}, {})
+    terms, _ = column_aggregate._terms(specs, [sf10])
+    cap = 4_194_304
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+
+    monkeypatch.setattr(column_aggregate, "_off_chip", lambda: False)
+    column_aggregate._group_kernel.cache_clear()
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()  # a described chip's executable is no use
+    try:
+        with enable_x64():
+            kernel = column_aggregate._group_kernel(("s",), terms, ("f", "l"),
+                                                    slots, "tiled")
+            lanes = {c: (shape((cap,), jnp.int32 if c in "sfl" else jnp.int64),
+                         shape((cap,), jnp.bool_)) for c in "sflqpdt"}
+            text = kernel.lower(
+                lanes, shape((1, 2), jnp.int64), shape((), jnp.int32),
+                shape((cap,), jnp.bool_) if keep else None,
+                shape((2, 3), jnp.int64), shape((), jnp.int32),
+                shape((15, slots, 12), jnp.int64)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+        column_aggregate._group_kernel.cache_clear()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "jit_filter_group_aggregate" in text
 
 
 # -- the benchmark's readers find what they read ------------------------------------------

@@ -8,7 +8,8 @@ standard error: the mean milliseconds a request of every span of the program
 (and how many of them a request opened), each MERGE's route, what the
 resident probe's spans and counters say of how widely it engaged, every
 re-sort of the slab and how many inverse permutations were built, the routes
-and group counts of the aggregate queries, and the device's time in the
+and group counts of the aggregate queries with the program that answered the
+grouped ones (``tiled`` or ``wide``) and the tiled share, and the device's time in the
 window by XLA module, each module with its longest operations and the
 arguments its operations name. A builder's instrument for
 PERF.md; nothing of the benchmark reads it.
@@ -62,12 +63,20 @@ def report(run) -> None:
     if aggregates:
         # grouped queries also open delta.scan.deviceAggregate.groups (the
         # merge of the files' partials by value), whose mean is above
+        grouped = run.counters.get("scan.aggregate.grouped", 0)
+        tiled = run.counters.get("scan.aggregate.grouped.tiled", 0)
         print("aggregate routes:", json.dumps(collections.Counter(
             d.get("route") for d in aggregates)), "groups a query:",
             json.dumps(collections.Counter(
                 str(d.get("groups")) for d in aggregates)),
-            "grouped, in the window:", run.counters.get(
-                "scan.aggregate.grouped", 0), file=sys.stderr)
+            "grouped, in the window:", grouped,
+            # which formulation of the grouped program answered (PR 33)
+            "programs:", json.dumps(collections.Counter(
+                str(d.get("program"))
+                for d in span_data("delta.columnCache.aggregate"))),
+            "tiled share of grouped, %:",
+            round(100 * tiled / grouped, 2) if grouped else None,
+            file=sys.stderr)
     if run.trace is not None:
         print("device ms a request by module:",
               json.dumps(module_split(run.trace, len(done))), file=sys.stderr)
