@@ -338,14 +338,28 @@ class MergeIntoCommand:
     # -- main -------------------------------------------------------------
 
     def run(self) -> int:
-        with telemetry.record_operation("delta.dml.merge",
-                                        path=self.delta_log.data_path):
+        # the statement's shape on its root span, so that a trace tells an
+        # insert-only MERGE from a keyed delete from an upsert
+        kinds = [c.kind for c in self.matched_clauses + self.not_matched_clauses]
+        if not self.matched_clauses:
+            telemetry.bump_counter("merge.clause.insertOnly")
+        if "delete" in kinds:
+            telemetry.bump_counter("merge.clause.delete")
+        with telemetry.record_operation(
+                "delta.dml.merge",
+                {"clauses": ",".join(kinds), "sourceRows": self.source.num_rows},
+                path=self.delta_log.data_path) as ev:
             try:
-                return self.delta_log.with_new_transaction(self._body)
+                version = self.delta_log.with_new_transaction(self._body)
             except _StaleResidentSlab:
                 # nothing was committed and the entry is gone: this time the
                 # join decodes the files and the slab is built anew
-                return self.delta_log.with_new_transaction(self._body)
+                version = self.delta_log.with_new_transaction(self._body)
+            ev.data.update(
+                inserted=self.metrics.get("numTargetRowsInserted", 0),
+                updated=self.metrics.get("numTargetRowsUpdated", 0),
+                deleted=self.metrics.get("numTargetRowsDeleted", 0))
+            return version
 
     @property
     def _pairs_only(self) -> bool:
@@ -730,10 +744,11 @@ class MergeIntoCommand:
         resident = None
         resident_tried = False
         whole_table = True
-        if (shape_eligible and candidates
-                and self._pairs_only_shape(equi, read_cols, key_need, pos_col,
-                                           insert_only)
-                and self._resident_entry_cached(txn, equi)):
+        pairs_shape = bool(
+            shape_eligible and candidates
+            and self._pairs_only_shape(equi, read_cols, key_need, pos_col,
+                                       insert_only))
+        if pairs_shape and self._resident_entry_cached(txn, equi):
             resident_tried = True
             with self._phase("delta.dml.merge.keyDecode", "key_decode_ms"):
                 resident = self._launch_resident_probe(
@@ -759,7 +774,8 @@ class MergeIntoCommand:
             # a designed decline: decode late and take the path below, the
             # probe still in hand
             telemetry.bump_counter("merge.resident.pairsOnly.declined")
-        elif equi:
+        elif equi and not (pairs_shape
+                           and self._builds_table_slab(candidates, src, mode)):
             # distributed findTouchedFiles probe: restrict the candidates to
             # files whose equi keys intersect the source BEFORE the join
             # decodes full rows (conf-gated; result-identical — see the method)
@@ -778,40 +794,11 @@ class MergeIntoCommand:
             # device alternative (no hindsight miss against a route that
             # could not have run)
             self._audit_eligible = base_eligible
-            if device_eligible and mode == "auto":
-                # pre-decode routing check from AddFile stats row counts: on a
-                # slow link even the *optimistic* plan (int32 keys) loses to the
-                # host hash join — skip the early key decode entirely then.
-                # This is the COLD price (slab upload + sort + probe); the
-                # cache-hit case was already evaluated above with its own,
-                # upload-free economics.
-                n_est = _rows_from_stats(candidates)
-                if n_est is not None:
-                    import jax
-
-                    from delta_tpu.parallel import link
-
-                    rows = n_est + src.num_rows
-                    if not (len(jax.devices()) > 1 and conf.get_bool(
-                            "delta.tpu.merge.devicePath.preferMesh", False)):
-                        device_s = link.cold_merge_device_s(
-                            n_est, src.num_rows, link.profile())
-                    else:
-                        device_s = link.estimate_device_s(
-                            up_bytes=rows * 4,
-                            down_bytes=rows // 8,
-                            kernel_rows=rows,
-                            shards=len(jax.devices()),
-                        ).device_s
-                    host_est_s = rows * link.constant("HOST_JOIN_S_PER_ROW")
-                    self._router.setdefault("deviceEstS", round(device_s, 3))
-                    self._router.setdefault("hostEstS", round(host_est_s, 3))
-                    if device_s > host_est_s:
-                        device_eligible = False
-                        from delta_tpu.utils.telemetry import bump_counter
-
-                        bump_counter("merge.device.declined")
-                        self._router.update(reason="cold-estimate")
+            if (device_eligible and mode == "auto"
+                    and self._cold_estimate_declines(candidates, src)):
+                device_eligible = False
+                telemetry.bump_counter("merge.device.declined")
+                self._router.update(reason="cold-estimate")
 
             # row-group skipping is only safe when unmatched target rows never
             # need writing back: DV mode (matched rows mark by physical
@@ -837,14 +824,7 @@ class MergeIntoCommand:
                 if resident is not None:
                     via = "resident"
             if resident is None and device_eligible:
-                import jax
-
-                prefer_mesh = (
-                    len(jax.devices()) > 1
-                    and conf.get_bool("delta.tpu.merge.devicePath.preferMesh",
-                                      False)
-                )
-                if not prefer_mesh:
+                if not self._prefers_mesh():
                     # fused cold pipeline: per-file key decode streams into a
                     # pre-sized HBM slab (upload overlaps decode), then the
                     # resident probe joins + pairs on device — and the
@@ -1108,6 +1088,61 @@ class MergeIntoCommand:
         if read_cols is None or not {c.lower() for c in read_cols} <= key_need:
             return False
         return all(isinstance(t_e, ir.Column) for t_e, _ in equi)
+
+    @staticmethod
+    def _prefers_mesh() -> bool:
+        """The multichip all-gather join is asked for (opt-in) and there is
+        more than one device to run it on: no slab is built then."""
+        import jax
+
+        return len(jax.devices()) > 1 and conf.get_bool(
+            "delta.tpu.merge.devicePath.preferMesh", False)
+
+    def _cold_estimate_declines(self, candidates, src) -> bool:
+        """``mode=auto``'s pre-decode routing check from the AddFile stats'
+        row counts: on a slow link even the *optimistic* cold plan (slab
+        upload, sort, probe over int32 keys) loses to the host hash join,
+        and the early key decode is skipped. The cache-hit case has its own,
+        upload-free economics (`_launch_resident_probe`). Both estimates go
+        on the router event; no stats, no verdict."""
+        n_est = _rows_from_stats(candidates)
+        if n_est is None:
+            return False
+        import jax
+
+        from delta_tpu.parallel import link
+
+        rows = n_est + src.num_rows
+        if not self._prefers_mesh():
+            device_s = link.cold_merge_device_s(
+                n_est, src.num_rows, link.profile())
+        else:
+            device_s = link.estimate_device_s(
+                up_bytes=rows * 4,
+                down_bytes=rows // 8,
+                kernel_rows=rows,
+                shards=len(jax.devices()),
+            ).device_s
+        host_est_s = rows * link.constant("HOST_JOIN_S_PER_ROW")
+        self._router.setdefault("deviceEstS", round(device_s, 3))
+        self._router.setdefault("hostEstS", round(host_est_s, 3))
+        return device_s > host_est_s
+
+    def _builds_table_slab(self, candidates, src, mode: str) -> bool:
+        """Whether a MERGE that is pairs-only in shape and finds no slab to
+        serve it will build the table's now (`_launch_slab_pipeline` over
+        every file). The touched-files pre-probe is then not run: it reads
+        the key columns the build reads, nothing else of a row is decoded
+        for it to spare, and a slab over the files it leaves is this
+        MERGE's and not the table's (never registered), so that a small
+        source into a table of many files (a refresh function, a trickle of
+        CDC) would find no slab at its next MERGE either."""
+        from delta_tpu.ops.key_cache import key_cache_enabled
+
+        if not key_cache_enabled() or self._prefers_mesh():
+            return False
+        return mode == "force" or not self._cold_estimate_declines(
+            candidates, src)
 
     def _resident_entry_cached(self, txn, equi) -> bool:
         """A slab for this table and key signature is in the key cache

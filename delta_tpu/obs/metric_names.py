@@ -119,6 +119,8 @@ COUNTERS = frozenset({
     "merge.device.declined",      # link cost model chose the host
     "merge.device.fallback",      # device path raised; host join took over
     "merge.device.cacheHit",      # engaged from an HBM-resident key lane
+    "merge.clause.insertOnly",    # MERGEs with no WHEN MATCHED clause
+    "merge.clause.delete",        # MERGEs with a WHEN MATCHED THEN DELETE clause
     "merge.resident.pairsOnly",   # the resident probe's pairs were the join
     "merge.resident.pairsOnly.declined",  # engaged, then decoded after all
     "merge.resident.probe.overflow",  # candidate rows past the pair scratch
@@ -400,6 +402,8 @@ DESCRIPTIONS = {
     "merge.device.declined": "MERGEs where the cost model chose the host join.",
     "merge.device.fallback": "MERGEs (mode=auto) whose device path raised and fell back to the host join.",
     "merge.device.cacheHit": "Device MERGEs served from an HBM-resident key lane.",
+    "merge.clause.insertOnly": "MERGE statements with no WHEN MATCHED clause (the de-duplicating insert; the join fetches no pair).",
+    "merge.clause.delete": "MERGE statements with a WHEN MATCHED THEN DELETE clause.",
     "merge.resident.pairsOnly": "Resident MERGEs that took the pairs-only route: no touched-files pre-probe, no decode of the target.",
     "merge.resident.pairsOnly.declined": "Pairs-only MERGEs that decoded the target after all (probe overflow, a slab that disagrees with the snapshot).",
     "merge.resident.probe.overflow": "Resident probes declined to the host join because the matched keys' candidate slab rows (dead versions, duplicate target keys) pass the pair kernel's scratch bound.",

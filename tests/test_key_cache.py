@@ -947,3 +947,49 @@ def test_hbm_budget_pressure_evicts_lru_first():
     cache._evict(keep=None)
     assert entries[0].is_resident
     hbm_ledger.reset()
+
+
+def test_an_append_writes_the_mirrors_in_place_and_a_regrow_copies_them():
+    """The host mirrors are as long as the slab: a file's keys go into the
+    room the backing arrays have (no copy of what is there), the arrays are
+    replaced when they are outgrown, and mirrors set from outside (as tests
+    do) are taken over at the next append."""
+    from delta_tpu.ops.key_cache import ResidentJoinKeys, _slab_capacity
+
+    e = ResidentJoinKeys("/mirror-log", "mid", 0, "sig", ["k"])
+    e.capacity = _slab_capacity(3000)
+    want_k, want_v = [], []
+    rng = np.random.default_rng(4)
+    bases = set()
+    for i in range(5):
+        keys = rng.integers(-50, 50, 700)
+        valid = rng.random(700) < 0.9
+        assert e._append_file(f"f{i}", keys, valid)
+        want_k.append(keys)
+        want_v.append(valid)
+        bases.add(e.h_keys.base.ctypes.data)
+        assert len(e.h_keys) == len(e.h_valid) == len(e.h_nullok) == e.num_rows
+        assert np.array_equal(e.h_keys, np.concatenate(want_k))
+        assert np.array_equal(e.h_valid, np.concatenate(want_v))
+        assert np.array_equal(e.h_nullok, np.concatenate(want_v))
+    # 3,500 rows are inside the capacity of 4,096: one backing array so far
+    assert e.num_rows == 3500 and len(bases) == 1
+    assert len(e.h_keys.base) >= e.capacity
+    # a vector flips validity through the view, in the backing array
+    assert e._set_dv("f1", np.array([0, 5]))
+    want_v[1] = want_v[1].copy()
+    want_v[1][[0, 5]] = False
+    assert np.array_equal(e.h_valid, np.concatenate(want_v))
+    # outgrown: the capacity steps, the mirrors move once, nothing is lost
+    assert e._append_file("big", np.arange(2000), np.ones(2000, bool))
+    assert e.capacity > 4096 and e.h_keys.base.ctypes.data not in bases
+    assert np.array_equal(e.h_keys[:3500], np.concatenate(want_k))
+    assert np.array_equal(e.h_keys[3500:], np.arange(2000))
+    # mirrors assigned from outside have no room behind them
+    e2 = ResidentJoinKeys("/mirror-log-2", "mid", 0, "sig", ["k"])
+    e2.h_keys, e2.h_valid, e2.h_nullok = (np.arange(10, dtype=np.int64),
+                                          np.ones(10, bool), np.ones(10, bool))
+    e2.num_rows = 10
+    assert e2._append_file("g", np.array([7, 8]), np.array([True, False]))
+    assert e2.h_keys.tolist() == list(range(10)) + [7, 8]
+    assert e2.h_valid.tolist() == [True] * 11 + [False]

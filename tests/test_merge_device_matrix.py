@@ -21,6 +21,7 @@ from delta_tpu.commands.merge import MergeClause, MergeIntoCommand
 from delta_tpu.commands.write import WriteIntoDelta
 from delta_tpu.expr import ir
 from delta_tpu.ops.key_cache import KeyCache
+from delta_tpu.utils import telemetry
 from delta_tpu.utils.config import conf
 from delta_tpu.utils.errors import DeltaUnsupportedOperationError
 
@@ -619,14 +620,28 @@ def test_pairs_only_stale_slab_does_not_commit(tmp_path, stale):
     _same_outcome(log_a, log_b, cmd_a, cmd_b, order=False)
 
 
-def test_a_slab_built_over_touched_files_only_is_not_the_tables(tmp_path):
+@pytest.mark.parametrize("update", ["assignments", "star"])
+def test_a_slab_built_over_touched_files_only_is_not_the_tables(tmp_path,
+                                                                update):
     """The touched-files pre-probe narrows a cold build to the files the
     source touches; registered as the table's, that slab answered a later
-    insert-only MERGE for files it had never seen (duplicate rows)."""
+    insert-only MERGE for files it had never seen (duplicate rows). A
+    MERGE that is pairs-only in shape (the star upsert) is not narrowed:
+    it builds the slab over every file, and that one is the table's."""
     log_a, log_b = _dv_tables(tmp_path)
-    _run(log_a, _source([10, 11]), "t.k = s.k", [UP], [INS], "force")
-    _run(log_b, _source([10, 11]), "t.k = s.k", [UP], [INS], "off")
-    assert not KeyCache.instance()._entries
+    up = UP if update == "star" else MergeClause(
+        "update", assignments={"v": "s.v", "tag": "s.tag"})
+    telemetry.clear_events()
+    _run(log_a, _source([10, 11]), "t.k = s.k", [up], [INS], "force")
+    _run(log_b, _source([10, 11]), "t.k = s.k", [up], [INS], "off")
+    entries = KeyCache.instance()._entries
+    probes = [e for e in telemetry.recent_events("delta.dist.mergeProbe")]
+    if update == "star":
+        assert len(probes) == 1  # the host run's; the device run built
+        (slab,) = entries.values()
+        assert len(slab.slabs) == 8 and slab.num_rows == 800
+    else:
+        assert len(probes) == 2 and not entries
     again = _source([510, 511, 900])
     cmd_a = _run(log_a, again, "t.k = s.k", [], [INS], "force")
     cmd_b = _run(log_b, again, "t.k = s.k", [], [INS], "off")

@@ -791,9 +791,10 @@ def _keyed_table(path, rows=8000, files=8):
             configuration={"delta.tpu.enableDeletionVectors": "true"})
 
 
-def _upsert(table, lo, hi, step=1):
+def _upsert(table, lo, hi, step=1, assignments=None):
     """MERGE every ``step``-th ``k`` in [lo, hi) into the table through the
-    command the public builder runs; returns the command (for ``phase_ms``)."""
+    command the public builder runs; returns the command (for ``phase_ms``).
+    A star update, or ``assignments``."""
     import numpy as np
 
     from delta_tpu.commands.merge import MergeClause, MergeIntoCommand
@@ -805,7 +806,7 @@ def _upsert(table, lo, hi, step=1):
                     "q": pa.array(np.zeros(n, np.int32))})
     cmd = MergeIntoCommand(
         table.delta_log, src, "t.k = s.k",
-        [MergeClause("update", assignments=None)],
+        [MergeClause("update", assignments=assignments)],
         [MergeClause("insert", assignments=None)],
         source_alias="s", target_alias="t")
     cmd.run()
@@ -824,33 +825,39 @@ def _fresh_device_caches():
     ColumnCache.reset()
 
 
-@pytest.mark.parametrize("resident", [False, True], ids=["cold", "resident"])
+@pytest.mark.parametrize("case", ["cold", "resident", "assigned"])
 def test_merge_phases_are_child_spans_that_fill_phase_ms(
-        tmp_path, _fresh_device_caches, resident):
+        tmp_path, _fresh_device_caches, case):
+    resident = case == "resident"
     t = _keyed_table(tmp_path / "t")
     with conf.set_temporarily(**DEVICE):
         if resident:
-            # a source in every file: the slab is built over the whole table
-            # and registered
-            _upsert(t, 5, 8010, step=500)
+            _upsert(t, 5, 8010, step=500)  # builds and registers the slab
         telemetry.clear_events()
-        cmd = _upsert(t, 7900, 8100)  # half existing keys, half fresh
+        # half existing keys, half fresh; explicit assignments read a target
+        # column, so that statement is not pairs-only in shape
+        cmd = _upsert(t, 7900, 8100, assignments=(
+            {"d": "s.d", "q": "t.q + s.q"} if case == "assigned" else None))
     assert cmd.metrics["numTargetRowsUpdated"] > 0
     events = telemetry.recent_events()
     [root] = [e for e in events if e.op_type == "delta.dml.merge"]
-    # a resident star upsert over deletion vectors takes the pairs-only
-    # route: the probe's pairs are the join, so no touched-files pre-probe
-    # runs and the row decode reads nothing (its span still fills decode_ms)
+    # a star upsert over deletion vectors is pairs-only in shape: resident,
+    # the probe's pairs are the join and the row decode reads nothing (its
+    # span still fills decode_ms); cold, it builds the table's slab over every
+    # file. Neither runs the touched-files pre-probe; a statement that reads
+    # a target column does
+    narrowed = case == "assigned"
     expect = {name: key for name, key in MERGE_PHASES.items()
-              if not (resident and name == "delta.dist.mergeProbe")}
+              if narrowed or name != "delta.dist.mergeProbe"}
     for name, key in expect.items():
         [ev] = [e for e in events if e.op_type == name]  # exactly once
         assert ev.parent_id == root.span_id, name
         assert cmd.phase_ms[key] == ev.duration_us / 1000.0, name
     by_name = {e.op_type: e for e in events}
-    if resident:
+    if not narrowed:
         assert "delta.dist.mergeProbe" not in by_name
         assert "probe_ms" not in cmd.phase_ms
+    if resident:
         assert not any(e.op_type.startswith("delta.scan") for e in events)
         assert by_name["delta.dml.merge.join"].data["route"] == "pairs-only"
     else:
@@ -1018,7 +1025,8 @@ def test_blackout_records_no_span_but_counts_and_times_the_phases(
     after = telemetry.counters("link")
     assert after["link.h2d.bytes"] > before.get("link.h2d.bytes", 0)
     assert after["link.d2h.bytes"] > before.get("link.d2h.bytes", 0)
-    assert set(MERGE_PHASES.values()) <= set(cmd.phase_ms)
+    # a cold star upsert builds the table's slab and runs no pre-probe
+    assert set(MERGE_PHASES.values()) - {"probe_ms"} <= set(cmd.phase_ms)
     assert all(v >= 0 for v in cmd.phase_ms.values())
     assert cmd.phase_ms["join_ms"] > 0
 

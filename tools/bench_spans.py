@@ -5,9 +5,11 @@
 
 The arguments and the result line are `benchmark/run.py`'s. Besides, on
 standard error: the mean milliseconds a request of every span of the program
-(and how many of them a request opened), each MERGE's route, what the
-resident probe's spans and counters say of how widely it engaged, every
-re-sort of the slab and how many inverse permutations were built, the routes
+(and how many of them a request opened), each MERGE's route, the same means a
+statement split by the root span's ``clauses`` (a refresh pair's RF1 and RF2
+apart), what the resident probe's spans and counters say of how widely it
+engaged, every re-sort of the slab and every inverse permutation built with
+the slab's ``rows``, ``inverse`` and ``flips``, the routes
 and group counts of the aggregate queries with the program that answered the
 grouped ones (``tiled`` or ``wide``) and the tiled share, and the device's time in the
 window by XLA module, each module with its longest operations and the
@@ -44,6 +46,11 @@ def report(run) -> None:
     print("span means a request [ms, spans]:", json.dumps(means),
           file=sys.stderr)
     print("merge routes:", json.dumps(routes), file=sys.stderr)
+    split = by_clauses(done)
+    if split:
+        print("span means a statement, by the root span's clauses "
+              "[statements, {span: ms}]:", json.dumps(split), file=sys.stderr)
+
     def span_data(name):
         return [s["data"] for r in done for s in r.spans if s["name"] == name]
 
@@ -57,7 +64,11 @@ def report(run) -> None:
                   "merge.resident.probe.overflow", 0), "inverse builds:",
               telemetry.counters("merge.keyCache").get(
                   "merge.keyCache.inverseBuilds", 0), file=sys.stderr)
+        # what a pair paid the device for: each re-sort (`inverse`: the view
+        # it replaced held an inverse permutation) and each inverse built
+        # (`flips`: the rows whose validity the advance flipped)
         print("slab sorts:", json.dumps(span_data("delta.keyCache.sort")),
+              "slab inverses:", json.dumps(span_data("delta.keyCache.inverse")),
               file=sys.stderr)
     aggregates = span_data("delta.scan.deviceAggregate")
     if aggregates:
@@ -80,6 +91,30 @@ def report(run) -> None:
     if run.trace is not None:
         print("device ms a request by module:",
               json.dumps(module_split(run.trace, len(done))), file=sys.stderr)
+
+
+def by_clauses(done):
+    """Every span's mean milliseconds a statement, the statements told apart
+    by the ``clauses`` their root span carries (``insert``: RF1's
+    de-duplicating insert; ``delete``: RF2's keyed delete; ``update,insert``:
+    the star upsert): a span belongs to the MERGE whose root span was open
+    when it started, on whatever thread. Empty where no request ran a
+    MERGE."""
+    total = collections.defaultdict(collections.Counter)
+    count = collections.Counter()
+    for r in done:
+        for root in r.spans:
+            if root["name"] != "delta.dml.merge" or not root["duration_us"]:
+                continue
+            key = root["data"].get("clauses", "?")
+            count[key] += 1
+            lo, hi = root["start_us"], root["start_us"] + root["duration_us"]
+            for s in r.spans:
+                if s["duration_us"] is not None and lo <= s["start_us"] < hi:
+                    total[key][s["name"]] += s["duration_us"]
+    return {key: [n, {name: round(us / 1e3 / n, 3)
+                      for name, us in total[key].most_common()}]
+            for key, n in count.items()}
 
 
 _NAME = re.compile(r"%([A-Za-z_][A-Za-z_0-9]*?)(?:\.\d+)*(?![\w.\-])")
