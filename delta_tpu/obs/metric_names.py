@@ -128,7 +128,8 @@ COUNTERS = frozenset({
     "merge.keyCache.builds",      # cold key-lane builds (inline or bg)
     "merge.keyCache.advances",    # incremental log-tail applications
     "merge.keyCache.invalidations",  # entries dropped by a rewrite epoch bump
-    "merge.keyCache.inverseBuilds",  # inverse permutations built for a flip
+    "merge.keyCache.flipSearches",  # flips mirrored in a live sorted view
+    "merge.keyCache.flipResorts",  # flips that dropped the view instead
     # -- router audit ledger + calibrator (obs/router_audit, obs/calibration)
     "router.audits",              # one per routed decision recorded
     "router.misses",              # hindsight: rejected route predicted faster
@@ -411,7 +412,8 @@ DESCRIPTIONS = {
     "merge.keyCache.builds": "Cold resident key-lane builds.",
     "merge.keyCache.advances": "Incremental log-tail applications to a key lane.",
     "merge.keyCache.invalidations": "Key-cache entries dropped by a rewrite epoch bump.",
-    "merge.keyCache.inverseBuilds": "Inverse permutations of a sorted key slab built on demand: the first validity flip that found a live sorted view without one (an advance that appends keys builds none).",
+    "merge.keyCache.flipSearches": "Validity flips on a live sorted view of a key slab that were mirrored in sorted space, the rows' sorted positions found by a search of the view (span delta.keyCache.locate). A flip on a stale view, as in an advance that appends keys, counts in neither.",
+    "merge.keyCache.flipResorts": "Validity flips on a live sorted view of more rows than are worth searching for: the flip stayed in row space and dropped the view, and the next probe re-sorted (its delta.keyCache.sort span says cause=flips).",
     "router.audits": "Routed decisions recorded in the audit ledger.",
     "router.misses": "Audits where a rejected route's prediction beat the actual.",
     "router.calibration.updates": "EWMA samples folded into the calibration state.",

@@ -158,18 +158,21 @@ from delta_tpu.ops.join_kernel import PendingJoin as PendingProbe
 
 @functools.lru_cache(maxsize=None)
 def _sort_kernel():
-    """Sort the slab's key lane once per KEY mutation (build/append), NOT
-    per probe: steady-state probes against an unchanged table then skip
-    the O(n log n) term entirely. One sort gives all three arrays a probe
+    """Sort the slab's key lane once per KEY mutation (build/append) or
+    per validity flip too large to search for (`_flip_by_search`), NOT per
+    probe: steady-state probes against an unchanged table then skip the
+    O(n log n) term entirely. One sort gives all three arrays a probe
     reads: the int32 that rides with each key is its physical row with the
     row's validity in the low bit, so the permutation and the sorted-space
     validity are two dense reads of the sorted payload, never a gather
     through the permutation. The payload is the second sort key: it orders
     as the row does, so ties among equal keys stay in physical-row order,
     valid or dead, without the row-id operand a stable sort would add
-    beside it. Padding rows encode as int64.max so they sort to the tail
-    and read invalid; a real key equal to int64.max may share their run —
-    harmless, validity excludes them."""
+    beside it: (sorted_keys, perm) rises strictly, which is
+    how a flip finds a row's sorted position (`_inverse_permutation_at`).
+    Padding rows encode as int64.max so they sort to the tail and read
+    invalid; a real key equal to int64.max may share their run, before
+    them — harmless, validity excludes them."""
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
@@ -188,24 +191,95 @@ def _sort_kernel():
     return kernel
 
 
+# entries of one node of the tree a flip's search descends: 128 is the width
+# at which the slab's 1-D arrays reshape into nodes without a copy (at 256
+# or 512 XLA re-tiles a capacity-sized plane: 229 MiB and 2.2 ms more at
+# 60.8M rows; PERF.md PR 35, call 97)
+_SEARCH_FAN = 128
+
+
+def _search_steps(cap: int) -> int:
+    """Nodes a row's search gathers, one a level of the tree over ``cap``
+    sorted rows below its dense top."""
+    steps = 0
+    while cap > _SEARCH_FAN:
+        cap = -(-cap // _SEARCH_FAN)
+        steps += 1
+    return steps
+
+
 @functools.lru_cache(maxsize=None)
-def _inverse_permutation():
-    """Physical row -> sorted position, from the resident permutation: one
-    capacity-sized scatter, paid by the first validity flip that finds a
-    live sorted view without it (`ResidentJoinKeys._dev_flip_valid`), never
-    by the sort. An advance that appends keys drops the view before its
-    kills run and so never asks."""
+def _inverse_permutation_at():
+    """Physical rows -> sorted positions, by search: what a validity flip
+    on a live sorted view needs of the inverse permutation, and nothing of
+    the capacity's length is built. `_sort_kernel` orders by (encoded key,
+    row), so (sorted_keys[p], perm[p]) rises strictly with p and row r
+    stands where (keys[r], r) does. The search descends a tree whose level
+    i+1 is every `_SEARCH_FAN`-th entry of level i (strided reads of the
+    resident arrays, nothing kept): a dense compare against the top, then
+    one node gathered a level and a count of its entries at or before the
+    row's. A gather costs the chip as much for one element as for a node,
+    so the tree's `_search_steps` gathers a row stand where a binary
+    search would pay 26 (14 ms against 125 for 65,536 rows of 60.8M,
+    PERF.md PR 35). ``rows`` are rows the view was sorted with (below its
+    ``n``: their encoded key is their key); a padding row (>= the
+    capacity) maps to the capacity, so the scatter after it drops it."""
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
 
     @jax.jit
-    def inverse_permutation(perm):
-        cap = perm.shape[0]
-        return jnp.zeros(cap, jnp.int32).at[perm].set(
-            jnp.arange(cap, dtype=jnp.int32))
+    def inverse_permutation_at(sorted_keys, perm, keys, rows):
+        cap = sorted_keys.shape[0]
+        fan = _SEARCH_FAN
+        r = jnp.minimum(rows, cap - 1)
+        k = keys[r]
+        levels = []
+        lk, lp = sorted_keys, perm
+        while lk.shape[0] > fan:
+            # a capacity is a multiple of 1,024: only an upper level pads
+            pad = -lk.shape[0] % fan
+            lk = jnp.pad(lk, (0, pad), constant_values=jnp.iinfo(
+                lk.dtype).max).reshape(-1, fan)
+            lp = jnp.pad(lp, (0, pad), constant_values=jnp.iinfo(
+                lp.dtype).max).reshape(-1, fan)
+            levels.append((lk, lp))
+            lk, lp = lk[:, 0], lp[:, 0]
 
-    return inverse_permutation
+        def last_at_or_before(ek, ep, kc, rc):
+            """In each row of entries, the last one at or before the
+            target (there is one: a node's first entry is)."""
+            before = (ek < kc[:, None]) | (
+                (ek == kc[:, None]) & (ep <= rc[:, None]))
+            return jnp.sum(before, axis=1, dtype=jnp.int32) - 1
+
+        def descend(args):
+            kc, rc = args
+            j = last_at_or_before(lk[None, :], lp[None, :], kc, rc)
+            for ek, ep in reversed(levels):
+                j = j * fan + last_at_or_before(ek[j], ep[j], kc, rc)
+            return j
+
+        # a chunk of rows at a time: 48 MiB of gathered nodes a level
+        chunk = min(r.shape[0], (1 << 22) // fan)
+        pos = jax.lax.map(descend, (k.reshape(-1, chunk),
+                                    r.reshape(-1, chunk))).reshape(-1)
+        return jnp.where(rows < cap, pos, cap)
+
+    return inverse_permutation_at
+
+
+def _flip_by_search(flips: int, cap: int) -> bool:
+    """Whether mirroring ``flips`` validity flips in a live sorted view of
+    ``cap`` rows by search is cheaper than dropping the view, which costs
+    the next probe one re-sort. A searched row costs what sorting eight
+    rows does for each node it gathers. On the chip (PERF.md PR 35, calls
+    93 and 97), at 60,817,408 rows and three nodes a row: the search 14.0
+    ms for 65,536 rows and 60.8 for 524,288 (one run of neighbouring rows,
+    as a refresh deletes them; 11.7 and 44.2 for scattered rows), 102 ns a
+    row; the re-sort 280.3 ms there and 191.5 at 37,748,736, 4.6-5.1 ns a
+    row: 22 sorted rows a searched one. They meet at 2.5M flips there."""
+    return 8 * flips * _search_steps(cap) <= cap
 
 
 def _slab_capacity(rows: int) -> int:
@@ -412,15 +486,6 @@ def _update_kernels():
         ),
         # int32-shipped slabs widen to the kernel's int64 on device
         "widen": jax.jit(lambda k: k.astype(jnp.int64)),
-        # row indices -> sorted positions through the inverse permutation;
-        # padding rows (>= cap) map out of range so the next scatter drops
-        "map_rows": jax.jit(
-            lambda inv, r: jnp.where(
-                r < inv.shape[0],
-                jnp.take(inv, jnp.minimum(r, inv.shape[0] - 1)),
-                inv.shape[0],
-            )
-        ),
         # contiguous appends skip the row-index upload entirely (start is a
         # scalar); uploaded keys may arrive int32-narrowed and cast up here
         "slice_append": jax.jit(
@@ -484,12 +549,12 @@ class ResidentJoinKeys:
         self._dev = None
         self._pending = None  # batched device updates (see device_batch)
         # True when the resident sorted view (sorted_keys + perm) lags the
-        # key lane: set by key appends, NOT by validity flips (DV kills and
-        # revives don't change sort order). The next probe re-sorts once.
+        # lanes: set by key appends, and by a validity flip of more rows
+        # than are worth finding in it (`_flip_by_search`; a smaller flip
+        # is mirrored in sorted space). The next probe re-sorts once.
         self._sort_stale = True
-        # the sorted view a key append last dropped held an inverse
-        # permutation (reported by the next sort's span)
-        self._inverse_dropped = False
+        # what dropped the view (reported by the next sort's span)
+        self._sort_cause = "append"
         self._lock = threading.RLock()
         self.last_used = 0.0
         # device-memory accounting (gc-backstopped so a transient
@@ -641,9 +706,8 @@ class ResidentJoinKeys:
     @property
     def device_bytes(self) -> int:
         # keys(8) + valid(1) + sorted view: sorted_keys(8) + perm(4) +
-        # sorted_valid(1); inv_perm(4) while a flip has built it
-        inverse = self._dev is not None and "inv_perm" in self._dev
-        return self.capacity * (26 if inverse else 22)
+        # sorted_valid(1), for the whole residency
+        return self.capacity * 22
 
     @property
     def is_resident(self) -> bool:
@@ -671,7 +735,7 @@ class ResidentJoinKeys:
                     "keys": jnp.zeros(self.capacity, jnp.int64),
                     "valid": jnp.zeros(self.capacity, bool),
                 }
-            self._sort_stale = True
+            self._drop_sorted_view("append")
             self._hbm.on(self, self.device_bytes)
 
     def ensure_resident(self) -> None:
@@ -686,7 +750,7 @@ class ResidentJoinKeys:
             with telemetry.record_operation(
                     "delta.keyCache.upload", {"rows": self.num_rows}):
                 self._dev = self._ship_mirrors()
-            self._sort_stale = True
+            self._drop_sorted_view("append")
             self._hbm.on(self, self.device_bytes)
 
     def _ship_mirrors(self) -> Dict[str, object]:
@@ -733,11 +797,11 @@ class ResidentJoinKeys:
             return
         if not self._sort_stale and "sorted_keys" in self._dev:
             return
-        # `inverse`: whether the view this sort replaces had one built, so
-        # a trace says how often a sorted view's life includes a flip
+        # `cause`: what dropped the view this sort replaces, a key append
+        # or a flip too large to search for (`flips`)
         with telemetry.record_operation(
                 "delta.keyCache.sort",
-                {"rows": self.num_rows, "inverse": self._inverse_dropped}), \
+                {"rows": self.num_rows, "cause": self._sort_cause}), \
                 enable_x64():
             sk, pm, sv = _sort_kernel()(
                 self._dev["keys"], self._dev["valid"],
@@ -746,40 +810,43 @@ class ResidentJoinKeys:
         self._dev["perm"] = pm
         self._dev["sorted_valid"] = sv
         self._sort_stale = False
-        self._inverse_dropped = False
 
-    def _account_device(self) -> None:
-        """Bring the HBM account to `device_bytes` (the inverse permutation
-        comes and goes within one residency)."""
-        self._hbm.off()
-        self._hbm.on(self, self.device_bytes)
+    def _drop_sorted_view(self, cause: str) -> None:
+        """The sorted view lags the lanes: drop it (frees HBM) and let the
+        next probe re-sort."""
+        self._sort_stale = True
+        self._sort_cause = cause
+        for view in ("sorted_keys", "perm", "sorted_valid"):
+            self._dev.pop(view, None)
 
     def _dev_flip_valid(self, rows: np.ndarray, value: bool) -> None:
         """Validity flip in ROW space plus, when the sorted view is live,
-        the mirrored flip in SORTED space via the inverse permutation (a
-        k-row gather+scatter). The first such flip of a view builds the
-        inverse from the resident permutation, one capacity-sized scatter;
-        it stays until the next key append drops the view. A flip on a
-        stale view is a row-space flip and builds nothing."""
+        the mirrored flip in SORTED space, at the positions a search of the
+        view finds for the rows (`_inverse_permutation_at`). A flip of more
+        rows than `_flip_by_search` allows stays in row space and drops the
+        view: the next probe's sort carries validity in its payload anyway.
+        A flip on a stale view is a row-space flip and nothing else."""
         d = _next_pow2(max(len(rows), 1), floor=64)
         padded = np.full(d, self.capacity, np.int32)
         padded[: len(rows)] = rows
         kern = _update_kernels()["kill" if not value else "revive"]
         rows_dev = link.to_device(padded)
         self._dev["valid"] = kern(self._dev["valid"], rows_dev)
-        if not self._sort_stale and "sorted_valid" in self._dev:
-            if "inv_perm" not in self._dev:
-                with telemetry.record_operation(
-                        "delta.keyCache.inverse",
-                        {"rows": self.num_rows, "flips": len(rows)}):
-                    self._dev["inv_perm"] = _inverse_permutation()(
-                        self._dev["perm"])
-                telemetry.bump_counter("merge.keyCache.inverseBuilds")
-                self._account_device()
-            spos = _update_kernels()["map_rows"](
-                self._dev["inv_perm"], rows_dev)
-            self._dev["sorted_valid"] = kern(
-                self._dev["sorted_valid"], spos)
+        if self._sort_stale or "sorted_valid" not in self._dev:
+            return
+        if not _flip_by_search(len(rows), self.capacity):
+            self._drop_sorted_view("flips")
+            telemetry.bump_counter("merge.keyCache.flipResorts")
+            return
+        with telemetry.record_operation(
+                "delta.keyCache.locate",
+                {"rows": self.num_rows, "flips": len(rows),
+                 "steps": _search_steps(self.capacity)}), enable_x64():
+            spos = _inverse_permutation_at()(
+                self._dev["sorted_keys"], self._dev["perm"],
+                self._dev["keys"], rows_dev)
+        telemetry.bump_counter("merge.keyCache.flipSearches")
+        self._dev["sorted_valid"] = kern(self._dev["sorted_valid"], spos)
 
     def _dev_kill(self, rows: np.ndarray) -> None:
         self._dev_flip_valid(rows, False)
@@ -804,14 +871,7 @@ class ResidentJoinKeys:
             and bool((row_idx == np.arange(row_idx[0], row_idx[0] + k,
                                            dtype=row_idx.dtype)).all())
         )
-        # key rows changed: the sorted view lags; drop it (frees HBM) and
-        # let the next probe re-sort
-        self._sort_stale = True
-        for view in ("sorted_keys", "perm", "sorted_valid"):
-            self._dev.pop(view, None)
-        if self._dev.pop("inv_perm", None) is not None:
-            self._inverse_dropped = True
-            self._account_device()
+        self._drop_sorted_view("append")  # key rows changed
         with enable_x64():
             if contiguous:
                 self._dev["keys"], self._dev["valid"] = (

@@ -425,7 +425,7 @@ def test_sort_equals_the_numpy_oracle(case):
     """The re-sort's three arrays against numpy: `perm` is the stable
     argsort of the encoded keys (padding as int64.max; ties in physical-row
     order, valid or dead), `sorted_keys` the encoded keys through it,
-    `sorted_valid` the live rows through it — and no inverse is built."""
+    `sorted_valid` the live rows through it — and nothing else is resident."""
     from delta_tpu.ops.key_cache import ResidentJoinKeys
 
     keys, dead = _sort_case(case)
@@ -447,7 +447,9 @@ def test_sort_equals_the_numpy_oracle(case):
     assert (np.asarray(e._dev["perm"]) == perm).all()
     assert (np.asarray(e._dev["sorted_keys"]) == enc[perm]).all()
     assert (np.asarray(e._dev["sorted_valid"]) == live[perm]).all()
-    assert "inv_perm" not in e._dev and e.device_bytes == 22 * cap
+    assert set(e._dev) == {"keys", "valid", "sorted_keys", "perm",
+                           "sorted_valid"}
+    assert e.device_bytes == 22 * cap
 
 
 def test_slab_capacity_leaves_the_payload_its_validity_bit():
@@ -461,11 +463,14 @@ def test_slab_capacity_leaves_the_payload_its_validity_bit():
         _slab_capacity((1 << 30) + 1)
 
 
-def _inverse_builds():
+def _flip_counts():
+    """(flips mirrored in a live sorted view by search, flips that dropped
+    the view instead), the process's counts so far."""
     from delta_tpu.utils import telemetry
 
-    return telemetry.counters("merge.keyCache").get(
-        "merge.keyCache.inverseBuilds", 0)
+    c = telemetry.counters("merge.keyCache")
+    return (c.get("merge.keyCache.flipSearches", 0),
+            c.get("merge.keyCache.flipResorts", 0))
 
 
 def _probe_bits(e, keys):
@@ -473,11 +478,96 @@ def _probe_bits(e, keys):
                          np.ones(len(keys), bool)).result().s_matched.tolist()
 
 
-def test_inverse_permutation_is_built_by_the_first_flip_that_needs_it():
-    """(a) the advance's shape, an append and a kill in one batch, builds no
-    inverse: the append drops the sorted view before the kill runs. (b) a
-    kill and a revive on a live view build exactly one, at the first flip.
-    (c) the next append drops it, and the accounts read 22 B a row again."""
+LOCATE_CASES = [
+    "unique-keys", "runs-of-1-to-7", "run-longer-than-a-probe-block",
+    "every-key-equal", "int64-max-beside-the-padding", "dead-rows-in-runs",
+    "rows-padded-with-cap", "length-3-times-a-power-of-two",
+    "length-1000-full", "length-of-the-refresh-slab-over-2-to-the-15th",
+    "length-of-the-refresh-slab-over-2-to-the-11th", "one-row",
+]
+
+
+def _locate_case(case):
+    """(keys and validity of a slab's ``cap`` device rows, its row count,
+    the rows to find, padded as a flip pads them)."""
+    rng = np.random.RandomState(len(case))
+    cap, n = 1024, 700
+    if case == "length-3-times-a-power-of-two":
+        cap, n = 1536, 1100
+    elif case == "length-1000-full":
+        cap, n = 1000, 1000
+    elif case == "length-of-the-refresh-slab-over-2-to-the-15th":
+        cap, n = 60_817_408 >> 15, 1800  # 1,856 = 2^6 x 29: pads a node
+    elif case == "length-of-the-refresh-slab-over-2-to-the-11th":
+        # 29,696 rows: two levels of nodes, the upper one padded (232 of
+        # 256), under a top of two entries; the slab's own tree has three
+        cap, n = 60_817_408 >> 11, 29_000
+    elif case == "run-longer-than-a-probe-block":
+        cap, n = 4096, 3000
+    elif case == "one-row":
+        n = 1
+    keys = np.repeat(rng.permutation(n).astype(np.int64) * 5 - 900,
+                     rng.randint(1, 8, n))[:n]  # runs of 1-7, in row order
+    keys = keys[rng.permutation(n)]
+    valid = np.ones(n, bool)
+    if case == "unique-keys":
+        keys = rng.permutation(n).astype(np.int64) << 34
+    elif case == "run-longer-than-a-probe-block":
+        keys[rng.choice(n, 1500, replace=False)] = 77  # blocks reach 1,024
+    elif case == "every-key-equal":
+        keys[:] = -3
+    elif case == "int64-max-beside-the-padding":
+        keys[[0, 300, n - 1]] = np.iinfo(np.int64).max
+    if case in ("dead-rows-in-runs", "int64-max-beside-the-padding"):
+        valid[rng.choice(n, n // 3, replace=False)] = False
+        valid[300] = False
+    k = min(n, 200)
+    rows = rng.choice(n, k, replace=False).astype(np.int32)
+    if case == "int64-max-beside-the-padding":
+        rows[:3] = [0, 300, n - 1]
+        rows = np.unique(rows)
+    d = 256
+    if case == "rows-padded-with-cap":
+        rows = rows[:70]  # 186 of the 256 are padding
+    dev_keys, dev_valid = np.zeros(cap, np.int64), np.zeros(cap, bool)
+    dev_keys[:n], dev_valid[:n] = keys, valid
+    padded = np.full(d, cap, np.int32)
+    padded[:len(rows)] = rows
+    return dev_keys, dev_valid, n, padded
+
+
+@pytest.mark.parametrize("case", LOCATE_CASES)
+def test_inverse_permutation_at_equals_the_numpy_oracle(case):
+    """The search that a flip on a live sorted view runs, handed the
+    sort's arrays directly (so a length need be no power of two, as the
+    refresh slab's 60,817,408 is none): every real row's position is
+    `argsort(perm)[row]`, valid or dead, alone or inside a run of equal
+    keys; a padding row maps to the capacity, which a scatter drops."""
+    from delta_tpu.ops.key_cache import _inverse_permutation_at, _sort_kernel
+    from delta_tpu.utils.jaxcompat import enable_x64
+
+    keys, valid, n, rows = _locate_case(case)
+    cap = len(keys)
+    with enable_x64():
+        sk, pm, sv = _sort_kernel()(keys, valid, np.int32(n))
+        got = np.asarray(_inverse_permutation_at()(sk, pm, keys, rows))
+    assert got.dtype == np.int32 and got.shape == rows.shape
+    real = rows < cap
+    assert real.any() and (rows[real] < n).all()
+    want = np.argsort(np.asarray(pm))
+    assert (got[real] == want[rows[real]]).all()
+    assert (got[~real] == cap).all()
+    # what the flip relies on: the rows stand where the search says
+    assert (np.asarray(pm)[got[real]] == rows[real]).all()
+    assert (np.asarray(sv)[got[real]] == valid[rows[real]]).all()
+
+
+def test_a_flip_on_a_live_view_searches_it_and_an_append_never_does():
+    """(a) the advance's shape, an append and a kill in one batch, searches
+    nothing: the append drops the sorted view before the kill runs. (b) a
+    kill and a revive on a live view each search it once, and the probe
+    reads both back. (c) the next append drops the view; through all of it
+    the accounts read 22 B a row."""
     from delta_tpu.obs import hbm_ledger
     from delta_tpu.ops.key_cache import ResidentJoinKeys
     from delta_tpu.utils import telemetry
@@ -490,51 +580,165 @@ def test_inverse_permutation_is_built_by_the_first_flip_that_needs_it():
     e._append_file("a", a, np.ones(600, bool))
     e.ensure_resident()
     cap = e.capacity
+
+    def accounts():
+        return e.device_bytes, hbm_ledger.totals()["keyCache"]
+
+    assert accounts() == (22 * cap, 22 * cap)
     assert _probe_bits(e, [a[5], 1000]) == [True, False]
     assert not e._sort_stale
-    before = _inverse_builds()
+    before = _flip_counts()
 
     with e.device_batch():
         e._append_file("b", np.arange(1000, 1100, dtype=np.int64),
                        np.ones(100, bool))
         assert e._set_dv("a", np.array([5, 7]))
-    assert e._sort_stale and "inv_perm" not in e._dev
-    assert _inverse_builds() == before
+    assert e._sort_stale and _flip_counts() == before
     assert _probe_bits(e, [a[5], a[6], a[7], 1000]) == [False, True, False, True]
-    assert _inverse_builds() == before and "inv_perm" not in e._dev
-    assert e.device_bytes == 22 * cap
-    assert hbm_ledger.totals()["keyCache"] == 22 * cap
+    assert _flip_counts() == before and accounts() == (22 * cap, 22 * cap)
     sorts = telemetry.recent_events("delta.keyCache.sort")
-    assert [ev.data["inverse"] for ev in sorts] == [False, False]
+    assert [ev.data["cause"] for ev in sorts] == ["append", "append"]
+    assert not telemetry.recent_events("delta.keyCache.locate")
 
     telemetry.clear_events()
     with e.device_batch():  # an advance that flips and appends nothing
         assert e._set_dv("b", np.array([50]))
-    assert not e._sort_stale and _inverse_builds() == before + 1
+    assert not e._sort_stale
+    assert _flip_counts() == (before[0] + 1, before[1])
+    assert accounts() == (22 * cap, 22 * cap)
     assert e._set_dv("a", np.array([7]))  # revives row 5: the second flip
-    assert _inverse_builds() == before + 1
-    built = telemetry.recent_events("delta.keyCache.inverse")
-    assert [ev.data["flips"] for ev in built] == [1]
-    assert built[0].data["rows"] == 700
+    assert _flip_counts() == (before[0] + 2, before[1])
+    found = telemetry.recent_events("delta.keyCache.locate")
+    assert [(ev.data["rows"], ev.data["flips"], ev.data["steps"])
+            for ev in found] == [(700, 1, 1), (700, 1, 1)]
+    assert not e._sort_stale
     assert _probe_bits(e, [a[5], a[7], 1050, 1051]) == [True, False, False, True]
-    inv = np.asarray(e._dev["inv_perm"])
-    assert (inv == np.argsort(np.asarray(e._dev["perm"]))).all()
-    assert e.device_bytes == 26 * cap
-    assert hbm_ledger.totals()["keyCache"] == 26 * cap
+    assert not telemetry.recent_events("delta.keyCache.sort")
+    assert set(e._dev) == {"keys", "valid", "sorted_keys", "perm",
+                           "sorted_valid"}
+    assert accounts() == (22 * cap, 22 * cap)
 
-    telemetry.clear_events()
     e._append_file("c", np.array([2000], np.int64), np.ones(1, bool))
-    assert e._sort_stale and "inv_perm" not in e._dev
-    assert e.device_bytes == 22 * cap
-    assert hbm_ledger.totals()["keyCache"] == 22 * cap
-    e._kill_file("b")  # stale view: a row-space flip, nothing built
-    assert _inverse_builds() == before + 1
+    assert e._sort_stale and set(e._dev) == {"keys", "valid"}
+    assert accounts() == (22 * cap, 22 * cap)
+    e._kill_file("b")  # stale view: a row-space flip, nothing searched
+    assert _flip_counts() == (before[0] + 2, before[1])
     assert _probe_bits(e, [2000, 1051, a[5]]) == [True, False, True]
     sorts = telemetry.recent_events("delta.keyCache.sort")
-    assert [ev.data["inverse"] for ev in sorts] == [True]
+    assert [ev.data["cause"] for ev in sorts] == ["append"]
     e.drop_device()
     assert hbm_ledger.totals()["keyCache"] == 0
     hbm_ledger.reset()
+
+
+def test_kill_then_revive_on_one_live_view_reads_back_through_the_probe():
+    """Runs of equal keys, some rows dead before the view was sorted: a
+    vector grows, shrinks and goes (RESTORE's shape), the view is never
+    re-sorted, and after every flip the probe's pairs are the live rows of
+    the probed keys."""
+    from delta_tpu.ops.key_cache import ResidentJoinKeys
+    from delta_tpu.utils import telemetry
+
+    rng = np.random.RandomState(23)
+    keys = rng.randint(0, 120, 900).astype(np.int64)  # runs of ~7
+    first = np.array([0, 1, 2, 450, 899])
+    grown = np.array([0, 1, 2, 3, 7, 450, 451, 899])
+    null_ok = rng.rand(900) < 0.9
+    null_ok[grown] = True
+    e = ResidentJoinKeys("log", "mid", 0, "sig", ["k"])
+    e._append_file("f", keys, null_ok)
+    e.ensure_resident()
+    probe = np.arange(120, dtype=np.int64)
+
+    def live_rows():
+        pairs = e.probe_async(probe, np.ones(120, bool)).result().t_pairs
+        return pairs[0].tolist()
+
+    def want(dead):
+        ok = null_ok.copy()
+        ok[dead] = False
+        return np.nonzero(ok)[0].tolist()
+
+    none = np.empty(0, np.int64)
+    assert live_rows() == want(none)
+    telemetry.clear_events()
+    searches, resorts = _flip_counts()
+    for dead, flips in ((first, 1), (grown, 1), (first, 1), (none, 1),
+                        (none, 0)):
+        assert e._set_dv("f", dead)
+        searches += flips  # a kill or a revive; none where nothing changed
+        assert _flip_counts() == (searches, resorts) and not e._sort_stale
+        assert live_rows() == want(dead)
+    assert not telemetry.recent_events("delta.keyCache.sort")
+
+
+def test_a_flip_too_large_to_search_for_drops_the_view_and_the_sort_carries_it():
+    """Both sides of `_flip_by_search`, which reads the flips and the
+    capacity alone: the largest flip it admits is mirrored in the live
+    view, one row more stays in row space, leaves the view stale and
+    counts a re-sort, and the next probe answers from a sort whose span
+    says `cause=flips`."""
+    from delta_tpu.obs import hbm_ledger
+    from delta_tpu.ops.key_cache import (
+        ResidentJoinKeys, _flip_by_search, _search_steps)
+    from delta_tpu.utils import telemetry
+
+    hbm_ledger.reset()
+    n = 3000
+    e = ResidentJoinKeys("log", "mid", 0, "sig", ["k"])
+    e._append_file("f", np.arange(n, dtype=np.int64) // 3, np.ones(n, bool))
+    e.ensure_resident()
+    cap = e.capacity
+    assert cap == 4096 and _search_steps(cap) == 1
+    most = max(d for d in range(1, n) if _flip_by_search(d, cap))
+    assert 8 <= most < 1000 and not _flip_by_search(most + 1, cap)
+    assert _probe_bits(e, [0, 999]) == [True, True]
+    telemetry.clear_events()
+    before = _flip_counts()
+
+    assert e._set_dv("f", np.arange(most))  # rows 0..most-1 die
+    assert not e._sort_stale and "sorted_valid" in e._dev
+    assert _flip_counts() == (before[0] + 1, before[1])
+    assert e.device_bytes == hbm_ledger.totals()["keyCache"] == 22 * cap
+
+    alive = np.arange(most + 1) + most  # revives `most` rows, kills one more
+    assert e._set_dv("f", alive)
+    # the batchless diff is a kill (most + 1 rows) and a revive (most rows)
+    assert e._sort_stale and "sorted_valid" not in e._dev
+    assert _flip_counts() == (before[0] + 1, before[1] + 1)
+    assert e.device_bytes == hbm_ledger.totals()["keyCache"] == 22 * cap
+    assert not telemetry.recent_events("delta.keyCache.sort")
+    keys = np.arange(1000, dtype=np.int64)
+    dead = np.zeros(n, bool)
+    dead[alive] = True
+    want = [bool((~dead[3 * k:3 * k + 3]).any()) for k in keys]
+    assert _probe_bits(e, keys) == want
+    sorts = telemetry.recent_events("delta.keyCache.sort")
+    assert [(ev.data["rows"], ev.data["cause"]) for ev in sorts] == [
+        (n, "flips")]
+    assert len(telemetry.recent_events("delta.keyCache.locate")) == 1
+    assert e.device_bytes == hbm_ledger.totals()["keyCache"] == 22 * cap
+    e.drop_device()
+    hbm_ledger.reset()
+
+
+@pytest.mark.parametrize("flips,cap,steps,search", [
+    (65_536, 60_817_408, 3, True),     # a refresh function's rows at SF10
+    (105_000, 60_817_408, 3, True),    # the most an RF2 can delete there
+    (4_000_000, 60_817_408, 3, False),  # a whole file's rows
+    (1_000_000, 37_748_736, 3, True),  # a 1M-row upsert's vectors
+    (2_000_000, 37_748_736, 3, False),
+    (128, 1024, 1, True),
+    (129, 1024, 1, False),
+    (10**6, 128, 0, True),  # the dense top alone
+    (1, 16_385, 2, True),
+])
+def test_the_search_or_resort_rule_reads_the_flips_and_the_capacity(
+        flips, cap, steps, search):
+    from delta_tpu.ops.key_cache import _flip_by_search, _search_steps
+
+    assert _search_steps(cap) == steps
+    assert _flip_by_search(flips, cap) is search
 
 
 def test_set_dv_out_of_range_positions_signal_rebuild(tmp_table):

@@ -701,6 +701,44 @@ def test_the_tile_kernel_compiles_for_the_chip_at_a_files_shape(
     assert "jit_filter_group_aggregate" in text
 
 
+def test_a_flips_search_compiles_for_the_chip_at_the_refresh_slabs_shape(one_v5e):
+    """`ops/key_cache.py`'s search for a flip's rows (here because one file
+    of the suite describes a chip: a second could meet a worker that may not
+    load the compiler). At TPC-H SF10's capacity and a refresh function's
+    bucket the chip's compiler takes it, its module is the one
+    `merge_inverse_ms` reads, and its scratch is the two int64 operands'
+    32-bit planes and no re-tiled copy of a capacity-sized plane beside
+    them (which a node wider than 128 entries brings: 698 MiB)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from delta_tpu.ops import key_cache
+    from delta_tpu.utils.jaxcompat import enable_x64
+
+    cap, flips = 60_817_408, 65_536
+    assert key_cache._slab_capacity(59_986_052) == cap
+    assert key_cache._search_steps(cap) == 3
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()  # a described chip's executable is no use
+    try:
+        with enable_x64():
+            compiled = key_cache._inverse_permutation_at().lower(
+                shape((cap,), jnp.int64), shape((cap,), jnp.int32),
+                shape((cap,), jnp.int64), shape((flips,), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert "HloModule jit_inverse_permutation_at" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == 4 * flips
+    assert memory.temp_size_in_bytes < 8 * cap + (32 << 20)
+
+
 # -- the benchmark's readers find what they read ------------------------------------------
 
 

@@ -5,8 +5,9 @@ agrees with the benchmark's plain reference
 (`benchmark/tables/lineitem_refresh.py`) on seeded tables of a few thousand
 rows in several files, with deletion vectors and without, on the device route
 and on the host's; once the slab is warm both statements take the resident
-pairs-only route, the slab is re-sorted and its inverse permutation built once
-a pair, and the root span says which statement it was and what it did."""
+pairs-only route, the slab is re-sorted once a pair and its sorted view
+searched once a pair for the rows the delete before flipped, and the root span
+says which statement it was and what it did."""
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -179,18 +180,18 @@ def test_warm_pairs_take_the_pairs_only_route_and_say_what_they_did(tmp_path):
     gen, base, table = _load(tmp_path, 9, file_rows=250)
     assert len(table.delta_log.update().all_files) == 12
     counters0 = telemetry.counters("merge")
-    seen = []
+    seen, found = [], []
     for k in range(3):
         s = gen.refresh_set(base, k, 20)
         for name, f in (("rf1", s.rf1), ("rf2", s.rf2)):
             telemetry.clear_events()
-            builds = telemetry.counters("merge").get(
-                "merge.keyCache.inverseBuilds", 0)
             report = _send(table, f)
             root, slab, router = _statement_spans()
-            seen.append((name, router.get("route"), slab.count(
-                "delta.keyCache.sort"), telemetry.counters("merge").get(
-                    "merge.keyCache.inverseBuilds", 0) - builds))
+            seen.append((name, router.get("route"),
+                         slab.count("delta.keyCache.sort"),
+                         slab.count("delta.keyCache.locate")))
+            found += [e.data for e in telemetry.recent_events(
+                "delta.keyCache.locate")]
             assert not telemetry.recent_events("delta.dist.mergeProbe")
             assert root["clauses"] == ("insert" if name == "rf1" else "delete")
             assert root["sourceRows"] == len(f)
@@ -199,7 +200,7 @@ def test_warm_pairs_take_the_pairs_only_route_and_say_what_they_did(tmp_path):
     # the first RF1 decodes the keys and builds the slab; then the slab alone
     assert seen[0] == ("rf1", "decode", 1, 0)
     assert seen[1] == ("rf2", "pairs-only", 1, 0)
-    # a warm pair: the inverse under RF1 (it flips the rows the RF2 before
+    # a warm pair: the search under RF1 (it flips the rows the RF2 before
     # it deleted, on a live sorted view), the re-sort under RF2 (RF1's rows)
     assert seen[2:] == [("rf1", "pairs-only", 0, 1),
                         ("rf2", "pairs-only", 1, 0)] * 2
@@ -210,8 +211,12 @@ def test_warm_pairs_take_the_pairs_only_route_and_say_what_they_did(tmp_path):
     assert moved["merge.resident.pairsOnly"] == 5
     assert moved["merge.keyCache.builds"] == 1
     assert moved.get("merge.resident.pairsOnly.declined", 0) == 0
+    assert moved["merge.keyCache.flipSearches"] == 2
+    assert moved.get("merge.keyCache.flipResorts", 0) == 0
     sorts = [e.data for e in telemetry.recent_events("delta.keyCache.sort")]
-    assert sorts[-1]["inverse"] is True
+    assert sorts[-1]["cause"] == "append"
+    assert [d["steps"] for d in found] == [1, 1]
+    assert all(20 <= d["flips"] <= 140 for d in found)
 
 
 @pytest.mark.parametrize("first", ["rf1", "rf2"])
@@ -369,8 +374,8 @@ def test_the_comparison_counts_each_kind_of_difference(fault):
 
 def test_bench_spans_tells_a_pairs_two_statements_apart(tmp_path):
     """`tools/bench_spans.py` splits a request's spans by the `clauses` of
-    the root span they started under: the inverse under RF1, the re-sort and
-    the vector under RF2."""
+    the root span they started under: the search of the sorted view under
+    RF1, the re-sort and the vector under RF2."""
     import importlib.util
     import os
     from types import SimpleNamespace
@@ -396,8 +401,8 @@ def test_bench_spans_tells_a_pairs_two_statements_apart(tmp_path):
     assert sorted(split) == ["delete", "insert"]
     (n_rf1, rf1), (n_rf2, rf2) = split["insert"], split["delete"]
     assert n_rf1 == n_rf2 == 2
-    assert "delta.keyCache.inverse" in rf1 and "delta.keyCache.sort" not in rf1
-    assert "delta.keyCache.sort" in rf2 and "delta.keyCache.inverse" not in rf2
+    assert "delta.keyCache.locate" in rf1 and "delta.keyCache.sort" not in rf1
+    assert "delta.keyCache.sort" in rf2 and "delta.keyCache.locate" not in rf2
     assert "delta.dml.merge.deletionVectors" in rf2
     assert "delta.dml.merge.deletionVectors" not in rf1
     assert rf1["delta.dml.merge"] >= rf1["delta.dml.merge.write"] > 0

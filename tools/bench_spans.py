@@ -8,8 +8,9 @@ standard error: the mean milliseconds a request of every span of the program
 (and how many of them a request opened), each MERGE's route, the same means a
 statement split by the root span's ``clauses`` (a refresh pair's RF1 and RF2
 apart), what the resident probe's spans and counters say of how widely it
-engaged, every re-sort of the slab and every inverse permutation built with
-the slab's ``rows``, ``inverse`` and ``flips``, the routes
+engaged, every re-sort of the slab (``rows``, ``cause``) and every search of
+its sorted view for flipped rows (``rows``, ``flips``, ``steps``) with the
+counts of flips searched for and of flips that re-sorted instead, the routes
 and group counts of the aggregate queries with the program that answered the
 grouped ones (``tiled`` or ``wide``) and the tiled share, and the device's time in the
 window by XLA module, each module with its longest operations and the
@@ -59,16 +60,18 @@ def report(run) -> None:
         from delta_tpu.utils import telemetry
 
         # the counters are the process's: set-up's MERGEs count too
+        slab = telemetry.counters("merge.keyCache")
         print("device probes:", json.dumps(probes), "overflows:",
               telemetry.counters("merge.resident.probe").get(
-                  "merge.resident.probe.overflow", 0), "inverse builds:",
-              telemetry.counters("merge.keyCache").get(
-                  "merge.keyCache.inverseBuilds", 0), file=sys.stderr)
-        # what a pair paid the device for: each re-sort (`inverse`: the view
-        # it replaced held an inverse permutation) and each inverse built
+                  "merge.resident.probe.overflow", 0), "flip searches:",
+              slab.get("merge.keyCache.flipSearches", 0), "flip re-sorts:",
+              slab.get("merge.keyCache.flipResorts", 0), file=sys.stderr)
+        # what a pair paid the device for: each re-sort (`cause`: a key
+        # append dropped the view it replaces, or a flip too large to
+        # search for) and each search of a live view for flipped rows
         # (`flips`: the rows whose validity the advance flipped)
         print("slab sorts:", json.dumps(span_data("delta.keyCache.sort")),
-              "slab inverses:", json.dumps(span_data("delta.keyCache.inverse")),
+              "slab searches:", json.dumps(span_data("delta.keyCache.locate")),
               file=sys.stderr)
     aggregates = span_data("delta.scan.deviceAggregate")
     if aggregates:
