@@ -469,10 +469,14 @@ class MergeIntoCommand:
         self._emit_router()
         scan_ms = timer.lap_ms()
 
-        with self._phase("delta.dml.merge.apply", "apply_ms"):
+        # the three stages tile the span: which of them an apply's time is
+        with self._phase("delta.dml.merge.apply", "apply_ms"), \
+                telemetry.span_stages() as stage:
             if not insert_only:
                 # insert-only merges can't modify target rows, so duplicate
                 # matches are harmless (reference fast path, `:397-450`)
+                stage("delta.dml.merge.apply.multiMatch",
+                      {"pairs": matched_pairs.num_rows})
                 self._check_multi_match(matched_pairs)
 
             removes: List[Action] = []
@@ -483,6 +487,8 @@ class MergeIntoCommand:
 
             if not insert_only:
                 # matched block → per-clause masks
+                sev = stage("delta.dml.merge.apply.matched",
+                            {"pairs": matched_pairs.num_rows})
                 upd, n_updated, n_deleted, n_pair_copied, claimed_tbl, fired_fids = (
                     self._apply_matched(
                         matched_pairs, target_cols, metadata, dv_mode=use_dv
@@ -515,11 +521,15 @@ class MergeIntoCommand:
                         n_copied += copied.num_rows
                         if copied.num_rows:
                             out_blocks.append(copied)
+                sev.data.update(updated=n_updated, deleted=n_deleted,
+                                copied=n_copied)
 
             # not-matched source rows → insert clauses
+            sev = stage("delta.dml.merge.apply.notMatched")
             inserts, n_inserted = self._apply_not_matched(
                 matched_pairs, src, target_cols, source_cols, metadata
             )
+            sev.data["inserted"] = n_inserted
             if inserts is not None and inserts.num_rows:
                 out_blocks.append(inserts)
                 if self._use_cdf:
@@ -551,20 +561,27 @@ class MergeIntoCommand:
         with self._phase("delta.dml.merge.write", "write_ms"):
             adds: List[Action] = list(dv_adds)
             cdc_actions: List[Action] = []
-            if self._cdf_blocks:
-                cdc_actions = list(cdf_exec.write_change_data(
-                    self.delta_log.data_path, self._cdf_blocks, metadata
-                ))
-            if out_blocks:
-                out = pa.concat_tables(out_blocks, promote_options="permissive")
-                if out.column_names != target_cols:
-                    out = out.select(target_cols)
-                if out.num_rows:
-                    adds += list(
-                        write_exec.write_files(
-                            self.delta_log.data_path, out, metadata, data_change=True
-                        )
+            out = None
+            # what comes before the shared writer, whose own stages
+            # (`delta.write.prepare`, `.encode`, `.stats`) tile the rest
+            with telemetry.record_operation(
+                    "delta.dml.merge.write.concat",
+                    {"blocks": len(out_blocks), "rows": 0}) as cev:
+                if self._cdf_blocks:
+                    cdc_actions = list(cdf_exec.write_change_data(
+                        self.delta_log.data_path, self._cdf_blocks, metadata
+                    ))
+                if out_blocks:
+                    out = pa.concat_tables(out_blocks, promote_options="permissive")
+                    if out.column_names != target_cols:
+                        out = out.select(target_cols)
+                    cev.data["rows"] = out.num_rows
+            if out is not None and out.num_rows:
+                adds += list(
+                    write_exec.write_files(
+                        self.delta_log.data_path, out, metadata, data_change=True
                     )
+                )
         rewrite_ms = timer.lap_ms()
 
         self.metrics.update(

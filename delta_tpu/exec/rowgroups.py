@@ -92,13 +92,17 @@ class FooterCache:
 
     def get(self, abs_path: str):
         """The file's parsed footer; cached when the cache is enabled."""
+        return self.lookup(abs_path)[0]
+
+    def lookup(self, abs_path: str):
+        """``(the file's parsed footer, whether the cache held it)``."""
         import pyarrow.parquet as pq
 
         from delta_tpu.utils.telemetry import bump_counter
 
         cap = self.capacity()
         if cap <= 0:
-            return pq.read_metadata(abs_path)
+            return pq.read_metadata(abs_path), False
         st = os.stat(abs_path)
         key = (st.st_size, st.st_mtime_ns)
         with self._lock:
@@ -106,7 +110,7 @@ class FooterCache:
             if hit is not None and hit[0] == key:
                 self._entries.move_to_end(abs_path)
                 bump_counter("footerCache.hits")
-                return hit[1]
+                return hit[1], True
         meta = pq.read_metadata(abs_path)
         bump_counter("footerCache.misses")
         with self._lock:
@@ -115,7 +119,7 @@ class FooterCache:
             while len(self._entries) > cap:
                 self._entries.popitem(last=False)
                 bump_counter("footerCache.evictions")
-        return meta
+        return meta, False
 
     def clear(self) -> None:
         with self._lock:

@@ -203,6 +203,59 @@ def read_files_as_table(
         return {"groups": len(idx),
                 "bytes": sum(meta.row_group(i).total_byte_size for i in idx)}
 
+    # the inside of a decode's ``open`` stage, as its child spans:
+    # ``.open.plan`` (`_plan_groups`), ``.open.survivors`` (the row groups'
+    # offsets, and on the device route the pass over the mask's slices),
+    # ``.open.file`` (`_open_file`)
+
+    def _plan_groups(abs_path, add, pos_hint):
+        """The file's footer and the row groups worth reading by its
+        statistics and the position hint: ``(meta | None, keep_idx,
+        skipped_bytes, plan_fired)``."""
+        from delta_tpu.exec import rowgroups
+
+        with telemetry.record_operation("delta.scan.decode.open.plan") as ev:
+            try:
+                meta, cached = rowgroups.FooterCache.instance().lookup(abs_path)
+            except Exception:
+                return None, [], 0, []
+            n_rg = meta.num_row_groups
+            keep_idx = list(range(n_rg))
+            skipped_bytes = 0
+            plan_fired: list = []
+            if predicate is not None and n_rg > 1:
+                part_row = (
+                    typed_partition_row(add, part_schema) if part_cols else None
+                )
+                plan = rowgroups.plan_row_groups(
+                    meta, predicate, part_row, pcols_lower, pred_types,
+                    rewrites=pred_rewrites,
+                )
+                keep_idx, skipped_bytes = plan.keep, plan.skipped_bytes
+                plan_fired = plan.fired
+            if pos_hint is not None and n_rg:
+                wanted = rowgroups.row_groups_for_positions(meta, pos_hint)
+                for i in keep_idx:
+                    if i not in wanted:
+                        skipped_bytes += meta.row_group(i).total_byte_size
+                keep_idx = [i for i in keep_idx if i in wanted]
+            ev.data.update(rowGroups=n_rg, kept=len(keep_idx),
+                           footerCached=cached)
+            return meta, keep_idx, skipped_bytes, plan_fired
+
+    def _open_file(abs_path, meta):
+        """``(ParquetFile, the projected columns this file has)``: files
+        written before a schema evolution lack the newer columns, and the
+        read fills them with nulls. ``meta`` is the footer where the planner
+        fetched one.
+        memory_map: decoded columns reference page-cache pages instead of
+        round-tripping file bytes through the Arrow memory pool — on
+        single-core hosts the pool churn costs more than the decode."""
+        with telemetry.record_operation("delta.scan.decode.open.file"):
+            pf = pq.ParquetFile(abs_path, memory_map=True, metadata=meta)
+            present = set(pf.schema_arrow.names)
+            return pf, [c for c in data_cols if c in present]
+
     def _decode_pruned(abs_path, meta, keep_idx, add, need_positions, stage):
         """Decode only ``keep_idx`` row groups (late-materializing around
         the predicate columns); returns (table, physical_positions | None,
@@ -211,16 +264,17 @@ def read_files_as_table(
 
         from delta_tpu.exec import rowgroups
 
-        offsets = rowgroups.row_group_offsets(meta)
+        with telemetry.record_operation("delta.scan.decode.open.survivors",
+                                        {"survivors": len(keep_idx)}):
+            offsets = rowgroups.row_group_offsets(meta)
         late_skipped = 0
         late_bytes = 0
         if not keep_idx:
             t = _dummy(0)
             pos = np.empty(0, dtype=np.int64) if need_positions else None
             return t, pos, 0, 0
-        pf = pq.ParquetFile(abs_path, memory_map=True, metadata=meta)
+        pf, file_cols = _open_file(abs_path, meta)
         present = set(pf.schema_arrow.names)
-        file_cols = [c for c in data_cols if c in present]
         if not file_cols:
             t = _dummy(int(sum(meta.row_group(i).num_rows for i in keep_idx)))
         else:
@@ -316,21 +370,22 @@ def read_files_as_table(
 
         from delta_tpu.exec import rowgroups
 
-        offsets = rowgroups.row_group_offsets(meta)
-        if len(dev_mask) != offsets[-1]:
-            return None
-        survivors = []
-        dev_skipped = dev_bytes = surv_bytes = 0
-        for i in keep_idx:
-            if dev_mask[offsets[i]:offsets[i + 1]].any():
-                survivors.append(i)
-                surv_bytes += meta.row_group(i).total_byte_size
-            else:
-                dev_skipped += 1
-                dev_bytes += meta.row_group(i).total_byte_size
-        pf = pq.ParquetFile(abs_path, memory_map=True, metadata=meta)
-        present = set(pf.schema_arrow.names)
-        file_cols = [c for c in data_cols if c in present]
+        with telemetry.record_operation(
+                "delta.scan.decode.open.survivors") as ev:
+            offsets = rowgroups.row_group_offsets(meta)
+            if len(dev_mask) != offsets[-1]:
+                return None
+            survivors = []
+            dev_skipped = dev_bytes = surv_bytes = 0
+            for i in keep_idx:
+                if dev_mask[offsets[i]:offsets[i + 1]].any():
+                    survivors.append(i)
+                    surv_bytes += meta.row_group(i).total_byte_size
+                else:
+                    dev_skipped += 1
+                    dev_bytes += meta.row_group(i).total_byte_size
+            ev.data["survivors"] = len(survivors)
+        pf, file_cols = _open_file(abs_path, meta)
         if not survivors:
             t = (pf.schema_arrow.empty_table().select(file_cols)
                  if file_cols else _dummy(0))
@@ -371,33 +426,10 @@ def read_files_as_table(
         positions = None
         meta = None
         if rg_skipping and (predicate is not None or pos_hint is not None):
-            from delta_tpu.exec import rowgroups
-
-            try:
-                meta = rowgroups.read_footer(abs_path)
-            except Exception:
-                meta = None
+            meta, keep_idx, skipped_bytes, plan_fired = _plan_groups(
+                abs_path, add, pos_hint)
         if meta is not None and meta.num_row_groups > 0:
             n_rg = meta.num_row_groups
-            keep_idx = list(range(n_rg))
-            skipped_bytes = 0
-            plan_fired: list = []
-            if predicate is not None and n_rg > 1:
-                part_row = (
-                    typed_partition_row(add, part_schema) if part_cols else None
-                )
-                plan = rowgroups.plan_row_groups(
-                    meta, predicate, part_row, pcols_lower, pred_types,
-                    rewrites=pred_rewrites,
-                )
-                keep_idx, skipped_bytes = plan.keep, plan.skipped_bytes
-                plan_fired = plan.fired
-            if pos_hint is not None:
-                wanted = rowgroups.row_groups_for_positions(meta, pos_hint)
-                for i in keep_idx:
-                    if i not in wanted:
-                        skipped_bytes += meta.row_group(i).total_byte_size
-                keep_idx = [i for i in keep_idx if i in wanted]
             pruned = n_rg - len(keep_idx)
             late_capable = (
                 late_materialize and predicate is not None
@@ -427,17 +459,8 @@ def read_files_as_table(
                 rg_stats.append((n_rg, 0, 0, 0, 0, (), 0, 0, 0))
         if t is None:
             # full decode — the seed path; reuse the already-parsed footer
-            # when the planner fetched one.
-            # memory_map: decoded columns reference page-cache pages
-            # instead of round-tripping file bytes through the Arrow
-            # memory pool — on single-core hosts the pool churn costs
-            # more than the decode
-            pf = pq.ParquetFile(abs_path, memory_map=True, metadata=meta)
-            # project to the columns this file actually has (files written
-            # before a schema evolution lack the newer columns — read
-            # fills them w/ null)
-            present = set(pf.schema_arrow.names)
-            file_cols = [c for c in data_cols if c in present]
+            # when the planner fetched one
+            pf, file_cols = _open_file(abs_path, meta)
             stage("delta.scan.decode.rowGroups",
                   _groups(pf.metadata, range(pf.metadata.num_row_groups)))
             if file_cols:

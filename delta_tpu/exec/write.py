@@ -26,6 +26,7 @@ from delta_tpu.expr.vectorized import arrow_type_for
 from delta_tpu.protocol.actions import AddFile, Metadata
 from delta_tpu.schema import constraints as constraints_mod
 from delta_tpu.schema.types import StructType
+from delta_tpu.utils import telemetry
 from delta_tpu.utils.config import DeltaConfigs
 from delta_tpu.utils.errors import SchemaMismatchError
 
@@ -160,7 +161,64 @@ def write_files(
     the GIL) — the host fan-out the reference gets from `FileFormatWriter`
     parallel tasks (`files/TransactionalWrite.scala:182-192`). Batches larger
     than ``delta.tpu.write.targetFileRows`` split into multiple files so the
-    encode parallelizes and later scans decode in parallel."""
+    encode parallelizes and later scans decode in parallel.
+
+    Three spans tile the call under whatever span the caller holds:
+    ``delta.write.prepare`` (everything before the first byte is encoded),
+    then for each file, on the thread that writes it, ``delta.write.encode``
+    and ``delta.write.stats``."""
+    with telemetry.record_operation(
+            "delta.write.prepare",
+            {"rows": table.num_rows, "columns": table.num_columns,
+             "chunksIn": table.column(0).num_chunks if table.num_columns else 0}
+    ) as pev:
+        jobs, num_indexed = _plan_files(table, metadata, target_file_rows,
+                                        constraints)
+        pev.data["files"] = len(jobs)
+
+    def write_one(job) -> AddFile:
+        pv, rel, file_data = job
+        abs_path = os.path.join(data_path, rel.replace("/", os.sep))
+        with telemetry.record_operation(
+                "delta.write.encode", {"rows": file_data.num_rows}) as eev:
+            size, mtime = pq_exec.write_parquet_file(file_data, abs_path)
+            eev.data["bytes"] = size
+        with telemetry.record_operation(
+                "delta.write.stats", {"columns": file_data.num_columns}):
+            stats = pq_exec.stats_json(file_data, num_indexed)
+        return AddFile(
+            # AddFile.path is URI-encoded per the protocol (the hive-
+            # escaped dir's '%' becomes '%25'); readers unquote once.
+            # safe set = URI path chars java Path.toUri leaves bare.
+            path=urllib.parse.quote(rel, safe="/:@!$&'()*+,;=-._~"),
+            partition_values=pv,
+            size=size,
+            modification_time=mtime,
+            data_change=data_change,
+            stats=stats,
+        )
+
+    if len(jobs) <= 1:
+        return [write_one(j) for j in jobs]
+    from concurrent.futures import ThreadPoolExecutor
+
+    workers = min(len(jobs), os.cpu_count() or 4)
+    # span-context propagation: a file's encode and stats spans parent under
+    # the enclosing command span instead of orphan worker roots
+    with ThreadPoolExecutor(max_workers=workers,
+                            thread_name_prefix="delta-parquet-write") as pool:
+        return list(pool.map(telemetry.propagated(write_one), jobs))
+
+
+def _plan_files(
+    table: pa.Table,
+    metadata: Metadata,
+    target_file_rows: Optional[int],
+    constraints: Optional[List[constraints_mod.Constraint]],
+) -> Tuple[List[Tuple[Dict[str, Optional[str]], str, pa.Table]], int]:
+    """`write_files` up to the encoder: the batch checked, normalized to the
+    table's schema and split into ``(partition values, relative path, file
+    table)`` jobs; and how many leading columns get statistics."""
     from delta_tpu.utils.config import conf
 
     schema: StructType = metadata.schema
@@ -225,31 +283,4 @@ def write_files(
             rel = f"{prefix}/{name}" if prefix else name
             jobs.append((pv, rel, file_data))
 
-    def write_one(job) -> AddFile:
-        pv, rel, file_data = job
-        abs_path = os.path.join(data_path, rel.replace("/", os.sep))
-        size, mtime = pq_exec.write_parquet_file(file_data, abs_path)
-        return AddFile(
-            # AddFile.path is URI-encoded per the protocol (the hive-
-            # escaped dir's '%' becomes '%25'); readers unquote once.
-            # safe set = URI path chars java Path.toUri leaves bare.
-            path=urllib.parse.quote(rel, safe="/:@!$&'()*+,;=-._~"),
-            partition_values=pv,
-            size=size,
-            modification_time=mtime,
-            data_change=data_change,
-            stats=pq_exec.stats_json(file_data, num_indexed),
-        )
-
-    if len(jobs) <= 1:
-        return [write_one(j) for j in jobs]
-    from concurrent.futures import ThreadPoolExecutor
-
-    from delta_tpu.utils import telemetry
-
-    workers = min(len(jobs), os.cpu_count() or 4)
-    # span-context propagation: per-file write counters/events parent under
-    # the enclosing command span instead of orphan worker roots
-    with ThreadPoolExecutor(max_workers=workers,
-                            thread_name_prefix="delta-parquet-write") as pool:
-        return list(pool.map(telemetry.propagated(write_one), jobs))
+    return jobs, num_indexed

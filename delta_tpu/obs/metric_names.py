@@ -231,6 +231,9 @@ ENGINE_COUNTERS = frozenset({
     "link.d2h.bytes",
     "link.d2h.count",
     "link.d2h.waitUs",
+    # -- the interpreter's collections (utils/telemetry._on_gc) ------------
+    "host.gc.collections",
+    "host.gc.pauseUs",
     "device.compiles",
     "device.compileUs",
     "device.cacheFetches",
@@ -489,6 +492,8 @@ DESCRIPTIONS = {
     "link.d2h.bytes": "Bytes fetched device to host (exact, from nbytes).",
     "link.d2h.count": "Blocking device-to-host fetches.",
     "link.d2h.waitUs": "Wall microseconds in blocking fetches: the wait for the kernel that makes the array, then the copy.",
+    "host.gc.collections": "Collections of the interpreter's garbage collector, every generation (a gc.callbacks entry; a pause of a millisecond or more is also an event host.gc on the spans' clock).",
+    "host.gc.pauseUs": "Wall microseconds inside those collections: every thread of the process stands still for them.",
     "scan.prune.deviceFallback": "Device file prunes that raised and fell back to the host evaluator.",
     "columnCache.hits": "Scan column-cache lane hits (file, column resident).",
     "columnCache.misses": "Scan column-cache lane misses (cold decode).",

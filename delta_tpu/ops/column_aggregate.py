@@ -57,7 +57,9 @@ groups a file can hold. A decline says why in the span's ``route``
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import time
 from decimal import Decimal
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -1135,18 +1137,41 @@ def _group_keys(snapshot, group_by, fields, parts) -> Tuple[Dict[str, str], Dict
     return names, types
 
 
+def _launches(lev, kernel, calls, carry):
+    """``kernel(*arguments, carry)`` once for each of ``calls``, the carry
+    threaded through. ``calls`` is lazy, so a launch's arguments are made
+    between two launches; the stage's event ``lev`` gets ``launches`` and
+    ``dispatchUs``, the time inside the calls of ``kernel`` alone: the
+    stage's length less that is what the arguments took (`_rows_on_device`,
+    `_keep_mask`, `_layout_on_device`). A count, not a span a launch: a span
+    costs what a tenth of a launch does."""
+    n = inside = 0
+    for args in calls:
+        t0 = time.perf_counter_ns()
+        carry = kernel(*args, carry)
+        inside += time.perf_counter_ns() - t0
+        n += 1
+    lev.data.update(launches=n, dispatchUs=inside // 1000)
+    return carry
+
+
 def _launch_ungrouped(preds, bounds, specs, per_file, data_path, rows):
     """The ungrouped program once a file, the carry summed on the device;
-    one fetch: four slots a spec."""
+    one fetch: four slots a spec. Two stages tile the span: ``.launch`` (to
+    the last launch enqueued) and ``.fetch`` (the wait for the device and
+    the download)."""
     carry = _empty_carry(specs)
     if per_file:
         with telemetry.record_operation("delta.columnCache.aggregate",
-                                        {"rows": rows}), enable_x64():
+                                        {"rows": rows}), enable_x64(), \
+                telemetry.span_stages() as stage:
+            lev = stage("delta.columnCache.aggregate.launch")
             kernel = _aggregate_kernel(preds, specs)
             dev_bounds, carry = link.to_device(bounds), link.to_device(carry)
-            for f in per_file:
-                carry = kernel(f.env, dev_bounds, _rows_on_device(f.rows),
-                               _keep_mask(f, data_path), carry)
+            carry = _launches(lev, kernel, (
+                (f.env, dev_bounds, _rows_on_device(f.rows),
+                 _keep_mask(f, data_path)) for f in per_file), carry)
+            stage("delta.columnCache.aggregate.fetch")
             carry = link.to_host(carry)
     return carry
 
@@ -1155,7 +1180,8 @@ def _launch_grouped(preds, bounds, terms, keys, per_file, data_path, rows):
     """The grouped program once a file, each into its own row of the carry;
     one fetch: ``(partials[file, slot, column], each file's key layout, the
     program that ran: `_fits_tiles`)``. Declines ``groups`` when a file's
-    keys could number more than ``GROUP_SLOTS``."""
+    keys could number more than ``GROUP_SLOTS``. The span's two stages are
+    `_launch_ungrouped`'s."""
     layouts, slots = [], 1
     for f in per_file:
         file_layout, file_slots = _key_layout(f, keys)
@@ -1170,17 +1196,34 @@ def _launch_grouped(preds, bounds, terms, keys, per_file, data_path, rows):
                                      sorted(per_file[0].env)) else "wide"
     with telemetry.record_operation("delta.columnCache.aggregate",
                                     {"rows": rows, "program": program}), \
-            enable_x64():
+            enable_x64(), telemetry.span_stages() as stage:
+        lev = stage("delta.columnCache.aggregate.launch")
         kernel = _group_kernel(preds, terms, keys, slots, program)
         width = 1 + sum(len(c) for c in _term_columns(terms))
         dev_bounds = link.to_device(bounds)
-        carry = _zeros_on_device((len(per_file), slots, width))
-        for i, (f, file_layout) in enumerate(zip(per_file, layouts)):
-            carry = kernel(f.env, dev_bounds, _rows_on_device(f.rows),
-                           _keep_mask(f, data_path),
-                           _layout_on_device(file_layout), _rows_on_device(i),
-                           carry)
+        carry = _launches(lev, kernel, (
+            (f.env, dev_bounds, _rows_on_device(f.rows),
+             _keep_mask(f, data_path), _layout_on_device(file_layout),
+             _rows_on_device(i))
+            for i, (f, file_layout) in enumerate(zip(per_file, layouts))),
+            _zeros_on_device((len(per_file), slots, width)))
+        stage("delta.columnCache.aggregate.fetch")
         return link.to_host(carry), layouts, program
+
+
+@contextlib.contextmanager
+def _stage(op_type: str):
+    """A child span of the query's that a decline passes through unmarked:
+    a decline is the route's answer (`device_aggregate` writes it on the
+    query's span), not an error of the stage it was reached in."""
+    declined = None
+    with telemetry.record_operation(op_type) as ev:
+        try:
+            yield ev
+        except _Decline as d:
+            declined = d
+    if declined is not None:
+        raise declined
 
 
 def _device_aggregate(snapshot, filters, parsed_items, group_by, order_keys, ev):
@@ -1190,60 +1233,68 @@ def _device_aggregate(snapshot, filters, parsed_items, group_by, order_keys, ev)
         raise _Decline("off")
     from delta_tpu.expr.synthesis import schema_types
 
-    metadata = snapshot.metadata
-    fields = schema_types(metadata)
-    parts = {c.lower() for c in metadata.partition_columns}
-    key_names, key_types = _group_keys(snapshot, group_by, fields, parts)
-    keys = tuple(key_names)
-    specs, types, inners, layout = _specs(parsed_items, key_names, fields, parts)
-    typed = _typed(specs, types, inners)
+    # two stages name what the query's span holds beside planning, the
+    # launches and the merge of the groups
+    with _stage("delta.scan.deviceAggregate.resolve"):
+        metadata = snapshot.metadata
+        fields = schema_types(metadata)
+        parts = {c.lower() for c in metadata.partition_columns}
+        key_names, key_types = _group_keys(snapshot, group_by, fields, parts)
+        keys = tuple(key_names)
+        specs, types, inners, layout = _specs(parsed_items, key_names, fields,
+                                              parts)
+        typed = _typed(specs, types, inners)
     scan = pruning.files_for_scan(snapshot, list(filters))
-    preds, bounds = _ranges(
-        ir.and_all(scan.data_filters) if scan.data_filters else None,
-        fields, metadata.partition_columns)
-    need = sorted(set(preds) | set(keys) | {c for s in specs for c in s.cols})
-    if not need:
-        raise _Decline("shape")  # COUNT(*) of a whole table: the log's to answer
-    log_path = snapshot.delta_log.log_path
-    data_path = snapshot.delta_log.data_path
-    cache = column_cache.ColumnCache.instance()
-    # resident, or what is missing fits the budget beside what is
-    held, cold = 0, []
-    for add in scan.files:
-        lanes = [cache.get(log_path, add.path, c) for c in need]
-        if all(e is not None for e in lanes):
-            held += sum(e.nbytes for e in lanes)
-        else:
-            cold.append(add)
-    if cold and held + _lane_bytes(cold, need, fields) > column_cache.lane_budget():
-        raise _Decline("budget")
-    counters = {"hits": 0, "misses": 0, "coldBytes": 0}
-    per_file: List[_FileLanes] = []
-    for add in scan.files:
-        lanes = column_cache._ensure_lanes(cache, log_path, data_path, add, need,
-                                           cache.epoch(log_path), counters)
-        if lanes is None or any(e.dict_codes is not None
-                                for c, e in lanes.items() if c not in keys):
-            raise _Decline("type")
-        per_file.append(_FileLanes(
-            add, {c: (e.values, e.valid) for c, e in lanes.items()},
-            max(e.n for e in lanes.values()),
-            {c: (e.lo, e.hi) for c, e in lanes.items()},
-            {c: lanes[c].dict_codes for c in keys}))
-    for name in ("hits", "misses"):
-        if counters[name]:
-            telemetry.bump_counter(f"columnCache.{name}", counters[name])
-    _check_overflow(specs, typed, per_file, bool(keys))
-    rows = sum(f.rows for f in per_file)
-    ev.data.update(files=len(per_file), rows=rows, hits=counters["hits"],
-                   misses=counters["misses"])
-    names = [alias if kind == "agg" else alias or payload
-             for kind, payload, alias in parsed_items]
+    with _stage("delta.scan.deviceAggregate.lanes") as lev:
+        preds, bounds = _ranges(
+            ir.and_all(scan.data_filters) if scan.data_filters else None,
+            fields, metadata.partition_columns)
+        need = sorted(set(preds) | set(keys) | {c for s in specs for c in s.cols})
+        if not need:
+            raise _Decline("shape")  # COUNT(*) of a whole table: the log's to answer
+        log_path = snapshot.delta_log.log_path
+        data_path = snapshot.delta_log.data_path
+        cache = column_cache.ColumnCache.instance()
+        # resident, or what is missing fits the budget beside what is
+        held, cold = 0, []
+        for add in scan.files:
+            lanes = [cache.get(log_path, add.path, c) for c in need]
+            if all(e is not None for e in lanes):
+                held += sum(e.nbytes for e in lanes)
+            else:
+                cold.append(add)
+        if cold and held + _lane_bytes(cold, need, fields) > column_cache.lane_budget():
+            raise _Decline("budget")
+        counters = {"hits": 0, "misses": 0, "coldBytes": 0}
+        per_file: List[_FileLanes] = []
+        for add in scan.files:
+            lanes = column_cache._ensure_lanes(cache, log_path, data_path, add,
+                                               need, cache.epoch(log_path),
+                                               counters)
+            if lanes is None or any(e.dict_codes is not None
+                                    for c, e in lanes.items() if c not in keys):
+                raise _Decline("type")
+            per_file.append(_FileLanes(
+                add, {c: (e.values, e.valid) for c, e in lanes.items()},
+                max(e.n for e in lanes.values()),
+                {c: (e.lo, e.hi) for c, e in lanes.items()},
+                {c: lanes[c].dict_codes for c in keys}))
+        for name in ("hits", "misses"):
+            if counters[name]:
+                telemetry.bump_counter(f"columnCache.{name}", counters[name])
+        _check_overflow(specs, typed, per_file, bool(keys))
+        rows = sum(f.rows for f in per_file)
+        ev.data.update(files=len(per_file), rows=rows, hits=counters["hits"],
+                       misses=counters["misses"])
+        lev.data.update(files=len(per_file), lanes=len(per_file) * len(need))
+        names = [alias if kind == "agg" else alias or payload
+                 for kind, payload, alias in parsed_items]
+        if keys:
+            terms, spec_term = _terms(specs, per_file)
     if not keys:
         carry = _launch_ungrouped(preds, bounds, specs, per_file, data_path, rows)
         return names, [_column(spec.func, types_of, [carry[_SLOTS * k:_SLOTS * (k + 1)]])
                        for k, (spec, types_of) in enumerate(zip(specs, typed))]
-    terms, spec_term = _terms(specs, per_file)
     partials, layouts, program = _launch_grouped(preds, bounds, terms, keys,
                                                  per_file, data_path, rows)
     with telemetry.record_operation("delta.scan.deviceAggregate.groups") as gev:

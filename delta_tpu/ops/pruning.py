@@ -568,11 +568,10 @@ def files_for_scan(
     step is the skipping path the reference leaves unwired. Unpartitioned
     tables with an exactly-lowerable predicate serve from the resident
     state cache instead of materializing every AddFile."""
-    from delta_tpu.utils.telemetry import observe, record_operation, with_status
+    from delta_tpu.utils.telemetry import observe, record_operation
 
     with record_operation("delta.scan.planning") as pev:
-        with with_status("Filtering files for query"):
-            scan = _files_for_scan_impl(snapshot, filters, keep_num_indexed_cols)
+        scan = _files_for_scan_impl(snapshot, filters, keep_num_indexed_cols)
         pev.data.update(
             filesTotal=scan.total.files, filesAfterPartition=scan.partition.files,
             filesScanned=scan.scanned.files,

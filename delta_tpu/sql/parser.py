@@ -27,7 +27,7 @@ from delta_tpu.log.deltalog import DeltaLog
 from delta_tpu.schema.types import StructField, StructType
 from delta_tpu.sql.lexer import Token, tokenize
 from delta_tpu.utils.errors import DeltaAnalysisError, DeltaParseError
-from delta_tpu.utils import errors
+from delta_tpu.utils import errors, telemetry
 
 __all__ = ["execute_sql", "parse_statement"]
 
@@ -434,8 +434,6 @@ def _select(p: _Parser):
     def run():
         # the query's root span: the log's update, then either the device
         # aggregate or the scan (`delta.scan`) and the host's aggregate
-        from delta_tpu.utils import telemetry
-
         with telemetry.record_operation("delta.sql.select") as ev:
             out = select()
             ev.data["rowsOut"] = out.num_rows
@@ -447,71 +445,76 @@ def _select(p: _Parser):
         from delta_tpu.expr.parser import parse_expression
         from delta_tpu.expr.vectorized import evaluate
 
-        log = _log_for(path)
-        sel_version, sel_timestamp = version, timestamp
-        if not log.table_exists and path[0] == "path":
-            # `delta.\`/t@v3\`` embedded time travel (reads only)
-            from delta_tpu.log.deltalog import extract_path_time_travel
+        # one span for what comes before the plan: the handle, the snapshot
+        # (`delta.log.update` inside it) and the select list and predicate
+        # parsed against its schema
+        with telemetry.record_operation("delta.sql.select.resolve"):
+            log = _log_for(path)
+            sel_version, sel_timestamp = version, timestamp
+            if not log.table_exists and path[0] == "path":
+                # `delta.\`/t@v3\`` embedded time travel (reads only)
+                from delta_tpu.log.deltalog import extract_path_time_travel
 
-            spec = extract_path_time_travel(path[1])
-            if spec is not None:
-                base_log = DeltaLog.for_table(spec[0])
-                if base_log.table_exists:
-                    log = base_log
-                    if sel_version is None and sel_timestamp is None:
-                        sel_version, sel_timestamp = spec[1], spec[2]
-        snap = log.snapshot_for(sel_version, sel_timestamp)
-        schema_cols = [f.name for f in snap.metadata.schema.fields]
-        lower = {c.lower(): c for c in schema_cols}
-        parsed_items = None
-        read_cols = None
-        has_agg = False
-        if not star:
-            # projection pushdown: decode only the referenced columns
-            parsed_items = []
-            needed = set()
-            for text, alias in items:
-                key = text.strip("`").lower()
-                agg = _parse_aggregate(text)
-                if agg is not None:
-                    func, inner = agg
-                    if inner == "*":
-                        if func != "count":
-                            raise errors.sql_star_only_in_count(func)
-                        inner_e = None
+                spec = extract_path_time_travel(path[1])
+                if spec is not None:
+                    base_log = DeltaLog.for_table(spec[0])
+                    if base_log.table_exists:
+                        log = base_log
+                        if sel_version is None and sel_timestamp is None:
+                            sel_version, sel_timestamp = spec[1], spec[2]
+            snap = log.snapshot_for(sel_version, sel_timestamp)
+            schema_cols = [f.name for f in snap.metadata.schema.fields]
+            lower = {c.lower(): c for c in schema_cols}
+            parsed_items = None
+            read_cols = None
+            has_agg = False
+            if not star:
+                # projection pushdown: decode only the referenced columns
+                parsed_items = []
+                needed = set()
+                for text, alias in items:
+                    key = text.strip("`").lower()
+                    agg = _parse_aggregate(text)
+                    if agg is not None:
+                        func, inner = agg
+                        if inner == "*":
+                            if func != "count":
+                                raise errors.sql_star_only_in_count(func)
+                            inner_e = None
+                        else:
+                            inner_e = parse_expression(inner)
+                            for r in _ir.references(inner_e):
+                                if r.lower() in lower:
+                                    needed.add(lower[r.lower()])
+                        parsed_items.append(
+                            ("agg", (func, inner_e), alias or text))
+                        has_agg = True
+                    elif key in lower:
+                        parsed_items.append(("col", lower[key], alias))
+                        needed.add(lower[key])
                     else:
-                        inner_e = parse_expression(inner)
-                        for r in _ir.references(inner_e):
+                        e = parse_expression(text)
+                        parsed_items.append(("expr", e, alias or text))
+                        for r in _ir.references(e):
                             if r.lower() in lower:
                                 needed.add(lower[r.lower()])
-                    parsed_items.append(
-                        ("agg", (func, inner_e), alias or text))
-                    has_agg = True
-                elif key in lower:
-                    parsed_items.append(("col", lower[key], alias))
-                    needed.add(lower[key])
+                for g in group_by:
+                    if g.strip("`").lower() in lower:
+                        needed.add(lower[g.strip("`").lower()])
+                for col, _dir in order:
+                    if col.strip("`").lower() in lower:
+                        needed.add(lower[col.strip("`").lower()])
+                if needed:
+                    read_cols = [c for c in schema_cols if c in needed]
+                elif has_agg and schema_cols:
+                    # aggregate-only projection (e.g. COUNT(*)): one narrow
+                    # column is enough to carry the row count
+                    read_cols = [schema_cols[0]]
                 else:
-                    e = parse_expression(text)
-                    parsed_items.append(("expr", e, alias or text))
-                    for r in _ir.references(e):
-                        if r.lower() in lower:
-                            needed.add(lower[r.lower()])
-            for g in group_by:
-                if g.strip("`").lower() in lower:
-                    needed.add(lower[g.strip("`").lower()])
-            for col, _dir in order:
-                if col.strip("`").lower() in lower:
-                    needed.add(lower[col.strip("`").lower()])
-            if needed:
-                read_cols = [c for c in schema_cols if c in needed]
-            elif has_agg and schema_cols:
-                # aggregate-only projection (e.g. COUNT(*)): one narrow
-                # column is enough to carry the row count
-                read_cols = [schema_cols[0]]
-            else:
-                read_cols = None
-        if (has_agg or group_by) and star:
-            raise DeltaParseError("SELECT * cannot be combined with GROUP BY")
+                    read_cols = None
+            if (has_agg or group_by) and star:
+                raise DeltaParseError("SELECT * cannot be combined with GROUP BY")
+            agg_filters = [parse_expression(cond)] if has_agg and cond else []
         out = None
         hidden: List[str] = []
         order_keys = [c.strip("`").lower() for c, _d in order]
@@ -521,9 +524,8 @@ def _select(p: _Parser):
             # (ops/column_aggregate), or declines
             from delta_tpu.ops.column_aggregate import device_aggregate
 
-            out = device_aggregate(
-                snap, [parse_expression(cond)] if cond else [], parsed_items,
-                group_by, order_keys)
+            out = device_aggregate(snap, agg_filters, parsed_items, group_by,
+                                   order_keys)
             if out is not None:
                 # group keys carried only for ORDER BY follow the select list
                 hidden = out.column_names[len(parsed_items):]
@@ -561,14 +563,18 @@ def _select(p: _Parser):
             else:
                 out = table
         if order and not pre_sort:
-            out_lower = {c.lower(): c for c in out.column_names}
-            keys = []
-            for col, direction in order:
-                real = out_lower.get(col.strip("`").lower())
-                if real is None:
-                    raise errors.column_not_found_in_table(col, out.column_names)
-                keys.append((real, direction))
-            out = out.sort_by(keys)
+            # the sort of the answer: over a few rows, Arrow's fixed cost
+            with telemetry.record_operation("delta.sql.select.order",
+                                            {"rows": out.num_rows}):
+                out_lower = {c.lower(): c for c in out.column_names}
+                keys = []
+                for col, direction in order:
+                    real = out_lower.get(col.strip("`").lower())
+                    if real is None:
+                        raise errors.column_not_found_in_table(
+                            col, out.column_names)
+                    keys.append((real, direction))
+                out = out.sort_by(keys)
         if hidden:
             # group keys carried only for ORDER BY drop out of the result
             out = out.drop_columns(hidden)

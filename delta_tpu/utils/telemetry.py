@@ -51,6 +51,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import gc
 import itertools
 import json
 import logging
@@ -80,7 +81,7 @@ __all__ = [
     "histogram_rows", "bucket_quantile", "drop_labeled_series",
     "current_trace_id", "last_sampled_trace_id", "add_span_sink",
     "remove_span_sink", "TRACEPARENT_ENV", "exc_text", "open_spans",
-    "add_span_counts", "span_stages",
+    "add_span_counts", "span_stages", "GC_EVENT_US",
 ]
 
 
@@ -309,6 +310,8 @@ def _conf_snapshot() -> Tuple[int, bool, int, float]:
         rate = 1.0
     cached = (gen, enabled, size, rate)
     _CONF_CACHE = cached
+    if enabled and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
     return cached
 
 
@@ -636,6 +639,70 @@ def span_stages() -> Iterator[Any]:
             cm.__exit__(None, None, None)
 
 
+# -- the interpreter's own pauses --------------------------------------------
+#
+# A collection stops every thread of the process and no span shows it. One
+# ``gc.callbacks`` entry, installed when telemetry is first found enabled,
+# counts every collection (``host.gc.collections``, ``host.gc.pauseUs``) and
+# keeps a pause of GC_EVENT_US or more as an event ``host.gc`` that has a
+# start and a length on the spans' clock, so whoever lays spans against a
+# timeline (the benchmark's idle gaps) names the gap a collection made.
+#
+# A collection can start between any two bytecodes, also while its thread
+# holds ``_LOCK``, and there is one every few hundred allocations: the
+# callback takes no lock and touches nothing but what is below. Whoever
+# reads counters or events folds that in first, under the lock
+# (`_fold_gc_locked`).
+
+#: a collection at least this long is an event, not only a count
+GC_EVENT_US = 1000
+
+# written by the callback alone (collections never overlap: the interpreter
+# runs one at a time): when the one under way started; collections and
+# microseconds since the process began; the pauses not yet in the ring
+_GC_START_NS = 0
+_GC_TOTAL = [0, 0]
+_GC_EVENTS: List[UsageEvent] = []
+# how much of _GC_TOTAL the counters hold already; written under _LOCK
+_GC_FOLDED = [0, 0]
+_GC_COUNTERS = ("host.gc.collections", "host.gc.pauseUs")
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    global _GC_START_NS
+    if phase == "start":
+        _GC_START_NS = time.perf_counter_ns()
+        return
+    us = (time.perf_counter_ns() - _GC_START_NS) // 1000
+    _GC_TOTAL[0] += 1
+    _GC_TOTAL[1] += us
+    if us >= GC_EVENT_US and _enabled():
+        # no parent: the pause is the process's, whatever span this thread
+        # had open when the collector came round
+        th = threading.current_thread()
+        _GC_EVENTS.append(UsageEvent(
+            "host.gc", int(time.time() * 1000), duration_ms=us // 1000,
+            data={"generation": info.get("generation"),
+                  "collected": info.get("collected")},
+            start_us=_GC_START_NS // 1000, duration_us=us,
+            thread_id=th.ident or 0, thread_name=th.name))
+
+
+def _fold_gc_locked() -> None:
+    """The collections since the last fold into the counters, their events
+    into the ring; callers hold ``_LOCK``. Each total is read once and only
+    grows, so a collection that comes round meanwhile is folded next time."""
+    for i, name in enumerate(_GC_COUNTERS):
+        total = _GC_TOTAL[i]
+        if total != _GC_FOLDED[i]:
+            _COUNTERS[name] = _COUNTERS.get(name, 0) + total - _GC_FOLDED[i]
+            _GC_FOLDED[i] = total
+    n = len(_GC_EVENTS)
+    if n:
+        _BUFFER.extend(_GC_EVENTS[:n])
+        del _GC_EVENTS[:n]
+
+
 def exc_text(e: BaseException) -> str:
     """An exception as it rides an event payload: ``Type: message[:300]``."""
     return f"{type(e).__name__}: {str(e)[:300]}"
@@ -648,7 +715,8 @@ def with_status(message: str, **tags: str) -> Iterator[None]:
     files for query", `PartitionFiltering.scala:34`). Logs at INFO on entry
     and records a `delta.status` usage event with the duration on exit, so
     operators can see WHAT a long-running command is doing, not just that
-    it is running."""
+    it is running. For the long steps (a checkpoint, VACUUM's listing): a
+    scan's planning is `delta.scan.planning` and opens none."""
     logger.info("%s", message)
     with record_operation("delta.status", {"message": message}, **tags):
         yield
@@ -674,11 +742,13 @@ def _prefix_match(name: str, prefix: str) -> bool:
 
 def recent_events(op_prefix: str = "") -> List[UsageEvent]:
     with _LOCK:
+        _fold_gc_locked()
         return [e for e in _BUFFER if _prefix_match(e.op_type, op_prefix)]
 
 
 def clear_events() -> None:
     with _LOCK:
+        _fold_gc_locked()
         _BUFFER.clear()
 
 
@@ -700,11 +770,13 @@ def bump_counter(name: str, by: int = 1) -> None:
 
 def counters(prefix: str = "") -> Dict[str, int]:
     with _LOCK:
+        _fold_gc_locked()
         return {k: v for k, v in _COUNTERS.items() if _prefix_match(k, prefix)}
 
 
 def clear_counters() -> None:
     with _LOCK:
+        _fold_gc_locked()
         _COUNTERS.clear()
 
 
@@ -801,6 +873,7 @@ def clear_metrics() -> None:
 def reset_all() -> None:
     """Events + counters + gauges + histograms back to empty (tests)."""
     with _LOCK:
+        _fold_gc_locked()
         _BUFFER.clear()
         _COUNTERS.clear()
         _GAUGES.clear()
@@ -851,6 +924,7 @@ def prometheus_text() -> str:
     classify and document each series; ``# TYPE`` is emitted once per metric
     name (label sets of one gauge/histogram share their header)."""
     with _LOCK:
+        _fold_gc_locked()
         ctrs = sorted(_COUNTERS.items())
         gags = sorted(_GAUGES.items())
         hists = sorted(_HISTOGRAMS.items(), key=lambda kv: kv[0])
@@ -918,6 +992,7 @@ def bucket_quantile(counts: Sequence[int], count: int, q: float) -> Optional[flo
 def metrics_snapshot() -> Dict[str, Any]:
     """JSON-able snapshot of the whole registry."""
     with _LOCK:
+        _fold_gc_locked()
         ctrs = dict(_COUNTERS)
         gags = dict(_GAUGES)
         hists = [((n, lb), list(h.counts), h.sum, h.count)
@@ -969,6 +1044,7 @@ def export_chrome_trace(path: Optional[str] = None, op_prefix: str = "",
     pid = os.getpid()
     now_us = _now_us()
     with _LOCK:
+        _fold_gc_locked()
         events = list(_BUFFER)
         # open spans are still LIVE (add_span_data mutates ev.data with no
         # lock): copy their payloads while we hold the lock, or a concurrent

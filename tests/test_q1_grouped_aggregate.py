@@ -118,8 +118,10 @@ def test_q1_span_says_groups_and_columns(lineitem_table):
     assert data["files"] == 4 and data["rows"] == 6_000
     (merge,) = telemetry.recent_events(SPAN + ".groups")
     assert merge.data["groups"] == 4
-    stage = telemetry.recent_events("delta.columnCache.aggregate")
-    assert len(stage) == 1
+    # one span for the launches, its two stages inside it (PR 36)
+    assert [e.op_type[len("delta.columnCache.aggregate"):]
+            for e in telemetry.recent_events("delta.columnCache.aggregate")
+            ] == [".launch", ".fetch", ""]
     c1 = telemetry.counters()
     for name in ("scan.aggregate.device", "scan.aggregate.grouped"):
         assert c1[name] - c0.get(name, 0) == 1
@@ -608,7 +610,8 @@ def test_the_rule_takes_q1_at_sf10s_extremes_and_the_span_says_so(lineitem_table
     with conf.set_temporarily(**FORCE):
         execute_sql(Q1.format(path=path, delta=90))
     (query,) = _spans()
-    (stage,) = telemetry.recent_events("delta.columnCache.aggregate")
+    (stage,) = [e for e in telemetry.recent_events("delta.columnCache.aggregate")
+                if e.op_type == "delta.columnCache.aggregate"]
     assert query["program"] == stage.data["program"] == "tiled"
     c1 = telemetry.counters()
     for name in ("scan.aggregate.grouped", "scan.aggregate.grouped.tiled"):
@@ -792,8 +795,16 @@ def test_the_cells_metrics_read_a_run_of_the_engine(lineitem_table):
     assert set(values) == {
         "scan_plan_ms", "scan_lane_hit_pct", "device_idle_pct.scan",
         "agg_device_ms", "agg_link_B", "select_span_cover_pct",
-        "select_idle_unattributed_pct", "group_agg_roofline", "group_merge_ms"}
+        "select_idle_unattributed_pct", "group_agg_roofline", "group_merge_ms",
+        # PR 36: the stages inside the launches' span and the query's
+        "agg_launch_cover_pct", "agg_launch_ms", "agg_fetch_wait_ms",
+        "agg_lanes_ms"}
     assert all(v is not None for v in values.values()), values
+    assert values["agg_launch_ms"] > 0 and values["agg_fetch_wait_ms"] > 0
+    assert values["agg_launch_cover_pct"] > 50 and values["agg_lanes_ms"] > 0
+    assert values["agg_launch_ms"] + values["agg_fetch_wait_ms"] \
+        + values["agg_lanes_ms"] + values["scan_plan_ms"] \
+        + values["group_merge_ms"] < values["agg_device_ms"]
     assert values["scan_lane_hit_pct"] == 100.0
     assert values["agg_link_B"] == 4 * 8 * 12 * 8 + 16
     assert 0 < values["group_agg_roofline"] and values["group_merge_ms"] > 0

@@ -49,7 +49,8 @@ def _fresh_lanes():
 def _routes():
     """(route, ...) of the aggregate spans recorded since the last clear."""
     return [e.data.get("route")
-            for e in telemetry.recent_events("delta.scan.deviceAggregate")]
+            for e in telemetry.recent_events("delta.scan.deviceAggregate")
+            if e.op_type == "delta.scan.deviceAggregate"]  # not its stages
 
 
 def _both(sql):
@@ -490,8 +491,15 @@ def test_the_cells_metrics_read_a_run_of_the_engine(lineitem_table):
     assert set(values) == {
         "scan_plan_ms", "scan_lane_hit_pct", "device_idle_pct.scan",
         "agg_device_ms", "agg_roofline", "agg_link_B", "select_span_cover_pct",
-        "select_idle_unattributed_pct"}
+        "select_idle_unattributed_pct",
+        # PR 36: the stages inside the launches' span and the query's
+        "agg_launch_cover_pct", "agg_launch_ms", "agg_fetch_wait_ms",
+        "agg_lanes_ms"}
     assert all(v is not None for v in values.values()), values
+    assert values["agg_launch_ms"] > 0 and values["agg_fetch_wait_ms"] > 0
+    assert values["agg_launch_cover_pct"] > 50 and values["agg_lanes_ms"] > 0
+    assert values["agg_launch_ms"] + values["agg_fetch_wait_ms"] \
+        + values["agg_lanes_ms"] + values["scan_plan_ms"] < values["agg_device_ms"]
     assert values["scan_lane_hit_pct"] == 100.0
     assert values["agg_link_B"] == 48 + 32 + 32
     assert 0 < values["agg_roofline"] and values["agg_device_ms"] > 0

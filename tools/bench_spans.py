@@ -12,10 +12,17 @@ engaged, every re-sort of the slab (``rows``, ``cause``) and every search of
 its sorted view for flipped rows (``rows``, ``flips``, ``steps``) with the
 counts of flips searched for and of flips that re-sorted instead, the routes
 and group counts of the aggregate queries with the program that answered the
-grouped ones (``tiled`` or ``wide``) and the tiled share, and the device's time in the
-window by XLA module, each module with its longest operations and the
-arguments its operations name. A builder's instrument for
-PERF.md; nothing of the benchmark reads it.
+grouped ones (``tiled`` or ``wide``) and the tiled share, under each leaf span
+that has stages inside it (a MERGE's ``.write`` and ``.apply``, a decode's
+``.open``, an aggregate query's launches, its own span and its root) the
+span's mean, each stage's, their sum and the span's cover, what a launch of an
+aggregate query costs the host split into its arguments and its dispatch, the
+interpreter's collections in the window (count, total, every pause of a
+millisecond or more with the request it fell in, and the slowest requests
+with the pause inside each), and the device's time in the window by XLA
+module, each module with its longest operations and the arguments its
+operations name. A builder's instrument for PERF.md; nothing of the benchmark
+reads it.
 
 ``--decode-route`` makes `MergeIntoCommand._pairs_only_shape` read false, so
 that a resident MERGE decodes the target as before PR 26: the same program
@@ -91,9 +98,110 @@ def report(run) -> None:
             "tiled share of grouped, %:",
             round(100 * tiled / grouped, 2) if grouped else None,
             file=sys.stderr)
+    print("stages inside the leaf spans [span: its mean ms, {stage: ms}, "
+          "their sum, the span's cover %]:", json.dumps(insides(run)),
+          file=sys.stderr)
+    launches = launch_split(done)
+    if launches:
+        print("an aggregate query's launches:", json.dumps(launches),
+              file=sys.stderr)
+    print("the interpreter's collections:", json.dumps(gc_pauses(run)),
+          file=sys.stderr)
     if run.trace is not None:
         print("device ms a request by module:",
               json.dumps(module_split(run.trace, len(done))), file=sys.stderr)
+
+
+# the leaf spans that have stages inside them, each with its stages
+_WRITE, _APPLY = "delta.dml.merge.write", "delta.dml.merge.apply"
+_OPEN, _LAUNCHES = "delta.scan.decode.open", "delta.columnCache.aggregate"
+_QUERY, _SELECT = "delta.scan.deviceAggregate", "delta.sql.select"
+STAGES = {
+    _WRITE: [_WRITE + ".concat", "delta.write.prepare", "delta.write.encode",
+             "delta.write.stats"],
+    _APPLY: [_APPLY + ".multiMatch", _APPLY + ".matched",
+             _APPLY + ".notMatched"],
+    _OPEN: [_OPEN + ".plan", _OPEN + ".survivors", _OPEN + ".file"],
+    _LAUNCHES: [_LAUNCHES + ".launch", _LAUNCHES + ".fetch"],
+    _QUERY: [_QUERY + ".resolve", "delta.scan.planning", _QUERY + ".lanes",
+             _LAUNCHES, _QUERY + ".groups"],
+    _SELECT: [_SELECT + ".resolve", _QUERY, "delta.scan", _SELECT + ".order"],
+}
+
+
+def insides(run):
+    """For each leaf span of `STAGES` that the window's requests opened: its
+    mean milliseconds a request, each stage's (the benchmark's own reader
+    `child_span_mean_ms`, so a listed metric reads the same), the stages'
+    sum, and the span's cover (`span_cover_pct`)."""
+    from benchmark.metrics.readers import (child_span_mean_ms, span_cover_pct,
+                                           span_mean_ms)
+
+    out = {}
+    for parent, stages in STAGES.items():
+        mean = span_mean_ms.read(run, {"spans": [parent]})
+        if mean is None:
+            continue
+        each = {s: round(child_span_mean_ms.read(
+            run, {"parent": parent, "spans": [s]}), 4) for s in stages}
+        cover = span_cover_pct.read(run, {"root": parent})
+        out[parent] = [round(mean, 4), each, round(sum(each.values()), 4),
+                       None if cover is None else round(cover, 3)]
+    return out
+
+
+def launch_split(done):
+    """What the host pays a launch: the ``.launch`` stages' length over their
+    ``launches``, split into the time inside the calls of the jitted function
+    (``dispatchUs``) and the rest, which made the arguments; and the fetch's
+    wait a query. None where no request ran an aggregate query's launches."""
+    def spans(name):
+        return [s for r in done for s in r.spans if s["name"] == name]
+
+    stages = spans(_LAUNCHES + ".launch")
+    if not stages:
+        return None
+    n = sum(s["data"]["launches"] for s in stages)
+    stage_us = sum(s["duration_us"] for s in stages)
+    dispatch_us = sum(s["data"]["dispatchUs"] for s in stages)
+    fetch_us = sum(s["duration_us"] for s in spans(_LAUNCHES + ".fetch"))
+    return {"launches a query": round(n / len(stages), 2),
+            "host us a launch": round(stage_us / n, 2),
+            "of it dispatch": round(dispatch_us / n, 2),
+            "of it arguments": round((stage_us - dispatch_us) / n, 2),
+            "fetch ms a query": round(fetch_us / 1e3 / len(stages), 4)}
+
+
+def gc_pauses(run):
+    """The interpreter's collections in the window: how many and how long by
+    the counters (every generation), the pauses long enough to be events
+    (``host.gc``) each with the request it fell in, and the slowest requests
+    with the pause inside each: whether a request that stalled stalled
+    there."""
+    def pauses(r):
+        return [s for s in r.spans if s["name"] == "host.gc"]
+
+    events = [{"request": r.index, "ms": round(s["duration_us"] / 1e3, 3),
+               "generation": s["data"].get("generation"),
+               "collected": s["data"].get("collected"),
+               "request_ms": round((r.end - r.start) * 1e3, 3)}
+              for r in run.requests for s in pauses(r)]
+    slowest = sorted(run.requests, key=lambda r: r.start - r.end)[:5]
+    walls = sorted(r.end - r.start for r in run.requests)
+    count = run.counters.get("host.gc.collections", 0)
+    return {"collections": count,
+            "pause_ms": round(run.counters.get("host.gc.pauseUs", 0) / 1e3, 3),
+            "a request": round(count / max(len(run.requests), 1), 2),
+            "events": len(events),
+            "events_ms": round(sum(e["ms"] for e in events), 3),
+            "longest": sorted(events, key=lambda e: -e["ms"])[:8],
+            "median_request_ms": round(walls[len(walls) // 2] * 1e3, 3)
+            if walls else None,
+            "slowest_requests": [
+                {"request": r.index,
+                 "ms": round((r.end - r.start) * 1e3, 3),
+                 "gc_ms": round(sum(s["duration_us"] for s in pauses(r)) / 1e3,
+                                3)} for r in slowest]}
 
 
 def by_clauses(done):
