@@ -130,6 +130,8 @@ COUNTERS = frozenset({
     "merge.keyCache.invalidations",  # entries dropped by a rewrite epoch bump
     "merge.keyCache.flipSearches",  # flips mirrored in a live sorted view
     "merge.keyCache.flipResorts",  # flips that dropped the view instead
+    "merge.keyCache.tailSorts",   # sorts of a slab's tail run alone
+    "merge.keyCache.folds",       # whole sorts that emptied a full tail
     # -- router audit ledger + calibrator (obs/router_audit, obs/calibration)
     "router.audits",              # one per routed decision recorded
     "router.misses",              # hindsight: rejected route predicted faster
@@ -415,8 +417,10 @@ DESCRIPTIONS = {
     "merge.keyCache.builds": "Cold resident key-lane builds.",
     "merge.keyCache.advances": "Incremental log-tail applications to a key lane.",
     "merge.keyCache.invalidations": "Key-cache entries dropped by a rewrite epoch bump.",
-    "merge.keyCache.flipSearches": "Validity flips on a live sorted view of a key slab that were mirrored in sorted space, the rows' sorted positions found by a search of the view (span delta.keyCache.locate). A flip on a stale view, as in an advance that appends keys, counts in neither.",
-    "merge.keyCache.flipResorts": "Validity flips on a live sorted view of more rows than are worth searching for: the flip stayed in row space and dropped the view, and the next probe re-sorted (its delta.keyCache.sort span says cause=flips).",
+    "merge.keyCache.flipSearches": "Validity flips of rows of a key slab's live big sorted run that were mirrored in sorted space, the rows' sorted positions found by a search of the run (span delta.keyCache.locate). A flip of the tail run's rows alone, or on a dropped view, counts in neither.",
+    "merge.keyCache.flipResorts": "Validity flips of more rows of a live big sorted run than are worth searching for: the flip stayed in row space and dropped both sorted runs, and the next probe sorted the whole slab (its delta.keyCache.sort span says tier=all, cause=flips).",
+    "merge.keyCache.tailSorts": "Sorts of a key slab's tail run alone (delta.keyCache.sort with tier=tail): the rows appended after the big sorted run's, or flipped among them, sorted at the tail's fixed capacity while the big run stayed as it was.",
+    "merge.keyCache.folds": "Sorts of a whole key slab because an append found no room in the tail run (delta.keyCache.sort with tier=all, cause=fold): the big run takes every row in and the tail is empty again.",
     "router.audits": "Routed decisions recorded in the audit ledger.",
     "router.misses": "Audits where a rejected route's prediction beat the actual.",
     "router.calibration.updates": "EWMA samples folded into the calibration state.",

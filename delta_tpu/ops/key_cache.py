@@ -156,34 +156,66 @@ class PhysicalProbe:
 from delta_tpu.ops.join_kernel import PendingJoin as PendingProbe
 
 
+def _tail_capacity(cap: int) -> int:
+    """Rows of the slab's second sorted run, the tail, from the slab's
+    capacity alone (a static shape: every program that reads the tail
+    compiles once a capacity, whatever the tail holds). An append of ``a``
+    rows costs the device one sort of the tail now and its share ``a / T``
+    of the fold that empties it later, and every probe reads the tail
+    beside the big run. On the chip (PERF.md PR 37, call 123), at
+    60,817,408 rows: the whole sort 280.0 ms, the tail's 4.7 / 5.9 / 8.4 /
+    14.1 / 29.9 ms at 0.5M / 1M / 2M / 4M / 8M (3.9 of it whatever the
+    tail: the int64 lanes split into 32-bit planes at the capacity's
+    length before the window is cut), so a refresh function's 60,000 rows
+    cost 37.5 / 21.9 / 16.4 / 18.1 / 31.9 ms an append, and a probe of
+    65,536 keys 1.4-1.5 ms more over a tail of 2M or 4M than over none.
+    At 37,748,736: the whole sort 191.2, the tail's 7.4 / 13.0 / 28.7 at
+    2M / 4M / 8M, and a probe of 1,048,576 keys 24.8 / 41.4 ms more over
+    4M / 8M than over none, so an upsert's 1M-row file costs 103 (and the
+    tail holds two) / 85.6 / 94.0 ms an append at 2M / 4M / 8M. A
+    sixteenth of the capacity, rounded up to a power of two (the probe's
+    blocks divide one), is 4,194,304 at both."""
+    return _next_pow2(cap // 16, floor=64)
+
+
 @functools.lru_cache(maxsize=None)
 def _sort_kernel():
-    """Sort the slab's key lane once per KEY mutation (build/append) or
-    per validity flip too large to search for (`_flip_by_search`), NOT per
-    probe: steady-state probes against an unchanged table then skip the
-    O(n log n) term entirely. One sort gives all three arrays a probe
-    reads: the int32 that rides with each key is its physical row with the
-    row's validity in the low bit, so the permutation and the sorted-space
-    validity are two dense reads of the sorted payload, never a gather
-    through the permutation. The payload is the second sort key: it orders
-    as the row does, so ties among equal keys stay in physical-row order,
-    valid or dead, without the row-id operand a stable sort would add
-    beside it: (sorted_keys, perm) rises strictly, which is
-    how a flip finds a row's sorted position (`_inverse_permutation_at`).
-    Padding rows encode as int64.max so they sort to the tail and read
-    invalid; a real key equal to int64.max may share their run, before
-    them — harmless, validity excludes them."""
+    """Sort one of the slab's two runs from the row-space lanes: the big
+    run (``size`` the capacity: at a build, a re-ship, a fold of a full
+    tail, or a validity flip too large to search for, `_flip_by_search`)
+    or the tail (``size`` `_tail_capacity`: once per key append that fits
+    it), NOT per probe: steady-state probes against an unchanged table
+    skip the O(n log n) term entirely. ``window`` is (start, lo, n): the
+    run is sorted from the ``size`` lane rows from ``start`` on, of which
+    rows in [lo, n) are its own and every other is padding (the big run's
+    rows, which the tail's window reaches back over where the capacity
+    ends less than a tail past them, and rows not yet appended).
+
+    One sort gives all three arrays a probe reads of a run: the int32 that
+    rides with each key is its physical row with the row's validity in the
+    low bit, so the permutation and the sorted-space validity are two
+    dense reads of the sorted payload, never a gather through the
+    permutation. The payload is the second sort key: it orders as the row
+    does, so ties among equal keys stay in physical-row order, valid or
+    dead, without the row-id operand a stable sort would add beside it:
+    (sorted_keys, perm) rises strictly, which is how a flip finds a row's
+    sorted position (`_inverse_permutation_at`). Padding rows encode as
+    int64.max so they sort to the run's end and read invalid; a real key
+    equal to int64.max may share their run of equal keys — harmless,
+    validity excludes them."""
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
 
-    @jax.jit
-    def kernel(keys, valid, n):
-        cap = keys.shape[0]
-        iota = jnp.arange(cap, dtype=jnp.int32)
-        inside = iota < n
+    @functools.partial(jax.jit, static_argnums=(3,))
+    def kernel(keys, valid, window, size):
+        start, lo, n = window[0], window[1], window[2]
+        keys = jax.lax.dynamic_slice(keys, (start,), (size,))
+        valid = jax.lax.dynamic_slice(valid, (start,), (size,))
+        row = start + jnp.arange(size, dtype=jnp.int32)
+        inside = (row >= lo) & (row < n)
         enc = jnp.where(inside, keys, jnp.iinfo(jnp.int64).max)
-        payload = (iota << 1) | (valid & inside).astype(jnp.int32)
+        payload = (row << 1) | (valid & inside).astype(jnp.int32)
         sk, payload = jax.lax.sort((enc, payload), num_keys=2,
                                    is_stable=False)
         return sk, payload >> 1, (payload & 1) == 1
@@ -300,25 +332,27 @@ _PROBE_SCRATCH_BYTES = 512 << 20
 
 
 def _probe_block(cap: int, m: int) -> int:
-    """Slab rows a block (a power of two that divides every capacity): the
+    """Rows a block of a sorted run of ``cap`` rows (a power of two that
+    divides every capacity and every tail, and is no longer than the run): the
     probe gathers one block a source key and sorts one boundary a block with
     the source, so B follows the square root of the slab rows a source key.
     On a v5e, 37.7M-row capacity and 1M keys, B = 64, 128, 256, 512: 77, 58,
     59, 67 ms a probe (PR 28, chip call 29); a gathered row costs ~11 ns at
     128 or at 256 keys, the parent's block-window probe 583 + 358 ms."""
     b = 64
-    while b < 1024 and b * b * m < 256 * cap:
+    while b < min(1024, cap) and b * b * m < 256 * cap:
         b *= 2
     return b
 
 
 @functools.lru_cache(maxsize=None)
 def _probe_sorted_kernel():
-    """Source-centric membership probe of the PRE-SORTED slab, the first of
-    the join's two programs: its work follows the source (m keys), and only
-    one dense read follows the slab.
+    """Source-centric membership probe of the slab's two PRE-SORTED runs
+    (the big run, then the tail; `_sort_kernel`), the first of the join's
+    two programs: its work follows the source (m keys), and only one dense
+    read follows each run. Over a run:
 
-      - the slab is tiled into blocks of B rows (`_probe_block`); one dense
+      - the run is tiled into blocks of B rows (`_probe_block`); one dense
         pass takes each block's first key (its boundary) and how long, and
         how valid, the run of that key at the block's head is;
       - boundaries and source are sorted together, so a running count of
@@ -330,18 +364,22 @@ def _probe_sorted_kernel():
         give where its run starts, how long it is and how many rows of it
         are valid.
 
-    Nothing of the capacity's length is written. One head array carries
-    [multi | valid pairs (4 bytes LE) | candidate rows (4 bytes LE) |
-    s_bits], a single small fetch; for `_pair_kernel` stay on the device
-    each distinct matched source key's run (start, length) and its MINIMAL
-    original source index: `_first_match_recovery`'s stable-tie semantics,
-    so the pairs are row-identical to the host's."""
+    A slab row is in one sorted run or the other, so a source key matched
+    where either run matched it, the pairs are both runs' pairs and the
+    counts add; equal keys may lie in both (a dead version in the big run,
+    the live one in the tail). Nothing of the capacity's length is
+    written. One head array carries [multi | valid pairs (4 bytes LE) |
+    candidate rows (4 bytes LE) | s_bits], a single small fetch; for
+    `_pair_kernel` stay on the device, the big run's first and then the
+    tail's, each distinct matched source key's run of equal slab keys
+    (start, length; a tail's start counts on from the big run's end) and
+    its MINIMAL original source index: `_first_match_recovery`'s
+    stable-tie semantics, so the pairs are row-identical to the host's."""
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
 
-    @jax.jit
-    def kernel(sorted_keys, sorted_valid, s_keys):
+    def one_run(sorted_keys, sorted_valid, s_keys):
         cap = sorted_keys.shape[0]
         m = s_keys.shape[0]
         blk = _probe_block(cap, m)  # static under jit
@@ -406,13 +444,23 @@ def _probe_sorted_kernel():
         run_len = jnp.where(first & (run_valid > 0), run_len, 0)
         counts = jnp.stack([jnp.sum(jnp.where(first, run_valid, 0)),
                             jnp.sum(run_len)])
+        return multi, counts, s_match, j0 * blk + below, run_len, o
+
+    @jax.jit
+    def kernel(sorted_keys, sorted_valid, tail_keys, tail_valid, s_keys):
+        multi, counts, s_match, *big = one_run(
+            sorted_keys, sorted_valid, s_keys)
+        t_multi, t_counts, t_match, t_lo, *tail = one_run(
+            tail_keys, tail_valid, s_keys)
+        counts = counts + t_counts
         head = jnp.concatenate([
-            multi.astype(jnp.uint8).reshape(1),
+            (multi | t_multi).astype(jnp.uint8).reshape(1),
             ((counts[:, None] >> jnp.arange(0, 32, 8)) & 0xFF).astype(
                 jnp.uint8).reshape(-1),
-            jnp.packbits(s_match.astype(jnp.uint8)),
+            jnp.packbits((s_match | t_match).astype(jnp.uint8)),
         ])
-        return head, j0 * blk + below, run_len, o
+        tail = (t_lo + sorted_keys.shape[0], *tail)
+        return (head, *(jnp.concatenate(x) for x in zip(big, tail)))
 
     return kernel
 
@@ -429,25 +477,33 @@ def _decode_head(head: np.ndarray, cap_s: int, m: int):
 @functools.lru_cache(maxsize=None)
 def _pair_kernel():
     """The join's second program: the pairs, from the source side. Every
-    matched run (start, length, source row) of `_probe_sorted_kernel` is
-    laid out into ``cand_cap`` candidate slots (a scatter of the run starts
-    and two running scans), each slot reads its slab row's validity and
-    physical row through the permutation, and a sort by physical row brings
-    the valid pairs to the front in the host's order: a dense (2, out_cap)
-    int32 buffer of (physical row ascending, first-match source row). Dead
-    versions of a key are candidates and not pairs. ``cand_cap`` and
-    ``out_cap`` are static buckets from the head's two counts; slots past
-    the count hold the int32 maximum (sliced off host-side)."""
+    matched run (start, length, source row) of `_probe_sorted_kernel`, the
+    big run's and then the tail's, is laid out into ``cand_cap`` candidate
+    slots (a scatter of the run starts and two running scans), each slot
+    reads its slab row's validity and physical row through the two runs'
+    permutations laid end to end (one dense copy, 0.8 ms at 65M rows,
+    PERF.md PR 37, where a second pair of gathers would cost 16 ns a slot),
+    and a sort by physical row brings the valid pairs to the front in the
+    host's order: a dense (2, out_cap) int32 buffer of (physical row
+    ascending, first-match source row). A tail's rows lie after every row
+    of the big run, and one call lays both out, so ``cand_cap`` and
+    ``out_cap`` are static buckets from the head's two summed counts, as
+    for one run. Dead versions of a key are candidates and not pairs;
+    slots past the count hold the int32 maximum (sliced off host-side)."""
     ensure_compilation_cache()
     import jax
     import jax.numpy as jnp
 
-    @functools.partial(jax.jit, static_argnums=(5, 6))
-    def kernel(run_lo, run_len, run_src, sorted_valid, perm, cand_cap, out_cap):
+    @functools.partial(jax.jit, static_argnums=(7, 8))
+    def kernel(run_lo, run_len, run_src, sorted_valid, perm, tail_valid,
+               tail_perm, cand_cap, out_cap):
+        sorted_valid = jnp.concatenate([sorted_valid, tail_valid])
+        perm = jnp.concatenate([perm, tail_perm])
         total = run_len.shape[0]
         live = run_len > 0
         end = run_lo + run_len
-        # runs of distinct keys lie apart and in order in the sorted slab
+        # runs of distinct keys lie apart and in order in a sorted run, and
+        # the tail's after the big run's
         prev_end = jnp.concatenate([
             jnp.zeros(1, jnp.int32),
             jax.lax.cummax(jnp.where(live, end, 0))[:-1]])
@@ -494,6 +550,11 @@ def _update_kernels():
                 jax.lax.dynamic_update_slice(v, nv, (start,)),
             )
         ),
+        # a tail that holds no row: all padding, as `_sort_kernel` leaves it
+        "empty_tail": jax.jit(
+            lambda t: (jnp.full(t, jnp.iinfo(jnp.int64).max, jnp.int64),
+                       jnp.zeros(t, jnp.int32), jnp.zeros(t, bool)),
+            static_argnums=0),
     }
 
 
@@ -514,6 +575,12 @@ def _appended(mirror: np.ndarray, new: np.ndarray, room: int) -> np.ndarray:
         base[:n] = mirror
     base[n:n + m] = new
     return base[:n + m]
+
+
+# the device arrays of the slab's two sorted runs, as `_sort_kernel` returns
+# a run's
+_BIG_RUN = ("sorted_keys", "perm", "sorted_valid")
+_TAIL_RUN = ("tail_keys", "tail_perm", "tail_valid")
 
 
 class ResidentJoinKeys:
@@ -548,12 +615,19 @@ class ResidentJoinKeys:
         self._dead = 0
         self._dev = None
         self._pending = None  # batched device updates (see device_batch)
-        # True when the resident sorted view (sorted_keys + perm) lags the
-        # lanes: set by key appends, and by a validity flip of more rows
-        # than are worth finding in it (`_flip_by_search`; a smaller flip
-        # is mirrored in sorted space). The next probe re-sorts once.
-        self._sort_stale = True
-        # what dropped the view (reported by the next sort's span)
+        # The resident sorted view is two sorted runs of (sorted keys,
+        # perm, sorted validity): the big run over the rows [0, _sorted_n)
+        # it was last sorted with, at the capacity, and the tail over
+        # [_sorted_n, num_rows), at `_tail_capacity`. Which of them lags
+        # the lanes: None; "tail" after a key append that fits the tail, or
+        # a validity flip of its rows; "all" (both runs dropped) after any
+        # other change of key rows, an append the tail has no room for
+        # (the fold), or a flip of more of the big run's rows than are
+        # worth finding in it (`_flip_by_search`; a smaller one is mirrored
+        # in sorted space). The next probe sorts what lags, once.
+        self._stale: Optional[str] = "all"
+        self._sorted_n = 0
+        # what made it lag (reported by the next sort's span)
         self._sort_cause = "append"
         self._lock = threading.RLock()
         self.last_used = 0.0
@@ -705,9 +779,10 @@ class ResidentJoinKeys:
 
     @property
     def device_bytes(self) -> int:
-        # keys(8) + valid(1) + sorted view: sorted_keys(8) + perm(4) +
-        # sorted_valid(1), for the whole residency
-        return self.capacity * 22
+        # keys(8) + valid(1) + the big sorted run: sorted_keys(8) + perm(4)
+        # + sorted_valid(1), a capacity row; the tail's 13 a tail row: for
+        # the whole residency
+        return self.capacity * 22 + _tail_capacity(self.capacity) * 13
 
     @property
     def is_resident(self) -> bool:
@@ -790,57 +865,89 @@ class ResidentJoinKeys:
         return {"keys": dk, "valid": dv}
 
     def _ensure_sorted(self) -> None:
-        """Dispatch the slab sort if the sorted view is stale (caller holds
-        the entry lock). The dispatch is async (~ms); the probe kernel that
-        consumes the handles queues behind it on the device."""
-        if self._dev is None:
+        """Dispatch the sort of whichever sorted run lags the lanes (caller
+        holds the entry lock): the tail alone from its window of the lanes,
+        or the whole slab into the big run, which leaves the tail empty.
+        The dispatch is async (~ms); the probe kernel that consumes the
+        handles queues behind it on the device."""
+        if self._dev is None or self._stale is None:
             return
-        if not self._sort_stale and "sorted_keys" in self._dev:
-            return
-        # `cause`: what dropped the view this sort replaces, a key append
-        # or a flip too large to search for (`flips`)
+        tier, n = self._stale, self.num_rows
+        t = _tail_capacity(self.capacity)
+        if tier == "all":
+            size, lo, start = self.capacity, 0, 0
+        else:
+            # a tail's length back from the capacity's end where the big
+            # run ends nearer to it than that: those rows read as padding
+            size, lo = t, self._sorted_n
+            start = min(lo, self.capacity - t)
+        # `tier`: the tail alone or both runs; `cause`: what made it lag, a
+        # key append, a flip too large to search for (`flips`, or a flip of
+        # the tail's rows), an append the tail had no room for (`fold`)
         with telemetry.record_operation(
                 "delta.keyCache.sort",
-                {"rows": self.num_rows, "cause": self._sort_cause}), \
+                {"rows": n - lo, "tier": tier, "cause": self._sort_cause}), \
                 enable_x64():
-            sk, pm, sv = _sort_kernel()(
+            run = _sort_kernel()(
                 self._dev["keys"], self._dev["valid"],
-                link.to_device(np.int32(self.num_rows)))
-        self._dev["sorted_keys"] = sk
-        self._dev["perm"] = pm
-        self._dev["sorted_valid"] = sv
-        self._sort_stale = False
+                link.to_device(np.array([start, lo, n], np.int32)), size)
+            if tier == "all":
+                self._sorted_n = n
+                self._dev.update(zip(_BIG_RUN, run))
+                run = _update_kernels()["empty_tail"](t)
+            self._dev.update(zip(_TAIL_RUN, run))
+        if tier == "tail":
+            telemetry.bump_counter("merge.keyCache.tailSorts")
+        elif self._sort_cause == "fold":
+            telemetry.bump_counter("merge.keyCache.folds")
+        self._stale = None
 
     def _drop_sorted_view(self, cause: str) -> None:
-        """The sorted view lags the lanes: drop it (frees HBM) and let the
-        next probe re-sort."""
-        self._sort_stale = True
-        self._sort_cause = cause
-        for view in ("sorted_keys", "perm", "sorted_valid"):
+        """Both sorted runs lag the lanes: drop them (frees HBM) and let
+        the next probe sort the whole slab into the big run."""
+        self._stale, self._sorted_n, self._sort_cause = "all", 0, cause
+        for view in _BIG_RUN + _TAIL_RUN:
             self._dev.pop(view, None)
+
+    def _tail_lags(self, cause: str) -> None:
+        """The tail lags the lanes (the big run, if live, stays so)."""
+        if self._stale is None:
+            self._stale, self._sort_cause = "tail", cause
 
     def _dev_flip_valid(self, rows: np.ndarray, value: bool) -> None:
         """Validity flip in ROW space plus, when the sorted view is live,
-        the mirrored flip in SORTED space, at the positions a search of the
-        view finds for the rows (`_inverse_permutation_at`). A flip of more
-        rows than `_flip_by_search` allows stays in row space and drops the
-        view: the next probe's sort carries validity in its payload anyway.
-        A flip on a stale view is a row-space flip and nothing else."""
+        the mirrored flip in SORTED space. Rows of the big run flip at the
+        positions a search of it finds for them (`_inverse_permutation_at`);
+        more of them than `_flip_by_search` allows stay in row space and
+        drop the view: the next probe's sort carries validity in its
+        payload anyway. Rows of the tail are not searched for: the tail
+        lags, and its sort carries them. A flip on a dropped view is a
+        row-space flip and nothing else."""
         d = _next_pow2(max(len(rows), 1), floor=64)
         padded = np.full(d, self.capacity, np.int32)
         padded[: len(rows)] = rows
         kern = _update_kernels()["kill" if not value else "revive"]
         rows_dev = link.to_device(padded)
         self._dev["valid"] = kern(self._dev["valid"], rows_dev)
-        if self._sort_stale or "sorted_valid" not in self._dev:
+        if self._stale == "all":
             return
-        if not _flip_by_search(len(rows), self.capacity):
+        flips = int(np.count_nonzero(rows < self._sorted_n))
+        if flips < len(rows):
+            self._tail_lags("flips")
+        if flips == 0:
+            return
+        if not _flip_by_search(flips, self.capacity):
             self._drop_sorted_view("flips")
             telemetry.bump_counter("merge.keyCache.flipResorts")
             return
+        if flips < len(rows):
+            # the search's program at the flip's own shape: the tail's rows
+            # are padding to it
+            rows_dev = link.to_device(np.where(
+                padded < self._sorted_n, padded, np.int32(self.capacity)))
         with telemetry.record_operation(
                 "delta.keyCache.locate",
-                {"rows": self.num_rows, "flips": len(rows),
+                {"rows": self.num_rows, "flips": flips,
                  "steps": _search_steps(self.capacity)}), enable_x64():
             spos = _inverse_permutation_at()(
                 self._dev["sorted_keys"], self._dev["perm"],
@@ -865,13 +972,20 @@ class ResidentJoinKeys:
         nk[:k] = keys
         nv = np.zeros(a, bool)
         nv[:k] = valid
-        contiguous = (
-            k > 0
-            and row_idx[0] + a <= self.capacity
-            and bool((row_idx == np.arange(row_idx[0], row_idx[0] + k,
-                                           dtype=row_idx.dtype)).all())
-        )
-        self._drop_sorted_view("append")  # key rows changed
+        one_run = k > 0 and bool(
+            (row_idx == np.arange(row_idx[0], row_idx[0] + k,
+                                  dtype=row_idx.dtype)).all())
+        contiguous = one_run and row_idx[0] + a <= self.capacity
+        # key rows changed. One run of rows after the live big run's stays
+        # out of it: the tail's next sort takes them in, if it has room for
+        # them; if not, one sort of the whole slab (the fold) empties it
+        past_big = (one_run and self._stale != "all"
+                    and row_idx[0] >= self._sorted_n)
+        if past_big and row_idx[0] + k - self._sorted_n <= _tail_capacity(
+                self.capacity):
+            self._tail_lags("append")
+        else:
+            self._drop_sorted_view("fold" if past_big else "append")
         with enable_x64():
             if contiguous:
                 self._dev["keys"], self._dev["valid"] = (
@@ -950,9 +1064,7 @@ class ResidentJoinKeys:
             self._ensure_sorted()
             # pin this version's arrays: jax arrays are immutable, so a
             # concurrent tail advance replaces, never mutates, these
-            dev = {"sorted_keys": self._dev["sorted_keys"],
-                   "sorted_valid": self._dev["sorted_valid"],
-                   "perm": self._dev["perm"]}
+            dev = {view: self._dev[view] for view in _BIG_RUN + _TAIL_RUN}
             slabs = dict(self.slabs)
         m = len(s_enc)
         cap_s = _bucket(m)
@@ -962,13 +1074,15 @@ class ResidentJoinKeys:
         from delta_tpu.obs import hbm_ledger
 
         # transient probe scratch (the uploaded source lane, the source
-        # sorted with the block boundaries, one chunk of gathered blocks) in
-        # the HBM ledger while the probe is in flight; released on the
-        # staging thread, which always runs to completion
-        blk = _probe_block(cap, cap_s)
-        ranked = cap_s + cap // blk
-        scratch_bytes = int(s_in.nbytes) + 40 * ranked + 9 * min(
-            ranked * blk, 1 << 24)
+        # sorted with a run's block boundaries, one chunk of gathered
+        # blocks, for the big run and then the tail) in the HBM ledger while
+        # the probe is in flight; released on the staging thread, which
+        # always runs to completion
+        tail = _tail_capacity(cap)
+        blk, tail_blk = _probe_block(cap, cap_s), _probe_block(tail, cap_s)
+        ranked, tail_ranked = cap_s + cap // blk, cap_s + tail // tail_blk
+        scratch_bytes = int(s_in.nbytes) + 40 * (ranked + tail_ranked) + 9 * (
+            min(ranked * blk, 1 << 24) + min(tail_ranked * tail_blk, 1 << 24))
         hbm_ledger.adjust("scratch", scratch_bytes)
         # scratch growth applies eviction pressure immediately (no cache or
         # entry lock held at this point; this probe's arrays are pinned in
@@ -990,10 +1104,13 @@ class ResidentJoinKeys:
                             "delta.merge.deviceProbe",
                             {"slabRows": int(n), "sourceRows": int(m),
                              "insertOnly": insert_only, "blockRows": blk,
-                             "candidates": ranked * blk}):
+                             "tailBlockRows": tail_blk,
+                             "candidates": ranked * blk
+                             + tail_ranked * tail_blk}):
                     with enable_x64():
                         head_dev, *runs = _probe_sorted_kernel()(
                             dev["sorted_keys"], dev["sorted_valid"],
+                            dev["tail_keys"], dev["tail_valid"],
                             link.to_device(s_in))
                         # blocks until the kernel is done
                         state["head"] = _decode_head(
@@ -1011,10 +1128,13 @@ class ResidentJoinKeys:
                             telemetry.bump_counter(
                                 "merge.resident.probe.overflow")
                             return
-                        pair_bytes = 16 * cand_cap
+                        # and the two runs' validity and permutation
+                        # laid end to end
+                        pair_bytes = 16 * cand_cap + 5 * (cap + tail)
                         hbm_ledger.adjust("scratch", pair_bytes)
                         state["pairs_dev"] = _pair_kernel()(
                             *runs, dev["sorted_valid"], dev["perm"],
+                            dev["tail_valid"], dev["tail_perm"],
                             cand_cap, out_cap)
             except BaseException as e:
                 state["err"] = e

@@ -331,9 +331,12 @@ def test_probe_declines_counted_when_candidates_pass_the_scratch(monkeypatch):
     assert len(res.t_pairs[0]) == 600
     data = telemetry.recent_events("delta.merge.deviceProbe")[-1].data
     assert data["matched"] == 600 and data["candidateRows"] == 600
+    tail = kc._tail_capacity(e.capacity)
     assert data["blockRows"] == kc._probe_block(e.capacity, 1024)
-    assert data["candidates"] == (1024 + e.capacity // data["blockRows"]) \
-        * data["blockRows"]
+    assert data["tailBlockRows"] == kc._probe_block(tail, 1024)
+    assert data["candidates"] == (
+        (1024 + e.capacity // data["blockRows"]) * data["blockRows"]
+        + (1024 + tail // data["tailBlockRows"]) * data["tailBlockRows"])
     monkeypatch.setattr(kc, "_PROBE_SCRATCH_BYTES", 16 * 2048 - 1)
     before = telemetry.counters("merge.resident.probe").get(
         "merge.resident.probe.overflow", 0)
@@ -366,8 +369,9 @@ def test_probe_many_above_max_misses_no_overflow():
 
 
 def test_probe_after_kill_and_append_resorts(tmp_table):
-    """Key appends invalidate the sorted view; kills do not. Both must
-    still probe correctly afterwards."""
+    """A key append leaves the tail of the sorted view behind the lanes, and
+    the big run live; kills leave neither behind. Both must still probe
+    correctly afterwards."""
     from delta_tpu.ops.key_cache import ResidentJoinKeys
 
     e = ResidentJoinKeys("log", "mid", 0, "sig", ["k"])
@@ -375,18 +379,22 @@ def test_probe_after_kill_and_append_resorts(tmp_table):
     e.ensure_resident()
     r = e.probe_async(np.array([20], np.int64), np.array([True])).result()
     assert r.s_matched.tolist() == [True]
-    assert not e._sort_stale
+    assert e._stale is None and e._sorted_n == 3
     e._kill_file("a")  # validity flip only: no resort needed
-    assert not e._sort_stale
+    assert e._stale is None
     r = e.probe_async(np.array([20], np.int64), np.array([True])).result()
     assert r.s_matched.tolist() == [False]
     e._append_file("b", np.array([40, 20], np.int64), np.ones(2, bool))
-    assert e._sort_stale  # key rows changed
+    assert e._stale == "tail"  # key rows changed, after the big run's
     r = e.probe_async(np.array([20, 10, 40], np.int64),
                       np.ones(3, bool)).result()
     assert r.s_matched.tolist() == [True, False, True]
-    assert not e._sort_stale
+    assert e._stale is None and e._sorted_n == 3
 
+
+# what a slab holds on the device once a probe has sorted it
+_BOTH_RUNS = {"keys", "valid", "sorted_keys", "perm", "sorted_valid",
+              "tail_keys", "tail_perm", "tail_valid"}
 
 SORT_CASES = [
     "duplicates-valid-and-dead", "padding", "int64-max-key", "all-invalid",
@@ -422,11 +430,12 @@ def _sort_case(case):
 
 @pytest.mark.parametrize("case", SORT_CASES)
 def test_sort_equals_the_numpy_oracle(case):
-    """The re-sort's three arrays against numpy: `perm` is the stable
-    argsort of the encoded keys (padding as int64.max; ties in physical-row
-    order, valid or dead), `sorted_keys` the encoded keys through it,
-    `sorted_valid` the live rows through it — and nothing else is resident."""
-    from delta_tpu.ops.key_cache import ResidentJoinKeys
+    """The whole slab's sort against numpy: the big run's `perm` is the
+    stable argsort of the encoded keys (padding as int64.max; ties in
+    physical-row order, valid or dead), `sorted_keys` the encoded keys
+    through it, `sorted_valid` the live rows through it; the tail it leaves
+    is all padding — and nothing else is resident."""
+    from delta_tpu.ops.key_cache import ResidentJoinKeys, _tail_capacity
 
     keys, dead = _sort_case(case)
     n = len(keys)
@@ -447,9 +456,13 @@ def test_sort_equals_the_numpy_oracle(case):
     assert (np.asarray(e._dev["perm"]) == perm).all()
     assert (np.asarray(e._dev["sorted_keys"]) == enc[perm]).all()
     assert (np.asarray(e._dev["sorted_valid"]) == live[perm]).all()
-    assert set(e._dev) == {"keys", "valid", "sorted_keys", "perm",
-                           "sorted_valid"}
-    assert e.device_bytes == 22 * cap
+    assert set(e._dev) == _BOTH_RUNS
+    tail = _tail_capacity(cap)
+    assert e._dev["tail_keys"].shape == e._dev["tail_perm"].shape == (tail,)
+    assert (np.asarray(e._dev["tail_keys"]) == np.iinfo(np.int64).max).all()
+    assert not np.asarray(e._dev["tail_valid"]).any()
+    assert e._sorted_n == n and e._stale is None
+    assert e.device_bytes == 22 * cap + 13 * tail
 
 
 def test_slab_capacity_leaves_the_payload_its_validity_bit():
@@ -549,7 +562,8 @@ def test_inverse_permutation_at_equals_the_numpy_oracle(case):
     keys, valid, n, rows = _locate_case(case)
     cap = len(keys)
     with enable_x64():
-        sk, pm, sv = _sort_kernel()(keys, valid, np.int32(n))
+        sk, pm, sv = _sort_kernel()(
+            keys, valid, np.array([0, 0, n], np.int32), cap)
         got = np.asarray(_inverse_permutation_at()(sk, pm, keys, rows))
     assert got.dtype == np.int32 and got.shape == rows.shape
     real = rows < cap
@@ -562,14 +576,34 @@ def test_inverse_permutation_at_equals_the_numpy_oracle(case):
     assert (np.asarray(sv)[got[real]] == valid[rows[real]]).all()
 
 
-def test_a_flip_on_a_live_view_searches_it_and_an_append_never_does():
-    """(a) the advance's shape, an append and a kill in one batch, searches
-    nothing: the append drops the sorted view before the kill runs. (b) a
-    kill and a revive on a live view each search it once, and the probe
-    reads both back. (c) the next append drops the view; through all of it
-    the accounts read 22 B a row."""
+def _sort_counts():
+    """(sorts of the tail alone, folds of a full tail into the big run), the
+    process's counts so far."""
+    from delta_tpu.utils import telemetry
+
+    c = telemetry.counters("merge.keyCache")
+    return (c.get("merge.keyCache.tailSorts", 0),
+            c.get("merge.keyCache.folds", 0))
+
+
+def _sorts(telemetry):
+    return [(ev.data["rows"], ev.data["tier"], ev.data["cause"])
+            for ev in telemetry.recent_events("delta.keyCache.sort")]
+
+
+def test_a_flip_searches_the_big_run_and_never_the_tail():
+    """(a) the advance's shape, an append that fits the tail and a kill of
+    the big run's rows in one batch: the tail lags, the big run stays live
+    and is searched for the kill, and the next probe sorts the tail alone.
+    (b) a kill of the tail's rows searches nothing: the tail lags and its
+    sort carries them. (c) a kill and a revive in the big run each search it
+    once, one flip over both runs searches for the big run's rows only, and
+    the probe reads them all back. (d) an append the tail has no room for
+    drops both runs, a flip then is a row-space flip, and one sort of the
+    whole slab (the fold) follows; through all of it the accounts read 22 B
+    a row and 13 B a row of the tail."""
     from delta_tpu.obs import hbm_ledger
-    from delta_tpu.ops.key_cache import ResidentJoinKeys
+    from delta_tpu.ops.key_cache import ResidentJoinKeys, _tail_capacity
     from delta_tpu.utils import telemetry
 
     hbm_ledger.reset()
@@ -580,55 +614,261 @@ def test_a_flip_on_a_live_view_searches_it_and_an_append_never_does():
     e._append_file("a", a, np.ones(600, bool))
     e.ensure_resident()
     cap = e.capacity
+    held = 22 * cap + 13 * _tail_capacity(cap)
+    assert _tail_capacity(cap) == 64
 
     def accounts():
         return e.device_bytes, hbm_ledger.totals()["keyCache"]
 
-    assert accounts() == (22 * cap, 22 * cap)
+    assert accounts() == (held, held)
     assert _probe_bits(e, [a[5], 1000]) == [True, False]
-    assert not e._sort_stale
-    before = _flip_counts()
-
-    with e.device_batch():
-        e._append_file("b", np.arange(1000, 1100, dtype=np.int64),
-                       np.ones(100, bool))
-        assert e._set_dv("a", np.array([5, 7]))
-    assert e._sort_stale and _flip_counts() == before
-    assert _probe_bits(e, [a[5], a[6], a[7], 1000]) == [False, True, False, True]
-    assert _flip_counts() == before and accounts() == (22 * cap, 22 * cap)
-    sorts = telemetry.recent_events("delta.keyCache.sort")
-    assert [ev.data["cause"] for ev in sorts] == ["append", "append"]
-    assert not telemetry.recent_events("delta.keyCache.locate")
+    assert e._stale is None and e._sorted_n == 600
+    assert _sorts(telemetry) == [(600, "all", "append")]
+    flips, sorts = _flip_counts(), _sort_counts()
 
     telemetry.clear_events()
-    with e.device_batch():  # an advance that flips and appends nothing
-        assert e._set_dv("b", np.array([50]))
-    assert not e._sort_stale
-    assert _flip_counts() == (before[0] + 1, before[1])
-    assert accounts() == (22 * cap, 22 * cap)
-    assert e._set_dv("a", np.array([7]))  # revives row 5: the second flip
-    assert _flip_counts() == (before[0] + 2, before[1])
+    with e.device_batch():  # (a)
+        e._append_file("b", np.arange(1000, 1040, dtype=np.int64),
+                       np.ones(40, bool))
+        assert e._set_dv("a", np.array([5, 7]))
+    assert e._stale == "tail" and e._sorted_n == 600
+    assert set(e._dev) == _BOTH_RUNS
+    assert _flip_counts() == (flips[0] + 1, flips[1])
+    assert _probe_bits(e, [a[5], a[6], a[7], 1000]) == [False, True, False, True]
+    assert _sorts(telemetry) == [(40, "tail", "append")]
+    assert _sort_counts() == (sorts[0] + 1, sorts[1])
+    assert e._stale is None and e._sorted_n == 600
     found = telemetry.recent_events("delta.keyCache.locate")
     assert [(ev.data["rows"], ev.data["flips"], ev.data["steps"])
-            for ev in found] == [(700, 1, 1), (700, 1, 1)]
-    assert not e._sort_stale
-    assert _probe_bits(e, [a[5], a[7], 1050, 1051]) == [True, False, False, True]
-    assert not telemetry.recent_events("delta.keyCache.sort")
-    assert set(e._dev) == {"keys", "valid", "sorted_keys", "perm",
-                           "sorted_valid"}
-    assert accounts() == (22 * cap, 22 * cap)
+            for ev in found] == [(640, 2, 1)]
 
-    e._append_file("c", np.array([2000], np.int64), np.ones(1, bool))
-    assert e._sort_stale and set(e._dev) == {"keys", "valid"}
-    assert accounts() == (22 * cap, 22 * cap)
-    e._kill_file("b")  # stale view: a row-space flip, nothing searched
-    assert _flip_counts() == (before[0] + 2, before[1])
-    assert _probe_bits(e, [2000, 1051, a[5]]) == [True, False, True]
-    sorts = telemetry.recent_events("delta.keyCache.sort")
-    assert [ev.data["cause"] for ev in sorts] == ["append"]
+    telemetry.clear_events()
+    with e.device_batch():  # (b): an advance that flips the tail's rows
+        assert e._set_dv("b", np.array([10]))
+    assert e._stale == "tail" and _flip_counts() == (flips[0] + 1, flips[1])
+    assert not telemetry.recent_events("delta.keyCache.locate")
+    assert _probe_bits(e, [1009, 1010, 1011]) == [True, False, True]
+    assert _sorts(telemetry) == [(40, "tail", "flips")]
+    assert _sort_counts() == (sorts[0] + 2, sorts[1])
+
+    telemetry.clear_events()
+    assert e._set_dv("a", np.array([7]))  # (c) revives row 5
+    assert _flip_counts() == (flips[0] + 2, flips[1]) and e._stale is None
+    e._dev_kill(np.array([9, 610, 639], np.int32))  # one flip, both runs
+    assert _flip_counts() == (flips[0] + 3, flips[1]) and e._stale == "tail"
+    found = telemetry.recent_events("delta.keyCache.locate")
+    assert [(ev.data["rows"], ev.data["flips"], ev.data["steps"])
+            for ev in found] == [(640, 1, 1), (640, 1, 1)]
+    assert _probe_bits(e, [a[5], a[7], a[9], 1010, 1011, 1039]) == [
+        True, False, False, False, True, False]
+    assert _sorts(telemetry) == [(40, "tail", "flips")]
+    assert set(e._dev) == _BOTH_RUNS and accounts() == (held, held)
+
+    telemetry.clear_events()
+    e._append_file("c", np.arange(2000, 2025, dtype=np.int64),
+                   np.ones(25, bool))  # (d): 65 rows past the big run's
+    assert e._stale == "all" and set(e._dev) == {"keys", "valid"}
+    assert accounts() == (held, held)
+    searched = _flip_counts()
+    e._kill_file("b")  # no sorted view: a row-space flip, nothing searched
+    assert _flip_counts() == searched
+    assert _probe_bits(e, [2000, 1011, a[5]]) == [True, False, True]
+    assert _sorts(telemetry) == [(665, "all", "fold")]
+    assert _sort_counts() == (sorts[0] + 3, sorts[1] + 1)
+    assert e._sorted_n == 665 and set(e._dev) == _BOTH_RUNS
     e.drop_device()
     assert hbm_ledger.totals()["keyCache"] == 0
     hbm_ledger.reset()
+
+
+TWO_RUN_CASES = [
+    "runs-of-1-to-7-over-both-runs", "dead-in-the-big-run-live-in-the-tail",
+    "null-and-dead-keys-on-both-sides", "int64-max-in-both-runs",
+    "tail-of-one-row", "empty-tail", "tail-exactly-full",
+    "tail-window-reaches-back-over-the-big-run", "flip-lands-in-the-tail",
+    "flip-over-both-runs", "flip-too-large-to-search-for",
+    "second-append-into-a-sorted-tail",
+]
+
+
+def _two_run_case(case):
+    """A slab's life in steps, each ("file", name, keys, null_ok) or
+    ("dv", name, dead positions) or ("probe",): everything before the first
+    "probe" goes into the big run, what follows into the tail (capacity
+    1024 or 4096, a tail of 64 or 256 rows). With them the source keys and
+    their ok flags."""
+    rng = np.random.RandomState(len(case))
+    big = np.repeat(rng.permutation(200).astype(np.int64) * 7 - 300,
+                    rng.randint(1, 8, 200))[:700]
+    big = big[rng.permutation(700)]
+    ok_big = np.ones(700, bool)
+    tail = rng.choice(big, 40)  # more rows of keys the big run holds
+    tail[::5] = 5000 + np.arange(8)  # and some it does not
+    ok_tail = np.ones(40, bool)
+    steps = [("file", "a", big, ok_big), ("probe",),
+             ("file", "b", tail, ok_tail)]
+    s = np.concatenate([rng.choice(big, 150), tail[:20], [5003, 5003, -1]])
+    s_ok = np.ones(len(s), bool)
+    if case == "dead-in-the-big-run-live-in-the-tail":
+        # an upsert's shape: every tail row re-states a row of the big run,
+        # whose versions there all die in the same advance
+        tail = np.unique(big)[:20]
+        steps = steps[:2] + [("file", "b", tail, ok_tail[:20]),
+                             ("dv", "a", np.nonzero(np.isin(big, tail))[0])]
+        s = np.concatenate([tail, np.unique(big)[20:40]])
+    elif case == "null-and-dead-keys-on-both-sides":
+        ok_big = rng.rand(700) > 0.1
+        ok_tail = rng.rand(40) > 0.2
+        steps = [("file", "a", big, ok_big),
+                 ("dv", "a", rng.choice(700, 90, replace=False)), ("probe",),
+                 ("file", "b", tail, ok_tail), ("dv", "b", np.array([3, 4]))]
+        s_ok = rng.rand(len(s)) > 0.2
+    elif case == "int64-max-in-both-runs":
+        big, tail = big.copy(), tail.copy()
+        big[[0, 350, 699]] = tail[[1, 39]] = np.iinfo(np.int64).max
+        steps = [("file", "a", big, ok_big), ("dv", "a", np.array([350])),
+                 ("probe",), ("file", "b", tail, ok_tail)]
+        s = np.concatenate([s, [np.iinfo(np.int64).max]])
+    elif case == "tail-of-one-row":
+        steps[2] = ("file", "b", big[:1], ok_tail[:1])
+    elif case == "empty-tail":
+        steps = steps[:2]
+    elif case == "tail-exactly-full":
+        tail = rng.choice(big, 64)
+        steps[2] = ("file", "b", tail, np.ones(64, bool))
+    elif case == "tail-window-reaches-back-over-the-big-run":
+        # 1,000 rows in the big run of a 1,024-row slab: the tail's window
+        # starts 40 rows inside it
+        big = np.concatenate([big, big[:300] + 1])
+        steps = [("file", "a", big, np.ones(1000, bool)), ("probe",),
+                 ("file", "b", tail[:24], ok_tail[:24])]
+    elif case == "flip-lands-in-the-tail":
+        steps += [("probe",), ("dv", "b", np.array([1, 2, 7, 39]))]
+    elif case == "flip-over-both-runs":
+        steps += [("probe",), ("kill", np.array([3, 650, 699, 700, 739]))]
+    elif case == "flip-too-large-to-search-for":
+        steps += [("probe",), ("dv", "a", np.arange(0, 700, 2))]
+    elif case == "second-append-into-a-sorted-tail":
+        steps += [("probe",), ("file", "c", tail[:20] + 7, ok_tail[:20])]
+    if len(s_ok) != len(s):
+        s_ok = np.ones(len(s), bool)
+    return steps, s, s_ok
+
+
+@pytest.mark.parametrize("case", TWO_RUN_CASES)
+def test_a_two_run_probe_equals_one_whole_sort(case):
+    """A slab whose sorted view is a big run and a tail answers a probe
+    exactly as the same rows under one whole sort do, and as numpy does:
+    the matched flags of every source row, `any_multi`, the pairs (physical
+    row ascending, minimal original source row), the count of pairs. Its
+    candidate rows are no more than one run's (a key's dead versions in a
+    run that holds no live one are no candidates) but for the tail's own
+    padding, which a source key equal to int64.max meets."""
+    from delta_tpu.ops.key_cache import ResidentJoinKeys, _tail_capacity
+    from delta_tpu.utils import telemetry
+
+    steps, s, s_ok = _two_run_case(case)
+    two = ResidentJoinKeys("log", "mid", 0, "sig", ["k"])
+    one = ResidentJoinKeys("log", "mid", 0, "sig", ["k"])
+    telemetry.clear_events()
+    keys, valid = np.empty(0, np.int64), np.empty(0, bool)
+    for step in steps:
+        if step[0] == "probe":
+            two.probe_async(s, s_ok).result()
+            continue
+        for e in (two, one):
+            if step[0] == "file":
+                e._append_file(step[1], step[2], step[3])
+            elif step[0] == "dv":
+                assert e._set_dv(step[1], step[2])
+            elif e._dev is not None:  # "kill": rows of the slab, at once
+                e._dev_kill(step[1].astype(np.int32))
+        if step[0] == "file":
+            keys = np.concatenate([keys, step[2]])
+            valid = np.concatenate([valid, step[3]])
+        elif step[0] == "dv":
+            off, rows = two.slabs[step[1]]
+            valid[off:off + rows] = two.h_nullok[off:off + rows]
+            valid[off + step[2]] = False
+        else:
+            valid[step[1]] = False
+            two.h_valid[step[1]] = one.h_valid[step[1]] = False
+    big_rows = len(steps[0][2])
+    got = two.probe_async(s, s_ok).result()
+    got_data = telemetry.recent_events("delta.merge.deviceProbe")[-1].data
+    tiers = [ev.data["tier"]
+             for ev in telemetry.recent_events("delta.keyCache.sort")]
+    if case == "flip-too-large-to-search-for":
+        assert tiers == ["all", "tail", "all"] and two._sorted_n == len(keys)
+    else:
+        assert tiers[0] == "all" and set(tiers[1:]) <= {"tail"}
+        assert two._sorted_n == big_rows
+        assert len(tiers) == {"empty-tail": 1, "flip-lands-in-the-tail": 3,
+                              "flip-over-both-runs": 3,
+                              "second-append-into-a-sorted-tail": 3}.get(
+                                  case, 2)
+    want = one.probe_async(s, s_ok).result()
+    want_data = telemetry.recent_events("delta.merge.deviceProbe")[-1].data
+    assert one._sorted_n == len(keys)  # one whole sort, an empty tail
+    exp_s, exp_multi, exp_phys, exp_src = _oracle(keys, valid, s, s_ok)
+    for res in (got, want):
+        assert (res.s_matched == exp_s).all()
+        assert res.any_multi == exp_multi
+        assert res.num_rows == len(keys)
+        assert (res.t_pairs[0] == exp_phys).all()
+        assert (res.t_pairs[1] == exp_src).all()
+    assert got_data["matched"] == want_data["matched"] == len(exp_phys)
+    room = _tail_capacity(two.capacity) if "int64-max" in case else 0
+    assert len(exp_phys) <= got_data["candidateRows"] \
+        <= want_data["candidateRows"] + room
+    assert len(exp_phys) > 0
+
+
+@pytest.mark.parametrize("over", [0, 1])
+def test_the_tail_takes_appends_until_it_is_full_and_then_folds(over):
+    """Appends after the big run's rows are sorted into the tail, each by
+    one sort of the tail alone, until one finds no room: a tail exactly full
+    is still a tail, one row over it is one sort of the whole slab (the
+    fold), which leaves the tail empty for the appends that follow. The
+    rule reads the capacity and the tail's fill alone."""
+    from delta_tpu.ops.key_cache import ResidentJoinKeys, _tail_capacity
+    from delta_tpu.utils import telemetry
+
+    e = ResidentJoinKeys("log", "mid", 0, "sig", ["k"])
+    e._append_file("a", np.arange(3000, dtype=np.int64) * 2, np.ones(3000, bool))
+    e.ensure_resident()
+    tail = _tail_capacity(e.capacity)
+    assert (e.capacity, tail) == (4096, 256)
+    assert _probe_bits(e, [10, 11]) == [True, False]
+    before = _sort_counts()
+    telemetry.clear_events()
+    e._append_file("b", np.arange(100, dtype=np.int64) * 2 + 1,
+                   np.ones(100, bool))
+    assert e._stale == "tail"
+    assert _probe_bits(e, [10, 11, 199, 201]) == [True, True, True, False]
+    rest = tail - 100 + over
+    e._append_file("c", np.arange(rest, dtype=np.int64) * 2 + 7001,
+                   np.ones(rest, bool))
+    assert e._stale == ("all" if over else "tail")
+    assert _probe_bits(e, [11, 7001, 7001 + 2 * (rest - 1), 7001 + 2 * rest]) \
+        == [True, True, True, False]
+    if over:
+        assert _sorts(telemetry) == [(100, "tail", "append"),
+                                     (3100 + rest, "all", "fold")]
+        assert _sort_counts() == (before[0] + 1, before[1] + 1)
+        assert e._sorted_n == 3100 + rest
+        assert not np.asarray(e._dev["tail_valid"]).any()
+    else:
+        assert _sorts(telemetry) == [(100, "tail", "append"),
+                                     (tail, "tail", "append")]
+        assert _sort_counts() == (before[0] + 2, before[1])
+        assert e._sorted_n == 3000
+        assert np.asarray(e._dev["tail_valid"]).all()
+    # the next append goes into the tail either way, or folds a full one
+    e._append_file("d", np.array([-5], np.int64), np.ones(1, bool))
+    assert e._stale == ("tail" if over else "all")
+    assert _probe_bits(e, [-5, 11, 7001]) == [True, True, True]
+    assert _sort_counts() == (before[0] + 2, before[1] + 1)
 
 
 def test_kill_then_revive_on_one_live_view_reads_back_through_the_probe():
@@ -667,20 +907,20 @@ def test_kill_then_revive_on_one_live_view_reads_back_through_the_probe():
                         (none, 0)):
         assert e._set_dv("f", dead)
         searches += flips  # a kill or a revive; none where nothing changed
-        assert _flip_counts() == (searches, resorts) and not e._sort_stale
+        assert _flip_counts() == (searches, resorts) and e._stale is None
         assert live_rows() == want(dead)
     assert not telemetry.recent_events("delta.keyCache.sort")
 
 
 def test_a_flip_too_large_to_search_for_drops_the_view_and_the_sort_carries_it():
     """Both sides of `_flip_by_search`, which reads the flips and the
-    capacity alone: the largest flip it admits is mirrored in the live
-    view, one row more stays in row space, leaves the view stale and
-    counts a re-sort, and the next probe answers from a sort whose span
-    says `cause=flips`."""
+    capacity alone: the largest flip it admits is mirrored in the live big
+    run, one row more stays in row space, drops both runs and counts a
+    re-sort, and the next probe answers from a sort of the whole slab whose
+    span says `cause=flips`."""
     from delta_tpu.obs import hbm_ledger
     from delta_tpu.ops.key_cache import (
-        ResidentJoinKeys, _flip_by_search, _search_steps)
+        ResidentJoinKeys, _flip_by_search, _search_steps, _tail_capacity)
     from delta_tpu.utils import telemetry
 
     hbm_ledger.reset()
@@ -696,17 +936,18 @@ def test_a_flip_too_large_to_search_for_drops_the_view_and_the_sort_carries_it()
     telemetry.clear_events()
     before = _flip_counts()
 
+    held = 22 * cap + 13 * _tail_capacity(cap)
     assert e._set_dv("f", np.arange(most))  # rows 0..most-1 die
-    assert not e._sort_stale and "sorted_valid" in e._dev
+    assert e._stale is None and set(e._dev) == _BOTH_RUNS
     assert _flip_counts() == (before[0] + 1, before[1])
-    assert e.device_bytes == hbm_ledger.totals()["keyCache"] == 22 * cap
+    assert e.device_bytes == hbm_ledger.totals()["keyCache"] == held
 
     alive = np.arange(most + 1) + most  # revives `most` rows, kills one more
     assert e._set_dv("f", alive)
     # the batchless diff is a kill (most + 1 rows) and a revive (most rows)
-    assert e._sort_stale and "sorted_valid" not in e._dev
+    assert e._stale == "all" and set(e._dev) == {"keys", "valid"}
     assert _flip_counts() == (before[0] + 1, before[1] + 1)
-    assert e.device_bytes == hbm_ledger.totals()["keyCache"] == 22 * cap
+    assert e.device_bytes == hbm_ledger.totals()["keyCache"] == held
     assert not telemetry.recent_events("delta.keyCache.sort")
     keys = np.arange(1000, dtype=np.int64)
     dead = np.zeros(n, bool)
@@ -714,10 +955,10 @@ def test_a_flip_too_large_to_search_for_drops_the_view_and_the_sort_carries_it()
     want = [bool((~dead[3 * k:3 * k + 3]).any()) for k in keys]
     assert _probe_bits(e, keys) == want
     sorts = telemetry.recent_events("delta.keyCache.sort")
-    assert [(ev.data["rows"], ev.data["cause"]) for ev in sorts] == [
-        (n, "flips")]
+    assert [(ev.data["rows"], ev.data["tier"], ev.data["cause"])
+            for ev in sorts] == [(n, "all", "flips")]
     assert len(telemetry.recent_events("delta.keyCache.locate")) == 1
-    assert e.device_bytes == hbm_ledger.totals()["keyCache"] == 22 * cap
+    assert e.device_bytes == hbm_ledger.totals()["keyCache"] == held
     e.drop_device()
     hbm_ledger.reset()
 

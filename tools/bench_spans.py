@@ -8,9 +8,11 @@ standard error: the mean milliseconds a request of every span of the program
 (and how many of them a request opened), each MERGE's route, the same means a
 statement split by the root span's ``clauses`` (a refresh pair's RF1 and RF2
 apart), what the resident probe's spans and counters say of how widely it
-engaged, every re-sort of the slab (``rows``, ``cause``) and every search of
-its sorted view for flipped rows (``rows``, ``flips``, ``steps``) with the
-counts of flips searched for and of flips that re-sorted instead, the routes
+engaged, every sort of the slab split by ``tier`` (the tail run alone, or the
+whole slab; ``rows``, ``cause``) and every search of its big sorted run for
+flipped rows (``rows``, ``flips``, ``steps``) with the counts of flips
+searched for, of flips that re-sorted instead, of tail sorts and of folds, the
+routes
 and group counts of the aggregate queries with the program that answered the
 grouped ones (``tiled`` or ``wide``) and the tiled share, under each leaf span
 that has stages inside it (a MERGE's ``.write`` and ``.apply``, a decode's
@@ -72,13 +74,19 @@ def report(run) -> None:
               telemetry.counters("merge.resident.probe").get(
                   "merge.resident.probe.overflow", 0), "flip searches:",
               slab.get("merge.keyCache.flipSearches", 0), "flip re-sorts:",
-              slab.get("merge.keyCache.flipResorts", 0), file=sys.stderr)
-        # what a pair paid the device for: each re-sort (`cause`: a key
-        # append dropped the view it replaces, or a flip too large to
-        # search for) and each search of a live view for flipped rows
-        # (`flips`: the rows whose validity the advance flipped)
-        print("slab sorts:", json.dumps(span_data("delta.keyCache.sort")),
-              "slab searches:", json.dumps(span_data("delta.keyCache.locate")),
+              slab.get("merge.keyCache.flipResorts", 0), "tail sorts:",
+              slab.get("merge.keyCache.tailSorts", 0), "folds:",
+              slab.get("merge.keyCache.folds", 0), file=sys.stderr)
+        # what a pair paid the device for: each sort of the slab's tail run
+        # alone and each sort of the whole slab (`cause`: a key append, a
+        # flip too large to search for, or `fold`, an append that found the
+        # tail full), and each search of the live big run for flipped rows
+        # (`flips`: the rows of it whose validity the advance flipped)
+        sorts = span_data("delta.keyCache.sort")
+        for tier in ("tail", "all"):
+            print(f"slab sorts, tier={tier}:", json.dumps(
+                [d for d in sorts if d.get("tier") == tier]), file=sys.stderr)
+        print("slab searches:", json.dumps(span_data("delta.keyCache.locate")),
               file=sys.stderr)
     aggregates = span_data("delta.scan.deviceAggregate")
     if aggregates:
