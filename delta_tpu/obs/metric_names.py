@@ -213,6 +213,8 @@ ENGINE_COUNTERS = frozenset({
     "scan.prune.deviceFallback",
     "columnCache.hits",
     "columnCache.misses",
+    "columnCache.keep.hits",      # a file's keep mask found resident
+    "columnCache.keep.misses",    # built from the file's vector and uploaded
     "columnCache.evictions",
     "columnCache.invalidations",
     "scan.rewrites.synthesized",
@@ -501,6 +503,8 @@ DESCRIPTIONS = {
     "scan.prune.deviceFallback": "Device file prunes that raised and fell back to the host evaluator.",
     "columnCache.hits": "Scan column-cache lane hits (file, column resident).",
     "columnCache.misses": "Scan column-cache lane misses (cold decode).",
+    "columnCache.keep.hits": "Launches of a device aggregate over a file with a deletion vector that found the file's keep mask resident (built from the same vector by a query before).",
+    "columnCache.keep.misses": "Keep masks built from a file's deletion vector and uploaded (delta.columnCache.keepMask with cached=false): once a vector, the first query after the commit that wrote it.",
     "columnCache.evictions": "Scan column-cache lanes evicted by the LRU bound.",
     "columnCache.invalidations": "Scan column-cache lanes dropped by a rewrite epoch bump.",
     "scan.rewrites.synthesized": "Conjuncts lowered to stats bounds only via predicate synthesis.",

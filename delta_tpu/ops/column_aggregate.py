@@ -157,6 +157,11 @@ class _FileLanes(NamedTuple):
     codes: Dict[str, Optional[Dict[str, int]]]  # string column -> value -> code
 
 
+def _lane_rows(f: _FileLanes) -> int:
+    """The padded length the file's lanes share."""
+    return next(iter(f.env.values()))[0].shape[0]
+
+
 # -- the kernels -----------------------------------------------------------------
 
 
@@ -985,8 +990,7 @@ def _fits_tiles(terms, per_file, columns) -> bool:
     into an int32; and the validity bits of the lanes fit one word."""
     if not per_file or len(columns) > 30:
         return False
-    if max(next(iter(f.env.values()))[0].shape[0] for f in per_file) \
-            > 8 * _DOT_ROWS:
+    if max(_lane_rows(f) for f in per_file) > 8 * _DOT_ROWS:
         return False
     # over all the files at once: an extreme of the union is some file's
     union = {c: (min(f.ranges[c][0] for f in per_file),
@@ -1060,22 +1064,25 @@ def _lane_bytes(files, columns, fields) -> int:
             rows = max((f.size or 0) // 64, 1024)
         if f.deletion_vector is not None:
             rows += int(f.deletion_vector.get("cardinality", 0))
-        total += column_cache._next_pow2(max(rows, 1), floor=64) * width
+        # a byte a row more where a vector comes as a keep mask
+        total += column_cache._next_pow2(max(rows, 1), floor=64) \
+            * (width + (f.deletion_vector is not None))
     return total
 
 
-def _keep_mask(f: _FileLanes, data_path: str):
-    """The file's deletion vector as a row mask on the device; None for a
-    file that has none."""
-    from delta_tpu.protocol.deletion_vectors import (DeletionVectorDescriptor,
-                                                     read_deletion_vector)
-
+def _keep_mask(f: _FileLanes, table):
+    """The file's deletion vector as a row mask on the device, a resident
+    lane of the column cache (`column_cache.ensure_keep`: built and
+    uploaded once a vector, not once a launch); None for a file that has
+    none. ``table`` is ``(cache, log path, data path)``."""
     if f.add.deletion_vector is None:
         return None
-    keep = np.ones(next(iter(f.env.values()))[0].shape[0], bool)
-    keep[read_deletion_vector(DeletionVectorDescriptor.from_dict(
-        f.add.deletion_vector), data_path)] = False
-    return link.to_device(keep)
+    with telemetry.record_operation("delta.columnCache.keepMask",
+                                    {"rows": f.rows}) as ev:
+        mask, deleted, cached = column_cache.ensure_keep(
+            *table, f.add, _lane_rows(f))
+        ev.data.update(deleted=deleted, cached=cached)
+    return mask
 
 
 def device_aggregate(snapshot, filters: Sequence[ir.Expression], parsed_items,
@@ -1155,7 +1162,7 @@ def _launches(lev, kernel, calls, carry):
     return carry
 
 
-def _launch_ungrouped(preds, bounds, specs, per_file, data_path, rows):
+def _launch_ungrouped(preds, bounds, specs, per_file, table, rows):
     """The ungrouped program once a file, the carry summed on the device;
     one fetch: four slots a spec. Two stages tile the span: ``.launch`` (to
     the last launch enqueued) and ``.fetch`` (the wait for the device and
@@ -1170,13 +1177,13 @@ def _launch_ungrouped(preds, bounds, specs, per_file, data_path, rows):
             dev_bounds, carry = link.to_device(bounds), link.to_device(carry)
             carry = _launches(lev, kernel, (
                 (f.env, dev_bounds, _rows_on_device(f.rows),
-                 _keep_mask(f, data_path)) for f in per_file), carry)
+                 _keep_mask(f, table)) for f in per_file), carry)
             stage("delta.columnCache.aggregate.fetch")
             carry = link.to_host(carry)
     return carry
 
 
-def _launch_grouped(preds, bounds, terms, keys, per_file, data_path, rows):
+def _launch_grouped(preds, bounds, terms, keys, per_file, table, rows):
     """The grouped program once a file, each into its own row of the carry;
     one fetch: ``(partials[file, slot, column], each file's key layout, the
     program that ran: `_fits_tiles`)``. Declines ``groups`` when a file's
@@ -1203,12 +1210,16 @@ def _launch_grouped(preds, bounds, terms, keys, per_file, data_path, rows):
         dev_bounds = link.to_device(bounds)
         carry = _launches(lev, kernel, (
             (f.env, dev_bounds, _rows_on_device(f.rows),
-             _keep_mask(f, data_path), _layout_on_device(file_layout),
+             _keep_mask(f, table), _layout_on_device(file_layout),
              _rows_on_device(i))
             for i, (f, file_layout) in enumerate(zip(per_file, layouts))),
-            _zeros_on_device((len(per_file), slots, width)))
+            # the program is keyed on the carry's shape: a row a file, in
+            # power-of-two counts, so a table that gains a file a commit
+            # compiles once a doubling and not once a commit
+            _zeros_on_device((column_cache._next_pow2(len(per_file), floor=8),
+                              slots, width)))
         stage("delta.columnCache.aggregate.fetch")
-        return link.to_host(carry), layouts, program
+        return link.to_host(carry)[:len(per_file)], layouts, program
 
 
 @contextlib.contextmanager
@@ -1261,6 +1272,9 @@ def _device_aggregate(snapshot, filters, parsed_items, group_by, order_keys, ev)
             lanes = [cache.get(log_path, add.path, c) for c in need]
             if all(e is not None for e in lanes):
                 held += sum(e.nbytes for e in lanes)
+                mask = add.deletion_vector is not None and cache.get(
+                    log_path, add.path, column_cache.KEEP)
+                held += mask.nbytes if mask else 0
             else:
                 cold.append(add)
         if cold and held + _lane_bytes(cold, need, fields) > column_cache.lane_budget():
@@ -1285,18 +1299,22 @@ def _device_aggregate(snapshot, filters, parsed_items, group_by, order_keys, ev)
         _check_overflow(specs, typed, per_file, bool(keys))
         rows = sum(f.rows for f in per_file)
         ev.data.update(files=len(per_file), rows=rows, hits=counters["hits"],
-                       misses=counters["misses"])
+                       misses=counters["misses"],
+                       vectors=sum(f.add.deletion_vector is not None
+                                   for f in per_file),
+                       laneShapes=sorted({_lane_rows(f) for f in per_file}))
         lev.data.update(files=len(per_file), lanes=len(per_file) * len(need))
         names = [alias if kind == "agg" else alias or payload
                  for kind, payload, alias in parsed_items]
         if keys:
             terms, spec_term = _terms(specs, per_file)
     if not keys:
-        carry = _launch_ungrouped(preds, bounds, specs, per_file, data_path, rows)
+        carry = _launch_ungrouped(preds, bounds, specs, per_file,
+                                  (cache, log_path, data_path), rows)
         return names, [_column(spec.func, types_of, [carry[_SLOTS * k:_SLOTS * (k + 1)]])
                        for k, (spec, types_of) in enumerate(zip(specs, typed))]
-    partials, layouts, program = _launch_grouped(preds, bounds, terms, keys,
-                                                 per_file, data_path, rows)
+    partials, layouts, program = _launch_grouped(
+        preds, bounds, terms, keys, per_file, (cache, log_path, data_path), rows)
     with telemetry.record_operation("delta.scan.deviceAggregate.groups") as gev:
         order, slots_of = _merge_groups(partials, per_file, layouts, keys,
                                         terms, spec_term, specs)

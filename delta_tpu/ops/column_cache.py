@@ -55,8 +55,12 @@ from delta_tpu.utils.config import conf
 from delta_tpu.utils.jaxcache import ensure_compilation_cache
 from delta_tpu.utils.jaxcompat import enable_x64
 
-__all__ = ["ResidentColumn", "ColumnCache", "device_residual_masks",
-           "column_cache_enabled"]
+__all__ = ["ResidentColumn", "ResidentKeep", "ColumnCache",
+           "device_residual_masks", "column_cache_enabled"]
+
+#: the column slot of a file's keep mask in the cache's key: no column of a
+#: table is named so
+KEEP = "\x00keep"
 
 
 def column_cache_enabled() -> bool:
@@ -184,6 +188,47 @@ class ResidentColumn:
             self._account.off()
 
 
+class ResidentKeep:
+    """One file's deletion vector resident as a row mask beside the file's
+    lanes: False where the vector names the row, padded with True to the
+    lanes' length (the programs cut the padding by the file's row count).
+    An entry of the cache like a lane, under the column slot ``KEEP``:
+    counted in its bytes, dropped with the table's epoch, and freed when
+    the file's last lane is evicted. ``vector`` is the identity of the
+    vector it was built from (a commit that deletes more rows of the file
+    writes a new one): `ensure_keep` rebuilds on another."""
+
+    __slots__ = ("log_path", "file_path", "column", "vector", "mask",
+                 "deleted", "nbytes", "epoch", "last_used", "_account",
+                 "_lock", "__weakref__")
+
+    def __init__(self, log_path: str, file_path: str, vector: Tuple[str, str],
+                 deleted_rows: np.ndarray, cap: int, epoch: int):
+        self.log_path = log_path
+        self.file_path = file_path
+        self.column = KEEP
+        self.vector = vector
+        self.deleted = int(len(deleted_rows))
+        keep = np.ones(cap, dtype=bool)
+        keep[deleted_rows] = False
+        self.nbytes = int(keep.nbytes)
+        self.epoch = epoch
+        self.last_used = 0
+        self._lock = threading.Lock()
+        self._account = hbm_ledger.Account("columnCache")
+        self.mask = link.to_device(keep)
+        self._account.on(self, self.nbytes)
+
+    @property
+    def is_resident(self) -> bool:
+        return self.mask is not None
+
+    def drop_device(self) -> None:
+        with self._lock:
+            self.mask = None
+            self._account.off()
+
+
 class ColumnCache:
     """Process-wide registry of resident scan-column lanes, keyed by
     (log path, file path, column). Locking and epoch discipline mirror
@@ -271,9 +316,12 @@ class ColumnCache:
                 return False
             self._tick += 1
             entry.last_used = self._tick
-            self._entries[(entry.log_path, entry.file_path,
-                           entry.column)] = entry
-        self._evict(keep=(entry.log_path, entry.file_path, entry.column))
+            key = (entry.log_path, entry.file_path, entry.column)
+            replaced = self._entries.get(key)
+            if entry.column == KEEP and replaced is not None:
+                replaced.drop_device()  # the mask of the vector before
+            self._entries[key] = entry
+        self._evict(keep=key)
         return True
 
     def resident_bytes(self) -> int:
@@ -310,6 +358,7 @@ class ColumnCache:
         budget = lane_budget()
         max_entries = int(conf.get("delta.tpu.columnCache.maxEntries", 4096))
         dropped = 0
+        lost: set = set()  # (table, file) that lost an entry
         with self._lock:
             resident = [(k, e) for k, e in self._entries.items()
                         if e.is_resident]
@@ -323,6 +372,15 @@ class ColumnCache:
                 e.drop_device()
                 total -= e.nbytes
                 dropped += 1
+                lost.add(k[:2])
+            # a keep mask goes with the file's last lane
+            for table_file in lost:
+                if not any(k[:2] == table_file and k[2] != KEEP
+                           for k in self._entries):
+                    orphan = self._entries.pop(table_file + (KEEP,), None)
+                    if orphan is not None and orphan.is_resident:
+                        orphan.drop_device()
+                        dropped += 1
         if dropped:
             bump_counter("columnCache.evictions", dropped)
         self._publish_residency()
@@ -422,6 +480,35 @@ def _ensure_lanes(cache: "ColumnCache", log_path: str, data_path: str, add,
             cache.register(entry)  # epoch race → served uncached, still exact
             out[c] = entry
     return out
+
+
+def ensure_keep(cache: "ColumnCache", log_path: str, data_path: str, add,
+                cap: int) -> Tuple[Any, int, bool]:
+    """``(mask, rows deleted, whether the cache held it)``: the keep mask of
+    a file that has a deletion vector, as long as the file's lanes
+    (``cap``), resident; the caller holds the array, so an eviction between
+    this and the launch frees nothing in use. Built from the vector once
+    (one read, one host array, one upload), then every launch of every
+    query reuses it until a commit gives the file another vector. A build
+    that raced a rewrite of the table is served and not cached, as a lane
+    is."""
+    from delta_tpu.protocol.deletion_vectors import (DeletionVectorDescriptor,
+                                                     read_deletion_vector)
+
+    dv = DeletionVectorDescriptor.from_dict(add.deletion_vector)
+    vector = (dv.storage_type, dv.path_or_inline_dv)
+    e = cache.get(log_path, add.path, KEEP)
+    mask = None if e is None or e.vector != vector else e.mask
+    if mask is not None and mask.shape[0] == cap:
+        telemetry.bump_counter("columnCache.keep.hits")
+        return mask, e.deleted, True
+    telemetry.bump_counter("columnCache.keep.misses")
+    e = ResidentKeep(log_path, add.path, vector,
+                     read_deletion_vector(dv, data_path), cap,
+                     cache.epoch(log_path))
+    mask = e.mask
+    cache.register(e)
+    return mask, e.deleted, False
 
 
 def device_residual_masks(snapshot, files, predicate) -> Optional[Dict[str, np.ndarray]]:

@@ -157,9 +157,10 @@ def test_a_fresh_delta_compiles_nothing(lineitem_table):
     assert c1["scan.aggregate.grouped"] - c0["scan.aggregate.grouped"] == 4
     assert c1.get("columnCache.misses", 0) == c0.get("columnCache.misses", 0)
     moved = sum(c1[k] - c0.get(k, 0) for k in ("link.h2d.bytes", "link.d2h.bytes"))
-    # 4 files x 8 slots (3 flags x 2 statuses, no NULL among them) x
+    # a row a file of the carry, the files counted up to a power of two (8
+    # for these 4) x 8 slots (3 flags x 2 statuses, no NULL among them) x
     # (1 + 6 counts + 5 sums) x 8 B down, the bounds up
-    assert moved == 4 * (4 * 8 * 12 * 8 + 16)
+    assert moved == 4 * (8 * 8 * 12 * 8 + 16)
 
 
 @pytest.mark.parametrize("program", ["wide", "tiled"])
@@ -655,13 +656,18 @@ def one_v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("slots,keep", [(8, False), (32, True)])
+@pytest.mark.parametrize("slots,keep,cap,files", [
+    (8, False, 4_194_304, 16), (32, True, 4_194_304, 16),
+    # PR 38, the power stream: a refresh function's file (60,000 rows pad to
+    # 65,536: two grid steps) and a loaded file under a keep mask, a table of
+    # 17 to 32 files
+    (8, False, 65_536, 32), (8, True, 4_194_304, 32)])
 def test_the_tile_kernel_compiles_for_the_chip_at_a_files_shape(
-        one_v5e, monkeypatch, slots, keep):
+        one_v5e, monkeypatch, slots, keep, cap, files):
     """What the interpreter cannot show: the chip's compiler takes the tile
     kernel as written (an int8 contraction along lanes, 32-bit words recast
-    as int8 rows, only 32-bit integers under x64) at 4,194,304 rows, and the
-    launch is one module with one kernel in it."""
+    as int8 rows, only 32-bit integers under x64) at 4,194,304 rows and at
+    65,536, and the launch is one module with one kernel in it."""
     import jax
     import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache
@@ -676,7 +682,6 @@ def test_the_tile_kernel_compiles_for_the_chip_at_a_files_shape(
         None, {}, 4_000_000, {"p": (90_100, 10_494_950), "d": (0, 10), "t": (0, 8),
                               "q": (100, 5_000)}, {})
     terms, _ = column_aggregate._terms(specs, [sf10])
-    cap = 4_194_304
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
@@ -695,7 +700,7 @@ def test_the_tile_kernel_compiles_for_the_chip_at_a_files_shape(
                 lanes, shape((1, 2), jnp.int64), shape((), jnp.int32),
                 shape((cap,), jnp.bool_) if keep else None,
                 shape((2, 3), jnp.int64), shape((), jnp.int32),
-                shape((15, slots, 12), jnp.int64)).compile().as_text()
+                shape((files, slots, 12), jnp.int64)).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
@@ -806,7 +811,7 @@ def test_the_cells_metrics_read_a_run_of_the_engine(lineitem_table):
         + values["agg_lanes_ms"] + values["scan_plan_ms"] \
         + values["group_merge_ms"] < values["agg_device_ms"]
     assert values["scan_lane_hit_pct"] == 100.0
-    assert values["agg_link_B"] == 4 * 8 * 12 * 8 + 16
+    assert values["agg_link_B"] == 8 * 8 * 12 * 8 + 16  # 4 files: 8 rows
     assert 0 < values["group_agg_roofline"] and values["group_merge_ms"] > 0
     assert values["agg_device_ms"] > values["group_merge_ms"]
     assert [m.name for m in cell.end_to_end] == ["scan_per_s", "scan_p95_ms",

@@ -7,7 +7,10 @@ The arguments and the result line are `benchmark/run.py`'s. Besides, on
 standard error: the mean milliseconds a request of every span of the program
 (and how many of them a request opened), each MERGE's route, the same means a
 statement split by the root span's ``clauses`` (a refresh pair's RF1 and RF2
-apart), what the resident probe's spans and counters say of how widely it
+apart), where a request holds MERGEs and queries (a power stream) the same
+means a query with the first query after each MERGE apart from the rest and
+the grouped apart from the ungrouped, each with the bytes it sent up the link
+and its keep masks (``cached`` or built), what the resident probe's spans and counters say of how widely it
 engaged, every sort of the slab split by ``tier`` (the tail run alone, or the
 whole slab; ``rows``, ``cause``) and every search of its big sorted run for
 flipped rows (``rows``, ``flips``, ``steps``) with the counts of flips
@@ -60,6 +63,17 @@ def report(run) -> None:
     if split:
         print("span means a statement, by the root span's clauses "
               "[statements, {span: ms}]:", json.dumps(split), file=sys.stderr)
+
+    apart = queries_apart(done)
+    if apart:
+        from delta_tpu.utils import telemetry
+
+        print("span means a query of a stream, the first after a MERGE apart "
+              "[queries, {span: ms}, h2d bytes a query, keep masks "
+              "{cached: n}]:", json.dumps(apart), "keep masks, the process's "
+              "[hits, misses]:", [telemetry.counters("columnCache.keep").get(
+                  f"columnCache.keep.{k}", 0) for k in ("hits", "misses")],
+              file=sys.stderr)
 
     def span_data(name):
         return [s["data"] for r in done for s in r.spans if s["name"] == name]
@@ -234,6 +248,45 @@ def by_clauses(done):
     return {key: [n, {name: round(us / 1e3 / n, 3)
                       for name, us in total[key].most_common()}]
             for key, n in count.items()}
+
+
+def queries_apart(done):
+    """Where a request holds MERGEs and queries (a power stream): every
+    span's mean milliseconds a query, a span belonging to the query whose
+    root span ``delta.sql.select`` was open when it started. The queries are
+    told apart by whether the statement before was a MERGE (``first``: the
+    query that meets the new version, loads the new file's lanes and builds
+    the keep mask of a new vector) and by whether the answer was grouped.
+    Empty where no request holds both kinds of root."""
+    total = collections.defaultdict(collections.Counter)
+    count, up = collections.Counter(), collections.Counter()
+    masks = collections.defaultdict(collections.Counter)
+    for r in done:
+        roots = sorted((s for s in r.spans if s["duration_us"] and s["name"]
+                        in ("delta.dml.merge", _SELECT)),
+                       key=lambda s: s["start_us"])
+        if len({s["name"] for s in roots}) < 2:
+            continue
+        for before, root in zip([None] + roots, roots):
+            if root["name"] != _SELECT:
+                continue
+            lo, hi = root["start_us"], root["start_us"] + root["duration_us"]
+            inside = [s for s in r.spans if s["duration_us"] is not None
+                      and lo <= s["start_us"] < hi]
+            grouped = any(s["name"] == _QUERY and "groups" in s["data"]
+                          for s in inside)
+            key = ("rest" if before and before["name"] == _SELECT else "first") \
+                + (" grouped" if grouped else " ungrouped")
+            count[key] += 1
+            for s in inside:
+                total[key][s["name"]] += s["duration_us"]
+                up[key] += s["data"].get("h2dBytes", 0)
+                if s["name"] == "delta.columnCache.keepMask":
+                    masks[key][str(s["data"].get("cached"))] += 1
+    return {key: [n, {name: round(us / 1e3 / n, 3)
+                      for name, us in total[key].most_common()},
+                  round(up[key] / n, 1), dict(masks[key])]
+            for key, n in sorted(count.items())}
 
 
 _NAME = re.compile(r"%([A-Za-z_][A-Za-z_0-9]*?)(?:\.\d+)*(?![\w.\-])")
