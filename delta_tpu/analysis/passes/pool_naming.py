@@ -37,6 +37,7 @@ REGISTERED_POOLS = frozenset({
     "delta-vacuum-delete",        # commands/vacuum.py parallel delete
     "delta-replay-prep",          # replay/shadow.py candidate clone prep
     "delta-dist-exec",            # parallel/executor.py sharded work items
+    "delta-merge-dv",             # commands/merge.py vectors beside the write
     # dedicated threads (threading.Thread name)
     "delta-dist-supervisor",      # parallel/executor.py heartbeat watchdog
     "delta-ckpt-async",           # log/checkpointer.py coalescing daemon

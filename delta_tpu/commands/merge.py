@@ -33,6 +33,8 @@ fast path (`:397-450`) and `MergeStats` (`:79-174`) follow the reference.
 from __future__ import annotations
 
 import contextlib
+import os
+import threading
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple, Union
@@ -181,6 +183,8 @@ class MergeIntoCommand:
         # wall-clock per phase (decode/key/join/apply/write ms), filled by
         # the phase spans (`_phase`); the router audit carries a copy
         self.phase_ms: Dict[str, float] = {}
+        # the vectors' phase ends on a worker thread (`_write_vectors`)
+        self._phase_lock = threading.Lock()
         # set by _join when the device kernel ran: JoinResult with exact
         # per-target match counts and per-source matched flags
         self._device_join = None
@@ -374,15 +378,18 @@ class MergeIntoCommand:
         own duration is ``phase_ms[key]``, so a phase has one clock reading,
         a start and a parent. The phases tile `_body` and `_join`: they do
         not overlap on the calling thread, and only a few lines lie between
-        them. A phase that opens twice in one MERGE (the pairs-only route
-        that declines and decodes late) adds up. Under a telemetry blackout
-        the span is the no-op event and the phase is timed here."""
+        them; the vectors' phase alone may run on a worker thread, beside
+        the write's (`_write_vectors`). A phase that opens twice in one MERGE
+        (the pairs-only route that declines and decodes late) adds up. Under
+        a telemetry blackout the span is the no-op event and the phase is
+        timed here."""
         t0 = time.perf_counter_ns()
         with telemetry.record_operation(op_type, data) as ev:
             yield ev
-        self.phase_ms[key] = self.phase_ms.get(key, 0.0) + (
-            ev.duration_us / 1000.0 if ev.duration_us is not None
-            else (time.perf_counter_ns() - t0) / 1e6)
+        ms = (ev.duration_us / 1000.0 if ev.duration_us is not None
+              else (time.perf_counter_ns() - t0) / 1e6)
+        with self._phase_lock:
+            self.phase_ms[key] = self.phase_ms.get(key, 0.0) + ms
 
     def _body(self, txn) -> int:
         # self-calibrating cost model: install any persisted constant
@@ -410,7 +417,8 @@ class MergeIntoCommand:
         self._router: Dict[str, Any] = {}
         self._cdf_blocks = []
         self._use_cdf = cdf_exec.cdf_enabled(txn.metadata)
-        self.phase_ms.clear()
+        with self._phase_lock:
+            self.phase_ms.clear()
         timer = Timer()
         with self._phase("delta.dml.merge.analyze", "analyze_ms"):
             metadata = self._migrate_schema(txn)
@@ -535,53 +543,68 @@ class MergeIntoCommand:
                 if self._use_cdf:
                     self._cdf_blocks.append(("insert", inserts))
 
-        if use_dv:
-            # claimed rows are marked deleted via per-file deletion vectors;
-            # everything else stays live in place — the file rewrite (and
-            # its copy block above) disappears entirely
-            with self._phase("delta.dml.merge.deletionVectors", "dv_ms",
-                             {"files": 0, "rows": 0}) as dv_ev:
-                if claimed_tbl is not None and claimed_tbl.num_rows:
-                    fids = claimed_tbl.column(_FID).to_numpy(zero_copy_only=False)
-                    poss = claimed_tbl.column(POSITION_COL).to_numpy(zero_copy_only=False)
-                    touched = np.unique(fids)
-                    dv_ev.data.update(files=len(touched), rows=len(poss))
-                    for fid in touched:
-                        add = candidates[int(fid)]
-                        claimed = poss[fids == fid]
-                        rm, re_add = dv_common.dv_mark_deleted(
-                            self.delta_log.data_path, add, claimed)
-                        if self._pairs_only:
-                            self._check_claimed_were_live(
-                                add, re_add, len(claimed))
-                        removes.append(rm)
-                        if re_add is not None:
-                            dv_adds.append(re_add)
+        # claimed rows are marked deleted via per-file deletion vectors;
+        # everything else stays live in place — the file rewrite (and its
+        # copy block above) disappears entirely. A file's vector and the
+        # new data file need nothing of each other: where the statement made
+        # both, the vectors are written beside the data file, not before it
+        marked: List[Tuple[Action, Optional[Action]]] = []
+        pool = beside = None
+        if use_dv and self._writes_beside(claimed_tbl, out_blocks):
+            from concurrent.futures import ThreadPoolExecutor
 
-        with self._phase("delta.dml.merge.write", "write_ms"):
-            adds: List[Action] = list(dv_adds)
-            cdc_actions: List[Action] = []
-            out = None
-            # what comes before the shared writer, whose own stages
-            # (`delta.write.prepare`, `.encode`, `.stats`) tile the rest
-            with telemetry.record_operation(
-                    "delta.dml.merge.write.concat",
-                    {"blocks": len(out_blocks), "rows": 0}) as cev:
-                if self._cdf_blocks:
-                    cdc_actions = list(cdf_exec.write_change_data(
-                        self.delta_log.data_path, self._cdf_blocks, metadata
-                    ))
-                if out_blocks:
-                    out = pa.concat_tables(out_blocks, promote_options="permissive")
-                    if out.column_names != target_cols:
-                        out = out.select(target_cols)
-                    cev.data["rows"] = out.num_rows
-            if out is not None and out.num_rows:
-                adds += list(
-                    write_exec.write_files(
-                        self.delta_log.data_path, out, metadata, data_change=True
+            telemetry.bump_counter("merge.dv.overlapped")
+            # one thread holds the phase's span, the others run its jobs
+            pool = ThreadPoolExecutor(
+                max_workers=1 + min(len(candidates), os.cpu_count() or 4),
+                thread_name_prefix="delta-merge-dv")
+            beside = pool.submit(
+                telemetry.propagated(self._write_vectors),
+                candidates, claimed_tbl, pool)
+        elif use_dv:
+            marked = self._write_vectors(candidates, claimed_tbl)
+
+        written: List[Action] = []
+        cdc_actions: List[Action] = []
+        try:
+            with self._phase("delta.dml.merge.write", "write_ms"):
+                out = None
+                # what comes before the shared writer, whose own stages
+                # (`delta.write.prepare`, `.encode`, `.stats`) tile the rest
+                with telemetry.record_operation(
+                        "delta.dml.merge.write.concat",
+                        {"blocks": len(out_blocks), "rows": 0}) as cev:
+                    if self._cdf_blocks:
+                        cdc_actions = list(cdf_exec.write_change_data(
+                            self.delta_log.data_path, self._cdf_blocks, metadata
+                        ))
+                    if out_blocks:
+                        out = pa.concat_tables(out_blocks, promote_options="permissive")
+                        if out.column_names != target_cols:
+                            out = out.select(target_cols)
+                        cev.data["rows"] = out.num_rows
+                if out is not None and out.num_rows:
+                    written = list(
+                        write_exec.write_files(
+                            self.delta_log.data_path, out, metadata, data_change=True
+                        )
                     )
-                )
+        finally:
+            # every file and vector is on disk before the commit is built;
+            # a vector job's error is the statement's, whatever the write did
+            if beside is not None:
+                try:
+                    marked = beside.result()
+                except BaseException:
+                    self._remove_files(written + cdc_actions)
+                    raise
+                finally:
+                    pool.shutdown()
+        for rm, re_add in marked:  # by file id, as the jobs were made
+            removes.append(rm)
+            if re_add is not None:
+                dv_adds.append(re_add)
+        adds: List[Action] = dv_adds + written
         rewrite_ms = timer.lap_ms()
 
         self.metrics.update(
@@ -1247,6 +1270,62 @@ class MergeIntoCommand:
             self._join_path = "resident"
             self._router["route"] = "pairs-only"
             return joined
+
+    @staticmethod
+    def _writes_beside(claimed_tbl, out_blocks) -> bool:
+        """Whether the statement made both claimed rows to mark and rows to
+        write: only then is there a write for the vectors to run beside.
+        One of the two alone (a keyed delete, an insert) runs inline: no
+        pool, no thread hop."""
+        return (claimed_tbl is not None and claimed_tbl.num_rows > 0
+                and any(b.num_rows for b in out_blocks))
+
+    def _write_vectors(self, candidates, claimed_tbl, pool=None):
+        """The phase ``delta.dml.merge.deletionVectors``: one job for every
+        file a matched clause claimed rows of, by file id (each is one
+        `dv_mark_deleted`, with its own `AddFile`, its own positions and its
+        own vector file); returns ``(remove, re-add or None)`` a job, in that
+        order. With a ``pool`` the caller's thread is writing the data file
+        meanwhile: this runs on one of the pool's threads and the jobs on
+        the others, under its span. Without, the jobs run inline, one after
+        another."""
+        import numpy as np
+
+        def mark(job):
+            add, claimed = job
+            return dv_common.dv_mark_deleted(
+                self.delta_log.data_path, add, claimed)
+
+        with self._phase("delta.dml.merge.deletionVectors", "dv_ms",
+                         {"files": 0, "rows": 0,
+                          "overlapped": pool is not None}) as dv_ev:
+            if claimed_tbl is None or not claimed_tbl.num_rows:
+                return []
+            fids = claimed_tbl.column(_FID).to_numpy(zero_copy_only=False)
+            poss = claimed_tbl.column(POSITION_COL).to_numpy(zero_copy_only=False)
+            jobs = [(candidates[int(fid)], poss[fids == fid])
+                    for fid in np.unique(fids)]
+            dv_ev.data.update(files=len(jobs), rows=len(poss))
+            if pool is not None:
+                marked = list(pool.map(telemetry.propagated(mark), jobs))
+            else:
+                marked = [mark(j) for j in jobs]
+            if self._pairs_only:
+                for (add, claimed), (_, re_add) in zip(jobs, marked):
+                    self._check_claimed_were_live(add, re_add, len(claimed))
+        return marked
+
+    def _remove_files(self, actions) -> None:
+        """Best effort: take the files this attempt wrote (and will not
+        commit) off the table's directory, as if the failure had come
+        before the write."""
+        from delta_tpu.exec.scan import _abs_data_path
+
+        for a in actions:
+            try:
+                os.remove(_abs_data_path(self.delta_log.data_path, a.path))
+            except OSError:
+                pass
 
     def _stale_slab(self, why: str) -> None:
         """Drop the table's slab and end this run of the body: `run` makes

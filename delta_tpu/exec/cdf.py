@@ -78,7 +78,7 @@ def write_change_data(
     out = pa.concat_tables(parts, promote_options="permissive")
     rel = f"{CDC_DIR}/cdc-{uuid.uuid4()}.c000.snappy.parquet"
     abs_path = os.path.join(data_path, CDC_DIR, os.path.basename(rel))
-    size, _ = write_parquet_file(out, abs_path)
+    size, _mtime, _footer = write_parquet_file(out, abs_path)
     return [AddCDCFile(path=rel, partition_values={}, size=size)]
 
 

@@ -59,6 +59,20 @@ def _stat_value(scalar: pa.Scalar, round_up: bool = False) -> Any:
     return json_stat_value(scalar.as_py(), round_up)
 
 
+def has_stat_bounds(t: pa.DataType) -> bool:
+    """Whether a column of Arrow type ``t`` gets ``minValues``/``maxValues``
+    at all: one rule for the decode path and the footer path."""
+    return (
+        pa.types.is_integer(t)
+        or pa.types.is_floating(t)
+        or pa.types.is_string(t)
+        or pa.types.is_date(t)
+        or pa.types.is_timestamp(t)
+        or pa.types.is_boolean(t)
+        or pa.types.is_decimal(t)
+    )
+
+
 def collect_stats(table: pa.Table, num_indexed_cols: int = 32) -> Dict[str, Any]:
     """Per-file stats over the first ``num_indexed_cols`` leaf columns."""
     mins: Dict[str, Any] = {}
@@ -67,17 +81,7 @@ def collect_stats(table: pa.Table, num_indexed_cols: int = 32) -> Dict[str, Any]
     for name in table.column_names[: num_indexed_cols if num_indexed_cols >= 0 else None]:
         col = table.column(name)
         nulls[name] = col.null_count
-        t = col.type
-        skippable = (
-            pa.types.is_integer(t)
-            or pa.types.is_floating(t)
-            or pa.types.is_string(t)
-            or pa.types.is_date(t)
-            or pa.types.is_timestamp(t)
-            or pa.types.is_boolean(t)
-            or pa.types.is_decimal(t)
-        )
-        if not skippable or col.null_count == len(col):
+        if not has_stat_bounds(col.type) or col.null_count == len(col):
             continue
         try:
             mn = _stat_value(pc.min(col))
@@ -125,10 +129,17 @@ def _compresses_well(col: pa.ChunkedArray, sample_bytes: int = 65536) -> bool:
 
 def write_parquet_file(
     table: pa.Table, abs_path: str, compression: Optional[str] = None
-) -> Tuple[int, int]:
-    """Write one Parquet file; returns (size_bytes, mtime_ms).
+) -> Tuple[int, int, "pq.FileMetaData"]:
+    """Write one Parquet file; returns (size_bytes, mtime_ms, footer).
 
-    Encoding policy (measured on store_sales-shaped data, single host core):
+    ``footer`` is the ``FileMetaData`` the encoder made for the file it just
+    wrote (nothing is read back): every column's min, max and null count for
+    every row group are in it, so `exec.rowgroups.stats_from_footer` gives
+    the file's protocol statistics without a second pass over the rows.
+
+    One file encodes on one host core (pyarrow hands Python no threaded
+    column encode); `write_files` spreads several files over a pool.
+    Encoding policy (measured on store_sales-shaped data, on that one core):
 
     - dictionary pages only for string/binary columns — dictionary-encoding
       high-cardinality numerics bloats files and makes reads 4-5x slower;
@@ -179,14 +190,16 @@ def write_parquet_file(
     rg_rows = int(conf.get("delta.tpu.write.rowGroupRows", 131_072))
     if rg_rows > 0:
         kwargs["row_group_size"] = rg_rows
-    pq.write_table(table, abs_path, compression=codec, **kwargs)
+    footer: List[pq.FileMetaData] = []
+    pq.write_table(table, abs_path, compression=codec,
+                   metadata_collector=footer, **kwargs)
     st = os.stat(abs_path)
     from delta_tpu.utils.telemetry import bump_counter
 
     bump_counter("parquet.files.written")
     bump_counter("parquet.bytes.written", st.st_size)
     bump_counter("parquet.rows.written", table.num_rows)
-    return st.st_size, int(st.st_mtime * 1000)
+    return st.st_size, int(st.st_mtime * 1000), footer[0]
 
 
 def read_parquet_files(

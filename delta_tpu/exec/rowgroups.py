@@ -26,8 +26,10 @@ its stale entry on the next lookup. Capacity:
 
 :func:`stats_from_footer` derives protocol AddFile stats
 (minValues/maxValues/nullCount/numRecords) from the same footer statistics —
-CONVERT TO DELTA uses it to stop decoding whole data files just to compute
-stats, falling back to a full decode when the footer is absent or unsafe.
+the shared writer (`exec/write.write_files`) hands it the footer its encoder
+just made, and CONVERT TO DELTA the footer it read, so neither decodes or
+walks a whole data file just to compute stats; both fall back to the rows
+themselves when the footer is absent or cannot give the same answer.
 """
 from __future__ import annotations
 
@@ -340,34 +342,40 @@ def row_groups_for_positions(meta, positions) -> FrozenSet[int]:
 
 
 # ---------------------------------------------------------------------------
-# Footer-derived AddFile stats (CONVERT TO DELTA)
+# Footer-derived AddFile stats (the shared writer, CONVERT TO DELTA)
 # ---------------------------------------------------------------------------
 
 
 def stats_from_footer(meta, num_indexed_cols: int = 32) -> Optional[Dict[str, Any]]:
     """Protocol stats (numRecords/minValues/maxValues/nullCount) derived
     from footer row-group statistics, or ``None`` when the footer cannot
-    stand in for a full decode:
+    stand in for a pass over the rows:
 
+    * an indexed column that is not a flat leaf (struct, list, map);
     * any indexed column chunk without a statistics block (stats-disabled
       writer, or bounds omitted for oversized binary values) while the
       chunk holds non-null values;
-    * NaN float bounds (legacy writers — bounds untrustworthy).
+    * NaN float bounds (legacy writers — bounds untrustworthy);
+    * a float bound of zero: writers store a zero minimum as -0.0 and a zero
+      maximum as +0.0 whatever the rows held, so the sign is not the data's.
 
+    It serves the write path (`exec/write.write_files`, on the footer the
+    encoder hands back) and CONVERT TO DELTA (on a footer read from disk).
     Bounds the decode path would not emit either (binary, decimal,
     non-finite floats) are simply omitted — that matches
-    ``exec.parquet.collect_stats`` encoding rules, so footer-derived and
-    decode-derived stats agree wherever both exist."""
+    ``exec.parquet.collect_stats`` encoding rules, and what this returns is
+    what ``collect_stats`` returns for the same rows, key for key and in the
+    same order, so the two JSON strings are equal byte for byte."""
     import pyarrow as pa
 
-    from delta_tpu.exec.parquet import json_stat_value
+    from delta_tpu.exec.parquet import has_stat_bounds, json_stat_value
 
     try:
         arrow_schema = meta.schema.to_arrow_schema()
     except Exception:
         return None
     col_index = _column_index(meta)
-    n_rgs = meta.num_row_groups
+    row_groups = [meta.row_group(i) for i in range(meta.num_row_groups)]
     names = arrow_schema.names[: num_indexed_cols if num_indexed_cols >= 0 else None]
     mins: Dict[str, Any] = {}
     maxs: Dict[str, Any] = {}
@@ -382,8 +390,7 @@ def stats_from_footer(meta, num_indexed_cols: int = 32) -> Optional[Dict[str, An
         col_mins: List[Any] = []
         col_maxs: List[Any] = []
         bounds_incomplete = False
-        for i in range(n_rgs):
-            rg = meta.row_group(i)
+        for rg in row_groups:
             try:
                 st = rg.column(j).statistics
             except Exception:
@@ -408,16 +415,7 @@ def stats_from_footer(meta, num_indexed_cols: int = 32) -> Optional[Dict[str, An
                 # binary): only a decode can produce them
                 bounds_incomplete = True
         nulls[name] = total_null
-        skippable = (
-            pa.types.is_integer(t)
-            or pa.types.is_floating(t)
-            or pa.types.is_string(t)
-            or pa.types.is_date(t)
-            or pa.types.is_timestamp(t)
-            or pa.types.is_boolean(t)
-            or pa.types.is_decimal(t)
-        )
-        if not skippable or total_null == meta.num_rows:
+        if not has_stat_bounds(t) or total_null == meta.num_rows:
             continue  # same columns collect_stats skips
         if bounds_incomplete or not col_mins:
             return None
@@ -426,6 +424,8 @@ def stats_from_footer(meta, num_indexed_cols: int = 32) -> Optional[Dict[str, An
             mx_v = max(col_maxs)
         except TypeError:
             return None
+        if is_float and (mn_v == 0 or mx_v == 0):
+            return None  # the zero's sign is the writer's, not the rows'
         mn_j = json_stat_value(mn_v)
         mx_j = json_stat_value(mx_v, round_up=True)
         if mn_j is not None:

@@ -13,6 +13,7 @@ VACUUM.
 """
 from __future__ import annotations
 
+import json
 import os
 import urllib.parse
 import uuid
@@ -22,6 +23,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 
 from delta_tpu.exec import parquet as pq_exec
+from delta_tpu.exec.rowgroups import stats_from_footer
 from delta_tpu.expr.vectorized import arrow_type_for
 from delta_tpu.protocol.actions import AddFile, Metadata
 from delta_tpu.schema import constraints as constraints_mod
@@ -166,7 +168,14 @@ def write_files(
     Three spans tile the call under whatever span the caller holds:
     ``delta.write.prepare`` (everything before the first byte is encoded),
     then for each file, on the thread that writes it, ``delta.write.encode``
-    and ``delta.write.stats``."""
+    and ``delta.write.stats``. A file's statistics come from the footer its
+    encoder just made (`rowgroups.stats_from_footer`: the encoder has
+    computed every column's min, max and null count already); where the
+    footer declines (a nested column, bounds withheld or not the rows' own)
+    they come from a pass over the file's rows (`parquet.stats_json`), and
+    the string is the same either way. ``source`` on the stats span says
+    which (``footer`` or ``decode``); ``write.stats.footer`` and
+    ``write.stats.decoded`` count the files."""
     with telemetry.record_operation(
             "delta.write.prepare",
             {"rows": table.num_rows, "columns": table.num_columns,
@@ -181,11 +190,19 @@ def write_files(
         abs_path = os.path.join(data_path, rel.replace("/", os.sep))
         with telemetry.record_operation(
                 "delta.write.encode", {"rows": file_data.num_rows}) as eev:
-            size, mtime = pq_exec.write_parquet_file(file_data, abs_path)
+            size, mtime, footer = pq_exec.write_parquet_file(file_data, abs_path)
             eev.data["bytes"] = size
         with telemetry.record_operation(
-                "delta.write.stats", {"columns": file_data.num_columns}):
-            stats = pq_exec.stats_json(file_data, num_indexed)
+                "delta.write.stats", {"columns": file_data.num_columns}) as sev:
+            from_footer = stats_from_footer(footer, num_indexed)
+            if from_footer is not None:
+                stats = json.dumps(from_footer)
+                sev.data["source"] = "footer"
+                telemetry.bump_counter("write.stats.footer")
+            else:
+                stats = pq_exec.stats_json(file_data, num_indexed)
+                sev.data["source"] = "decode"
+                telemetry.bump_counter("write.stats.decoded")
         return AddFile(
             # AddFile.path is URI-encoded per the protocol (the hive-
             # escaped dir's '%' becomes '%25'); readers unquote once.

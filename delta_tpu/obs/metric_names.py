@@ -124,6 +124,7 @@ COUNTERS = frozenset({
     "merge.resident.pairsOnly",   # the resident probe's pairs were the join
     "merge.resident.pairsOnly.declined",  # engaged, then decoded after all
     "merge.resident.probe.overflow",  # candidate rows past the pair scratch
+    "merge.dv.overlapped",        # vectors written beside the data file
     "merge.device.compiles",      # XLA compiles inside a MERGE's root span
     "merge.keyCache.builds",      # cold key-lane builds (inline or bg)
     "merge.keyCache.advances",    # incremental log-tail applications
@@ -193,6 +194,8 @@ ENGINE_COUNTERS = frozenset({
     "parquet.files.written",
     "parquet.bytes.written",
     "parquet.rows.written",
+    "write.stats.footer",         # files whose statistics the footer gave
+    "write.stats.decoded",        # files walked again for their statistics
     "scan.files.read",
     "scan.bytes.read",
     "scan.bytes.skipped",
@@ -412,6 +415,7 @@ DESCRIPTIONS = {
     "merge.device.cacheHit": "Device MERGEs served from an HBM-resident key lane.",
     "merge.clause.insertOnly": "MERGE statements with no WHEN MATCHED clause (the de-duplicating insert; the join fetches no pair).",
     "merge.clause.delete": "MERGE statements with a WHEN MATCHED THEN DELETE clause.",
+    "merge.dv.overlapped": "MERGE statements that made both deletion vectors and rows to write, and wrote the vectors on worker threads beside the data file (overlapped on the delta.dml.merge.deletionVectors span); a statement that made one of the two runs inline and does not count.",
     "merge.resident.pairsOnly": "Resident MERGEs that took the pairs-only route: no touched-files pre-probe, no decode of the target.",
     "merge.resident.pairsOnly.declined": "Pairs-only MERGEs that decoded the target after all (probe overflow, a slab that disagrees with the snapshot).",
     "merge.resident.probe.overflow": "Resident probes declined to the host join because the matched keys' candidate slab rows (dead versions, duplicate target keys) pass the pair kernel's scratch bound.",
@@ -473,6 +477,8 @@ DESCRIPTIONS = {
     "parquet.files.written": "Parquet data files written.",
     "parquet.bytes.written": "Parquet bytes written.",
     "parquet.rows.written": "Rows written to Parquet files.",
+    "write.stats.footer": "Data files whose AddFile statistics came from the footer their encoder had just made (source=footer on delta.write.stats): no second pass over the rows.",
+    "write.stats.decoded": "Data files whose footer could not give the statistics (a nested column, bounds withheld for an oversized value, NaN or zero float bounds) and whose rows were walked for them (source=decode).",
     "scan.files.read": "Data files decoded by scans.",
     "scan.bytes.read": "Compressed bytes of files decoded by scans.",
     "scan.bytes.skipped": "Uncompressed bytes skipped by row-group pruning.",

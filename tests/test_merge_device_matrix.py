@@ -647,3 +647,241 @@ def test_a_slab_built_over_touched_files_only_is_not_the_tables(tmp_path,
     cmd_b = _run(log_b, again, "t.k = s.k", [], [INS], "off")
     assert cmd_a.metrics["numTargetRowsInserted"] == 1
     assert _rows(log_a) == _rows(log_b)
+
+
+# -- the vectors beside the write (ISSUE 39) ---------------------------------
+#
+# A MERGE that made both claimed rows to mark and rows to write runs its
+# per-file vector jobs on worker threads while its own thread writes the data
+# file, and joins them before it builds the commit. Which path runs follows
+# from what the statement produced (`_writes_beside`; the tests patch that
+# observable for the inline copy, never a conf).
+
+OVERLAPPED = "merge.dv.overlapped"
+DVS = "delta.dml.merge.deletionVectors"
+COND = "t.k = s.k"
+
+
+def _overlapped():
+    return telemetry.counters("merge.dv").get(OVERLAPPED, 0)
+
+
+@contextlib.contextmanager
+def _inline_vectors(monkeypatch):
+    with monkeypatch.context() as m:
+        m.setattr(MergeIntoCommand, "_writes_beside",
+                  staticmethod(lambda claimed_tbl, out_blocks: False))
+        yield
+
+
+def _last_commit(log):
+    """The last commit's file actions in their order: kind, the file (named
+    by the commit that first added it and its place there, so that two
+    copies compare) and its vector's cardinality."""
+    from delta_tpu.protocol.actions import AddFile, FileAction
+
+    label, last = {}, []
+    for version, actions in log.get_changes(0):
+        files = [a for a in actions if isinstance(a, FileAction)]
+        for i, a in enumerate(a for a in files if isinstance(a, AddFile)
+                              and a.path not in label):
+            label[a.path] = f"v{version}#{i}"
+        last = files
+    return [(type(a).__name__, label[a.path],
+             (getattr(a, "deletion_vector", None) or {}).get("cardinality"))
+            for a in last]
+
+
+def _data_files(log):
+    import os
+
+    return {os.path.join(d, f) for d, _, fs in os.walk(log.data_path)
+            for f in fs if f.endswith(".parquet") and "_delta_log" not in d}
+
+
+@pytest.mark.parametrize("mode", ["force", "off"])
+def test_vectors_beside_the_write_commit_what_inline_commits(
+        tmp_path, monkeypatch, mode):
+    log_a, log_b = _dv_tables(tmp_path)
+    tcols = ["k", "v", "tag"]
+    if mode == "force":
+        for log in (log_a, log_b):
+            _prebuild(log, COND, tcols, tcols)
+    # the second round unites with the first's vectors and probes its file
+    for src in (_source([5, 6, 7, 650]),
+                _source([5, 8, 150, 399, 400, 799, 900, 901])):
+        c0 = _overlapped()
+        telemetry.clear_events()
+        cmd_a = _run(log_a, src, COND, [UP], [INS], mode)
+        assert _overlapped() == c0 + 1
+        [dv_a] = telemetry.recent_events(DVS)
+        [root_a] = [e for e in telemetry.recent_events("delta.dml.merge")
+                    if e.op_type == "delta.dml.merge"]
+        assert dv_a.data["overlapped"] is True
+        assert dv_a.thread_name.startswith("delta-merge-dv")
+        assert dv_a.thread_id != root_a.thread_id
+        telemetry.clear_events()
+        with _inline_vectors(monkeypatch):
+            cmd_b = _run(log_b, src, COND, [UP], [INS], mode)
+        assert _overlapped() == c0 + 1
+        [dv_b] = telemetry.recent_events(DVS)
+        [root_b] = [e for e in telemetry.recent_events("delta.dml.merge")
+                    if e.op_type == "delta.dml.merge"]
+        assert dv_b.data["overlapped"] is False
+        assert dv_b.thread_id == root_b.thread_id
+        for key in ("files", "rows"):
+            assert dv_a.data[key] == dv_b.data[key] > 0
+        assert cmd_a._pairs_only == cmd_b._pairs_only == (mode == "force")
+        # a file's id follows its (random) name, so the copies' new files
+        # hold their rows, and their commits their files, in their own order
+        _same_outcome(log_a, log_b, cmd_a, cmd_b, order=False)
+        assert sorted(_last_commit(log_a), key=str) == sorted(
+            _last_commit(log_b), key=str)
+    for log in (log_a, log_b):
+        # the removes, their re-adds in the same order, one data file
+        last = _last_commit(log)
+        assert [k for k, _, _ in last] == (
+            ["RemoveFile"] * 6 + ["AddFile"] * 6 + ["AddFile"])
+        files = [f for _, f, _ in last]
+        assert files[:6] == files[6:12] and files[12] == "v2#0"
+        assert {f: c for _, f, c in last[6:]} == {
+            "v0#0": 4, "v1#0": 1, "v0#1": 1, "v0#3": 1, "v0#4": 1, "v0#7": 1,
+            "v2#0": None}
+
+
+@pytest.mark.parametrize("fault", ["stale-slab", "os-error",
+                                   "os-error-and-the-write-fails",
+                                   "the-write-fails-alone"])
+def test_a_vector_job_that_raises_commits_nothing_and_leaves_no_data_file(
+        tmp_path, monkeypatch, fault):
+    """The vectors still decide: their error is the statement's, whatever
+    the write did meanwhile, the data file the attempt wrote is taken off
+    the directory, and nothing is committed."""
+    import os
+
+    from delta_tpu.commands import dml_common, merge as merge_mod
+
+    log_a, _ = _dv_tables(tmp_path)
+    tcols = ["k", "v", "tag"]
+    _prebuild(log_a, COND, tcols, tcols)
+    _run(log_a, _source([5, 6, 7, 650]), COND, [UP], [INS], "force")
+    source = _source([5, 6, 20, 150, 700, 950])
+    cmd = MergeIntoCommand(log_a, source, COND, [UP], [INS], **ALIAS)
+    _prebuild(log_a, COND, tcols, tcols)  # advanced to the snapshot
+    removed = []
+    real_remove = MergeIntoCommand._remove_files
+    monkeypatch.setattr(
+        MergeIntoCommand, "_remove_files",
+        lambda self, actions: (removed.extend(actions),
+                               real_remove(self, actions))[1])
+    if fault == "stale-slab":
+        _revive_deleted_rows(log_a)
+        error, text = merge_mod._StaleResidentSlab, "was deleted already"
+    if fault.startswith("os-error"):
+        real_mark, broken = dml_common.dv_mark_deleted, _file_of(log_a, 150)
+
+        def mark(data_path, add, positions):
+            if add.path == broken:
+                raise OSError("no space left on the device")
+            return real_mark(data_path, add, positions)
+
+        monkeypatch.setattr(dml_common, "dv_mark_deleted", mark)
+        error, text = OSError, "no space left"
+    if fault.endswith("the-write-fails") or fault == "the-write-fails-alone":
+        def write_files(*a, **k):
+            raise RuntimeError("the encoder died")
+
+        monkeypatch.setattr(merge_mod.write_exec, "write_files", write_files)
+        if fault == "the-write-fails-alone":
+            error, text = RuntimeError, "the encoder died"
+    version, before, c0 = log_a.update().version, _data_files(log_a), _overlapped()
+    with conf.set_temporarily(**{
+            "delta.tpu.merge.devicePath.mode": "force",
+            "delta.tpu.deletionVectors.enabled": True}):
+        with pytest.raises(error, match=text) as raised:
+            # one attempt: `run` would answer a stale slab with a second
+            log_a.with_new_transaction(cmd._body)
+    assert _overlapped() == c0 + 1
+    assert log_a.update().version == version
+    assert _data_files(log_a) == before
+    if fault in ("stale-slab", "os-error"):
+        [add] = removed  # the attempt had written its data file
+        assert add.path.endswith(".parquet")
+        assert not os.path.exists(os.path.join(log_a.data_path, add.path))
+    else:
+        assert removed == []
+    if fault == "os-error-and-the-write-fails":
+        assert "the encoder died" in str(raised.value.__context__)
+    monkeypatch.undo()
+    # the statement itself, afterwards: one commit, every row where it belongs
+    cmd_a = _run(log_a, source, COND, [UP], [INS], "force")
+    assert log_a.update().version == version + 1
+    assert cmd_a.metrics["numTargetRowsUpdated"] == 5
+    assert cmd_a.metrics["numTargetRowsInserted"] == 1
+    assert len(_data_files(log_a)) == len(before) + 1
+
+
+def test_spans_of_worker_threads_parent_under_the_merges_root(tmp_path):
+    log_a, _ = _dv_tables(tmp_path)
+    telemetry.clear_events()
+    with conf.set_temporarily(**{"delta.tpu.write.targetFileRows": 3}):
+        _run(log_a, _source([0, 3, 150, 399, 400, 799, 900, 901]), COND,
+             [UP], [INS], "off")
+    events = telemetry.recent_events()
+    by_id = {e.span_id: e for e in events}
+    [root] = [e for e in events if e.op_type == "delta.dml.merge"]
+    [dv] = [e for e in events if e.op_type == DVS]
+    [write] = [e for e in events if e.op_type == "delta.dml.merge.write"]
+    [commit] = [e for e in events if e.op_type == "delta.commit"]
+    assert dv.parent_id == write.parent_id == root.span_id
+    assert write.thread_id == root.thread_id != dv.thread_id
+    assert dv.data == {"files": 5, "rows": 6, "overlapped": True}
+    assert dv.start_us + dv.duration_us <= commit.start_us + 1
+    files = [e for e in events if e.op_type in ("delta.write.encode",
+                                                "delta.write.stats")]
+    assert len(files) == 2 * 3  # eight rows, three to a file
+    for e in files:
+        assert e.parent_id == write.span_id
+        assert e.thread_name.startswith("delta-parquet-write")
+    # no worker's span is an orphan root: each leads up to the MERGE's
+    workers = [e for e in events if e.thread_name.startswith(
+        ("delta-merge-dv", "delta-parquet-write"))]
+    assert {e.op_type for e in workers} >= {DVS, "delta.write.encode"}
+    for e in workers:
+        while e.parent_id is not None and e is not root:
+            e = by_id[e.parent_id]
+        assert e is root
+
+
+@pytest.mark.parametrize("mode", ["force", "off"])
+@pytest.mark.parametrize("shape", ["rf1", "rf2"])
+def test_a_merge_that_makes_vectors_or_rows_alone_opens_no_pool(
+        tmp_path, shape, mode):
+    """TPC-H's RF1 writes a file and no vector, RF2 a vector and no file:
+    there is nothing to run beside, and today's inline path runs."""
+    log_a, _ = _dv_tables(tmp_path)
+    tcols = ["k", "v", "tag"]
+    if mode == "force":
+        _prebuild(log_a, COND, tcols, tcols)
+    c0 = _overlapped()
+    telemetry.clear_events()
+    if shape == "rf1":
+        cmd = _run(log_a, _source([5, 900, 901]), COND, [], [INS], mode)
+        assert cmd.metrics["numTargetRowsInserted"] == 2
+    else:
+        cmd = _run(log_a, _source([5, 150, 151, 400, 900]), COND, [DEL], [],
+                   mode)
+        assert cmd.metrics["numTargetRowsDeleted"] == 4
+        assert cmd.metrics["numTargetFilesAdded"] == 3  # the re-adds alone
+    assert _overlapped() == c0
+    events = telemetry.recent_events()
+    [root] = [e for e in events if e.op_type == "delta.dml.merge"]
+    assert not any(e.thread_name.startswith("delta-merge-dv") for e in events)
+    dvs = [e for e in events if e.op_type == DVS]
+    if shape == "rf1":
+        assert dvs == []
+    else:
+        [dv] = dvs
+        assert dv.data == {"files": 3, "rows": 4, "overlapped": False}
+        assert dv.thread_id == root.thread_id
+        assert dv.parent_id == root.span_id

@@ -14,9 +14,12 @@ and its keep masks (``cached`` or built), what the resident probe's spans and co
 engaged, every sort of the slab split by ``tier`` (the tail run alone, or the
 whole slab; ``rows``, ``cause``) and every search of its big sorted run for
 flipped rows (``rows``, ``flips``, ``steps``) with the counts of flips
-searched for, of flips that re-sorted instead, of tail sorts and of folds, the
-routes
-and group counts of the aggregate queries with the program that answered the
+searched for, of flips that re-sorted instead, of tail sorts and of folds,
+where the written files' statistics came from (the counters
+``write.stats.footer`` / ``.decoded`` and ``source`` on ``delta.write.stats``)
+and how many MERGEs wrote their vectors beside their data file
+(``merge.dv.overlapped``, ``overlapped`` on the ``.deletionVectors`` span), the
+routes and group counts of the aggregate queries with the program that answered the
 grouped ones (``tiled`` or ``wide``) and the tiled share, under each leaf span
 that has stages inside it (a MERGE's ``.write`` and ``.apply``, a decode's
 ``.open``, an aggregate query's launches, its own span and its root) the
@@ -101,6 +104,24 @@ def report(run) -> None:
             print(f"slab sorts, tier={tier}:", json.dumps(
                 [d for d in sorts if d.get("tier") == tier]), file=sys.stderr)
         print("slab searches:", json.dumps(span_data("delta.keyCache.locate")),
+              file=sys.stderr)
+    stats = span_data("delta.write.stats")
+    if stats:
+        # where each written file's statistics came from, and how many of
+        # the window's MERGEs wrote their vectors beside their data file
+        print("written files' statistics, in the window [footer, decoded]:",
+              [run.counters.get(f"write.stats.{k}", 0)
+               for k in ("footer", "decoded")], "by the span's source:",
+              json.dumps(collections.Counter(
+                  str(d.get("source")) for d in stats)),
+              "MERGEs whose vectors ran beside the write:",
+              run.counters.get("merge.dv.overlapped", 0),
+              "of", sum(1 for r in done for s in r.spans
+                        if s["name"] == "delta.dml.merge"),
+              "by the .deletionVectors span's overlapped:",
+              json.dumps(collections.Counter(
+                  str(d.get("overlapped"))
+                  for d in span_data("delta.dml.merge.deletionVectors"))),
               file=sys.stderr)
     aggregates = span_data("delta.scan.deviceAggregate")
     if aggregates:
