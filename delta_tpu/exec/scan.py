@@ -33,6 +33,21 @@ def _abs_data_path(data_path: str, file_path: str) -> str:
     return os.path.join(data_path, urllib.parse.unquote(file_path).replace("/", os.sep))
 
 
+def _byte_array_decimals(schema, columns) -> int:
+    """The decimal leaves under the top-level ``columns`` that a footer's
+    schema stores as ``FIXED_LEN_BYTE_ARRAY``, the kind Arrow decodes one
+    value at a time."""
+    wanted = set(columns)
+    n = 0
+    for j in range(len(schema)):
+        c = schema.column(j)
+        if c.physical_type == "FIXED_LEN_BYTE_ARRAY" \
+                and c.converted_type == "DECIMAL" \
+                and c.path.split(".")[0] in wanted:
+            n += 1
+    return n
+
+
 def read_files_as_table(
     data_path: str,
     files: Sequence[AddFile],
@@ -223,12 +238,19 @@ def _read_files(rev, data_path, files, metadata, columns, per_file,
                 mt = mt.append_column(pa.field(c, at, f.nullable), arr)
         return mt
 
-    def _groups(meta, idx) -> dict:
-        """What a ``delta.scan.decode.rowGroups`` span says it read: the
-        groups, and their bytes as the footer states them (every column,
-        uncompressed)."""
-        return {"groups": len(idx),
-                "bytes": sum(meta.row_group(i).total_byte_size for i in idx)}
+    def _read_stage(stage, meta, idx, cols) -> None:
+        """Open the ``delta.scan.decode.rowGroups`` stage with what it
+        reads: the groups, their bytes as the footer states them (every
+        column, uncompressed) and, where the span is recorded,
+        ``flbaDecimals``: how many of the decoded columns are decimals
+        stored as fixed-length byte arrays (precision above 18, or a file
+        written before decimals were stored as integers)."""
+        ev = stage("delta.scan.decode.rowGroups",
+                   {"groups": len(idx),
+                    "bytes": sum(meta.row_group(i).total_byte_size
+                                 for i in idx)})
+        if ev.span_id:
+            ev.data["flbaDecimals"] = _byte_array_decimals(meta.schema, cols)
 
     # the inside of a decode's ``open`` stage, as its child spans:
     # ``.open.plan`` (`_plan_groups`), ``.open.survivors`` (the row groups'
@@ -319,7 +341,7 @@ def _read_files(rev, data_path, files, metadata, columns, per_file,
             t = None
             # one stage for the whole read: under late materialization that
             # is the predicate columns, their mask, then the rest
-            stage("delta.scan.decode.rowGroups", _groups(meta, keep_idx))
+            _read_stage(stage, meta, keep_idx, file_cols)
             if late_materialize and refs_covered \
                     and predicate is not None and pred_cols and rest_cols:
                 t1 = pf.read_row_groups(keep_idx, columns=pred_cols)
@@ -418,7 +440,7 @@ def _read_files(rev, data_path, files, metadata, columns, per_file,
                  if file_cols else _dummy(0))
             pos = np.empty(0, dtype=np.int64) if need_positions else None
             return t, pos, (dev_skipped, dev_bytes, 0)
-        stage("delta.scan.decode.rowGroups", _groups(meta, survivors))
+        _read_stage(stage, meta, survivors, file_cols)
         if file_cols:
             t = pf.read_row_groups(survivors, columns=file_cols)
         else:
@@ -488,8 +510,8 @@ def _read_files(rev, data_path, files, metadata, columns, per_file,
             # full decode — the seed path; reuse the already-parsed footer
             # when the planner fetched one
             pf, file_cols = _open_file(abs_path, meta)
-            stage("delta.scan.decode.rowGroups",
-                  _groups(pf.metadata, range(pf.metadata.num_row_groups)))
+            _read_stage(stage, pf.metadata,
+                        range(pf.metadata.num_row_groups), file_cols)
             if file_cols:
                 t = pf.read(columns=file_cols)
             else:

@@ -18,7 +18,9 @@ searched for, of flips that re-sorted instead, of tail sorts and of folds,
 where the written files' statistics came from (the counters
 ``write.stats.footer`` / ``.decoded`` and ``source`` on ``delta.write.stats``)
 and how many MERGEs wrote their vectors beside their data file
-(``merge.dv.overlapped``, ``overlapped`` on the ``.deletionVectors`` span), the
+(``merge.dv.overlapped``, ``overlapped`` on the ``.deletionVectors`` span),
+how many decimal columns each of the window's reads decoded from fixed-length
+byte arrays (``flbaDecimals`` on ``delta.scan.decode.rowGroups``), the
 routes and group counts of the aggregate queries with the program that answered the
 grouped ones (``tiled`` or ``wide``) and the tiled share, under each leaf span
 that has stages inside it (a MERGE's ``.write`` and ``.apply``, a decode's
@@ -122,6 +124,14 @@ def report(run) -> None:
               json.dumps(collections.Counter(
                   str(d.get("overlapped"))
                   for d in span_data("delta.dml.merge.deletionVectors"))),
+              file=sys.stderr)
+    reads = span_data("delta.scan.decode.rowGroups")
+    if reads:
+        # a decimal is an INT32 / INT64 column where its precision allows;
+        # a file written before that decodes its decimals from byte arrays
+        print("reads by their byte-array decimals [flbaDecimals: reads]:",
+              json.dumps(collections.Counter(
+                  str(d.get("flbaDecimals")) for d in reads)),
               file=sys.stderr)
     aggregates = span_data("delta.scan.deviceAggregate")
     if aggregates:
