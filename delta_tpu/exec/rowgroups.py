@@ -16,7 +16,10 @@ stats environment — so both tiers share one conservativeness story:
 * binary bounds are never used (truncation is undetectable);
 * columns missing from the file (schema evolution) resolve to NULL ⇒ keep;
 * partition-column references (mixed OR branches) bind to the file's typed
-  partition values, exactly like the file tier's ``stats_table``.
+  partition values, exactly like the file tier's ``stats_table``;
+* a row group's environment holds only the leaves the rewritten predicate
+  references (:func:`stats_leaves`): the footer's other statistics are
+  never read.
 
 The footer cache (:class:`FooterCache`) is a bounded LRU keyed by
 ``abs_path`` and validated by ``(size, mtime_ns)`` so hot-table queries stop
@@ -51,6 +54,7 @@ __all__ = [
     "footer_cache_info",
     "RowGroupPlan",
     "plan_row_groups",
+    "stats_leaves",
     "row_group_offsets",
     "row_groups_for_positions",
     "stats_from_footer",
@@ -164,10 +168,10 @@ class _StatsEnv(dict):
         return super().get(key)
 
 
-def _column_index(meta) -> Dict[str, int]:
+def _column_index(meta, leaves: Optional[FrozenSet[str]] = None) -> Dict[str, int]:
     """lowercased top-level leaf name -> column-chunk index. Nested leaves
     (``a.b``, list/map paths) are skipped — only flat columns carry stats
-    lanes, matching the file tier."""
+    lanes, matching the file tier. ``leaves`` keeps only those names."""
     out: Dict[str, int] = {}
     if meta.num_row_groups == 0:
         return out
@@ -176,8 +180,29 @@ def _column_index(meta) -> Dict[str, int]:
         p = rg0.column(j).path_in_schema
         if "." in p:
             continue
+        if leaves is not None and p.lower() not in leaves:
+            continue
         out[p.lower()] = j
     return out
+
+
+_STATS_LANES = ("min.", "max.", "nullcount.")
+
+
+def stats_leaves(rewritten: ir.Expression) -> FrozenSet[str]:
+    """Lowercased leaves whose footer statistics the skipping rewrite
+    ``rewritten`` reads: ``min.<c>``, ``max.<c>`` and ``nullCount.<c>`` name
+    leaf ``<c>``; ``numRecords`` and bare (partition) names read none. Only
+    :class:`ir.Column` reads a row, so a row group's verdict over these
+    leaves' statistics is its verdict over every column's."""
+    out = set()
+    for ref in ir.references(rewritten):
+        ref = ref.lower()
+        for lane in _STATS_LANES:
+            if ref.startswith(lane):
+                out.add(ref[len(lane):])
+                break
+    return frozenset(out)
 
 
 def _float_leaves(meta, col_index: Dict[str, int]) -> FrozenSet[str]:
@@ -238,12 +263,14 @@ class RowGroupPlan:
     uncompressed size of the pruned groups (the decode work avoided);
     ``fired`` lists the synthesized rewrites that individually excluded at
     least one pruned group (family + conjunct/rewrite shape fingerprints)
-    for ``ScanReport.rewritesFired`` attribution."""
+    for ``ScanReport.rewritesFired`` attribution; ``stats_columns`` counts
+    the file's leaves whose statistics the plan read."""
 
     keep: List[int]
     total: int
     skipped_bytes: int = 0
     fired: List[Dict[str, str]] = dataclass_field(default_factory=list)
+    stats_columns: int = 0
 
 
 def plan_row_groups(
@@ -253,6 +280,7 @@ def plan_row_groups(
     partition_cols: FrozenSet[str] = frozenset(),
     types: Optional[Dict[str, Any]] = None,
     rewrites: Optional[List] = None,
+    leaves: Optional[FrozenSet[str]] = None,
 ) -> RowGroupPlan:
     """Evaluate ``predicate`` against each row group's footer statistics;
     a group survives unless the rewritten can-match predicate is definitely
@@ -262,7 +290,9 @@ def plan_row_groups(
     shared rewrite the file tier evaluates, so both tiers keep one
     conservativeness story. ``rewrites`` short-circuits the rewrite: a
     scan-constant ``conjunct_rewrites(...)`` list computed ONCE by the
-    caller (the per-file decode loop must not re-derive it per footer)."""
+    caller (the per-file decode loop must not re-derive it per footer);
+    ``leaves`` likewise short-circuits ``stats_leaves`` of that rewrite.
+    Each row group's statistics environment holds those leaves alone."""
     from delta_tpu.expr import synthesis
     from delta_tpu.ops.pruning import conjunct_rewrites, skipping_predicate
 
@@ -278,7 +308,9 @@ def plan_row_groups(
         rewritten = skipping_predicate(predicate, partition_cols)
     if isinstance(rewritten, ir.Literal) and rewritten.value is None:
         return RowGroupPlan(all_groups, n)  # nothing lowerable: keep all
-    col_index = _column_index(meta)
+    if leaves is None:
+        leaves = stats_leaves(rewritten)
+    col_index = _column_index(meta, leaves)
     float_leaves = _float_leaves(meta, col_index)
     keep: List[int] = []
     skipped_bytes = 0
@@ -305,7 +337,7 @@ def plan_row_groups(
                     "conjunct": synthesis.shape(r.conjunct),
                     "rewrite": synthesis.shape(r.rewritten),
                 })
-    return RowGroupPlan(keep, n, skipped_bytes, fired)
+    return RowGroupPlan(keep, n, skipped_bytes, fired, len(col_index))
 
 
 def _safe_false(expr: ir.Expression, env: _StatsEnv) -> bool:

@@ -189,13 +189,18 @@ def _read_files(rev, data_path, files, metadata, columns, per_file,
     else:
         pred_types = None
     pred_rewrites = None
+    pred_leaves = None
     pcols_lower = frozenset(c.lower() for c in part_cols)
     if pred_types is not None:
+        from delta_tpu.exec.rowgroups import stats_leaves
         from delta_tpu.ops.pruning import conjunct_rewrites
 
-        # scan-constant: computed ONCE here, not per file in the decode pool
+        # scan-constant: computed ONCE here, not per file in the decode pool,
+        # with the leaves whose row-group statistics the rewrite reads
         pred_rewrites = conjunct_rewrites([predicate], pcols_lower,
                                           pred_types)
+        pred_leaves = stats_leaves(
+            ir.and_all([r.rewritten for r in pred_rewrites]))
     pos_hints = list(positions_of_interest) if positions_of_interest else None
     # per-file (rgTotal, rgPruned, rgLateSkipped, bytesSkippedPlanned,
     # bytesLateSkipped, planFired, rgDeviceSkipped, bytesDeviceSkipped,
@@ -260,7 +265,9 @@ def _read_files(rev, data_path, files, metadata, columns, per_file,
     def _plan_groups(abs_path, add, pos_hint):
         """The file's footer and the row groups worth reading by its
         statistics and the position hint: ``(meta | None, keep_idx,
-        skipped_bytes, plan_fired)``."""
+        skipped_bytes, plan_fired)``. Where the span is recorded it also
+        carries ``statsColumns``: the leaves whose statistics the planner
+        read (0 where it read none)."""
         from delta_tpu.exec import rowgroups
 
         with telemetry.record_operation("delta.scan.decode.open.plan") as ev:
@@ -272,16 +279,18 @@ def _read_files(rev, data_path, files, metadata, columns, per_file,
             keep_idx = list(range(n_rg))
             skipped_bytes = 0
             plan_fired: list = []
+            stats_columns = 0
             if predicate is not None and n_rg > 1:
                 part_row = (
                     typed_partition_row(add, part_schema) if part_cols else None
                 )
                 plan = rowgroups.plan_row_groups(
                     meta, predicate, part_row, pcols_lower, pred_types,
-                    rewrites=pred_rewrites,
+                    rewrites=pred_rewrites, leaves=pred_leaves,
                 )
                 keep_idx, skipped_bytes = plan.keep, plan.skipped_bytes
                 plan_fired = plan.fired
+                stats_columns = plan.stats_columns
             if pos_hint is not None and n_rg:
                 wanted = rowgroups.row_groups_for_positions(meta, pos_hint)
                 for i in keep_idx:
@@ -290,6 +299,8 @@ def _read_files(rev, data_path, files, metadata, columns, per_file,
                 keep_idx = [i for i in keep_idx if i in wanted]
             ev.data.update(rowGroups=n_rg, kept=len(keep_idx),
                            footerCached=cached)
+            if ev.span_id:
+                ev.data["statsColumns"] = stats_columns
             return meta, keep_idx, skipped_bytes, plan_fired
 
     def _open_file(abs_path, meta):

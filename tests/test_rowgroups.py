@@ -319,6 +319,195 @@ def test_planner_timestamp_bounds(tmp_path):
     assert plan.keep == [1]
 
 
+# -- the planner reads only the statistics its predicate names --------------
+
+
+def _wide_table(n=N, late=True):
+    """Many flat columns, sorted so that each kind of bound can prune: an
+    all-null group 2 in ``v``, an all-NaN group 0 in ``f`` (no bounds),
+    binary ``blob``, a mixed-case name, and ``late`` for schema evolution."""
+    i = np.arange(n)
+    f = i.astype(np.float64)
+    f[:RG] = np.nan
+    base = dt.datetime(2021, 1, 1)
+    cols = {
+        "id": pa.array(i, pa.int64()),
+        "v": pa.array([None if k // RG == 2 else int(k) for k in i], pa.int64()),
+        "f": pa.array(f, pa.float64()),
+        "name": pa.array(["k%05d" % k for k in i]),
+        "blob": pa.array([b"%05d" % k for k in i], pa.binary()),
+        "ts": pa.array([base + dt.timedelta(minutes=int(k)) for k in i],
+                       pa.timestamp("us")),
+        "d": pa.array([dt.date(2021, 1, 1) + dt.timedelta(days=int(k) // 10)
+                       for k in i], pa.date32()),
+        "MixedCase": pa.array(i // 10, pa.int64()),
+        "price": pa.array(i * 3, pa.int64()),
+    }
+    if late:
+        cols["late"] = pa.array(i, pa.int64())
+    rng = np.random.RandomState(3)
+    for j in range(12):
+        cols[f"pad{j}"] = pa.array(rng.randint(0, 9, n), pa.int64())
+    return pa.table(cols)
+
+
+def _wide_types():
+    from delta_tpu.schema import types as T
+
+    return {"id": T.LongType(), "v": T.LongType(), "f": T.DoubleType(),
+            "name": T.StringType(), "blob": T.BinaryType(),
+            "ts": T.TimestampType(), "d": T.DateType(),
+            "mixedcase": T.LongType(), "price": T.LongType(),
+            "late": T.LongType(), "p": T.StringType()}
+
+
+def _plan_every_column(meta, predicate, part_row, partition_cols, types):
+    """The plan as the planner made it when each row group's environment
+    held every flat column's statistics."""
+    from delta_tpu.expr import ir, synthesis
+    from delta_tpu.ops.pruning import conjunct_rewrites
+
+    rewrites = conjunct_rewrites([predicate], partition_cols, types)
+    rewritten = ir.and_all([r.rewritten for r in rewrites])
+    col_index = rowgroups._column_index(meta)
+    floats = rowgroups._float_leaves(meta, col_index)
+    keep, skipped, pruned = [], 0, []
+    for i in range(meta.num_row_groups):
+        env = rowgroups._rg_env(meta, i, col_index, floats, part_row)
+        try:
+            verdict = rewritten.eval(env)
+        except Exception:
+            verdict = None
+        if verdict is False:
+            skipped += meta.row_group(i).total_byte_size
+            pruned.append(env)
+        else:
+            keep.append(i)
+    fired = [{"family": r.family or "other",
+              "conjunct": synthesis.shape(r.conjunct),
+              "rewrite": synthesis.shape(r.rewritten)}
+             for r in rewrites
+             if r.synthesized and pruned
+             and any(rowgroups._safe_false(r.rewritten, e) for e in pruned)]
+    return keep, skipped, fired
+
+
+# (predicate, partition value of ``p`` or None, file has ``late``, the
+# kept groups, the leaves whose statistics the plan reads)
+PLAN_SHAPES = {
+    "compare": ("id >= 3200", None, True, [6, 7], 1),
+    "between": ("id BETWEEN 1200 AND 1700", None, True, [2, 3], 1),
+    "in": ("id IN (7, 3999)", None, True, [0, 7], 1),
+    "is_null": ("v IS NULL", None, True, [2], 1),
+    "is_not_null": ("v IS NOT NULL", None, True, [0, 1, 3, 4, 5, 6, 7], 1),
+    "or_across_columns": ("id < 300 OR price >= 11000", None, True, [0, 7], 2),
+    "arithmetic": ("price * 2 >= 22000", None, True, [7], 1),
+    "string_prefix": ("substr(name, 1, 4) = 'k001'", None, True, [0], 1),
+    "temporal": ("to_date(ts) = '2021-01-03'", None, True, [5, 6, 7], 1),
+    "date_shift": ("date_add(d, 30) <= '2021-03-01'", None, True, [0], 1),
+    "partition_or_prunes": ("p = 'b' OR id >= 3500", "a", True, [7], 1),
+    "partition_or_matches": ("p = 'b' OR id >= 3500", "b", True,
+                             list(range(8)), 1),
+    "nan_float_bounds": ("f > 3000.5", None, True, [0, 6, 7], 1),
+    "binary_bounds": ("blob = 'x' AND id >= 3500", None, True, [7], 2),
+    "missing_column_and": ("late > 5 AND id >= 3000", None, False, [6, 7], 1),
+    "missing_column_or": ("late > 5 OR id >= 3000", None, False,
+                          list(range(8)), 1),
+    "mixed_case": ("mixedCASE >= 300 AND MIXEDcase < 350", None, True, [6], 1),
+    "three_columns": ("id >= 500 AND v IS NOT NULL AND price < 3000", None,
+                      True, [1], 3),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(PLAN_SHAPES))
+def test_planner_reads_only_referenced_statistics(tmp_path, shape):
+    """The plan over the referenced leaves' statistics alone equals, field
+    for field, the plan over every column's."""
+    from delta_tpu.ops.pruning import conjunct_rewrites
+
+    text, part, late, kept, read = PLAN_SHAPES[shape]
+    meta = _write_rg_file(str(tmp_path / "w.parquet"), _wide_table(late=late), RG)
+    pred = parse_predicate(text)
+    part_row = {"p": part} if part is not None else None
+    pcols = frozenset({"p"}) if part is not None else frozenset()
+    types = _wide_types()
+    keep, skipped, fired = _plan_every_column(meta, pred, part_row, pcols, types)
+    plans = [rowgroups.plan_row_groups(meta, pred, part_row, pcols, types),
+             rowgroups.plan_row_groups(
+                 meta, pred, part_row, pcols, types,
+                 rewrites=conjunct_rewrites([pred], pcols, types))]
+    for plan in plans:
+        assert (plan.keep, plan.skipped_bytes, plan.fired) == (keep, skipped, fired)
+        assert plan.keep == kept
+        assert plan.stats_columns == read
+    if shape in ("arithmetic", "string_prefix", "temporal", "date_shift"):
+        assert fired  # the synthesized rewrite's attribution is compared
+
+
+def test_scan_plan_span_counts_the_statistics_it_read(tmp_table, rg_conf):
+    """``statsColumns`` on ``delta.scan.decode.open.plan``: a two-column
+    predicate over a many-column file reads two leaves' statistics; one
+    with no lowerable conjunct keeps every group and reads none."""
+    data = _wide_table()
+    WriteIntoDelta(DeltaLog.for_table(tmp_table), "append", data).run()
+    t = DeltaTable.for_path(tmp_table)
+    for pred, n_rows, read in (("id >= 3200 AND price < 10500", 300, 2),
+                               ("name LIKE '%7'", N // 10, 0)):
+        telemetry.clear_events()
+        got = t.to_arrow(filters=[pred], columns=["id"])
+        assert got.num_rows == n_rows
+        plans = telemetry.recent_events("delta.scan.decode.open.plan")
+        assert plans
+        for ev in plans:
+            assert ev.data["rowGroups"] == N // RG
+            assert ev.data["statsColumns"] == read < data.num_columns
+            if read == 0:
+                assert ev.data["kept"] == ev.data["rowGroups"]
+
+
+def test_bench_spans_prints_the_statistics_each_plan_read(tmp_table, rg_conf,
+                                                         capsys):
+    """`tools/bench_spans.py` prints ``footerCached`` and ``statsColumns``
+    of the window's row-group plans."""
+    import importlib.util
+    import json
+    import time
+
+    from benchmark.harness.runner import Request, Run
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_spans", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "bench_spans.py"))
+    bench_spans = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench_spans)
+    WriteIntoDelta(DeltaLog.for_table(tmp_table), "append", _wide_table()).run()
+    t = DeltaTable.for_path(tmp_table)
+    t.to_arrow(filters=["id >= 3200"], columns=["id"])
+    telemetry.clear_events()
+    t0 = time.perf_counter()
+    t.to_arrow(filters=["id >= 3200 AND v IS NOT NULL"], columns=["id"])
+    spans = [{"name": e.op_type, "start_us": e.start_us,
+              "duration_us": e.duration_us, "thread": e.thread_id,
+              "data": e.data} for e in telemetry.recent_events()]
+    run = Run(cell=None, seed=0, seconds=1.0, traced=True, requests=[
+        Request(0, t0, time.perf_counter(), True, spans=spans)],
+        trace=None, counters={})
+    bench_spans.report(run)
+    line = next(l for l in capsys.readouterr().err.splitlines()
+                if l.startswith("row-group plans"))
+    assert json.loads(line[line.index("{"):]) == {"True, 2": 1}
+
+
+def test_stats_leaves_of_a_rewrite():
+    from delta_tpu.expr import ir
+    from delta_tpu.ops.pruning import skipping_predicate
+
+    rw = skipping_predicate(parse_predicate(
+        "Id >= 3 AND v IS NULL AND p = 'a' AND v IS NOT NULL"), frozenset({"p"}))
+    assert rowgroups.stats_leaves(rw) == {"id", "v"}
+    assert rowgroups.stats_leaves(ir.Literal(None)) == frozenset()
+
+
 def test_row_groups_for_positions(tmp_path):
     p = str(tmp_path / "pos.parquet")
     t = pa.table({"v": pa.array(range(40), pa.int64())})

@@ -19,8 +19,11 @@ where the written files' statistics came from (the counters
 ``write.stats.footer`` / ``.decoded`` and ``source`` on ``delta.write.stats``)
 and how many MERGEs wrote their vectors beside their data file
 (``merge.dv.overlapped``, ``overlapped`` on the ``.deletionVectors`` span),
-how many decimal columns each of the window's reads decoded from fixed-length
-byte arrays (``flbaDecimals`` on ``delta.scan.decode.rowGroups``), the
+whether each row-group plan found its file's footer cached and how many
+leaves' statistics it read (``footerCached``, ``statsColumns`` on
+``delta.scan.decode.open.plan``), how many decimal columns each of the
+window's reads decoded from fixed-length byte arrays (``flbaDecimals`` on
+``delta.scan.decode.rowGroups``), the
 routes and group counts of the aggregate queries with the program that answered the
 grouped ones (``tiled`` or ``wide``) and the tiled share, under each leaf span
 that has stages inside it (a MERGE's ``.write`` and ``.apply``, a decode's
@@ -124,6 +127,15 @@ def report(run) -> None:
               json.dumps(collections.Counter(
                   str(d.get("overlapped"))
                   for d in span_data("delta.dml.merge.deletionVectors"))),
+              file=sys.stderr)
+    plans = span_data("delta.scan.decode.open.plan")
+    if plans:
+        # whether each file's footer was cached, and how many leaves'
+        # statistics its row-group plan read (a parent lacks the key: None)
+        print("row-group plans [footerCached, statsColumns: plans]:",
+              json.dumps(collections.Counter(
+                  f"{d.get('footerCached')}, {d.get('statsColumns')}"
+                  for d in plans)),
               file=sys.stderr)
     reads = span_data("delta.scan.decode.rowGroups")
     if reads:
