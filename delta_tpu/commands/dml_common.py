@@ -22,6 +22,7 @@ from delta_tpu.protocol.actions import AddFile
 __all__ = [
     "TouchedFile",
     "candidate_files",
+    "files_admitting",
     "read_candidates",
     "Timer",
     "POSITION_COL",
@@ -68,6 +69,52 @@ def candidate_files(txn, predicate: Optional[ir.Expression]) -> List[AddFile]:
     scan = pruning.files_for_scan(txn.snapshot, [predicate])
     kept_paths = {f.path for f in scan.files}
     return [f for f in matched if f.path in kept_paths]
+
+
+def files_admitting(files: Sequence[AddFile], metadata,
+                    keys: Sequence[tuple]) -> List[int]:
+    """Indices of the ``files`` whose statistics admit a value of every key
+    (findTouchedFiles from the statistics alone): ``keys`` pairs a target
+    column with the values an equality join compares it to. A file whose
+    ``[min, max]`` holds none of a key's values holds no row that joins (a
+    null equals nothing). Only integer and date columns narrow, whose
+    statistics are exact; a file without statistics is kept, and so is
+    every file by a key whose values do not cast to the column's type."""
+    import numpy as np
+    import pyarrow.compute as pc
+
+    from delta_tpu.expr.vectorized import arrow_type_for
+    from delta_tpu.ops.state_export import stats_table
+
+    def as_int64(a, typ):
+        if pa.types.is_date(typ):
+            a = pc.cast(pc.cast(a, typ), pa.int32())
+        return pc.cast(a, pa.int64())
+
+    if not files:
+        return []
+    keep = np.ones(len(files), bool)
+    fields = {f.name.lower(): f for f in metadata.schema.fields}
+    parts = {c.lower() for c in metadata.partition_columns}
+    for name, values in keys:
+        field = fields.get(name.lower())
+        typ = None if field is None else arrow_type_for(field.data_type)
+        if (name.lower() in parts or typ is None
+                or not (pa.types.is_integer(typ) or pa.types.is_date(typ))):
+            continue
+        try:
+            sv = np.unique(as_int64(pc.drop_null(values), typ).to_numpy())
+            st = stats_table(files, metadata, stats_columns=[field.name])
+            lo, hi = (as_int64(st.column(f"{b}.{field.name}"), typ)
+                      for b in ("min", "max"))
+        except pa.ArrowException:
+            continue
+        known = pc.and_(pc.is_valid(lo), pc.is_valid(hi)).to_numpy()
+        lo = pc.fill_null(lo, 0).to_numpy()
+        # the least value >= min is <= max; past the last, a sentinel
+        above = np.append(sv, np.iinfo(np.int64).max)[np.searchsorted(sv, lo)]
+        keep &= ~known | (above <= pc.fill_null(hi, 0).to_numpy())
+    return np.flatnonzero(keep).tolist()
 
 
 def read_candidates(

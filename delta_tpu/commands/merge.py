@@ -37,7 +37,7 @@ import os
 import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple, Union
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Set, Tuple, Union
 
 import pyarrow as pa
 import pyarrow.compute as pc
@@ -126,6 +126,45 @@ def _rows_from_stats(candidates) -> Optional[int]:
             return None
         total += int(n)
     return total
+
+
+def _empty_pairs(target: pa.Table, src: pa.Table) -> pa.Table:
+    """An empty pair table with the full combined (target + source) schema."""
+    combined = target.slice(0, 0)
+    for name in src.column_names:
+        combined = combined.append_column(name, pa.nulls(0, src.column(name).type))
+    return combined
+
+
+@dataclass
+class _ReadPlan:
+    """What a MERGE needs of the target, from the statement alone
+    (`MergeIntoCommand._read_plan`)."""
+
+    read_cols: Optional[List[str]]  # None: every column
+    key_need: Set[str]  # lower-case names of the columns the equi keys read
+    key_cols: List[str]  # those columns, as the schema names them
+    pos_col: Optional[str]  # matched rows are marked by position (DVs)
+    insert_only: bool
+    # a device route exists for the condition: 1-2 equi keys, no residual
+    shape_eligible: bool
+    pairs_only: bool  # `MergeIntoCommand._pairs_only_shape`
+
+
+@dataclass
+class _Launch:
+    """What the key-decode phase set going on the device. ``via`` is the
+    router's decision should the device answer, ``resident`` a slab and
+    its probe, ``(entry, PendingProbe)`` (`_launch_resident_probe`,
+    `_launch_slab_pipeline`), and ``pending`` the upload join's.
+    ``key_pieces`` are the key tables the launch decoded, one for each
+    candidate ``key_fids`` names, which the row decode may reuse."""
+
+    via: Optional[str] = None
+    resident: Optional[tuple] = None
+    pending: Any = None
+    key_pieces: Optional[List[pa.Table]] = None
+    key_fids: Optional[List[int]] = None
 
 
 @dataclass
@@ -368,7 +407,7 @@ class MergeIntoCommand:
     @property
     def _pairs_only(self) -> bool:
         """The resident probe's pairs were the join: no target row was
-        decoded (`_pairs_from_probe`)."""
+        decoded (`_pairs_only_route`)."""
         return self._router.get("route") == "pairs-only"
 
     @contextlib.contextmanager
@@ -404,6 +443,12 @@ class MergeIntoCommand:
         # path must not consume a previous run's device-join flags
         self._device_join = None
         self._resident_candidate = None
+        # the statement's one reading of the device path: 'auto' routes by
+        # the link's prices, 'force' pins the device, 'off' the host
+        self._device_mode = (
+            str(conf.get("delta.tpu.merge.devicePath.mode", "auto"))
+            if conf.get_bool("delta.tpu.merge.devicePath.enabled", True)
+            else "off")
         # (target rows, source rows) the join actually saw — the router
         # audit's workload sizes (obs/router_audit); slab rows when a
         # device probe ran (the probe's real n is the slab, not the
@@ -467,10 +512,7 @@ class MergeIntoCommand:
             ]
             candidates = candidate_files(txn, ir.and_all(target_only) if target_only else None)
         insert_only = not self.matched_clauses
-        # the join narrows the candidates itself (the distributed
-        # findTouchedFiles probe), unless the resident pairs-only route
-        # serves it: file ids in the pairs index the list it returns
-        matched_pairs, tgt_tables, candidates = self._join(
+        matched_pairs, tgt_tables = self._join(
             txn, candidates, src, equi, residual, metadata,
             prune_pred=ir.and_all(target_only) if target_only else None,
         )
@@ -636,99 +678,23 @@ class MergeIntoCommand:
             self._maybe_build_resident_keys()
         return version
 
-    # -- distributed touched-files probe ----------------------------------
-
-    def _probe_touched_files(self, candidates, src, equi, metadata):
-        """findTouchedFiles-style pre-probe on the sharded executor
-        (reference `MergeIntoCommand.scala` findTouchedFiles — phase 1 of
-        the two-phase merge): read ONLY the equi-key columns of each
-        candidate file as byte-weighted work items and keep the files whose
-        keys intersect the source keys.
-
-        Soundness: per-key-column ``is_in`` is a conservative superset of
-        exact tuple membership, so a touched file is never dropped;
-        untouched files contribute no matched pairs and are never
-        rewritten, and this MERGE has no NOT-MATCHED-BY-SOURCE clauses, so
-        restricting the candidate set is result-identical by construction.
-        Null target keys never equal a source key, so dropping all-miss
-        files stays exact under SQL join semantics.
-        """
-        from delta_tpu.utils.config import conf
-
-        if not conf.get_bool("delta.tpu.distributed.merge.probe.enabled", True):
-            return candidates
-        min_files = conf.get_int("delta.tpu.distributed.merge.probe.minFiles", 8)
-        if len(candidates) < max(min_files, 2):
-            return candidates
-        import pyarrow.compute as pc
-
-        cols = sorted({r.lower() for t_e, _ in equi for r in ir.references(t_e)})
-        svals = [(t_e, evaluate(s_e, src)) for t_e, s_e in equi]
-
-        def _touched(f) -> bool:
-            tbl = read_files_as_table(
-                self.delta_log.data_path, [f], metadata, columns=cols)
-            if tbl.num_rows == 0:
-                return False
-            for t_e, sv in svals:
-                tv, sv2 = _coerce_join_keys(evaluate(t_e, tbl), sv)
-                if isinstance(tv, pa.ChunkedArray):
-                    tv = tv.combine_chunks()
-                if isinstance(sv2, pa.ChunkedArray):
-                    sv2 = sv2.combine_chunks()
-                if not pc.any(pc.is_in(tv, value_set=sv2)).as_py():
-                    return False
-            return True
-
-        from delta_tpu.parallel.executor import run_sharded
-
-        telemetry.bump_counter("dist.merge.filesProbed", len(candidates))
-        with self._phase(
-            "delta.dist.mergeProbe", "probe_ms", {"candidates": len(candidates)}
-        ) as probe_ev:
-            try:
-                report = run_sharded(
-                    candidates, _touched,
-                    sizes=[f.size or 0 for f in candidates],
-                    label="merge-probe", on_failure="quarantine")
-            except Exception:  # noqa: BLE001 — probe machinery failure:
-                # the probe is an OPTIMIZATION — fall back to the full
-                # conservative candidate set rather than failing the MERGE
-                telemetry.bump_counter("dist.degraded.probe")
-                probe_ev.data["degraded"] = True
-                probe_ev.data["touched"] = len(candidates)
-                return candidates
-            # a quarantined probe item is a file whose keys we could not
-            # read — soundness demands it stays IN the candidate set (the
-            # probe may only drop files proven all-miss, hit is False)
-            touched = [f for f, hit in zip(candidates, report.results)
-                       if hit is not False]
-            if report.quarantined:
-                telemetry.bump_counter("dist.degraded.probe")
-            probe_ev.data["touched"] = len(touched)
-        return touched
-
     # -- join -------------------------------------------------------------
 
     def _join(self, txn, candidates: List[AddFile], src: pa.Table, equi, residual,
               metadata, prune_pred: Optional[ir.Expression] = None,
-              ) -> Tuple[pa.Table, Dict[int, pa.Table], List[AddFile]]:
+              ) -> Tuple[pa.Table, Dict[int, pa.Table]]:
         """Inner-join source×candidate-target. Returns (pair table with
         target cols bare + source cols prefixed + ids, per-file target
-        tables with row ids, the candidates as the join narrowed them: the
-        pairs' file ids index that list).
+        tables with row ids); the pairs' file ids index ``candidates``.
 
-        Resident pairs-only route: when the key cache holds the table's
-        slab and nothing of a target row is needed but which row it is
-        (`_pairs_only_shape`), the probe's pairs are the join: no
-        touched-files pre-probe, no decode of the target, see
-        `_pairs_from_probe`.
-
-        Device path otherwise: the join-key columns decode first (a cheap
-        projected Parquet read), the membership kernel launches
-        asynchronously, and the full-column decode of the candidates runs on
-        the host *while the device probes* — the kernel's wall-clock hides
-        under the decode.
+        The route is decided once, in the key-decode phase: a slab of the
+        key cache answers the resident probe; where none does, the device
+        build launches (`_launch_device_build`); where the condition's shape
+        has no device route, or the build declines, the host joins. A
+        resident probe whose statement needs nothing of a target row but
+        which row it is serves the join from its pairs alone
+        (`_pairs_only_route`); every other route decodes the target while
+        the device probes and joins over the decoded rows (`_decode_route`).
 
         ``prune_pred`` (the target-only conjuncts of the merge condition)
         enables row-group skipping inside candidate files: a pruned group
@@ -736,25 +702,52 @@ class MergeIntoCommand:
         condition). Applied only when unmatched target rows are never
         written back — DV mode (positions stay physical) or insert-only
         merges (target rows feed the join and nothing else)."""
-        import numpy as np
+        plan = self._read_plan(src, equi, residual, metadata)
+        if plan.pos_col is None and not plan.insert_only:
+            prune_pred = None
+        # audit: whether a device route even existed for this condition
+        # shape — a structurally host-only merge is audited without a
+        # device alternative (no hindsight miss against a route that could
+        # not have run)
+        self._audit_eligible = plan.shape_eligible and bool(candidates)
+        launch = _Launch()
+        with self._phase("delta.dml.merge.keyDecode", "key_decode_ms"):
+            if self._audit_eligible:
+                # the slab in HBM first: its probe ships only the source
+                # keys, economics apart from the cold build's
+                resident = self._launch_resident_probe(
+                    txn, candidates, src, equi, plan)
+                launch = (_Launch("resident", resident) if resident is not None
+                          else self._launch_device_build(
+                              txn, candidates, src, equi, metadata, plan,
+                              prune_pred))
+        if launch.via == "resident" and plan.pairs_only:
+            joined = self._pairs_only_route(launch.resident, candidates, src,
+                                            equi, metadata, plan)
+            if joined is not None:
+                return joined, {}
+            # a designed decline: decode late, the probe still in hand
+            telemetry.bump_counter("merge.resident.pairsOnly.declined")
+        return self._decode_route(candidates, src, equi, residual, metadata,
+                                  plan, prune_pred, launch)
 
-        # what the statement needs of the target: from the statement alone
+    def _read_plan(self, src, equi, residual, metadata) -> _ReadPlan:
+        """What the statement needs of the target, from the statement alone."""
         target_cols = [f.name for f in metadata.schema.fields]
         insert_only = not self.matched_clauses
         key_need = {r.lower() for t_e, _ in equi for r in ir.references(t_e)}
-        # insert-only merges never rewrite target rows: read only the columns
-        # the join condition touches (the reference's left-anti fast path
-        # reads the full target; we push the projection into the Parquet scan)
-        read_cols: Optional[List[str]] = None
         if insert_only:
+            # insert-only merges never rewrite target rows: read only the
+            # columns the join condition touches (the reference's left-anti
+            # fast path reads the full target; we push the projection into
+            # the Parquet scan)
             need = key_need | {
                 r.lower()
                 for c in residual
                 for r in ir.references(c)
                 if not r.startswith(_SRC)
             }
-            cols = [c for c in target_cols if c.lower() in need]
-            read_cols = cols or None
+            read_cols = [c for c in target_cols if c.lower() in need] or None
         else:
             read_cols = self._referenced_target_columns(
                 metadata, target_cols, [c for c in src.column_names
@@ -768,297 +761,305 @@ class MergeIntoCommand:
             if (not insert_only and dv_common.dv_enabled(metadata))
             else None
         )
-        mode = str(conf.get("delta.tpu.merge.devicePath.mode", "auto"))
-        shape_eligible = (
-            bool(conf.get("delta.tpu.merge.devicePath.enabled", True))
-            and mode != "off"
-            and 1 <= len(equi) <= 2
-            and not residual
-            and src.num_rows > 0
+        return _ReadPlan(
+            read_cols=read_cols, key_need=key_need,
+            key_cols=[c for c in target_cols if c.lower() in key_need],
+            pos_col=pos_col, insert_only=insert_only,
+            shape_eligible=(self._device_mode != "off"
+                            and 1 <= len(equi) <= 2
+                            and not residual
+                            and src.num_rows > 0),
+            pairs_only=self._pairs_only_shape(equi, read_cols, key_need,
+                                              pos_col, insert_only),
         )
 
-        # the resident route is decided first: when the slab is cached and
-        # the pairs alone will do, the probe launches on every file of the
-        # snapshot and the touched-files pre-probe (whose answer is a
-        # by-product of that probe) is not run
-        resident = None
-        resident_tried = False
-        whole_table = True
-        pairs_shape = bool(
-            shape_eligible and candidates
-            and self._pairs_only_shape(equi, read_cols, key_need, pos_col,
-                                       insert_only))
-        if pairs_shape and self._resident_entry_cached(txn, equi):
-            resident_tried = True
-            with self._phase("delta.dml.merge.keyDecode", "key_decode_ms"):
-                resident = self._launch_resident_probe(
-                    txn, candidates, src, equi, target_cols, key_need,
-                    pos_col, insert_only,
-                )
-        if resident is not None:
-            telemetry.bump_counter("merge.resident.pairsOnly")
-            self._audit_eligible = True
-            with self._phase("delta.dml.merge.rowDecode", "decode_ms"):
-                # nothing of the target is read
-                n_target = _rows_from_stats(candidates)
-                self._audit_units = (
-                    resident[0].num_rows if n_target is None else n_target,
-                    src.num_rows)
-            with self._phase("delta.dml.merge.join", "join_ms",
-                             {"route": "pairs-only"}):
-                joined = self._pairs_from_probe(
-                    resident[1], candidates, src, equi, read_cols or [],
-                    metadata, insert_only)
-            if joined is not None:
-                return joined, {}, candidates
-            # a designed decline: decode late and take the path below, the
-            # probe still in hand
-            telemetry.bump_counter("merge.resident.pairsOnly.declined")
-        elif equi and not (pairs_shape
-                           and self._builds_table_slab(candidates, src, mode)):
-            # distributed findTouchedFiles probe: restrict the candidates to
-            # files whose equi keys intersect the source BEFORE the join
-            # decodes full rows (conf-gated; result-identical — see the method)
-            n_all = len(candidates)
-            candidates = self._probe_touched_files(candidates, src, equi, metadata)
-            # a slab built over fewer files than the table holds must not
-            # be registered as the table's
-            whole_table = len(candidates) == n_all
-
-        # routing, the key-column decode, and the slab advance / upload launch
-        with self._phase("delta.dml.merge.keyDecode", "key_decode_ms"):
-            base_eligible = bool(shape_eligible and candidates)
-            device_eligible = base_eligible
-            # audit: whether a device route even existed for this condition
-            # shape — a structurally host-only merge is audited without a
-            # device alternative (no hindsight miss against a route that
-            # could not have run)
-            self._audit_eligible = base_eligible
-            if (device_eligible and mode == "auto"
-                    and self._cold_estimate_declines(candidates, src)):
-                device_eligible = False
-                telemetry.bump_counter("merge.device.declined")
-                self._router.update(reason="cold-estimate")
-
-            # row-group skipping is only safe when unmatched target rows never
-            # need writing back: DV mode (matched rows mark by physical
-            # position) or insert-only (target rows exist only to probe)
-            if pos_col is None and not insert_only:
-                prune_pred = None
-            pending = None
-            via = None
-            key_pieces: Optional[List[pa.Table]] = None
-            key_pieces_have_pos = False
-            if base_eligible:
-                # resident-operand path first: the target key lane already lives
-                # in HBM (ops/key_cache), so the probe ships only source keys —
-                # different economics from the cold upload path, hence evaluated
-                # before (and independent of) the upload-cost gate above. Not
-                # asked twice: what the pairs-only route was refused, or
-                # launched and then declined, stands
-                if not resident_tried:
-                    resident = self._launch_resident_probe(
-                        txn, candidates, src, equi, target_cols, key_need,
-                        pos_col, insert_only,
-                    )
-                if resident is not None:
-                    via = "resident"
-            if resident is None and device_eligible:
-                if not self._prefers_mesh():
-                    # fused cold pipeline: per-file key decode streams into a
-                    # pre-sized HBM slab (upload overlaps decode), then the
-                    # resident probe joins + pairs on device — and the
-                    # slab registers in the KeyCache so the NEXT merge against
-                    # this table skips the upload entirely
-                    resident, key_pieces = self._launch_slab_pipeline(
-                        txn, candidates, src, equi, target_cols, key_need,
-                        pos_col, insert_only, metadata, register=whole_table,
-                    )
-                    if resident is not None:
-                        via = "device-cold"
-                    key_pieces_have_pos = key_pieces is not None
-                if resident is None and key_pieces is None:
-                    # multichip mesh (all-gather sort-merge kernel, opt-in via
-                    # devicePath.preferMesh), or the slab pipeline bailed before
-                    # decoding: decode the key projection and launch the upload
-                    # join
-                    key_cols = [c for c in target_cols if c.lower() in key_need]
-                    key_pieces = read_files_as_table(
-                        self.delta_log.data_path, candidates, metadata,
-                        columns=key_cols or None, per_file=True,
-                        position_column=pos_col, predicate=prune_pred,
-                        # the key read and the full read below must stay
-                        # row-aligned (the device probe's indices map onto the
-                        # full decode) — stats-pruning is deterministic across
-                        # both, but late materialization's verdict depends on
-                        # the decoded columns
-                        late_materialize=False,
-                    )
-                if resident is None:
-                    key_tab = pa.concat_tables(key_pieces,
-                                               promote_options="permissive")
-                    if key_tab.num_rows:
-                        pending = self._launch_device_join(key_tab, src, equi)
-                        if pending is not None:
-                            via = "device-upload"
-                        else:
-                            self._router.setdefault("reason", "upload-declined")
-
-        with self._phase("delta.dml.merge.rowDecode", "decode_ms"):
-
-            # full-column decode (overlaps the in-flight device probe); when the
-            # key projection already covers every needed column, reuse it (the
-            # slab pipeline's pieces carry an extra position column — harmless,
-            # every write-side consumer projects to target_cols)
-            if key_pieces is not None and read_cols is not None and set(
-                c.lower() for c in read_cols
-            ) <= key_need and (not key_pieces_have_pos or pos_col is not None
-                               or insert_only):
-                raw_pieces = key_pieces
+    def _launch_device_build(self, txn, candidates, src, equi, metadata,
+                             plan: _ReadPlan, prune_pred) -> _Launch:
+        """The device route where no slab answered the resident probe. In
+        ``auto`` the stats' row counts may decline it first
+        (`_cold_estimate_declines`). The fused cold pipeline
+        (`_launch_slab_pipeline`) streams the key columns onto a new slab,
+        registers it and probes it; where the mesh is asked for, or the
+        pipeline bails, the key projection is decoded and the upload join
+        launches. A launch that comes to nothing leaves the host join, with
+        the key pieces it decoded."""
+        if (self._device_mode == "auto"
+                and self._cold_estimate_declines(candidates, src)):
+            telemetry.bump_counter("merge.device.declined")
+            self._router.setdefault("reason", "cold-estimate")
+            return _Launch()
+        key_pieces, fids = None, list(range(len(candidates)))
+        if not self._prefers_mesh():
+            resident, key_pieces = self._launch_slab_pipeline(
+                txn, candidates, src, equi, metadata, plan)
+            if resident is not None:
+                return _Launch("device-cold", resident, key_pieces=key_pieces,
+                               key_fids=fids)
+        if key_pieces is None:
+            fids = self._files_admitting_keys(candidates, src, equi, metadata)
+            key_pieces = read_files_as_table(
+                self.delta_log.data_path, [candidates[i] for i in fids], metadata,
+                columns=plan.key_cols or None, per_file=True,
+                position_column=plan.pos_col, predicate=prune_pred,
+                # the key read and the full read must stay row-aligned (the
+                # device probe's indices map onto the full decode) —
+                # stats-pruning is deterministic across both, but late
+                # materialization's verdict depends on the decoded columns
+                late_materialize=False,
+            )
+        launch = _Launch(key_pieces=key_pieces, key_fids=fids)
+        key_tab = pa.concat_tables(key_pieces, promote_options="permissive")
+        if key_tab.num_rows:
+            launch.pending = self._launch_device_join(key_tab, src, equi)
+            if launch.pending is not None:
+                launch.via = "device-upload"
             else:
+                self._router.setdefault("reason", "upload-declined")
+        return launch
+
+    def _pairs_only_route(self, resident, candidates, src, equi, metadata,
+                          plan: _ReadPlan) -> Optional[pa.Table]:
+        """The pairs-only join: the pair table from the resident probe's
+        pairs and the source alone, nothing of the target decoded. A pair is
+        (physical slab row, source row) and the slab maps a file to its
+        rows, so a pair *is* (file, position, source row); the key columns
+        are the source's key expressions in the target's types. Rows in the
+        order the decode route gives them: ascending candidate file, then
+        position.
+
+        None is a designed decline (the probe's windows overflowed, the slab
+        lacks a candidate file; in ``auto`` also a device exception): the
+        caller decodes after all. A pair in no candidate file means the slab
+        is not this snapshot's: `_stale_slab`."""
+        import numpy as np
+
+        from delta_tpu.expr.vectorized import arrow_type_for
+        from delta_tpu.ops import join_kernel
+        from delta_tpu.ops import key_cache as kc_mod
+
+        telemetry.bump_counter("merge.resident.pairsOnly")
+        with self._phase("delta.dml.merge.rowDecode", "decode_ms"):
+            # nothing of the target is read
+            n_target = _rows_from_stats(candidates)
+            self._audit_units = (
+                resident[0].num_rows if n_target is None else n_target,
+                src.num_rows)
+        key_cols = plan.read_cols or []
+        with self._phase("delta.dml.merge.join", "join_ms",
+                         {"route": "pairs-only"}), \
+                telemetry.span_stages() as stage:
+            stage(_JOIN_WAIT)
+            try:
+                res_p = resident[1].result()
+            except kc_mod.DeltaProbeOverflow:
+                return None
+            except Exception as e:  # noqa: BLE001 — host rung (auto only)
+                self._device_rung("device-finalize-fallback", e)
+                return None
+            stage(_JOIN_PAIRS)
+            slabs = [res_p.slabs.get(f.path) for f in candidates]
+            if any(ent is None for ent in slabs):
+                return None
+            declared = {f.name: arrow_type_for(f.data_type)
+                        for f in metadata.schema.fields}
+            fields = [pa.field(c, declared[c]) for c in key_cols]
+            if not plan.insert_only:
+                fields.append(pa.field(POSITION_COL, pa.int64()))
+            fields += [pa.field(_TID, pa.int64()), pa.field(_FID, pa.int64())]
+            fields += [pa.field(n, src.column(n).type) for n in src.column_names]
+            if plan.insert_only or not len(res_p.t_pairs[0]):
+                # insert-only consumes s_matched alone (no pairs were fetched)
+                joined = pa.schema(fields).empty_table()
+            else:
+                phys, s_rows = res_p.t_pairs
+                offs = np.array([off for off, _ in slabs], np.int64)
+                lo = np.searchsorted(phys, offs)
+                hi = np.searchsorted(
+                    phys, offs + np.array([n for _, n in slabs], np.int64))
+                per_file = hi - lo
+                if int(per_file.sum()) != len(phys):
+                    self._stale_slab("a matched slab row lies in no file of "
+                                     "the snapshot")
+                if (np.diff(offs) < 0).any():
+                    # the slab holds the files in another order: the pairs
+                    # (ascending slab row) go into the candidates' order
+                    order = np.concatenate(
+                        [np.arange(a, b) for a, b in zip(lo, hi)])
+                    phys, s_rows = phys[order], s_rows[order]
+                s_taken = src.take(pa.array(s_rows, pa.int64()))
+                cols = []
+                for c in key_cols:
+                    s_e = next(s_e for t_e, s_e in equi
+                               if t_e.name.lower() == c.lower())
+                    cols.append(pc.cast(evaluate(s_e, s_taken), declared[c]))
+                cols.append(pa.array(phys - np.repeat(offs, per_file)))
+                # an id per target row: a slab row is in one pair at most
+                cols.append(pa.array(np.arange(len(phys), dtype=np.int64)))
+                cols.append(pa.array(np.repeat(
+                    np.arange(len(candidates), dtype=np.int64), per_file)))
+                cols += s_taken.columns
+                joined = pa.table(cols, schema=pa.schema(fields))
+            self._device_join = join_kernel.JoinResult(
+                np.empty(0, np.int64), res_p.s_matched, res_p.any_multi)
+            self._join_path = "resident"
+            self._router["route"] = "pairs-only"
+            return joined
+
+    def _decode_route(self, candidates, src, equi, residual, metadata,
+                      plan: _ReadPlan, prune_pred, launch: _Launch,
+                      ) -> Tuple[pa.Table, Dict[int, pa.Table]]:
+        """Decode the target's rows — overlapping the device's probe where
+        a route launched — then join over them: the device's pairs mapped
+        onto the rows (the decision is ``launch.via``), or the host join
+        where none launched or the device's answer is not to be had. Only
+        the candidates whose statistics admit a source key are decoded
+        (`_files_admitting_keys`); the per-file tables are keyed by the
+        candidate's index."""
+        import numpy as np
+
+        with self._phase("delta.dml.merge.rowDecode", "decode_ms") as ev:
+            # when the key projection already covers every needed column,
+            # reuse it (the slab pipeline's pieces carry a position column
+            # where the plan has none, insert-only: harmless, every
+            # write-side consumer projects to target_cols)
+            raw_pieces, fids = launch.key_pieces, launch.key_fids
+            if (raw_pieces is None or plan.read_cols is None
+                    or not {c.lower() for c in plan.read_cols} <= plan.key_need):
+                if launch.pending is None:  # else row-aligned with its keys
+                    fids = self._files_admitting_keys(candidates, src, equi,
+                                                      metadata)
                 raw_pieces = read_files_as_table(
-                    self.delta_log.data_path, candidates, metadata,
-                    columns=read_cols, per_file=True, position_column=pos_col,
-                    predicate=prune_pred, late_materialize=False,
+                    self.delta_log.data_path, [candidates[i] for i in fids],
+                    metadata, columns=plan.read_cols, per_file=True,
+                    position_column=plan.pos_col, predicate=prune_pred,
+                    late_materialize=False,
                 )
+            ev.data["files"] = len(raw_pieces)
             tgt_tables: Dict[int, pa.Table] = {}
-            pieces: List[pa.Table] = []
             row_base = 0
-            for fid, t in enumerate(raw_pieces):
-                t = t.append_column(
-                    _TID,
-                    pa.array(np.arange(row_base, row_base + t.num_rows, dtype=np.int64)),
-                )
-                t = t.append_column(
-                    _FID, pa.array(np.full(t.num_rows, fid, dtype=np.int64))
-                )
-                row_base += t.num_rows
-                tgt_tables[fid] = t
-                pieces.append(t)
-            if not pieces:
-                empty = pa.schema(
+            for fid, t in zip(fids, raw_pieces):
+                n = t.num_rows
+                tgt_tables[fid] = t.append_column(_TID, pa.array(
+                    np.arange(row_base, row_base + n, dtype=np.int64))
+                ).append_column(_FID, pa.array(np.full(n, fid, np.int64)))
+                row_base += n
+            if not tgt_tables:
+                target = pa.schema(
                     [pa.field(_TID, pa.int64()), pa.field(_FID, pa.int64())]
                 ).empty_table()
-                target = empty
             else:
-                target = pa.concat_tables(pieces, promote_options="permissive")
+                target = pa.concat_tables(list(tgt_tables.values()),
+                                          promote_options="permissive")
             self._audit_units = (target.num_rows, src.num_rows)
 
-        def empty_pairs() -> pa.Table:
-            # empty pair table with the full combined (target + source) schema
-            combined = target.slice(0, 0)
-            for name in src.column_names:
-                combined = combined.append_column(
-                    name, pa.nulls(0, src.column(name).type)
-                )
-            return combined
-
         if target.num_rows == 0 or src.num_rows == 0:
-            return empty_pairs(), tgt_tables, candidates
+            return _empty_pairs(target, src), tgt_tables
 
-        # the wait for the device probe, or the host join; then the pair take
+        # the wait for the device's answer, or the host join; then the take
         with self._phase("delta.dml.merge.join", "join_ms",
                          {"route": "host"}) as join_ev:
-            if resident is not None or pending is not None:
+            if launch.via is not None:
                 # two stages tile the span on a device route: the wait for
                 # the device's answer, then the host's pair mapping and take
                 with telemetry.span_stages() as stage:
                     stage(_JOIN_WAIT)
-                    if pending is None:
-                        pending = self._finalize_resident(
-                            resident, candidates, tgt_tables, target, src,
-                            equi, pos_col, insert_only,
-                            got_pairs=lambda: stage(_JOIN_PAIRS),
-                        )
-                    res = pending.result()
+                    res = (launch.pending.result()
+                           if launch.pending is not None
+                           else self._finalize_resident(
+                               launch.resident, candidates, tgt_tables,
+                               target, plan,
+                               got_pairs=lambda: stage(_JOIN_PAIRS)))
                     stage(_JOIN_PAIRS)
-                    if res is None:
-                        self._router.setdefault(
-                            "reason", "device-finalize-fallback")
-                    else:
+                    if res is not None:
                         self._device_join = res
-                        self._join_path = via
+                        self._join_path = launch.via
                         self._router["route"] = join_ev.data["route"] = "decode"
                         # insert-only never consumes the pair rows (the
                         # not-matched block comes from s_matched): skip
                         # materializing them
-                        if insert_only:
-                            joined = empty_pairs()
-                        else:
-                            matched = np.flatnonzero(res.t_matched)
-                            joined = target.take(pa.array(matched, pa.int64()))
-                            s_taken = src.take(
-                                pa.array(res.t_first_s[matched], pa.int64())
-                            )
-                            for name in s_taken.column_names:
-                                joined = joined.append_column(
-                                    name, s_taken.column(name))
-                        return joined, tgt_tables, candidates
+                        if plan.insert_only:
+                            return _empty_pairs(target, src), tgt_tables
+                        matched = np.flatnonzero(res.t_matched)
+                        joined = target.take(pa.array(matched, pa.int64()))
+                        s_taken = src.take(
+                            pa.array(res.t_first_s[matched], pa.int64()))
+                        for name in s_taken.column_names:
+                            joined = joined.append_column(
+                                name, s_taken.column(name))
+                        return joined, tgt_tables
+                self._router.setdefault("reason", "device-finalize-fallback")
+            return self._host_pairs(target, src, equi, residual), tgt_tables
 
-            if equi:
-                # Join INDEX tables (keys + row positions), then take the full
-                # rows: Arrow's hash join refuses nested (struct/list/map)
-                # non-key payload columns, and carrying 2 int columns through
-                # the join beats carrying every column anyway.
-                key_cols = []
-                for t_e, s_e in equi:
-                    t_vals = evaluate(t_e, target)
-                    s_vals = evaluate(s_e, src)
-                    key_cols.append(_coerce_join_keys(t_vals, s_vals))
-                t_idx_cols = {"__trow__": pa.array(np.arange(target.num_rows), pa.int64())}
-                s_idx_cols = {"__srow__": pa.array(np.arange(src.num_rows), pa.int64())}
-                tkeys, skeys = [], []
-                for i, (t_vals, s_vals) in enumerate(key_cols):
-                    k = f"__k{i}__"
-                    t_idx_cols[k] = t_vals
-                    s_idx_cols[k] = s_vals
-                    tkeys.append(k)
-                    skeys.append(k)
-                pairs_idx = pa.table(t_idx_cols).join(
-                    pa.table(s_idx_cols), keys=tkeys, right_keys=skeys,
-                    join_type="inner", use_threads=False,
-                )
-                t_take = pairs_idx.column("__trow__")
-                s_take = pairs_idx.column("__srow__")
-                joined = target.take(t_take)
-                s_taken = src.take(s_take)
+    @staticmethod
+    def _files_admitting_keys(candidates, src, equi, metadata) -> List[int]:
+        """The candidates that may hold a row of the join: no other is
+        decoded, rewritten or marked (`dml_common.files_admitting`)."""
+        return dv_common.files_admitting(candidates, metadata, [
+            (t_e.name, evaluate(s_e, src)) for t_e, s_e in equi
+            if isinstance(t_e, ir.Column)])
+
+    @staticmethod
+    def _host_pairs(target: pa.Table, src: pa.Table, equi,
+                    residual) -> pa.Table:
+        """The host join: Arrow's hash join on the equi keys, the residual
+        conjuncts filtered after it; a condition without equi keys pairs
+        by blocked cartesian product."""
+        import numpy as np
+
+        if not equi:
+            # general condition: BLOCKED cartesian pairing — tile the
+            # target x source grid and stream each tile through the clause
+            # condition immediately, so peak memory is one tile of pairs
+            # (`delta.tpu.merge.nonEquiPairBudget`) regardless of input
+            # sizes. The reference handles arbitrary conditions via a real
+            # join (`MergeIntoCommand.scala:335-341`); this is the bounded
+            # equivalent for a columnar engine without a theta-join kernel.
+            budget = int(conf.get("delta.tpu.merge.nonEquiPairBudget",
+                                  8_000_000))
+            m = src.num_rows
+            tile = max(budget // max(m, 1), 1)
+            cond = ir.and_all(residual) if residual else None
+            pieces = []
+            s_base = np.tile(np.arange(m, dtype=np.int64), tile)
+            for t0 in range(0, target.num_rows, tile):
+                rows = min(tile, target.num_rows - t0)
+                t_idx = np.repeat(np.arange(t0, t0 + rows, dtype=np.int64), m)
+                piece = target.take(pa.array(t_idx, pa.int64()))
+                s_taken = src.take(pa.array(s_base[: rows * m], pa.int64()))
                 for name in s_taken.column_names:
-                    joined = joined.append_column(name, s_taken.column(name))
-                # take() emits one chunk per input chunk: defragment once here
-                # or every downstream mask/projection/encode pays per-chunk costs
-                joined = joined.combine_chunks()
-            else:
-                # general condition: BLOCKED cartesian pairing — tile the
-                # target x source grid and stream each tile through the clause
-                # condition immediately, so peak memory is one tile of pairs
-                # (`delta.tpu.merge.nonEquiPairBudget`) regardless of input
-                # sizes. The reference handles arbitrary conditions via a real
-                # join (`MergeIntoCommand.scala:335-341`); this is the bounded
-                # equivalent for a columnar engine without a theta-join kernel.
-                budget = int(conf.get("delta.tpu.merge.nonEquiPairBudget",
-                                      8_000_000))
-                m = src.num_rows
-                tile = max(budget // max(m, 1), 1)
-                cond = ir.and_all(residual) if residual else None
-                pieces = []
-                s_base = np.tile(np.arange(m, dtype=np.int64), tile)
-                for t0 in range(0, target.num_rows, tile):
-                    rows = min(tile, target.num_rows - t0)
-                    t_idx = np.repeat(np.arange(t0, t0 + rows, dtype=np.int64), m)
-                    piece = target.take(pa.array(t_idx, pa.int64()))
-                    s_taken = src.take(pa.array(s_base[: rows * m], pa.int64()))
-                    for name in s_taken.column_names:
-                        piece = piece.append_column(name, s_taken.column(name))
-                    if cond is not None:
-                        piece = piece.filter(boolean_mask(cond, piece))
-                    if piece.num_rows:
-                        pieces.append(piece.combine_chunks())
-                joined = (pa.concat_tables(pieces).combine_chunks()
-                          if pieces else empty_pairs())
-                return joined, tgt_tables, candidates
-            if residual:
-                joined = joined.filter(boolean_mask(ir.and_all(residual), joined))
-            return joined, tgt_tables, candidates
+                    piece = piece.append_column(name, s_taken.column(name))
+                if cond is not None:
+                    piece = piece.filter(boolean_mask(cond, piece))
+                if piece.num_rows:
+                    pieces.append(piece.combine_chunks())
+            return (pa.concat_tables(pieces).combine_chunks()
+                    if pieces else _empty_pairs(target, src))
+        # Join INDEX tables (keys + row positions), then take the full
+        # rows: Arrow's hash join refuses nested (struct/list/map)
+        # non-key payload columns, and carrying 2 int columns through
+        # the join beats carrying every column anyway.
+        t_idx_cols = {"__trow__": pa.array(np.arange(target.num_rows), pa.int64())}
+        s_idx_cols = {"__srow__": pa.array(np.arange(src.num_rows), pa.int64())}
+        keys = []
+        for i, (t_e, s_e) in enumerate(equi):
+            k = f"__k{i}__"
+            t_idx_cols[k], s_idx_cols[k] = _coerce_join_keys(
+                evaluate(t_e, target), evaluate(s_e, src))
+            keys.append(k)
+        pairs_idx = pa.table(t_idx_cols).join(
+            pa.table(s_idx_cols), keys=keys, right_keys=keys,
+            join_type="inner", use_threads=False,
+        )
+        joined = target.take(pairs_idx.column("__trow__"))
+        s_taken = src.take(pairs_idx.column("__srow__"))
+        for name in s_taken.column_names:
+            joined = joined.append_column(name, s_taken.column(name))
+        # take() emits one chunk per input chunk: defragment once here
+        # or every downstream mask/projection/encode pays per-chunk costs
+        joined = joined.combine_chunks()
+        if residual:
+            joined = joined.filter(boolean_mask(ir.and_all(residual), joined))
+        return joined
 
     def _referenced_target_columns(
         self, metadata, target_cols, src_prefixed, key_need, residual,
@@ -1168,109 +1169,6 @@ class MergeIntoCommand:
         self._router.setdefault("hostEstS", round(host_est_s, 3))
         return device_s > host_est_s
 
-    def _builds_table_slab(self, candidates, src, mode: str) -> bool:
-        """Whether a MERGE that is pairs-only in shape and finds no slab to
-        serve it will build the table's now (`_launch_slab_pipeline` over
-        every file). The touched-files pre-probe is then not run: it reads
-        the key columns the build reads, nothing else of a row is decoded
-        for it to spare, and a slab over the files it leaves is this
-        MERGE's and not the table's (never registered), so that a small
-        source into a table of many files (a refresh function, a trickle of
-        CDC) would find no slab at its next MERGE either."""
-        from delta_tpu.ops.key_cache import key_cache_enabled
-
-        if not key_cache_enabled() or self._prefers_mesh():
-            return False
-        return mode == "force" or not self._cold_estimate_declines(
-            candidates, src)
-
-    def _resident_entry_cached(self, txn, equi) -> bool:
-        """A slab for this table and key signature is in the key cache
-        (whether it can be advanced to the snapshot is
-        `_launch_resident_probe`'s to find out)."""
-        from delta_tpu.ops import key_cache as kc_mod
-
-        return kc_mod.key_cache_enabled() and kc_mod.KeyCache.instance().peek(
-            txn.snapshot.delta_log.log_path,
-            self._key_signature([t for t, _ in equi])) is not None
-
-    def _pairs_from_probe(self, probe, candidates, src, equi, key_cols,
-                          metadata, insert_only) -> Optional[pa.Table]:
-        """The pairs-only join: the pair table from the resident probe's
-        pairs and the source alone. A pair is (physical slab row, source
-        row) and the slab maps a file to its rows, so a pair *is* (file,
-        position, source row); the key columns are the source's key
-        expressions in the target's types. Rows in the order the decode
-        route gives them: ascending candidate file, then position.
-
-        None is a designed decline (the probe's windows overflowed, the slab
-        lacks a candidate file; in ``auto`` also a device exception): the
-        caller decodes after all. A pair in no candidate file means the slab
-        is not this snapshot's: `_stale_slab`."""
-        import numpy as np
-
-        from delta_tpu.expr.vectorized import arrow_type_for
-        from delta_tpu.ops import join_kernel
-        from delta_tpu.ops import key_cache as kc_mod
-
-        with telemetry.span_stages() as stage:
-            stage(_JOIN_WAIT)
-            try:
-                res_p = probe.result()
-            except kc_mod.DeltaProbeOverflow:
-                return None
-            except Exception as e:  # noqa: BLE001 — host rung (auto only)
-                self._device_rung("device-finalize-fallback", e)
-                return None
-            stage(_JOIN_PAIRS)
-            slabs = [res_p.slabs.get(f.path) for f in candidates]
-            if any(ent is None for ent in slabs):
-                return None
-            declared = {f.name: arrow_type_for(f.data_type)
-                        for f in metadata.schema.fields}
-            fields = [pa.field(c, declared[c]) for c in key_cols]
-            if not insert_only:
-                fields.append(pa.field(POSITION_COL, pa.int64()))
-            fields += [pa.field(_TID, pa.int64()), pa.field(_FID, pa.int64())]
-            fields += [pa.field(n, src.column(n).type) for n in src.column_names]
-            if insert_only or not len(res_p.t_pairs[0]):
-                # insert-only consumes s_matched alone (no pairs were fetched)
-                joined = pa.schema(fields).empty_table()
-            else:
-                phys, s_rows = res_p.t_pairs
-                offs = np.array([off for off, _ in slabs], np.int64)
-                lo = np.searchsorted(phys, offs)
-                hi = np.searchsorted(
-                    phys, offs + np.array([n for _, n in slabs], np.int64))
-                per_file = hi - lo
-                if int(per_file.sum()) != len(phys):
-                    self._stale_slab("a matched slab row lies in no file of "
-                                     "the snapshot")
-                if (np.diff(offs) < 0).any():
-                    # the slab holds the files in another order: the pairs
-                    # (ascending slab row) go into the candidates' order
-                    order = np.concatenate(
-                        [np.arange(a, b) for a, b in zip(lo, hi)])
-                    phys, s_rows = phys[order], s_rows[order]
-                s_taken = src.take(pa.array(s_rows, pa.int64()))
-                cols = []
-                for c in key_cols:
-                    s_e = next(s_e for t_e, s_e in equi
-                               if t_e.name.lower() == c.lower())
-                    cols.append(pc.cast(evaluate(s_e, s_taken), declared[c]))
-                cols.append(pa.array(phys - np.repeat(offs, per_file)))
-                # an id per target row: a slab row is in one pair at most
-                cols.append(pa.array(np.arange(len(phys), dtype=np.int64)))
-                cols.append(pa.array(np.repeat(
-                    np.arange(len(candidates), dtype=np.int64), per_file)))
-                cols += s_taken.columns
-                joined = pa.table(cols, schema=pa.schema(fields))
-            self._device_join = join_kernel.JoinResult(
-                np.empty(0, np.int64), res_p.s_matched, res_p.any_multi)
-            self._join_path = "resident"
-            self._router["route"] = "pairs-only"
-            return joined
-
     @staticmethod
     def _writes_beside(claimed_tbl, out_blocks) -> bool:
         """Whether the statement made both claimed rows to mark and rows to
@@ -1352,11 +1250,11 @@ class MergeIntoCommand:
         if stale:
             self._stale_slab(f"a matched row of {add.path} was deleted already")
 
-    def _launch_resident_probe(self, txn, candidates, src, equi, target_cols,
-                               key_need, pos_col, insert_only):
+    def _launch_resident_probe(self, txn, candidates, src, equi,
+                               plan: _ReadPlan):
         """Probe the HBM-resident target key lane (if one is current for this
         table + key signature): ships only the source keys. Returns
-        (entry, PendingProbe, s_keys, s_ok) or None — and when the lane
+        (entry, PendingProbe) or None — and when the lane
         doesn't exist yet, records the signature so a background build can
         start after this merge commits (the CDC steady-state warmup)."""
         import numpy as np
@@ -1370,25 +1268,24 @@ class MergeIntoCommand:
         # bit mapping back to the DV-filtered decode needs physical
         # positions; without them only DV-free candidates are alignable
         # (insert-only merges never consume per-target bits)
-        if (pos_col is None and not insert_only
+        if (plan.pos_col is None and not plan.insert_only
                 and any(f.deletion_vector is not None for f in candidates)):
             return None
         t_exprs = [t for t, _ in equi]
         s_exprs = [s for _, s in equi]
         sig = self._key_signature(t_exprs)
-        key_cols = [c for c in target_cols if c.lower() in key_need]
         entry = kc_mod.KeyCache.instance().get(
-            txn.snapshot, sig, key_cols, t_exprs, build_if_missing=False
+            txn.snapshot, sig, plan.key_cols, t_exprs, build_if_missing=False
         )
         if entry is None:
-            self._resident_candidate = (sig, key_cols, t_exprs)
+            self._resident_candidate = (sig, plan.key_cols, t_exprs)
             return None
         packed = kc_mod._pack_lanes(src, s_exprs, evaluate)
         if packed is None:
             return None
         s_keys, s_ok = packed
         self._router["cacheHit"] = True
-        if str(conf.get("delta.tpu.merge.devicePath.mode", "auto")) == "auto":
+        if self._device_mode == "auto":
             m = len(s_keys)
             n = entry.num_rows
             p = link.profile()
@@ -1403,23 +1300,20 @@ class MergeIntoCommand:
             self._router["deviceEstS"] = round(device_s, 3)
             self._router["hostEstS"] = round(host_s, 3)
             if device_s > host_s:
-                from delta_tpu.utils.telemetry import bump_counter
-
-                bump_counter("merge.device.declined")
+                telemetry.bump_counter("merge.device.declined")
                 self._router.update(reason="resident-estimate")
                 return None
         probe = entry.probe_async(
             s_keys, s_ok, expected_version=txn.snapshot.version,
-            insert_only=insert_only,
+            insert_only=plan.insert_only,
         )
         if probe is None:
             return None
         self._audit_slab_rows = entry.num_rows
-        return entry, probe, s_keys, s_ok
+        return entry, probe
 
-    def _launch_slab_pipeline(self, txn, candidates, src, equi, target_cols,
-                              key_need, pos_col, insert_only, metadata,
-                              register: bool = True):
+    def _launch_slab_pipeline(self, txn, candidates, src, equi, metadata,
+                              plan: _ReadPlan):
         """The cold fused device MERGE pipeline: decode the key projection
         per file, streaming each decoded file's packed lane onto a
         pre-sized HBM slab from an uploader thread (transfer overlaps the
@@ -1430,9 +1324,7 @@ class MergeIntoCommand:
         Returns ``(resident_tuple_or_None, key_pieces_or_None)`` —
         ``resident_tuple`` feeds `_finalize_resident`; ``key_pieces`` (the
         per-file decoded key tables, position column attached) is returned
-        even on build failure so the caller can reuse the decode.
-        ``register`` is False when the pre-probe narrowed ``candidates``:
-        such a slab serves this MERGE and is not the table's."""
+        even on build failure so the caller can reuse the decode."""
         import queue as queue_mod
         import threading as threading_mod
 
@@ -1440,7 +1332,7 @@ class MergeIntoCommand:
         from delta_tpu.ops import key_cache as kc_mod
 
         # DV alignment guard (mirrors the resident-hit path)
-        if (pos_col is None and not insert_only
+        if (plan.pos_col is None and not plan.insert_only
                 and any(f.deletion_vector is not None for f in candidates)):
             return None, None
         t_exprs = [t for t, _ in equi]
@@ -1451,12 +1343,11 @@ class MergeIntoCommand:
         s_keys, s_ok = packed
         snapshot = txn.snapshot
         sig = self._key_signature(t_exprs)
-        key_cols = [c for c in target_cols if c.lower() in key_need]
         cache = kc_mod.KeyCache.instance()
         try:
             builder = kc_mod.SlabBuilder(
                 snapshot.delta_log.log_path, snapshot.metadata.id,
-                snapshot.version, sig, key_cols, t_exprs,
+                snapshot.version, sig, plan.key_cols, t_exprs,
                 self.delta_log.data_path, candidates,
                 epoch=cache.epoch(snapshot.delta_log.log_path),
             )
@@ -1508,7 +1399,7 @@ class MergeIntoCommand:
             # attached so DV-filtered decodes scatter into slab layout
             key_pieces = read_files_as_table(
                 self.delta_log.data_path, candidates, metadata,
-                columns=key_cols or None, per_file=True,
+                columns=plan.key_cols or None, per_file=True,
                 position_column=POSITION_COL, predicate=None,
                 late_materialize=False, file_ready=on_ready,
             )
@@ -1527,78 +1418,65 @@ class MergeIntoCommand:
             self._router.setdefault("reason", "slab-build-failed")
             return None, key_pieces
         # under device eligibility the candidate set is the whole table (a
-        # residual-free condition prunes nothing) unless the touched-files
-        # pre-probe dropped files: only the complete slab is registered, so
-        # that future merges can cache-hit it
-        registered = register and cache.register(entry)
-        if registered:
+        # residual-free condition prunes nothing): the slab is the table's,
+        # and future merges cache-hit it
+        if cache.register(entry):
             self._resident_candidate = None  # no background build needed
         probe = entry.probe_async(
             s_keys, s_ok, expected_version=snapshot.version,
-            insert_only=insert_only,
+            insert_only=plan.insert_only,
         )
         if probe is None:
             self._router.setdefault("reason", "no-sentinel-room")
             return None, key_pieces
         self._audit_slab_rows = entry.num_rows
-        return (entry, probe, s_keys, s_ok), key_pieces
+        return (entry, probe), key_pieces
 
     def _finalize_resident(self, resident, candidates, tgt_tables, target,
-                           src, equi, pos_col, insert_only,
-                           got_pairs=lambda: None):
+                           plan: _ReadPlan, got_pairs=lambda: None):
         """Map the device-computed pairs (physical slab row → first-match
         source row) onto the DV-filtered decode: the host does only the
         O(matched) position mapping — no key re-derivation, no host-side
-        pairing sort. Returns a PendingJoin whose result is a JoinResult
-        (or None → the caller falls back to the host hash join).
-        ``got_pairs`` is called once the pairs are in host memory."""
+        pairing sort. Returns a JoinResult, or None: the caller falls back
+        to the host hash join. ``got_pairs`` is called once the pairs are in
+        host memory."""
         import numpy as np
 
         from delta_tpu.ops import join_kernel
         from delta_tpu.ops import key_cache as kc_mod
 
-        entry, probe, s_keys, s_ok = resident
-
-        def finalize():
-            # designed declines — the probe's candidate windows overflowed,
-            # or the pair mapping disagrees with the slab — surface as None
-            # (documented host-join fallback); any other exception is a
-            # device failure and takes _device_rung
-            try:
-                res_p = probe.result()
-                got_pairs()
-                n_target = target.num_rows
-                t_first_s = np.full(n_target, -1, np.int64)
-                if insert_only:
-                    # only s_matched / any_multi are consumed downstream
-                    return join_kernel.JoinResult(
-                        t_first_s, res_p.s_matched, res_p.any_multi
-                    )
-                row_base = 0
-                for fid in sorted(tgt_tables):
-                    t = tgt_tables[fid]
-                    add = candidates[fid]
-                    if pos_col is not None:
-                        positions = t.column(pos_col).to_numpy(
-                            zero_copy_only=False)
-                    else:
-                        positions = None
-                    got = res_p.pairs_for_file(add.path, positions,
-                                               t.num_rows)
-                    if got is None:
-                        return None  # slab/decode disagree: host fallback
-                    local_idx, s_rows = got
-                    t_first_s[row_base + local_idx] = s_rows
-                    row_base += t.num_rows
-                return join_kernel.JoinResult(t_first_s, res_p.s_matched,
-                                              res_p.any_multi)
-            except kc_mod.DeltaProbeOverflow:
-                return None
-            except Exception as e:  # noqa: BLE001 — host rung (auto only)
-                self._device_rung("device-finalize-fallback", e)
-                return None
-
-        return join_kernel.PendingJoin(finalize)
+        # designed declines — the probe's candidate windows overflowed, or
+        # the pair mapping disagrees with the slab — surface as None
+        # (documented host-join fallback); any other exception is a device
+        # failure and takes _device_rung
+        try:
+            res_p = resident[1].result()
+            got_pairs()
+            t_first_s = np.full(target.num_rows, -1, np.int64)
+            if plan.insert_only:
+                # only s_matched / any_multi are consumed downstream
+                return join_kernel.JoinResult(
+                    t_first_s, res_p.s_matched, res_p.any_multi)
+            row_base = 0
+            for fid in sorted(tgt_tables):
+                t = tgt_tables[fid]
+                positions = (None if plan.pos_col is None else
+                             t.column(plan.pos_col).to_numpy(
+                                 zero_copy_only=False))
+                got = res_p.pairs_for_file(candidates[fid].path, positions,
+                                           t.num_rows)
+                if got is None:
+                    return None  # slab/decode disagree: host fallback
+                local_idx, s_rows = got
+                t_first_s[row_base + local_idx] = s_rows
+                row_base += t.num_rows
+            return join_kernel.JoinResult(t_first_s, res_p.s_matched,
+                                          res_p.any_multi)
+        except kc_mod.DeltaProbeOverflow:
+            return None
+        except Exception as e:  # noqa: BLE001 — host rung (auto only)
+            self._device_rung("device-finalize-fallback", e)
+            return None
 
     def _device_rung(self, reason: str, e: BaseException) -> None:
         """An unexpected exception on the device path (compile refusal,
@@ -1610,7 +1488,7 @@ class MergeIntoCommand:
         the host join then took over (after a failed slab allocation the
         build continues on host mirrors and the device join may still
         engage)."""
-        if str(conf.get("delta.tpu.merge.devicePath.mode", "auto")) == "force":
+        if self._device_mode == "force":
             raise e
         self._router.setdefault("reason", reason)
         self._router.setdefault("error", telemetry.exc_text(e))
@@ -1748,7 +1626,7 @@ class MergeIntoCommand:
         self._resident_candidate = None
         if not key_cache_enabled():
             return
-        if str(conf.get("delta.tpu.merge.devicePath.mode", "auto")) == "off":
+        if self._device_mode == "off":
             return
         sig, key_cols, t_exprs = cand
         log = self.delta_log
@@ -1830,7 +1708,7 @@ class MergeIntoCommand:
             s_ok = s_ok0 & s_ok1
 
         budget_s = None
-        if str(conf.get("delta.tpu.merge.devicePath.mode", "auto")) == "auto":
+        if self._device_mode == "auto":
             from delta_tpu.parallel import link
 
             budget_s = (len(t_keys) + len(s_keys)) \

@@ -348,7 +348,7 @@ def _dim_distributed() -> HealthDimension:
     retries are routine (transient IO happens), but quarantined items mean
     committed work is INCOMPLETE (an OPTIMIZE skipped a group's rewrite)
     and degradations mean a structural capability (device plan, worker
-    pool, merge probe, lease coverage) silently fell back to a slower or
+    pool, lease coverage) silently fell back to a slower or
     more conservative path. Process-wide by nature, like the device
     dimension — the executor is shared across tables — but surfaced per
     doctor call so the operator sees WHY a job's output differs from its
@@ -371,7 +371,7 @@ def _dim_distributed() -> HealthDimension:
         detail=f"{retried} item retries, {quarantined} quarantined, "
                f"{speculated} speculative re-dispatches ({wins} won), "
                f"{recovered} orphaned slices recovered, "
-               f"{degraded} degradations (plan/pool/probe/lease rungs)",
+               f"{degraded} degradations (plan/pool/lease rungs)",
     )
 
 

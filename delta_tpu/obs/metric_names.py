@@ -257,8 +257,6 @@ ENGINE_COUNTERS = frozenset({
     "dist.items",                 # work items executed across all jobs
     "dist.steals",                # items stolen from another worker's deque
     "dist.plan.sharded",          # plan batches served by the shard_map kernel
-    "dist.merge.filesProbed",     # candidate files probed by the distributed
-                                  # MERGE touched-files pass
     "dist.optimize.groups",       # OPTIMIZE bin-pack groups rewritten by
                                   # sharded workers
     "dist.commit.fanin",          # distributed-job commits funneled through
@@ -275,8 +273,6 @@ ENGINE_COUNTERS = frozenset({
     "dist.lease.swept",           # expired _dist/ lease files swept
     "dist.degraded.pool",         # sharded jobs degraded to inline execution
     "dist.degraded.plan",         # shard_map plans degraded to the host pass
-    "dist.degraded.probe",        # MERGE probes degraded to the all-files
-                                  # superset
     "dist.degraded.lease",        # slices run uncovered after lease-write
                                   # failure
 })
@@ -418,7 +414,7 @@ DESCRIPTIONS = {
     "merge.clause.insertOnly": "MERGE statements with no WHEN MATCHED clause (the de-duplicating insert; the join fetches no pair).",
     "merge.clause.delete": "MERGE statements with a WHEN MATCHED THEN DELETE clause.",
     "merge.dv.overlapped": "MERGE statements that made both deletion vectors and rows to write, and wrote the vectors on worker threads beside the data file (overlapped on the delta.dml.merge.deletionVectors span); a statement that made one of the two runs inline and does not count.",
-    "merge.resident.pairsOnly": "Resident MERGEs that took the pairs-only route: no touched-files pre-probe, no decode of the target.",
+    "merge.resident.pairsOnly": "Resident MERGEs that took the pairs-only route: the probe's pairs are the join, no decode of the target.",
     "merge.resident.pairsOnly.declined": "Pairs-only MERGEs that decoded the target after all (probe overflow, a slab that disagrees with the snapshot).",
     "merge.resident.probe.overflow": "Resident probes declined to the host join because the matched keys' candidate slab rows (dead versions, duplicate target keys) pass the pair kernel's scratch bound.",
     "merge.device.compiles": "XLA compiles that ran with a delta.dml.merge span open on the compiling thread.",
@@ -550,7 +546,6 @@ DESCRIPTIONS = {
     "dist.items": "Work items executed across all sharded jobs.",
     "dist.steals": "Work items stolen from another worker's deque (skew relief).",
     "dist.plan.sharded": "Scan-plan batches served by the shard_map pruning kernel.",
-    "dist.merge.filesProbed": "Candidate files probed by the distributed MERGE touched-files pass.",
     "dist.optimize.groups": "OPTIMIZE bin-pack groups rewritten by sharded workers.",
     "dist.commit.fanin": "Distributed-job commits funneled through the group-commit coordinator.",
     "dist.items.retried": "Transient work-item attempts retried in place by the executor.",
@@ -561,7 +556,6 @@ DESCRIPTIONS = {
     "dist.lease.swept": "Expired distributed-lease files swept from _delta_log/_dist.",
     "dist.degraded.pool": "Sharded jobs that degraded to inline execution after pool failure.",
     "dist.degraded.plan": "shard_map scan plans that degraded to the host fine pass.",
-    "dist.degraded.probe": "Distributed MERGE probes that degraded to the all-files superset.",
     "dist.degraded.lease": "Distributed slices run uncovered after a lease-write failure.",
     "dist.item.duration_ms": "Per-work-item wall clock inside the distributed executor (ms).",
     # doctor distributed-supervision dimension (process-wide)
@@ -570,7 +564,7 @@ DESCRIPTIONS = {
     "table.health.distributed.itemsSpeculated": "Stuck items speculatively re-dispatched in this process.",
     "table.health.distributed.speculationWins": "Speculative re-dispatches that won in this process.",
     "table.health.distributed.slicesRecovered": "Orphaned distributed slices recovered by this process.",
-    "table.health.distributed.degraded": "Degradation-ladder rungs taken (pool+plan+probe+lease) in this process.",
+    "table.health.distributed.degraded": "Degradation-ladder rungs taken (pool+plan+lease) in this process.",
 }
 
 

@@ -358,11 +358,6 @@ class SqlConf:
         # Distributed OPTIMIZE: rewrite bin-pack groups on executor
         # workers (None = delta.tpu.distributed.workers).
         "delta.tpu.distributed.optimize.workers": None,
-        # Distributed MERGE: probe candidate files for touched ones on
-        # executor workers before the join (Spark's findTouchedFiles job);
-        # minFiles gates the fan-out below which inline always wins.
-        "delta.tpu.distributed.merge.probe.enabled": True,
-        "delta.tpu.distributed.merge.probe.minFiles": 8,
         # Funnel distributed-job commits through the group-commit
         # coordinator (txn/group_commit) as the single-writer fan-in.
         "delta.tpu.distributed.singleWriterFanIn": True,

@@ -110,7 +110,6 @@ def dist_body(proc: int, n_procs: int, table: str, out_dir: str,
         _barrier(out_dir, "opt", proc, n_procs)
         if proc == 0:
             from delta_tpu.commands.merge import MergeClause, MergeIntoCommand
-            from delta_tpu.utils.config import conf
 
             DeltaLog.clear_cache()
             mlog = DeltaLog.for_table(table)
@@ -119,18 +118,14 @@ def dist_body(proc: int, n_procs: int, table: str, out_dir: str,
                 "part": pa.array(["p0", "p3", "p0"]),
                 "v": pa.array([-1.0, -2.0, -3.0]),
             })
-            with conf.set_temporarily(
-                **{"delta.tpu.distributed.merge.probe.minFiles": 2}
-            ):
-                m = MergeIntoCommand(
-                    mlog, src, "t.id = s.id",
-                    [MergeClause("update", assignments=None)],
-                    [MergeClause("insert", assignments=None)],
-                    source_alias="s", target_alias="t")
-                m.run()
+            m = MergeIntoCommand(
+                mlog, src, "t.id = s.id",
+                [MergeClause("update", assignments=None)],
+                [MergeClause("insert", assignments=None)],
+                source_alias="s", target_alias="t")
+            m.run()
             result["merge_updated"] = m.metrics["numTargetRowsUpdated"]
             result["merge_inserted"] = m.metrics["numTargetRowsInserted"]
-            result["merge_probed"] = "probe_ms" in m.phase_ms
         _barrier(out_dir, "merge", proc, n_procs)
 
     DeltaLog.clear_cache()

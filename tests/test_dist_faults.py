@@ -694,7 +694,7 @@ def test_doctor_distributed_dimension(tmp_path):
     assert dim.severity == "ok"
 
     telemetry.bump_counter("dist.items.quarantined")
-    telemetry.bump_counter("dist.degraded.probe")
+    telemetry.bump_counter("dist.degraded.pool")
     rep = doctor(DeltaLog.for_table(path))
     dim = {d.name: d for d in rep.dimensions}["distributed"]
     assert dim.severity == "warn"

@@ -3,7 +3,7 @@ sharded scan planning in `ops/state_cache`): byte-weighted LPT vs the strided
 partitioner on a zipf-100k file population, the work-stealing executor's
 ordering/abort/steal semantics, shard_map plan identity on the virtual
 8-device mesh, per-device HBM attribution + the doctor's worst-device flag,
-and parallel OPTIMIZE / probe-restricted MERGE result identity."""
+and parallel OPTIMIZE result identity."""
 import time
 
 import numpy as np
@@ -11,7 +11,6 @@ import pyarrow as pa
 import pytest
 
 from delta_tpu import DeltaLog
-from delta_tpu.commands.merge import MergeClause, MergeIntoCommand
 from delta_tpu.commands.optimize import OptimizeCommand
 from delta_tpu.commands.write import WriteIntoDelta
 from delta_tpu.expr.parser import parse_expression
@@ -288,59 +287,3 @@ def test_optimize_workers_conf_default(tmp_path):
         cmd.run()
     assert cmd.shard_report is not None and cmd.shard_report.workers == 2
     assert telemetry.counters("dist").get("dist.optimize.groups", 0) >= 2
-
-
-# -- MERGE distributed touched-files probe ----------------------------------
-
-
-def _mk_many_files(path, n_files=10, rows=8):
-    log = DeltaLog.for_table(str(path))
-    for i in range(n_files):
-        base = i * rows
-        WriteIntoDelta(log, "append", pa.table({
-            "id": np.arange(base, base + rows, dtype=np.int64),
-            "v": np.arange(base, base + rows, dtype=np.float64),
-        })).run()
-    return log
-
-
-def test_merge_probe_identity_and_restriction(tmp_path):
-    """Probe on vs off: identical MERGE results; the probe restricts the
-    candidate set to files whose keys intersect the source (counted via
-    dist.merge.filesProbed) and can never drop a touched file."""
-    src = {"id": [3, 75], "v": [-1.0, -2.0]}  # touches files 0 and 9 only
-    cond = "t.id = s.id"
-    up = MergeClause("update", assignments=None)
-    ins = MergeClause("insert", assignments=None)
-
-    off_log = _mk_many_files(tmp_path / "off")
-    with conf.set_temporarily(**{"delta.tpu.distributed.merge.probe.enabled": False}):
-        m_off = MergeIntoCommand(off_log, pa.table(src), cond, [up], [ins],
-                                 source_alias="s", target_alias="t")
-        m_off.run()
-
-    on_log = _mk_many_files(tmp_path / "on")
-    before = telemetry.counters("dist").get("dist.merge.filesProbed", 0)
-    m_on = MergeIntoCommand(on_log, pa.table(src), cond, [up], [ins],
-                            source_alias="s", target_alias="t")
-    m_on.run()
-    after = telemetry.counters("dist").get("dist.merge.filesProbed", 0)
-    assert after == before + 10  # every candidate was probed
-    assert "probe_ms" in m_on.phase_ms
-    assert _rows(on_log) == _rows(off_log)
-    assert m_on.metrics["numTargetRowsUpdated"] == 2
-    assert m_on.metrics["numTargetRowsUpdated"] == m_off.metrics["numTargetRowsUpdated"]
-    assert m_on.metrics["numTargetRowsInserted"] == 0
-    # the probe kept only the 2 touched files: the rewrite removed exactly 2
-    assert m_on.metrics["numTargetFilesRemoved"] <= 2
-
-
-def test_merge_probe_skips_below_min_files(tmp_path):
-    log = _mk_many_files(tmp_path / "t", n_files=3)
-    before = telemetry.counters("dist").get("dist.merge.filesProbed", 0)
-    cmd = MergeIntoCommand(
-        log, pa.table({"id": [1], "v": [0.0]}), "t.id = s.id",
-        [MergeClause("update", assignments=None)], [],
-        source_alias="s", target_alias="t")
-    cmd.run()
-    assert telemetry.counters("dist").get("dist.merge.filesProbed", 0) == before

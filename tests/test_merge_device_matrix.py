@@ -302,8 +302,8 @@ def test_post_optimize_merge_parity(tmp_path, fused):
 # -- the resident pairs-only route (ISSUE 26) --------------------------------
 #
 # When the key cache serves the MERGE and nothing of a target row is needed
-# but which row it is, the probe's pairs are the join: no touched-files
-# pre-probe, no decode of the target. Every case below runs the same MERGE
+# but which row it is, the probe's pairs are the join: no decode of the
+# target. Every case below runs the same MERGE
 # on two copies of a table with deletion vectors, both with the slab in the
 # cache: copy a takes the route, copy b the decode route (its observable
 # patched false; never a conf), and everything they leave behind is compared.
@@ -521,6 +521,40 @@ def test_pairs_only_decline_takes_the_decode_route(tmp_path, monkeypatch, why):
     _same_outcome(log_a, log_b, cmd_a, cmd_b)
 
 
+ONE_PROBE_ROUTES = {  # route -> (decision, pairs-only)
+    "pairs-only": ("resident", True),
+    "declined-then-decoded": ("host", False),
+    "resident-decode": ("resident", False),
+    "cold-build": ("device-cold", False),
+}
+
+
+@pytest.mark.parametrize("route", list(ONE_PROBE_ROUTES))
+def test_a_statement_launches_the_resident_probe_at_most_once(tmp_path,
+                                                              route):
+    """The route is decided once: whichever the statement takes, the
+    device probes a slab once (one `delta.merge.deviceProbe` span) — also
+    where the pairs alone declined and the same probe maps onto the decoded
+    rows — and the table is what the host run leaves."""
+    log_a, log_b = _dv_tables(tmp_path)
+    source = _source([0, 150, 151, 400, 799, 900])
+    matched = [MergeClause("update", assignments={"v": "s.v"})
+               if route == "resident-decode" else UP]
+    if route != "cold-build":
+        _prebuild(log_a, "t.k = s.k", ["k", "v", "tag"], source.column_names)
+    if route == "declined-then-decoded":
+        _slab_lacks_a_file(log_a)
+    n0, d0 = _counts()
+    telemetry.clear_events()
+    cmd_a = _run(log_a, source, "t.k = s.k", matched, [INS], "force")
+    assert len(telemetry.recent_events("delta.merge.deviceProbe")) == 1
+    assert (cmd_a._join_path, cmd_a._pairs_only) == ONE_PROBE_ROUTES[route]
+    tried = route in ("pairs-only", "declined-then-decoded")
+    assert _counts() == (n0 + tried, d0 + (route == "declined-then-decoded"))
+    cmd_b = _run(log_b, source, "t.k = s.k", matched, [INS], "off")
+    _same_outcome(log_a, log_b, cmd_a, cmd_b, order=False)
+
+
 def _generated_tables(tmp_path):
     from delta_tpu import DeltaTable
     from delta_tpu.schema.types import DoubleType, LongType, StringType, StructType
@@ -542,8 +576,9 @@ def test_a_merge_that_needs_target_values_keeps_the_decode_route(
         tmp_path, why):
     """The route adapts to what the statement reads of the target: an
     explicit assignment keeps the unassigned columns, the change feed wants
-    pre-images, a generated column may read any column. Each keeps the path
-    of today whole, the touched-files pre-probe included."""
+    pre-images, a generated column may read any column. Each decodes the
+    three files whose key statistics admit a source key, of eight, and maps
+    the resident probe onto their rows."""
     from delta_tpu.utils import telemetry
 
     matched = [UP]
@@ -565,8 +600,9 @@ def test_a_merge_that_needs_target_values_keeps_the_decode_route(
     assert _counts() == (n0, d0)
     assert cmd._join_path == "resident" and not cmd._pairs_only
     names = [e.op_type for e in telemetry.recent_events()]
-    assert names.count("delta.dist.mergeProbe") == 1
     assert "delta.scan.read" in names  # the target was decoded
+    [decode] = telemetry.recent_events("delta.dml.merge.rowDecode")
+    assert decode.data["files"] == 3
     assert cmd.metrics["numTargetRowsUpdated"] == 3
     assert cmd.metrics["numTargetRowsInserted"] == 1
 
@@ -623,25 +659,24 @@ def test_pairs_only_stale_slab_does_not_commit(tmp_path, stale):
 @pytest.mark.parametrize("update", ["assignments", "star"])
 def test_a_slab_built_over_touched_files_only_is_not_the_tables(tmp_path,
                                                                 update):
-    """The touched-files pre-probe narrows a cold build to the files the
-    source touches; registered as the table's, that slab answered a later
-    insert-only MERGE for files it had never seen (duplicate rows). A
-    MERGE that is pairs-only in shape (the star upsert) is not narrowed:
-    it builds the slab over every file, and that one is the table's."""
+    """A slab built over only the files the source touches, registered as
+    the table's, once answered a later insert-only MERGE for files it had
+    never seen (duplicate rows). A cold build reads every file: whether the
+    statement is pairs-only in shape (the star upsert) or not, the slab it
+    registers is the table's, and the later insert agrees with the host. The
+    row decode reads the one file the source touches, where the build's key
+    tables do not serve it (the explicit assignment)."""
     log_a, log_b = _dv_tables(tmp_path)
     up = UP if update == "star" else MergeClause(
         "update", assignments={"v": "s.v", "tag": "s.tag"})
     telemetry.clear_events()
     _run(log_a, _source([10, 11]), "t.k = s.k", [up], [INS], "force")
     _run(log_b, _source([10, 11]), "t.k = s.k", [up], [INS], "off")
-    entries = KeyCache.instance()._entries
-    probes = [e for e in telemetry.recent_events("delta.dist.mergeProbe")]
-    if update == "star":
-        assert len(probes) == 1  # the host run's; the device run built
-        (slab,) = entries.values()
-        assert len(slab.slabs) == 8 and slab.num_rows == 800
-    else:
-        assert len(probes) == 2 and not entries
+    decoded = [e.data["files"] for e in
+               telemetry.recent_events("delta.dml.merge.rowDecode")]
+    assert decoded == [8 if update == "star" else 1, 1]
+    (slab,) = KeyCache.instance()._entries.values()
+    assert len(slab.slabs) == 8 and slab.num_rows == 800
     again = _source([510, 511, 900])
     cmd_a = _run(log_a, again, "t.k = s.k", [], [INS], "force")
     cmd_b = _run(log_b, again, "t.k = s.k", [], [INS], "off")

@@ -546,3 +546,61 @@ def test_merge_metrics_zero_touch_when_nothing_matches(tmp_path):
     cmd = _merge(log, _kv([9], [90]), "t.k = s.k", matched=[up(v="s.v")])
     assert cmd.metrics["numTargetRowsUpdated"] == 0
     assert cmd.metrics["numTargetRowsInserted"] == 0
+
+
+# ---------------------------------------------------------------------------
+# findTouchedFiles from the key statistics: a rewrite decodes only the files
+# whose [min, max] of the key admits a source key, and joins as before
+# ---------------------------------------------------------------------------
+
+TOUCHED_CASES = {  # case -> (target key type, source keys, files decoded of 4)
+    "long": (K64, pa.array([5, 207], K64), 2),
+    "int_target_long_source": (pa.int32(), pa.array([105, 2**40], K64), 1),
+    "text_source": (K64, pa.array(["5", "307"]), 2),
+    "fractional_source": (K64, pa.array([5.5, 105.0]), 4),
+    "date": (pa.date32(), pa.array([5, 120], pa.date32()), 2),
+    "text_key": (pa.string(), pa.array(["k005"]), 4),
+    "null_keys": (K64, pa.array([None, None], K64), 0),
+}
+
+
+@pytest.mark.parametrize("case", list(TOUCHED_CASES))
+def test_a_rewrite_decodes_only_files_whose_statistics_admit_a_key(
+        tmp_path, executor, case):
+    """Four files of 100 keys each, rewritten in place (no deletion
+    vectors). Only integer and date keys narrow (their statistics are
+    exact); a source key that does not cast to the key's type narrows
+    nothing; a null key admits no file. The table is the one the join's own
+    key semantics make, and the files rewritten are those with a match."""
+    from delta_tpu.utils import telemetry
+
+    typ, keys, n_decoded = TOUCHED_CASES[case]
+
+    def key_of(i):
+        if pa.types.is_string(typ):
+            return f"k{i:03d}"
+        return i
+
+    log = DeltaLog.for_table(str(tmp_path / "t"))
+    for lo in range(0, 400, 100):
+        WriteIntoDelta(log, "append", pa.table({
+            "k": pa.array([key_of(i) for i in range(lo, lo + 100)], typ),
+            "v": pa.array(np.arange(lo, lo + 100, dtype=np.float64))})).run()
+    telemetry.clear_events()
+    cmd = _merge(log, pa.table({"k": keys, "v": pa.array([-1.0] * len(keys))}),
+                 "t.k = s.k", matched=[up(v="s.v")])
+    [decode] = telemetry.recent_events("delta.dml.merge.rowDecode")
+    assert decode.data["files"] == n_decoded
+    # what the join matches: a text source compares as text, an integral
+    # float as the integer, a fraction and a null as nothing
+    hit = {"long": [5, 207], "int_target_long_source": [105],
+           "text_source": [5, 307], "fractional_source": [105], "date": [5, 120],
+           "text_key": [5], "null_keys": []}[case]
+    got = _rows(log)
+    assert [r["v"] for r in got] == [-1.0 if i in hit else float(i)
+                                     for i in range(400)]
+    assert [r["k"] for r in got] == [
+        r["k"] for r in pa.table({"k": pa.array(
+            [key_of(i) for i in range(400)], typ)}).to_pylist()]
+    assert cmd.metrics["numTargetRowsUpdated"] == len(hit)
+    assert cmd.metrics["numTargetFilesRemoved"] == len({i // 100 for i in hit})

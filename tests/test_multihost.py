@@ -137,8 +137,8 @@ def _run_workers(tmp_path, table: str, mode: str, out_name: str,
 
 def test_two_process_sharded_optimize_merge_identity(tmp_path):
     """2-process sharded execution over a shared table: each host commits
-    its byte-weighted LPT slice of the OPTIMIZE groups, proc 0 runs the
-    probe-restricted MERGE — and the end state is row-identical to the same
+    its byte-weighted LPT slice of the OPTIMIZE groups, proc 0 runs a
+    MERGE — and the end state is row-identical to the same
     OPTIMIZE+MERGE run single-process on a clone."""
     table = str(tmp_path / "table")
     solo = str(tmp_path / "solo")
@@ -160,8 +160,7 @@ def test_two_process_sharded_optimize_merge_identity(tmp_path):
     assert results[0]["optimize_version"] != results[1]["optimize_version"]
     assert all(r["shard_timings"] for r in results.values())
 
-    # proc 0's MERGE ran the distributed probe and updated/inserted
-    assert results[0]["merge_probed"] is True
+    # proc 0's MERGE updated/inserted
     assert results[0]["merge_updated"] == 2
     assert results[0]["merge_inserted"] == 1
 
@@ -169,7 +168,6 @@ def test_two_process_sharded_optimize_merge_identity(tmp_path):
     from delta_tpu.commands.merge import MergeClause, MergeIntoCommand
     from delta_tpu.commands.optimize import OptimizeCommand
     from delta_tpu.exec.scan import scan_to_table
-    from delta_tpu.utils.config import conf
 
     slog = DeltaLog.for_table(solo)
     OptimizeCommand(slog, min_file_size=1 << 30).run()
@@ -178,14 +176,11 @@ def test_two_process_sharded_optimize_merge_identity(tmp_path):
         "part": pa.array(["p0", "p3", "p0"]),
         "v": pa.array([-1.0, -2.0, -3.0]),
     })
-    with conf.set_temporarily(
-        **{"delta.tpu.distributed.merge.probe.enabled": False}
-    ):
-        MergeIntoCommand(
-            slog, src, "t.id = s.id",
-            [MergeClause("update", assignments=None)],
-            [MergeClause("insert", assignments=None)],
-            source_alias="s", target_alias="t").run()
+    MergeIntoCommand(
+        slog, src, "t.id = s.id",
+        [MergeClause("update", assignments=None)],
+        [MergeClause("insert", assignments=None)],
+        source_alias="s", target_alias="t").run()
     DeltaLog.clear_cache()
     want = scan_to_table(DeltaLog.for_table(solo).update()).sort_by("id")
     got = scan_to_table(DeltaLog.for_table(table).update()).sort_by("id")

@@ -134,7 +134,9 @@ def test_host_forced_merge_produces_populated_audit(tmp_path):
     assert rec["decision"] == "host"
     assert rec["predictedMs"]["host"] > 0
     assert rec["actualMs"] > 0
-    assert rec["units"]["targetRows"] == 100
+    # the rows the join saw: the one file whose key range holds a source
+    # key (the other 15 source keys lie past every file)
+    assert rec["units"]["targetRows"] == 50
     assert rec["units"]["sourceRows"] == 30
     assert "join_ms" in rec["extra"]["phases"]
     # host-only (device structurally off): no hindsight miss possible

@@ -742,7 +742,6 @@ def test_obs_public_api_matches_catalog():
 
 MERGE_PHASES = {  # span -> the phase_ms key it fills
     "delta.dml.merge.analyze": "analyze_ms",
-    "delta.dist.mergeProbe": "probe_ms",
     "delta.dml.merge.keyDecode": "key_decode_ms",
     "delta.dml.merge.rowDecode": "decode_ms",
     "delta.dml.merge.join": "join_ms",
@@ -842,19 +841,14 @@ def test_merge_phases_are_child_spans_that_fill_phase_ms(
     # a star upsert over deletion vectors is pairs-only in shape: resident,
     # the probe's pairs are the join and the row decode reads nothing (its
     # span still fills decode_ms); cold, it builds the table's slab over every
-    # file. Neither runs the touched-files pre-probe; a statement that reads
-    # a target column does
-    narrowed = case == "assigned"
-    expect = {name: key for name, key in MERGE_PHASES.items()
-              if narrowed or name != "delta.dist.mergeProbe"}
-    for name, key in expect.items():
+    # file. A statement that reads a target column decodes: every route
+    # opens each phase once
+    for name, key in MERGE_PHASES.items():
         [ev] = [e for e in events if e.op_type == name]  # exactly once
         assert ev.parent_id == root.span_id, name
         assert cmd.phase_ms[key] == ev.duration_us / 1000.0, name
+    assert set(cmd.phase_ms) == set(MERGE_PHASES.values())
     by_name = {e.op_type: e for e in events}
-    if not narrowed:
-        assert "delta.dist.mergeProbe" not in by_name
-        assert "probe_ms" not in cmd.phase_ms
     if resident:
         assert not any(e.op_type.startswith("delta.scan") for e in events)
         assert by_name["delta.dml.merge.join"].data["route"] == "pairs-only"
@@ -875,7 +869,8 @@ def test_merge_phases_are_child_spans_that_fill_phase_ms(
     assert dv.data["overlapped"] is True and dv.thread_id != root.thread_id
     assert dv.thread_name.startswith("delta-merge-dv")
     assert dv.start_us + dv.duration_us <= commit.start_us + 1
-    assert [e.op_type for e in phases][:len(expect) - 4] == list(expect)[:-4]
+    assert [e.op_type for e in phases][:len(MERGE_PHASES) - 4] == \
+        list(MERGE_PHASES)[:-4]
     phases.remove(dv)
     assert all(e.thread_id == root.thread_id for e in phases)
     for a, b in zip(phases, phases[1:]):
@@ -1034,8 +1029,7 @@ def test_blackout_records_no_span_but_counts_and_times_the_phases(
     after = telemetry.counters("link")
     assert after["link.h2d.bytes"] > before.get("link.h2d.bytes", 0)
     assert after["link.d2h.bytes"] > before.get("link.d2h.bytes", 0)
-    # a cold star upsert builds the table's slab and runs no pre-probe
-    assert set(MERGE_PHASES.values()) - {"probe_ms"} <= set(cmd.phase_ms)
+    assert set(MERGE_PHASES.values()) <= set(cmd.phase_ms)
     assert all(v >= 0 for v in cmd.phase_ms.values())
     assert cmd.phase_ms["join_ms"] > 0
 

@@ -173,10 +173,15 @@ def _statement_spans():
     return roots[-1].data, slab, router
 
 
+def _decoded_files():
+    """The files each row decode since the events were cleared read."""
+    return [e.data["files"] for e in
+            telemetry.recent_events("delta.dml.merge.rowDecode")]
+
+
 def test_warm_pairs_take_the_pairs_only_route_and_say_what_they_did(tmp_path):
-    """Twelve files, so that the touched-files pre-probe would run: the
-    first statement builds the table's slab all the same, and every
-    statement after it is served by the slab alone."""
+    """Twelve files: the first statement builds the table's slab over all
+    of them, and every statement after it is served by the slab alone."""
     gen, base, table = _load(tmp_path, 9, file_rows=250)
     assert len(table.delta_log.update().all_files) == 12
     counters0 = telemetry.counters("merge")
@@ -192,7 +197,6 @@ def test_warm_pairs_take_the_pairs_only_route_and_say_what_they_did(tmp_path):
                          slab.count("delta.keyCache.locate")))
             found += [e.data for e in telemetry.recent_events(
                 "delta.keyCache.locate")]
-            assert not telemetry.recent_events("delta.dist.mergeProbe")
             assert root["clauses"] == ("insert" if name == "rf1" else "delete")
             assert root["sourceRows"] == len(f)
             assert (root["inserted"], root["deleted"]) == report
@@ -223,12 +227,13 @@ def test_warm_pairs_take_the_pairs_only_route_and_say_what_they_did(tmp_path):
 def test_a_small_source_builds_the_tables_slab(tmp_path, first):
     """A source that touches one file of twelve, or none: the slab the
     first statement builds is the table's (every file, registered), not one
-    over the files the pre-probe would have left."""
+    over the files the source touches."""
     gen, base, table = _load(tmp_path, 4, file_rows=250)
     s = gen.refresh_set(base, 0, 5)
     telemetry.clear_events()
     report = _send(table, s.rf1 if first == "rf1" else s.rf2)
-    assert not telemetry.recent_events("delta.dist.mergeProbe")
+    # the row decode takes the key tables the build decoded, every file's
+    assert _decoded_files() == [12]
     entries = KeyCache.instance()._entries
     assert len(entries) == 1
     slab = next(iter(entries.values()))
@@ -238,10 +243,13 @@ def test_a_small_source_builds_the_tables_slab(tmp_path, first):
 
 
 @pytest.mark.parametrize("why", ["no_key_cache", "rewrite", "device_off"])
-def test_the_pre_probe_still_narrows_where_no_slab_is_built(tmp_path, why):
-    """Without a slab to build (the key cache off, the device path off) or
+def test_the_decode_route_agrees_where_no_slab_is_built(tmp_path, why):
+    """Without a slab to keep (the key cache off, the device path off) or
     with rows to rewrite (no deletion vectors: not pairs-only in shape) the
-    touched-files pre-probe runs as before."""
+    decode route joins and agrees with the reference. Where it decodes rows
+    it reads only the file whose key statistics admit one of RF2's five
+    orders, of twelve; with the key cache off the statement's own slab
+    decodes every file's keys, and those serve the decode."""
     gen, base, table = _load(tmp_path, 4, dv=why != "rewrite", file_rows=250,
                              mode="off" if why == "device_off" else "force")
     s = gen.refresh_set(base, 0, 5)
@@ -250,8 +258,7 @@ def test_the_pre_probe_still_narrows_where_no_slab_is_built(tmp_path, why):
     telemetry.clear_events()
     with conf.set_temporarily(**confs):
         report = _send(table, s.rf2)
-    probes = telemetry.recent_events("delta.dist.mergeProbe")
-    assert len(probes) == 1 and probes[0].data["touched"] == 1
+    assert _decoded_files() == [12 if why == "no_key_cache" else 1]
     _agrees(table, base, [s.rf2], [report])
 
 
